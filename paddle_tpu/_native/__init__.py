@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import ctypes
 import fcntl
+import hashlib
 import os
 import pathlib
 import subprocess
@@ -34,30 +35,44 @@ def _headers():
     return sorted(_CSRC.glob("*.h"))
 
 
-def _stale() -> bool:
-    if not _LIB.exists():
-        return True
-    lib_mtime = _LIB.stat().st_mtime
-    return any(p.stat().st_mtime > lib_mtime for p in (*_sources(), *_headers()))
+def _digest(paths) -> str:
+    """Identity of a library's sources. Staleness is decided by CONTENT: a
+    checkout or a copy of one (the chip tool copies the disk, build/ and
+    all) keeps no file times worth comparing."""
+    h = hashlib.sha256()
+    for p in paths:
+        h.update(p.name.encode() + b"\0" + p.read_bytes() + b"\0")
+    return h.hexdigest()
+
+
+def _stale(lib: pathlib.Path, sources) -> bool:
+    stamp = lib.with_suffix(".srchash")
+    return not (lib.exists() and stamp.exists()
+                and stamp.read_text() == _digest(sources))
+
+
+def _compile(lib: pathlib.Path, sources, cmd, verbose: bool) -> pathlib.Path:
+    """Run `cmd` (which writes `lib`) unless `lib` was built from exactly
+    these sources; idempotent, file-locked across processes."""
+    _BUILD.mkdir(exist_ok=True)
+    with open(_BUILD / ".build.lock", "w") as lk:
+        fcntl.flock(lk, fcntl.LOCK_EX)
+        try:
+            if _stale(lib, sources):
+                if verbose:
+                    print("[paddle_tpu._native]", " ".join(cmd))
+                subprocess.run(cmd, check=True, capture_output=not verbose)
+                lib.with_suffix(".srchash").write_text(_digest(sources))
+            return lib
+        finally:
+            fcntl.flock(lk, fcntl.LOCK_UN)
 
 
 def build(verbose: bool = False) -> pathlib.Path:
-    """Compile csrc/*.cc -> libpaddle_tpu_native.so (idempotent, file-locked)."""
-    _BUILD.mkdir(exist_ok=True)
-    lockfile = _BUILD / ".build.lock"
-    with open(lockfile, "w") as lk:
-        fcntl.flock(lk, fcntl.LOCK_EX)  # serialize across processes
-        try:
-            if not _stale():
-                return _LIB
-            cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-                   "-o", str(_LIB)] + [str(s) for s in _sources()]
-            if verbose:
-                print("[paddle_tpu._native]", " ".join(cmd))
-            subprocess.run(cmd, check=True, capture_output=not verbose)
-            return _LIB
-        finally:
-            fcntl.flock(lk, fcntl.LOCK_UN)
+    """Compile csrc/*.cc -> libpaddle_tpu_native.so."""
+    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
+           "-o", str(_LIB)] + [str(s) for s in _sources()]
+    return _compile(_LIB, (*_sources(), *_headers()), cmd, verbose)
 
 
 _CAPI_SRC = _DIR / "csrc_capi"
@@ -69,35 +84,24 @@ def build_capi(verbose: bool = False) -> pathlib.Path:
     reference `inference/capi_exp/`) into libpd_inference_c.so. Links
     libpython (the shim embeds the interpreter around the Predictor), so
     it is built separately from the main native lib on demand."""
-    _BUILD.mkdir(exist_ok=True)
     src = _CAPI_SRC / "pd_inference_capi.cc"
     hdr = _CAPI_SRC / "pd_inference_api.h"
-    if (_CAPI_LIB.exists()
-            and _CAPI_LIB.stat().st_mtime > src.stat().st_mtime
-            and _CAPI_LIB.stat().st_mtime > hdr.stat().st_mtime):
+    if not _stale(_CAPI_LIB, (src, hdr)):
         return _CAPI_LIB
-    lockfile = _BUILD / ".build.lock"
-    with open(lockfile, "w") as lk:
-        fcntl.flock(lk, fcntl.LOCK_EX)
-        try:
-            def cfg(*args):
-                return subprocess.run(
-                    ["python3-config", *args], check=True,
-                    capture_output=True, text=True).stdout.split()
-            includes = cfg("--includes")
-            try:
-                ldflags = cfg("--ldflags", "--embed")
-            except subprocess.CalledProcessError:
-                ldflags = cfg("--ldflags")
-            cmd = (["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
-                    "-pthread", f"-I{_CAPI_SRC}"] + includes
-                   + ["-o", str(_CAPI_LIB), str(src)] + ldflags)
-            if verbose:
-                print("[paddle_tpu._native]", " ".join(cmd))
-            subprocess.run(cmd, check=True, capture_output=not verbose)
-            return _CAPI_LIB
-        finally:
-            fcntl.flock(lk, fcntl.LOCK_UN)
+
+    def cfg(*args):
+        return subprocess.run(
+            ["python3-config", *args], check=True,
+            capture_output=True, text=True).stdout.split()
+    includes = cfg("--includes")
+    try:
+        ldflags = cfg("--ldflags", "--embed")
+    except subprocess.CalledProcessError:
+        ldflags = cfg("--ldflags")
+    cmd = (["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
+            "-pthread", f"-I{_CAPI_SRC}"] + includes
+           + ["-o", str(_CAPI_LIB), str(src)] + ldflags)
+    return _compile(_CAPI_LIB, (src, hdr), cmd, verbose)
 
 
 def load() -> ctypes.CDLL:

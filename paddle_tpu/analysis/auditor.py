@@ -40,6 +40,7 @@ eager jit cache; each (entry, name) site is audited once per process).
 """
 from __future__ import annotations
 
+import math
 import re
 import threading
 import warnings
@@ -58,10 +59,13 @@ AUDIT_ENV = "PADDLE_TPU_AUDIT"
 #: float widths for the upcast lattice (ml_dtypes bf16 has itemsize 2)
 _FLOAT_ORDER = {"bfloat16": 2, "float16": 2, "float32": 4, "float64": 8}
 
-#: primitives that move bytes across chips (the sharding-budget check)
-_COLLECTIVE_PRIMS = ("psum", "psum2", "all_gather", "reduce_scatter",
-                     "all_to_all", "ppermute", "psum_scatter", "pmax",
-                     "pmin")
+#: primitives that move bytes across chips (the sharding-budget check),
+#: by the names jax 0.9.0 traces: under shard_map's varying-axes typing a
+#: `lax.psum` arrives as `psum_invariant` and an all-gather of a varying
+#: value as `all_gather_invariant`
+_COLLECTIVE_PRIMS = ("psum", "psum_invariant", "all_gather",
+                     "all_gather_invariant", "reduce_scatter", "all_to_all",
+                     "ragged_all_to_all", "ppermute", "pmax", "pmin")
 
 #: primitives whose compute dtype defines the "model region" and whose
 #: f32 appearance inside a bf16 region is the classic AMP leak
@@ -404,9 +408,12 @@ def _check_bloat(report: AuditReport, consts, static_args=None):
     min_bytes = _min_const_bytes()
     small_total = 0
     for i, c in enumerate(consts):
-        nbytes = int(getattr(c, "nbytes", 0) or 0)
+        # sized from shape x dtype: the closed jaxpr's consts are typed
+        # literal wrappers (jax 0.9.0), which carry no `nbytes`
         shape = tuple(getattr(c, "shape", ()) or ())
         dtype = _dtype_name(getattr(c, "dtype", "?"))
+        nbytes = (math.prod(shape) * np.dtype(c.dtype).itemsize
+                  if hasattr(c, "dtype") else 0)
         if nbytes >= min_bytes:
             report.add(Finding(
                 check="bloat", severity="high", code="baked-constant",

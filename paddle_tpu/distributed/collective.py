@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from .._jax_compat import shard_map
+from jax import shard_map
 
 from ..cost_model import array_bytes as _array_bytes
 from ..framework.tensor import Tensor

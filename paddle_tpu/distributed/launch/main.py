@@ -66,6 +66,17 @@ class Pod:
             master = f"{master}:{find_free_port()}"
         self.master = master
         nproc = args.nproc_per_node
+        # decided from the environment the workers inherit: asking jax
+        # here would make this supervisor open the chip its worker needs
+        if nproc > 1 and os.environ.get("JAX_PLATFORMS") != "cpu":
+            raise ValueError(
+                f"--nproc_per_node={nproc}: on a TPU host ONE controller "
+                "process drives every local chip. A chip belongs to one "
+                "process at a time, and libtpu reads neither "
+                "JAX_VISIBLE_DEVICES nor CUDA_VISIBLE_DEVICES, so each of "
+                f"{nproc} workers would open all of them. Use "
+                "--nproc_per_node 1 and shard inside the process, or set "
+                "JAX_PLATFORMS=cpu for a CPU-simulated cluster.")
         world = args.nnodes * nproc
         mhost, mport = master.rsplit(":", 1)
         # endpoint list: one per worker process, rank-major over nodes,

@@ -337,6 +337,18 @@ class HybridParallelTrainStep:
         donate_args = (0, 2) if donate else ()
         self._step = jax.jit(step, donate_argnums=donate_args)
 
+    def _kernel_mesh(self):
+        """How this step's activations lie on the mesh, for the Pallas
+        dispatch sites (`tiling.kernel_mesh`): batch over the data axes,
+        heads over `mp`. Not declared under sequence parallelism — the
+        ring/Ulysses attention paths open their own shard_map."""
+        from ...ops.pallas.tiling import kernel_mesh
+        sizes = _axis_sizes(self.mesh)
+        if sizes.get("sp", 1) > 1:
+            return kernel_mesh(None)
+        return kernel_mesh(self.mesh, batch=_data_axes_of(sizes),
+                           heads="mp" if sizes.get("mp", 1) > 1 else None)
+
     # -- data placement ------------------------------------------------------
     def shard_batch(self, *batch):
         out = []
@@ -352,7 +364,7 @@ class HybridParallelTrainStep:
         rng = random_mod.default_generator().split()
         lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
         arrs = self.shard_batch(*batch)
-        with self.mesh:
+        with self.mesh, self._kernel_mesh():
             out = self._step(
                 self.params, self.buffers, self.opt_state,
                 self.scaler_state, rng, lr, self._t, *arrs)

@@ -15,10 +15,6 @@ from typing import Optional, Tuple
 
 def _worker(func, i, args, env, queue):
     os.environ.update(env)
-    # honor JAX_PLATFORMS in the child even against accelerator plugins
-    # that ignore the env var (see paddle_tpu._platform)
-    from .._platform import pin_platform
-    pin_platform()
     try:
         func(*args)
         queue.put((i, None))

@@ -102,11 +102,8 @@ def _on_flag_set(name: str, value):
         except Exception:
             pass
     elif name == "FLAGS_debug_nans":
-        try:
-            import jax
-            jax.config.update("jax_debug_nans", bool(value))
-        except Exception:
-            pass
+        import jax
+        jax.config.update("jax_debug_nans", bool(value))
     elif name == "FLAGS_compile_cache_dir":
         _apply_compile_cache_dir(value)
 
@@ -121,34 +118,52 @@ def _apply_compile_cache_dir(path):
     jax.monitoring channel the cache feeds). The size/time floors are
     dropped so every executable is cached — the cache exists for
     multi-minute pod-scale compiles, but CI exercises the same path with
-    tiny ones."""
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", path or None)
-        if path:
-            # each floor knob guarded on its own: a jax version missing one
-            # must not skip the reset_cache() below (without which a
-            # runtime enable is silently ignored — see comment there)
-            try:
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0)
-            except Exception:
-                pass
-            try:
-                jax.config.update(
-                    "jax_persistent_cache_min_entry_size_bytes", -1)
-            except Exception:
-                pass  # knob not present on older jax
-        try:
-            # jax latches its cache handle on the FIRST compile of the
-            # process; without a reset, enabling the dir after any compile
-            # (set_flags at runtime, not env) is silently ignored
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:
-            pass
-    except Exception:
-        pass  # jax absent / too old: the flag stays readable, inert
+    tiny ones.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache was placed from
+    outside: jax read the variable itself, and nothing here moves it."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_compilation_cache_dir", path or None)
+    if path:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # jax latches its cache handle on the FIRST compile of the process;
+    # without a reset, enabling the dir after any compile (set_flags at
+    # runtime, not env) is silently ignored
+    compilation_cache.reset_cache()
+
+
+def place_caches(checkout: str) -> str:
+    """Place the compile cache and, beside it, the kernel autotuner's
+    entries, for an entry point that compiles for the chip (chip_smoke.py,
+    bench.py); call before the first compile. Returns the cache root:
+    ``$JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``
+    — one fixed path, because the path is part of jax's cache key and a
+    directory that moves never hits. Autotuned block shapes are part of
+    the compiled program, so a cache that hits needs the same winners next
+    time: unless ``PADDLE_TPU_AUTOTUNE_CACHE_DIR`` says otherwise they
+    live in ``<root>/autotune``.
+
+    It also keeps the Python call stack out of op locations. jax strips
+    locations before hashing a program, but not from inside a Mosaic
+    kernel's serialized body, and a kernel is traced once per process —
+    under the autotuner in a process that tunes, under the compile check
+    in one that loads the winners. With full stacks in there the two
+    processes' train step and serving programs never shared a cache key
+    (chip run, PR 21); file:line locations are the same in both."""
+    import jax
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    root = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not root:
+        root = os.path.join(os.path.abspath(checkout), ".jax_cache")
+        set_flags({"FLAGS_compile_cache_dir": root})
+    if not os.environ.get("PADDLE_TPU_AUTOTUNE_CACHE_DIR"):
+        set_flags({"FLAGS_autotune_cache_dir":
+                   os.path.join(root, "autotune")})
+    return root
 
 
 # ---------------------------------------------------------------------------

@@ -93,7 +93,7 @@ class Tensor:
             dev = self.data.devices().pop()
         except Exception:
             return place_mod.CPUPlace()
-        if place_mod._platform_of(dev) == "cpu":
+        if dev.platform == "cpu":
             return place_mod.CPUPlace()
         return place_mod.TPUPlace(dev.id)
 

@@ -134,9 +134,12 @@ def _worker_loop(dataset, collate_fn, index_queue, result_queue,
                  iterable_mode: bool, batch_size: int, drop_last: bool,
                  num_workers: int, suppress_faults: bool = False):
     """Worker process entry (reference dataloader/worker.py _worker_loop)."""
-    from .._platform import pin_platform
-    pin_platform("cpu")  # never grab the TPU from a worker (config.update
-    # sticks where the env var is ignored by accelerator plugins)
+    # a worker never takes the chip its trainer parent holds: this runs
+    # first in a freshly spawned process (no backend yet), and the env var
+    # carries the choice to anything the worker itself starts
+    import jax
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
     if suppress_faults:  # a RESPAWNED worker must not re-die on the same
         from ..fault import default_injector  # armed kill clause forever
         default_injector().reset()

@@ -339,6 +339,7 @@ class GPT(nn.Layer):
         import jax
         import jax.numpy as jnp
         from ..ops.pallas import paged_attention as _pa
+        from ..ops.pallas import tiling as _tiling
         B, L = input_ids.shape
         if B != 1:
             raise ValueError(f"forward_prefill fills ONE slot's pages; got "
@@ -351,30 +352,33 @@ class GPT(nn.Layer):
         write_start = jnp.asarray(write_start, jnp.int32)
         page_row = jnp.take(cache.block_tables, slot, axis=0)
         mesh = self.tp_mesh() if use_tp else None
-        for li, blk in enumerate(self.blocks):
-            with jax.named_scope("ln"):
-                h = blk.ln1(x)
-            with jax.named_scope("attention"):
-                q, k, v = self._block_qkv(blk, h)
-                if mesh is not None:
-                    cache.k_pages[li], cache.v_pages[li] = \
-                        _pa.prefill_append_tp(
-                            cache.k_pages[li], cache.v_pages[li], k[0],
-                            v[0], page_row, length, mesh,
-                            axis=self._tp_axis, start=write_start)
-                else:
-                    cache.k_pages[li], cache.v_pages[li] = \
-                        _pa.prefill_append(
-                            cache.k_pages[li], cache.v_pages[li], k[0],
-                            v[0], page_row, length, start=write_start)
-                out = F.scaled_dot_product_attention(
-                    Tensor(q), Tensor(k), Tensor(v), is_causal=True,
-                    training=False)
-                out = reshape(out, [B, L, self.cfg.hidden_size])
-                x = x + blk.attn.proj(out)
-            with jax.named_scope("ln"):
-                h = blk.ln2(x)
-            x = x + blk.mlp(h)
+        # under a TP mesh the prefill program is multi-device: the Pallas
+        # dispatch sites run per shard, heads over the TP axis
+        with _tiling.kernel_mesh(mesh, heads=getattr(self, "_tp_axis", None)):
+            for li, blk in enumerate(self.blocks):
+                with jax.named_scope("ln"):
+                    h = blk.ln1(x)
+                with jax.named_scope("attention"):
+                    q, k, v = self._block_qkv(blk, h)
+                    if mesh is not None:
+                        cache.k_pages[li], cache.v_pages[li] = \
+                            _pa.prefill_append_tp(
+                                cache.k_pages[li], cache.v_pages[li], k[0],
+                                v[0], page_row, length, mesh,
+                                axis=self._tp_axis, start=write_start)
+                    else:
+                        cache.k_pages[li], cache.v_pages[li] = \
+                            _pa.prefill_append(
+                                cache.k_pages[li], cache.v_pages[li], k[0],
+                                v[0], page_row, length, start=write_start)
+                    out = F.scaled_dot_product_attention(
+                        Tensor(q), Tensor(k), Tensor(v), is_causal=True,
+                        training=False)
+                    out = reshape(out, [B, L, self.cfg.hidden_size])
+                    x = x + blk.attn.proj(out)
+                with jax.named_scope("ln"):
+                    h = blk.ln2(x)
+                x = x + blk.mlp(h)
         cache.context_lens = cache.context_lens.at[slot].set(length)
         with jax.named_scope("logits"):
             # logits of the LAST REAL position only (bucket padding past

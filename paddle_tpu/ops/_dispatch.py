@@ -371,7 +371,7 @@ def call(impl: Callable, tensors: Sequence[Any], kwargs: Optional[dict] = None,
         dev_ns, dev_src = _device_time.attribute(
             [o.data for o in outs if isinstance(o, Tensor)],
             flops, nbytes, t0)
-        if _metrics_mod.enabled():
+        if dev_ns is not None and _metrics_mod.enabled():
             _M_OP_DEVICE_TIME.observe(dev_ns / 1e9, op=name, src=dev_src)
         stack = _op_recorder.span_stack()
         _op_recorder.push(HostSpan(

@@ -79,6 +79,9 @@ _KEY_LOCKS: Dict[Tuple, threading.Lock] = {}
 # resolution log for bench/summary: one entry per *resolution* that went
 # past the memory cache (tuned / disk-hit), newest last
 _TUNED: List[dict] = []
+# candidates the compiler refused during a tune, newest last: a refused
+# non-default candidate is skipped, never hidden (summary()["refused"])
+_REFUSED: List[dict] = []
 
 
 # ------------------------------- knobs ---------------------------------------
@@ -125,14 +128,8 @@ def chip_label(interpret: bool = False) -> str:
     """Cache-key chip identity: the device kind (v5e vs v4 tune
     differently), with interpret-mode runs namespaced away from any real
     hardware's entries."""
-    kind = "unknown"
-    try:
-        import jax
-        d = jax.devices()[0]
-        kind = getattr(d, "device_kind", None) or d.platform
-    except Exception:
-        pass
-    kind = str(kind).strip().replace(" ", "_")
+    import jax
+    kind = jax.devices()[0].device_kind.strip().replace(" ", "_")
     return kind + ("+interpret" if interpret else "")
 
 
@@ -231,7 +228,7 @@ def get_config(op: str,
                interpret: bool = False) -> BlockConfig:
     """Resolve the block config for (op, key) — memory cache, then disk,
     then a measured tune; static `default` when tuning is off for this
-    platform/mode or every probe fails.
+    platform/mode. A default the compiler refuses raises (see `_tune`).
 
     `key` must already be shape-BUCKETED (tiling.shape_bucket) + dtype by
     the caller; chip identity is appended here. `bench(cfg)` runs one full
@@ -294,8 +291,14 @@ def get_config(op: str,
 
         if _metrics.enabled():
             _M_EVENTS.inc(event="miss", op=op)
-        cfg, probe_ms = _tune(op, candidates, default, bench, interpret)
-        if path is not None and probe_ms is not None:
+        import jax
+        # resolution runs at TRACE time of the user's jit, where jax stages
+        # every call — probes included — into the outer program instead of
+        # running it; only back on the eval trace does a probe compile,
+        # execute and get timed
+        with jax.core.eval_context():
+            cfg, probe_ms = _tune(op, candidates, default, bench, interpret)
+        if path is not None:
             _disk_store(path, {
                 "version": _ENTRY_VERSION, "op": op, "key": list(key),
                 "chip": chip, "config": cfg.to_json(),
@@ -311,11 +314,14 @@ def get_config(op: str,
 
 def _tune(op: str, candidates: Sequence[BlockConfig], default: BlockConfig,
           bench: Callable[[BlockConfig], None],
-          interpret: bool) -> Tuple[BlockConfig, Optional[float]]:
+          interpret: bool) -> Tuple[BlockConfig, float]:
     """Benchmark candidates (default first — candidate_configs guarantees
     its position, but re-assert here), bounded by count and wall budget.
-    Returns (winner, winner_probe_ms); a fully-failed sweep returns the
-    untimed default."""
+    Returns (winner, winner_probe_ms). A candidate the compiler refuses is
+    skipped and recorded (`summary()["refused"]`); the DEFAULT being
+    refused raises — it is what the kill switch and every untuned process
+    run, so a kernel whose default does not compile is a bug to fix, not
+    a reason to pick something else."""
     max_cfgs = _int_knob("PADDLE_TPU_AUTOTUNE_MAX_CONFIGS", 8)
     repeats = _int_knob("PADDLE_TPU_AUTOTUNE_REPEATS", 3)
     budget_s = _float_knob("PADDLE_TPU_AUTOTUNE_BUDGET_S", 20.0)
@@ -328,27 +334,74 @@ def _tune(op: str, candidates: Sequence[BlockConfig], default: BlockConfig,
     ordered = ordered[:max(max_cfgs, 1)]
     deadline = time.monotonic() + budget_s
     t_sweep = time.perf_counter()
-    best_cfg, best_s = default, None
-    for i, cfg in enumerate(ordered):
-        if i > 0 and time.monotonic() > deadline:
+    with kernel_context(op, config=default.label):
+        best_cfg, best_s = default, _time_candidate(bench, default, repeats)
+    for cfg in ordered[1:]:
+        if time.monotonic() > deadline:
             break  # budget spent; default was timed first
         try:
             secs = _time_candidate(bench, cfg, repeats)
-        except Exception:
-            # candidate fails to compile/run (Mosaic rejection, VMEM
-            # overflow the estimate missed): skip it, never crash a tune
+        except Exception as e:  # noqa: BLE001 — the compiler's refusals
+            # come as ValueError, NotImplementedError, MLIRError and
+            # JaxRuntimeError alike; the candidate is skipped and reported
             if _metrics.enabled():
                 _M_EVENTS.inc(event="probe_error", op=op)
+            with _lock:
+                _REFUSED.append({"op": op, "config": cfg.label,
+                                 "error": f"{type(e).__name__}: "
+                                          f"{str(e)[:300]}"})
             continue
-        if best_s is None or secs < best_s:
+        if secs < best_s:
             best_cfg, best_s = cfg, secs
     sweep_s = time.perf_counter() - t_sweep
     if _metrics.enabled():
         _M_PROBE_SECONDS.observe(sweep_s, op=op)
         _M_TUNES.inc(op=op)
-        if best_s is not None:
-            _M_CHOSEN.set(1000.0 * best_s, op=op, config=best_cfg.label)
-    return best_cfg, (1000.0 * best_s if best_s is not None else None)
+        _M_CHOSEN.set(1000.0 * best_s, op=op, config=best_cfg.label)
+    return best_cfg, 1000.0 * best_s
+
+
+# --------------------------- compile checks ----------------------------------
+
+
+class kernel_context:
+    """Names the kernel in whatever the compiler raises inside the block:
+    a Mosaic refusal says what it dislikes, not which op, shape and block
+    config asked for it. The exception propagates — there is no fallback
+    to hide it behind."""
+
+    def __init__(self, op: str, **what):
+        self._note = f"while compiling Pallas kernel {op!r}: " + ", ".join(
+            f"{k}={v}" for k, v in what.items())
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if exc is not None:
+            exc.add_note(self._note)
+        return False
+
+
+# (op, what...) of every kernel configuration already compiled and run once
+_CHECKED = set()
+
+
+def compile_check(op: str, run: Callable[[], object], **what):
+    """Compile and run `run()` once per (op, what), eagerly, BEFORE the
+    kernel is staged into a user's jit: there a refusal would surface at
+    the outer program's compile with nothing to say which kernel, shape
+    and block config it was. `run` builds small concrete inputs at the
+    production block shape and returns the kernel's outputs; `what`
+    (hashable values) both identifies the configuration and names it in
+    the note on whatever the compiler raises."""
+    import jax
+    key = (op,) + tuple(what.items())
+    if key in _CHECKED:
+        return
+    with kernel_context(op, **what), jax.core.eval_context():
+        jax.block_until_ready(run())
+    _CHECKED.add(key)
 
 
 # ----------------------------- introspection ---------------------------------
@@ -368,6 +421,12 @@ def tuned_log() -> List[dict]:
         return list(_TUNED)
 
 
+def refused_log() -> List[dict]:
+    """Candidates the compiler refused during this process's tunes."""
+    with _lock:
+        return list(_REFUSED)
+
+
 def summary() -> dict:
     """Bench-JSON-ready view of this process's autotune activity."""
     return {
@@ -376,6 +435,7 @@ def summary() -> dict:
         "cache_dir": cache_dir() or None,
         "events": events_snapshot(),
         "tuned": tuned_log(),
+        "refused": refused_log(),
     }
 
 
@@ -396,5 +456,7 @@ def reset_for_tests():
         _MEM_CACHE.clear()
         _KEY_LOCKS.clear()
         del _TUNED[:]
+        del _REFUSED[:]
+        _CHECKED.clear()
         for d in _RESET_HOOKS:
             d.clear()

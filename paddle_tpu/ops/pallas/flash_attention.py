@@ -19,11 +19,10 @@ saves softmax-out for bwd, and handles arbitrary attention masks). Here:
 * boolean or additive masks broadcastable to [B,H,Lq,Lk] are streamed
   block-by-block like K/V (the reference's fmha path also applies the
   mask inside the fused kernel);
-* dispatch is gated by an eager capability probe compiled at the exact
-  production shapes (a Mosaic failure inside the user's outer jit cannot
-  be caught — see `layer_norm._pallas_ln_ok`), so there is NO silent
-  runtime fallback: once probed OK, the Pallas path is the path taken,
-  including under `value_and_grad`.
+* dispatch is by shape/dtype eligibility alone: an eligible call takes
+  the Pallas path, after one eager compile check at the exact production
+  shapes (`autotune.compile_check`) whose failure RAISES, naming the
+  kernel — a kernel Mosaic refuses is a bug, not a route to XLA.
 
 `_stats` counts dispatch decisions at trace time so tests can assert the
 kernel path is actually exercised (round-1 review found the old fwd-only
@@ -37,10 +36,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from ..._jax_compat import (TPUCompilerParams as _TPUCompilerParams,
-                            DIM_PARALLEL as _DIM_P, DIM_ARBITRARY as _DIM_A)
 import numpy as np
+from jax.experimental.pallas import tpu as pltpu
 
 from . import autotune as _autotune
 from . import tiling as _tiling
@@ -596,16 +593,22 @@ def _fa_small_bwd_pallas(q, k, v, out, lse, do, mask, causal, scale,
             jnp.swapaxes(dv, 1, 2))
 
 
-def _use_small_path(Lq: int, Lk: int, H: int, D: int, mask=None) -> bool:
+def _use_small_path(Lq: int, Lk: int, H: int, D: int, dtype,
+                    mask=None) -> bool:
     if Lq != Lk or Lq > _SMALL_MAX_L:
         return False
-    # [H,L,L] f32 scores + q/k/v/o blocks must sit comfortably in VMEM;
-    # a mask block is resident too ([H,Lq,Lk] per program) — count its
-    # bytes so the budget stays honest if _SMALL_MAX_L is ever raised
-    vmem = H * Lq * Lk * 4 + 4 * H * Lq * D * 4
+    # Sized by the single-shot BACKWARD, the larger of the pair (dispatch
+    # cannot know whether a gradient will be asked for): three
+    # [H, Lq, Lk] f32 score-shaped arrays live at once (p, dp, ds) beside
+    # the double-buffered q/k/v/do/out in and dq/dk/dv out blocks. A mask
+    # block is resident too ([H, Lq, Lk] per program). Over the budget
+    # the grid-walk kernels take it — on the chip f32 H=12 L=512 asked
+    # the compiler for more scoped VMEM than a v5e core grants.
+    vmem = (3 * H * Lq * Lk * 4
+            + 2 * 8 * H * Lq * D * jnp.dtype(dtype).itemsize)
     if mask is not None:
-        vmem += H * Lq * Lk * mask.dtype.itemsize
-    return vmem <= 24 * 1024 * 1024
+        vmem += 2 * H * Lq * Lk * mask.dtype.itemsize
+    return vmem <= _tiling.VMEM_BUDGET
 
 
 def _static_blocks(Lq: int, Lk: int):
@@ -624,7 +627,7 @@ def _blocks_or_static(blocks, Lq: int, Lk: int):
 
 # ---- autotuned block selection (tiling/autotune layer) ----------------------
 #
-# Resolution happens at DISPATCH time (like the capability probe, and for
+# Resolution happens at DISPATCH time (like the compile check, and for
 # the same reason: it runs compiled kernels eagerly, which is legal at
 # trace time of a user's outer jit but not inside a pallas body). The
 # resolved (fwd, bwd) configs ride the custom_vjp as a nondiff static arg,
@@ -668,7 +671,7 @@ def _resolve_flash_blocks(q, k, mask, causal):
     B, Lq, H, D = q.shape
     Lk = k.shape[1]
     dtype = q.dtype
-    if _use_small_path(Lq, Lk, H, D, mask):
+    if _use_small_path(Lq, Lk, H, D, dtype, mask):
         return None
     # fused-vs-split bwd selection depends on EXACT Lq, not its bucket —
     # two lengths sharing a bucket can straddle the threshold, so the
@@ -789,14 +792,11 @@ def _compiler_params(interpret, n_arbitrary=1):
     across dim 3 (q-blocks) — marking dim 2 PARALLEL would let megacore
     TPUs (v4/v5p) split it across TensorCores with per-core scratch,
     losing dq partials."""
-    from jax.experimental.pallas import tpu as pltpu
-
     if interpret:
         return None
-    P = _DIM_P
-    A = _DIM_A
-    sem = (P,) * (4 - n_arbitrary) + (A,) * n_arbitrary
-    return _TPUCompilerParams(dimension_semantics=sem)
+    sem = ((pltpu.PARALLEL,) * (4 - n_arbitrary)
+           + (pltpu.ARBITRARY,) * n_arbitrary)
+    return pltpu.CompilerParams(dimension_semantics=sem)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -806,7 +806,6 @@ def _fa_fwd_pallas(q, k, v, mask, causal, scale, mask_is_bool=False,
     """Returns (out [B,L,H,D], lse [B,H,Lq] f32). mask may be None.
     `blocks` is the resolved (block_q, block_k); None = static picks."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     B, Lq, H, D = q.shape
     Lk = k.shape[1]
@@ -960,7 +959,6 @@ _FUSED_BWD_DQ_BYTES = 6 * 1024 * 1024
 def _fa_bwd_fused_pallas(q, k, v, out, lse, do, mask, causal, scale,
                          mask_is_bool=False, interpret=False, blocks=None):
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     B, Lq, H, D = q.shape
     Lk = k.shape[1]
@@ -1013,7 +1011,6 @@ def _fa_bwd_fused_pallas(q, k, v, out, lse, do, mask, causal, scale,
 def _fa_bwd_pallas(q, k, v, out, lse, do, mask, causal, scale,
                    mask_is_bool=False, interpret=False, blocks=None):
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     B, Lq, H, D = q.shape
     Lk = k.shape[1]
@@ -1090,7 +1087,7 @@ def _fa_bwd_pallas(q, k, v, out, lse, do, mask, causal, scale,
 def _fwd_any(q, k, v, mask, causal, scale, mask_is_bool, interpret,
              blocks=None):
     B, Lq, H, D = q.shape
-    if _use_small_path(Lq, k.shape[1], H, D, mask):
+    if _use_small_path(Lq, k.shape[1], H, D, q.dtype, mask):
         return _fa_small_fwd_pallas(q, k, v, mask, causal, scale,
                                     mask_is_bool=mask_is_bool,
                                     interpret=interpret)
@@ -1102,7 +1099,7 @@ def _fwd_any(q, k, v, mask, causal, scale, mask_is_bool, interpret,
 def _bwd_any(q, k, v, out, lse, do, mask, causal, scale, mask_is_bool,
              interpret, blocks=None):
     B, Lq, H, D = q.shape
-    if _use_small_path(Lq, k.shape[1], H, D, mask):
+    if _use_small_path(Lq, k.shape[1], H, D, q.dtype, mask):
         return _fa_small_bwd_pallas(q, k, v, out, lse, do, mask, causal,
                                     scale, mask_is_bool=mask_is_bool,
                                     interpret=interpret)
@@ -1157,9 +1154,6 @@ _flash_fused.defvjp(_flash_fused_fwd, _flash_fused_bwd)
 
 # --------------------------- dispatch ---------------------------------------
 
-_pallas_fa_status = {}
-
-
 def _mask_key(mask):
     if mask is None:
         return None
@@ -1167,56 +1161,41 @@ def _mask_key(mask):
         int(d != 1) for d in mask.shape)
 
 
-def _pallas_fa_ok(dtype, Lq, Lk, H, D, causal, mask=None,
-                  blocks=None) -> bool:
-    """Eager fwd+bwd compile probe at the exact production (L, H, D) shapes
-    AND the exact resolved block config.
+def _check_compiles(dtype, Lq, Lk, H, D, causal, mask=None, blocks=None):
+    """Eager fwd+bwd compile check (`autotune.compile_check`) at the exact
+    production (L, H, D) shapes AND the exact resolved block config —
+    including the BACKWARD kernels, so the custom_vjp path is known-good
+    under value_and_grad before it is staged into the user's jit. H is
+    part of the key: kernel SELECTION (`_use_small_path`) and the small
+    path's per-program VMEM footprint both depend on it."""
+    def run():
+        sc = float(1.0 / np.sqrt(D))
+        q = jnp.ones((2, Lq, H, D), dtype)
+        k = jnp.ones((2, Lk, H, D), dtype)
+        pm = None
+        is_bool = False
+        if mask is not None:
+            shp = tuple(1 if d == 1 else {0: 2, 1: H, 2: Lq, 3: Lk}[ax]
+                        for ax, d in enumerate(mask.shape))
+            is_bool = mask.dtype == jnp.bool_
+            pm = (jnp.ones(shp, jnp.bool_) if is_bool
+                  else jnp.zeros(shp, mask.dtype))
 
-    Mosaic failures inside a traced user program fire at outer-jit compile
-    time where try/except can't catch; capability is therefore established
-    eagerly — including for the BACKWARD kernels, so the custom_vjp path is
-    known-good under value_and_grad before we ever commit to it. H is part
-    of the probe: kernel SELECTION (`_use_small_path`) and the small path's
-    per-program VMEM footprint both depend on it, so probing a fixed tiny H
-    could validate a kernel production never runs. `blocks` is keyed too —
-    an autotuned config must be probed at that config.
-    """
-    key = (jnp.dtype(dtype).name, Lq, Lk, H, D, bool(causal),
-           _mask_key(mask), blocks, _INTERPRET)
-    if key not in _pallas_fa_status:
-        if not (_on_tpu() or _INTERPRET):
-            _pallas_fa_status[key] = False
-        else:
-            try:
-                sc = float(1.0 / np.sqrt(D))
-                q = jnp.ones((2, Lq, H, D), dtype)
-                k = jnp.ones((2, Lk, H, D), dtype)
-                pm = None
-                is_bool = False
-                if mask is not None:
-                    shp = tuple(1 if d == 1 else {0: 2, 1: H, 2: Lq,
-                                                  3: Lk}[ax]
-                                for ax, d in enumerate(mask.shape))
-                    is_bool = mask.dtype == jnp.bool_
-                    pm = (jnp.ones(shp, jnp.bool_) if is_bool
-                          else jnp.zeros(shp, mask.dtype))
+        def f(q, k, v):
+            return _flash_fused(q, k, v, pm, bool(causal), sc, is_bool,
+                                _INTERPRET, blocks).astype(jnp.float32).sum()
 
-                def f(q, k, v):
-                    return _flash_fused(
-                        q, k, v, pm, bool(causal), sc, is_bool,
-                        _INTERPRET, blocks).astype(jnp.float32).sum()
+        return jax.grad(f, argnums=(0, 1, 2))(q, k, k)
 
-                grads = jax.grad(f, argnums=(0, 1, 2))(q, k, k)
-                jax.block_until_ready(grads)
-                _pallas_fa_status[key] = True
-            except Exception:
-                _pallas_fa_status[key] = False
-    return _pallas_fa_status[key]
+    _autotune.compile_check(
+        "flash_attention", run, dtype=jnp.dtype(dtype).name,
+        q=(2, Lq, H, D), k=(2, Lk, H, D), causal=bool(causal),
+        mask=_mask_key(mask), blocks_fwd_bwd=blocks or "whole-sequence",
+        interpret=_INTERPRET)
 
 
 def _pallas_eligible(q, k, v, mask, causal) -> bool:
-    """Shape/dtype eligibility for the fused path (no probe — the caller
-    resolves blocks first, then probes via `_pallas_fa_ok`)."""
+    """Shape/dtype eligibility for the fused path."""
     if not (_on_tpu() or _INTERPRET):
         return False
     B, Lq, H, D = q.shape
@@ -1252,6 +1231,29 @@ def _pallas_eligible(q, k, v, mask, causal) -> bool:
     return True
 
 
+def _flash_per_shard(km, q, k, v, mask, causal, scale):
+    """The dispatch below, per shard of a declared multi-device program
+    (`tiling.kernel_mesh`): batch over the data axes, heads over the
+    tensor-parallel axis — attention never mixes either, so no collective
+    is needed. A dim the axis does not divide stays whole."""
+    from jax.sharding import PartitionSpec as P
+    B, H = q.shape[0], q.shape[2]
+    b_ax = km.batch if B % km.size(km.batch) == 0 else None
+    h_ax = km.heads if H % km.size(km.heads) == 0 else None
+    spec = P(b_ax, None, h_ax, None)
+    args, specs = (q, k, v), (spec, spec, spec)
+    if mask is not None:
+        args += (mask,)
+        specs += (P(b_ax if mask.shape[0] != 1 else None,
+                    h_ax if mask.shape[1] != 1 else None, None, None),)
+
+    def local(q, k, v, *m):
+        return flash_attention(q, k, v, mask=m[0] if m else None,
+                               causal=causal, scale=scale)
+
+    return _tiling.per_shard(km, local, specs, spec)(*args)
+
+
 def flash_attention(q, k, v, mask=None, causal=False, scale=None,
                     dropout_p=0.0, dropout_key=None):
     """Dispatch: fused Pallas fwd+bwd on TPU (masks + causal + any seq len
@@ -1270,16 +1272,18 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
         return flash_attention_xla(q, k, v, mask=mask, causal=causal,
                                    scale=scale, dropout_p=dropout_p,
                                    dropout_key=dropout_key)
+    km = _tiling.current_kernel_mesh()
+    if km is not None and (_on_tpu() or _INTERPRET):
+        return _flash_per_shard(km, q, k, v, mask, causal, scale)
     if _pallas_eligible(q, k, v, mask, causal):
         B, Lq, H, D = q.shape
-        # blocks resolve BEFORE the capability probe: the probe must
-        # compile exactly the (possibly autotuned) config production runs
+        # blocks resolve BEFORE the compile check: it must compile exactly
+        # the (possibly autotuned) config production runs
         blocks = _resolve_flash_blocks(q, k, mask, causal)
-        if _pallas_fa_ok(q.dtype, Lq, k.shape[1], H, D, causal, mask,
-                         blocks):
-            _stats["pallas"] += 1
-            is_bool = mask is not None and mask.dtype == jnp.bool_
-            return _flash_fused(q, k, v, mask, bool(causal), float(scale),
-                                is_bool, _INTERPRET, blocks)
+        _check_compiles(q.dtype, Lq, k.shape[1], H, D, causal, mask, blocks)
+        _stats["pallas"] += 1
+        is_bool = mask is not None and mask.dtype == jnp.bool_
+        return _flash_fused(q, k, v, mask, bool(causal), float(scale),
+                            is_bool, _INTERPRET, blocks)
     _stats["xla"] += 1
     return flash_attention_xla(q, k, v, mask=mask, causal=causal, scale=scale)

@@ -26,7 +26,8 @@ another single multiply-add over per-channel constants:
 
 The Pallas path runs on TPU (or under the interpreter in tests, so CPU CI
 exercises the kernels); elsewhere an identical XLA composition is used —
-`layer_norm.py` idiom: `_on_tpu()` gate + eager compile probe + fallback.
+`layer_norm.py` idiom: `_on_tpu()` + shape gate, then an eager compile
+check that raises (`autotune.compile_check`) — no fallback behind it.
 """
 from __future__ import annotations
 
@@ -34,9 +35,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
-from ..._jax_compat import (TPUCompilerParams as _TPUCompilerParams,
-                            DIM_PARALLEL as _DIM_P, DIM_ARBITRARY as _DIM_A)
 # shared with the unfused path in nn/functional: running-stat parity
 # requires the statistics formulation to be THE SAME code
 from .._bn_common import _bn_axes, _bn_stats
@@ -108,8 +108,8 @@ def _bn_act_fwd_pallas(x2d, z2d, k, c, act, has_add, interpret=False,
         out_specs=rowspec,
         out_shape=jax.ShapeDtypeStruct((R, C), x2d.dtype),
         compiler_params=(None if interpret
-                         else _TPUCompilerParams(
-                             dimension_semantics=(_DIM_P,))),
+                         else pltpu.CompilerParams(
+                             dimension_semantics=(pltpu.PARALLEL,))),
         interpret=interpret,
     )(*args)
 
@@ -131,7 +131,9 @@ def _bwd_reduce_kernel(x_ref, y_ref, dy_ref, mean_ref, inv_ref,
     x = x_ref[...].astype(jnp.float32)
     g = dy_ref[...].astype(jnp.float32)
     if act == "relu":
-        g = jnp.where(y_ref[...] > 0, g, 0.0)
+        # compared in f32: the v5e VPU has no bf16 compare, and Mosaic
+        # refuses one ("Target does not support this comparison")
+        g = jnp.where(y_ref[...].astype(jnp.float32) > 0, g, 0.0)
     xhat = (x - mean_ref[...]) * inv_ref[...]
     gx = g * xhat
     if R % br:  # edge block: OOB rows hold undefined reads — mask them out
@@ -164,8 +166,8 @@ def _bn_bwd_reduce_pallas(x2d, y2d, dy2d, mean, inv, act, interpret=False,
         out_shape=[jax.ShapeDtypeStruct((_SUBLANES, C), jnp.float32),
                    jax.ShapeDtypeStruct((_SUBLANES, C), jnp.float32)],
         compiler_params=(None if interpret
-                         else _TPUCompilerParams(
-                             dimension_semantics=(_DIM_A,))),
+                         else pltpu.CompilerParams(
+                             dimension_semantics=(pltpu.ARBITRARY,))),
         interpret=interpret,
     )(x2d, y2d, dy2d, mean, inv)
     return db[0], dg[0]
@@ -177,7 +179,7 @@ def _bwd_dx_kernel(x_ref, y_ref, dy_ref, a_ref, b_ref, c0_ref, *out_refs,
     x = x_ref[...].astype(jnp.float32)
     g = dy_ref[...].astype(jnp.float32)
     if act == "relu":
-        g = jnp.where(y_ref[...] > 0, g, 0.0)
+        g = jnp.where(y_ref[...].astype(jnp.float32) > 0, g, 0.0)
     dx = a_ref[...] * g + b_ref[...] * x + c0_ref[...]
     out_refs[0][...] = dx.astype(out_refs[0].dtype)
     if has_add:
@@ -206,16 +208,14 @@ def _bn_bwd_dx_pallas(x2d, y2d, dy2d, a, b, c0, act, has_add,
         out_specs=out_specs,
         out_shape=out_shape,
         compiler_params=(None if interpret
-                         else _TPUCompilerParams(
-                             dimension_semantics=(_DIM_P,))),
+                         else pltpu.CompilerParams(
+                             dimension_semantics=(pltpu.PARALLEL,))),
         interpret=interpret,
     )(x2d, y2d, dy2d, a, b, c0)
     return outs  # list: [dx] or [dx, dz] (out_shape is always a list)
 
 
-# ------------------------ block selection + probe ---------------------------
-
-_probe_status = {}
+# -------------------- block selection + compile check -----------------------
 
 
 def _bn_vmem_bytes(cfg, C: int, itemsize: int, has_add: bool) -> int:
@@ -278,37 +278,33 @@ def _block_rows_for(dtype, R: int, C: int, has_add: bool) -> int:
     return cfg["rows"]
 
 
-def _probe_ok(dtype, C: int, has_add: bool,
-              block_rows: int = _DEF_BLOCK_ROWS,
-              tail: bool = False) -> bool:
-    """Per-(dtype, channels, block-rows, tail?) EAGER compile probe at the
-    exact block shape production uses — a Mosaic failure inside a traced
-    user program cannot be caught (see layer_norm._pallas_ln_ok). `tail`
-    selects the `R % br` masked-reduce variant (a different Mosaic
-    program, gated by `if R % br:` in the kernel): production shapes with
-    a partial last block must probe THAT variant, so the probe array gets
-    one extra sublane of rows."""
-    key = (jnp.dtype(dtype).name, C, has_add, block_rows, tail, _INTERPRET)
-    if key not in _probe_status:
-        try:
-            x = jnp.ones((block_rows + (_SUBLANES if tail else 0), C),
-                         dtype)
-            v = jnp.ones((C,), jnp.float32)
-            y = _bn_act_fwd_pallas(x, x if has_add else None, v, v,
-                                   act="relu", has_add=has_add,
-                                   interpret=_INTERPRET,
-                                   block_rows=block_rows)
-            db, dg = _bn_bwd_reduce_pallas(x, y, x, v, v, act="relu",
-                                           interpret=_INTERPRET,
-                                           block_rows=block_rows)
-            outs = _bn_bwd_dx_pallas(x, y, x, v, v, v, act="relu",
-                                     has_add=has_add, interpret=_INTERPRET,
-                                     block_rows=block_rows)
-            jax.block_until_ready((y, db, dg, outs))
-            _probe_status[key] = True
-        except Exception:
-            _probe_status[key] = False
-    return _probe_status[key]
+def _check_compiles(dtype, C: int, has_add: bool, block_rows: int,
+                    tail: bool):
+    """Per-(dtype, channels, block-rows, tail?) eager compile check of the
+    whole fwd / bwd-reduce / bwd-dx chain at the exact block shape
+    production uses (`autotune.compile_check`). `tail` selects the
+    `R % br` masked-reduce variant (a different Mosaic program, gated by
+    `if R % br:` in the kernel): production shapes with a partial last
+    block must check THAT variant, so the array gets one extra sublane
+    of rows."""
+    def run():
+        x = jnp.ones((block_rows + (_SUBLANES if tail else 0), C), dtype)
+        v = jnp.ones((C,), jnp.float32)
+        y = _bn_act_fwd_pallas(x, x if has_add else None, v, v, act="relu",
+                               has_add=has_add, interpret=_INTERPRET,
+                               block_rows=block_rows)
+        db, dg = _bn_bwd_reduce_pallas(x, y, x, v, v, act="relu",
+                                       interpret=_INTERPRET,
+                                       block_rows=block_rows)
+        outs = _bn_bwd_dx_pallas(x, y, x, v, v, v, act="relu",
+                                 has_add=has_add, interpret=_INTERPRET,
+                                 block_rows=block_rows)
+        return y, db, dg, outs
+
+    _autotune.compile_check(
+        "fused_bn", run, dtype=jnp.dtype(dtype).name, channels=C,
+        has_add=has_add, block_rows=block_rows, tail=tail,
+        interpret=_INTERPRET)
 
 
 def _pallas_eligible(x, data_format: str, has_add: bool) -> bool:
@@ -327,7 +323,8 @@ def _pallas_eligible(x, data_format: str, has_add: bool) -> bool:
     if x.dtype not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
         return False
     br = _block_rows_for(x.dtype, R, C, has_add)
-    return _probe_ok(x.dtype, C, has_add, br, tail=R % br != 0)
+    _check_compiles(x.dtype, C, has_add, br, tail=R % br != 0)
+    return True
 
 
 # ----------------------------- fwd/bwd common -------------------------------

@@ -42,9 +42,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
-from ..._jax_compat import (TPUCompilerParams as _TPUCompilerParams,
-                            DIM_PARALLEL as _DIM_P, DIM_ARBITRARY as _DIM_A)
 from .._bn_common import _bn_stats
 from . import autotune as _autotune
 from . import fused_bn as _fused_bn
@@ -122,8 +121,9 @@ def _conv1x1_stats_pallas(x2d, w2d, interpret=False,
                    jax.ShapeDtypeStruct((_SUBLANES, Cout), jnp.float32),
                    jax.ShapeDtypeStruct((_SUBLANES, Cout), jnp.float32)],
         compiler_params=(None if interpret
-                         else _TPUCompilerParams(
-                             dimension_semantics=(_DIM_P, _DIM_A))),
+                         else pltpu.CompilerParams(
+                             dimension_semantics=(
+                                 pltpu.PARALLEL, pltpu.ARBITRARY))),
         interpret=interpret,
     )(x2d, w2d)
     return y, s[0], ss[0]
@@ -205,29 +205,25 @@ def _resolve_cfg(dtype, R: int, Cin: int, Cout: int,
     return cfg
 
 
-_probe_status = {}
-
-
-def _probe_ok(dtype, R: int, Cin: int, Cout: int, cfg) -> bool:
-    """Eager compile probe at the exact resolved block shape (a Mosaic
-    failure inside a traced user program cannot be caught — layer_norm /
-    fused_bn precedent). Probes the tail-masked variant when R % rows."""
+def _check_compiles(dtype, R: int, Cin: int, Cout: int, cfg):
+    """Eager compile check at the exact resolved block shape
+    (`autotune.compile_check`); the tail-masked variant when R % rows.
+    The XLA rewrite (impl=0) has nothing to check."""
     if cfg["impl"] == 0:
-        return True  # XLA rewrite: nothing to probe
+        return
     br, bc = cfg["rows"], cfg["cols"]
-    key = (jnp.dtype(dtype).name, Cin, Cout, br, bc, bool(R % br), _interp())
-    if key not in _probe_status:
-        try:
-            rows = br + (_SUBLANES if R % br else 0)
-            x = jnp.ones((rows, Cin), dtype)
-            w = jnp.ones((Cin, Cout), dtype)
-            outs = _conv1x1_stats_pallas(x, w, interpret=_interp(),
-                                         block_rows=br, block_cols=bc)
-            jax.block_until_ready(outs)
-            _probe_status[key] = True
-        except Exception:
-            _probe_status[key] = False
-    return _probe_status[key]
+
+    def run():
+        rows = br + (_SUBLANES if R % br else 0)
+        x = jnp.ones((rows, Cin), dtype)
+        w = jnp.ones((Cin, Cout), dtype)
+        return _conv1x1_stats_pallas(x, w, interpret=_interp(),
+                                     block_rows=br, block_cols=bc)
+
+    _autotune.compile_check(
+        "conv_bn", run, dtype=jnp.dtype(dtype).name, cin=Cin, cout=Cout,
+        block_rows=br, block_cols=bc, tail=bool(R % br),
+        interpret=_interp())
 
 
 def eligible(x_shape, w_shape, stride, padding, dilation, groups,
@@ -269,7 +265,8 @@ def eligible(x_shape, w_shape, stride, padding, dilation, groups,
                                 jnp.dtype(jnp.bfloat16)):
         return False
     cfg = _resolve_cfg(dtype, R, Cin, Cout, has_add=False)
-    return _probe_ok(dtype, R, Cin, Cout, cfg)
+    _check_compiles(dtype, R, Cin, Cout, cfg)
+    return True
 
 
 # ----------------------------- fwd/bwd common -------------------------------

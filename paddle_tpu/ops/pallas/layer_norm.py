@@ -26,6 +26,10 @@ from .tiling import on_tpu as _on_tpu
 
 _INTERPRET = False  # tests flip this: kernel runs in the Pallas interpreter
 
+# dispatch decisions, counted at trace time (same contract as
+# flash_attention._stats)
+_stats = {"pallas": 0, "xla": 0}
+
 _DEF_BLOCK_ROWS = 256  # static pick (the PADDLE_TPU_AUTOTUNE=0 behavior)
 
 
@@ -59,10 +63,10 @@ def _ln_fwd_pallas(x2d, gamma, beta, eps: float = 1e-5,
         y = xhat * g_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
         o_ref[...] = y.astype(o_ref.dtype)
 
-    br = block_rows  # STATIC block shape — the capability probe compiled
+    br = block_rows  # STATIC block shape — the compile check compiled
     # exactly (block_rows, N); the autotuner resolves br BEFORE dispatch
-    # (memory-cached per shape bucket), so no unprobed Mosaic variant can
-    # run inside the user's jit (callers gate on R >= _DEF_BLOCK_ROWS)
+    # (memory-cached per shape bucket), so no unchecked Mosaic variant
+    # runs inside the user's jit (callers gate on R >= _DEF_BLOCK_ROWS)
     grid = (pl.cdiv(R, br),)  # cover ALL rows; the edge block is masked
     return pl.pallas_call(
         kernel,
@@ -77,8 +81,6 @@ def _ln_fwd_pallas(x2d, gamma, beta, eps: float = 1e-5,
         interpret=interpret,
     )(x2d, gamma, beta)
 
-
-_pallas_ln_status = {}  # (dtype, N, block_rows) -> bool
 
 _MAX_PALLAS_N = 4096  # block (256, N) must fit VMEM with fp32 intermediates
 
@@ -134,39 +136,43 @@ def _block_rows_for(R: int, N: int, dtype) -> int:
     return hit if hit <= R else _DEF_BLOCK_ROWS
 
 
-def _pallas_ln_ok(dtype, N: int, block_rows: int = _DEF_BLOCK_ROWS) -> bool:
-    """Per-(dtype, hidden-size, block-rows) EAGER compile probe. A Mosaic
-    failure inside a traced user program cannot be caught (the exception
-    fires at compile time of the outer jit), so capability is established
-    eagerly with the exact kernel shape that production will use."""
-    key = (jnp.dtype(dtype).name, N, block_rows)
-    if key not in _pallas_ln_status:
-        if not (_on_tpu() or _INTERPRET) or N > _MAX_PALLAS_N:
-            _pallas_ln_status[key] = False
-        else:
-            try:
-                probe = jnp.ones((block_rows, N), dtype)
-                g = jnp.ones((N,), dtype)
-                jax.block_until_ready(_ln_fwd_pallas(
-                    probe, g, g, eps=1e-5, block_rows=block_rows,
-                    interpret=_INTERPRET))
-                _pallas_ln_status[key] = True
-            except Exception:
-                _pallas_ln_status[key] = False
-    return _pallas_ln_status[key]
+def _check_compiles(dtype, N: int, block_rows: int):
+    """Per-(dtype, hidden-size, block-rows) eager compile check at the
+    exact kernel shape production uses (`autotune.compile_check`)."""
+    def run():
+        probe = jnp.ones((block_rows, N), dtype)
+        g = jnp.ones((N,), dtype)
+        return _ln_fwd_pallas(probe, g, g, eps=1e-5, block_rows=block_rows,
+                              interpret=_INTERPRET)
+
+    _autotune.compile_check(
+        "layer_norm_fwd", run, dtype=jnp.dtype(dtype).name,
+        x=(block_rows, N), block_rows=block_rows, interpret=_INTERPRET)
 
 
 def _ln_fwd(x2d, gamma, beta, eps):
     """Forward output only — stats are recomputed where needed (backward),
     so the forward is a single read of x."""
+    km = _tiling.current_kernel_mesh()
+    if km is not None and (_on_tpu() or _INTERPRET):
+        # per shard of a declared multi-device program: rows follow the
+        # batch split (rows are independent), gamma/beta replicate
+        from jax.sharding import PartitionSpec as P
+        rows = P(km.batch if x2d.shape[0] % km.size(km.batch) == 0
+                 else None, None)
+        return _tiling.per_shard(
+            km, lambda x, g, b: _ln_fwd(x, g, b, eps),
+            (rows, P(), P()), rows)(x2d, gamma, beta)
     R, N = x2d.shape
     if isinstance(R, int) and R >= _DEF_BLOCK_ROWS and R % 8 == 0 \
             and N % 128 == 0 and x2d.dtype == gamma.dtype \
             and (_on_tpu() or _INTERPRET) and N <= _MAX_PALLAS_N:
         br = _block_rows_for(R, N, x2d.dtype)
-        if _pallas_ln_ok(x2d.dtype, N, br):
-            return _ln_fwd_pallas(x2d, gamma, beta, eps=eps, block_rows=br,
-                                  interpret=_INTERPRET)
+        _check_compiles(x2d.dtype, N, br)
+        _stats["pallas"] += 1
+        return _ln_fwd_pallas(x2d, gamma, beta, eps=eps, block_rows=br,
+                              interpret=_INTERPRET)
+    _stats["xla"] += 1
     mean, rstd = _ln_stats_xla(x2d, eps)
     xhat = (x2d.astype(jnp.float32) - mean[:, None]) * rstd[:, None]
     return (xhat * gamma.astype(jnp.float32) + beta.astype(jnp.float32)

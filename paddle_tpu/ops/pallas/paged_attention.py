@@ -49,9 +49,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas import tpu as pltpu
 
-from ..._jax_compat import (TPUCompilerParams as _TPUCompilerParams,
-                            DIM_PARALLEL as _DIM_P, DIM_ARBITRARY as _DIM_A)
 from . import autotune as _autotune
 from . import tiling as _tiling
 from .tiling import on_tpu as _on_tpu
@@ -60,7 +59,9 @@ _NEG = -1e30
 _CARRY_LANES = 128  # m/l scratch lane width (f32 native lane tile)
 
 # dispatch decisions, counted at trace time (reset freely in tests)
-_stats = {"pallas": 0, "xla": 0, "append": 0, "cow": 0}
+# ("xla_measured" counts the XLA dispatches that were the autotuner's
+# measured impl=0 choice, as opposed to a shape/platform gate)
+_stats = {"pallas": 0, "xla": 0, "xla_measured": 0, "append": 0, "cow": 0}
 
 # tests set True: the kernel runs in the Pallas interpreter on CPU, so
 # the real gather/online-softmax logic is exercised without a TPU
@@ -124,30 +125,31 @@ def _paged_attn_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
     # table slots all point at the null page)
     @pl.when(i * page_size < ctx)
     def _compute():
-        qb = q_ref[...]          # [bh, D]
-        kb = k_ref[...]          # [page_size, bh, D]
-        vb = v_ref[...]
-        # batched over heads: s[h, p] = q[h, :] . k[p, h, :]
-        s = jax.lax.dot_general(
-            qb, kb, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale  # [bh, page_size]
-        pos = i * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # One query token per head makes this a matrix-VECTOR product per
+        # head: nothing for the MXU to amortise, and Mosaic's matmul wants
+        # batch dims leading, which a [page, head, D] page is not. So the
+        # products run on the VPU at the page's own layout — reduce over
+        # lanes (D) for the scores, over the major axis (positions) for
+        # the softmax sums and the weighted values; no relayout anywhere.
+        qb = q_ref[...].astype(jnp.float32)[None]       # [1, bh, D]
+        kb = k_ref[...].astype(jnp.float32)             # [page, bh, D]
+        vb = v_ref[...].astype(jnp.float32)
+        s = jnp.sum(qb * kb, axis=-1, keepdims=True) * scale  # [page,bh,1]
+        pos = i * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         s = jnp.where(pos < ctx, s, _NEG)
-        m_prev = m_ref[...][:, :1]
-        l_prev = l_ref[...][:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        m_prev = m_ref[...][:, :1][None]                # [1, bh, 1]
+        l_prev = l_ref[...][:, :1][None]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
         # a page whose every position is past ctx never reaches here, but
         # the LAST live page's tail positions sit at the floor: zero them
         # (exp(_NEG - m) underflows only when m is real)
         p = jnp.where(s > 0.5 * _NEG, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = jnp.broadcast_to(
-            l_prev * corr + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(vb.dtype), vb, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_new = l_prev * corr + jnp.sum(p, axis=0, keepdims=True)
+        l_ref[...] = jnp.broadcast_to(l_new[0], l_ref.shape)
+        acc_ref[...] = acc_ref[...] * corr[0] + jnp.sum(p * vb, axis=0)
+        m_ref[...] = jnp.broadcast_to(m_new[0], m_ref.shape)
 
     @pl.when(i == n_pages - 1)
     def _finalize():
@@ -162,7 +164,6 @@ def _paged_attn_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
 def _paged_attn_pallas(q, k_pages, v_pages, block_tables, context_lens,
                        scale, block_h, interpret=False):
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     B, H, D = q.shape
     page_size = k_pages.shape[1]
@@ -190,8 +191,8 @@ def _paged_attn_pallas(q, k_pages, v_pages, block_tables, context_lens,
     else:
         # the page axis carries the softmax carry state -> ARBITRARY;
         # batch and head blocks are embarrassingly parallel
-        params = _TPUCompilerParams(
-            dimension_semantics=(_DIM_P, _DIM_P, _DIM_A))
+        params = pltpu.CompilerParams(dimension_semantics=(
+            pltpu.PARALLEL, pltpu.PARALLEL, pltpu.ARBITRARY))
     return pl.pallas_call(
         functools.partial(_paged_attn_kernel, page_size=page_size,
                           scale=scale, n_pages=n_pages),
@@ -217,9 +218,11 @@ _cfg_memo = _autotune.register_memo({})
 
 
 def _head_candidates(H: int):
-    """Head-block extents: every divisor-of-H option (a non-divisor would
-    need head tail-masking the kernel doesn't carry) plus whole-H."""
-    return [h for h in (2, 4, 8, 16) if h < H and H % h == 0] + [H]
+    """Head-block extents Mosaic accepts: the head axis is the blocks'
+    second-minor dim, so an extent is a multiple of 8 or the whole H —
+    and a divisor of H (a non-divisor would need head tail-masking the
+    kernel doesn't carry). GPT-2's H=12 has only whole-H."""
+    return [h for h in (8, 16, 32) if h < H and H % h == 0] + [H]
 
 
 def _resolve_cfg(dtype, H: int, D: int, page_size: int, n_pages: int):
@@ -279,35 +282,22 @@ def _resolve_cfg(dtype, H: int, D: int, page_size: int, n_pages: int):
     return cfg
 
 
-_probe_status = {}
+def _check_compiles(dtype, H: int, D: int, page_size: int, n_pages: int,
+                    heads: int):
+    """Eager compile check at the exact resolved head block
+    (`autotune.compile_check`)."""
+    def run():
+        q = jnp.ones((2, H, D), dtype)
+        kp = jnp.ones((max(n_pages, 2), page_size, H, D), dtype)
+        bt = jnp.zeros((2, n_pages), jnp.int32)
+        cl = jnp.full((2,), page_size, jnp.int32)
+        return _paged_attn_pallas(q, kp, kp, bt, cl, float(1.0 / np.sqrt(D)),
+                                  heads, interpret=_INTERPRET)
 
-
-def _pallas_ok(dtype, H: int, D: int, page_size: int, n_pages: int,
-               cfg) -> bool:
-    """Eager compile probe at the exact resolved config (Mosaic failures
-    inside a user's outer jit cannot be caught — flash/layer_norm
-    precedent). impl=0 needs no probe."""
-    if cfg["impl"] == 0:
-        return True
-    key = (jnp.dtype(dtype).name, H, D, page_size, n_pages, cfg["heads"],
-           _INTERPRET)
-    if key not in _probe_status:
-        if not (_on_tpu() or _INTERPRET):
-            _probe_status[key] = False
-        else:
-            try:
-                q = jnp.ones((2, H, D), dtype)
-                kp = jnp.ones((max(n_pages, 2), page_size, H, D), dtype)
-                bt = jnp.zeros((2, n_pages), jnp.int32)
-                cl = jnp.full((2,), page_size, jnp.int32)
-                out = _paged_attn_pallas(q, kp, kp, bt, cl,
-                                         float(1.0 / np.sqrt(D)),
-                                         cfg["heads"], interpret=_INTERPRET)
-                jax.block_until_ready(out)
-                _probe_status[key] = True
-            except Exception:
-                _probe_status[key] = False
-    return _probe_status[key]
+    _autotune.compile_check(
+        "paged_attn", run, dtype=jnp.dtype(dtype).name, heads=H, head_dim=D,
+        page_size=page_size, pages_per_seq=n_pages, block_heads=heads,
+        interpret=_INTERPRET)
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
@@ -321,8 +311,8 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 
     Dispatch mirrors `flash_attention`: the per-shape impl (Pallas page
     walk vs XLA gather) is resolved on the autotune layer, then the
-    resolved Pallas config is capability-probed eagerly; CPU without
-    interpret mode always takes the XLA path. Safe to call at trace time
+    resolved Pallas config gets one eager compile check that raises; CPU
+    without interpret mode always takes the XLA path. Safe to call at trace time
     of an outer jit (resolution runs eagerly at trace, like every kernel
     in this package)."""
     B, H, D = q.shape
@@ -336,12 +326,13 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
                 and isinstance(H, int))
     if eligible:
         cfg = _resolve_cfg(q.dtype, H, D, page_size, n_pages)
-        if cfg["impl"] == 1 and _pallas_ok(q.dtype, H, D, page_size,
-                                           n_pages, cfg):
+        if cfg["impl"] == 1:
+            _check_compiles(q.dtype, H, D, page_size, n_pages, cfg["heads"])
             _stats["pallas"] += 1
             return _paged_attn_pallas(q, k_pages, v_pages, block_tables,
                                       context_lens, float(scale),
                                       cfg["heads"], interpret=_INTERPRET)
+        _stats["xla_measured"] += 1
     _stats["xla"] += 1
     return paged_attention_xla(q, k_pages, v_pages, block_tables,
                                context_lens, scale=scale)
@@ -391,7 +382,7 @@ def decode_step_tp(q, k_new, v_new, k_pages, v_pages, block_tables,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ..._jax_compat import shard_map
+    from jax import shard_map
     B, H, D = q.shape
     n_shards = mesh.shape[axis]
     if H % n_shards:
@@ -428,7 +419,7 @@ def prefill_append_tp(k_pages, v_pages, k_seq, v_seq, page_ids, length,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ..._jax_compat import shard_map
+    from jax import shard_map
 
     def body(kp_s, vp_s, ks_s, vs_s, pid, ln, st):
         return prefill_append(kp_s, vp_s, ks_s, vs_s, pid, ln, start=st)

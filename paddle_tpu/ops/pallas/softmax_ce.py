@@ -31,10 +31,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from ..._jax_compat import (TPUCompilerParams as _TPUCompilerParams,
-                            DIM_PARALLEL as _DIM_P, DIM_ARBITRARY as _DIM_A)
 import numpy as np
+from jax.experimental.pallas import tpu as pltpu
 
 from . import autotune as _autotune
 from . import tiling as _tiling
@@ -195,7 +193,6 @@ def _ce_fwd_pallas(logits, labels, blocks=None, interpret=False):
     """logits [N, V], labels [N] int32 -> (nll [N] f32, lse [N] f32).
     `blocks` is the resolved (block_n, block_v); None = static picks."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     N, V = logits.shape
     block_n, block_v = blocks or _static_blocks(N, V)
@@ -206,8 +203,6 @@ def _ce_fwd_pallas(logits, labels, blocks=None, interpret=False):
     kernel = functools.partial(
         _ce_fwd_kernel, block_n=block_n, block_v=block_v, n_rows=N,
         n_cls=V, n_v=n_v)
-    P = _DIM_P
-    A = _DIM_A
     nll, lse = pl.pallas_call(
         kernel,
         grid=(n_n, n_v),
@@ -222,8 +217,9 @@ def _ce_fwd_pallas(logits, labels, blocks=None, interpret=False):
                         pltpu.VMEM((block_n, _CARRY_LANES), jnp.float32),
                         pltpu.VMEM((block_n, _CARRY_LANES), jnp.float32)],
         compiler_params=(None if interpret
-                         else _TPUCompilerParams(
-                             dimension_semantics=(P, A))),
+                         else pltpu.CompilerParams(
+                             dimension_semantics=(
+                                 pltpu.PARALLEL, pltpu.ARBITRARY))),
         interpret=interpret,
     )(logits, lab_p)
     return nll[:, 0], lse[:, 0]
@@ -232,7 +228,6 @@ def _ce_fwd_pallas(logits, labels, blocks=None, interpret=False):
 @functools.partial(jax.jit, static_argnames=("blocks", "interpret"))
 def _ce_bwd_pallas(logits, labels, lse, dnll, blocks=None, interpret=False):
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     N, V = logits.shape
     block_n, block_v = blocks or _static_blocks(N, V)
@@ -243,7 +238,6 @@ def _ce_bwd_pallas(logits, labels, lse, dnll, blocks=None, interpret=False):
     dnll_p = jnp.broadcast_to(dnll.astype(jnp.float32)[:, None],
                               (N, _STATS_LANES))
     rowspec = pl.BlockSpec((block_n, _STATS_LANES), lambda i, j: (i, 0))
-    P = _DIM_P
     dlogits = pl.pallas_call(
         functools.partial(_ce_bwd_kernel, block_n=block_n, block_v=block_v,
                           n_rows=N, n_cls=V),
@@ -255,8 +249,9 @@ def _ce_bwd_pallas(logits, labels, lse, dnll, blocks=None, interpret=False):
         out_specs=pl.BlockSpec((block_n, block_v), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N, V), logits.dtype),
         compiler_params=(None if interpret
-                         else _TPUCompilerParams(
-                             dimension_semantics=(P, P))),
+                         else pltpu.CompilerParams(
+                             dimension_semantics=(
+                                 pltpu.PARALLEL, pltpu.PARALLEL))),
         interpret=interpret,
     )(logits, lab_p, lse_p, dnll_p)
     return dlogits
@@ -287,28 +282,19 @@ def _fused_ce_bwd(interpret, blocks, res, dnll):
 _fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
 
 
-_status = {}
-
-
-def _probe_ok(dtype, N, V, blocks=None) -> bool:
-    """Eager fwd+bwd compile probe (see flash_attention._pallas_fa_ok) at
-    the RESOLVED block config — probing static picks while production runs
+def _check_compiles(dtype, N, V, blocks=None):
+    """Eager fwd+bwd compile check (`autotune.compile_check`) at the
+    RESOLVED block config — checking static picks while production runs
     tuned ones would validate a kernel production never executes."""
-    key = (jnp.dtype(dtype).name, N, V, blocks, _INTERPRET)
-    if key not in _status:
-        if not (_on_tpu() or _INTERPRET):
-            _status[key] = False
-        else:
-            try:
-                lg = jnp.ones((N, V), dtype)
-                lb = jnp.zeros((N,), jnp.int32)
-                g = jax.grad(lambda x: _fused_ce(x, lb, _INTERPRET,
-                                                 blocks).sum())(lg)
-                jax.block_until_ready(g)
-                _status[key] = True
-            except Exception:
-                _status[key] = False
-    return _status[key]
+    def run():
+        lg = jnp.ones((N, V), dtype)
+        lb = jnp.zeros((N,), jnp.int32)
+        return jax.grad(lambda x: _fused_ce(x, lb, _INTERPRET,
+                                            blocks).sum())(lg)
+
+    _autotune.compile_check(
+        "softmax_ce", run, dtype=jnp.dtype(dtype).name, logits=(N, V),
+        blocks_n_v=blocks or "static", interpret=_INTERPRET)
 
 
 def fused_softmax_ce_eligible(logits, labels) -> bool:
@@ -334,7 +320,8 @@ def fused_softmax_ce_eligible(logits, labels) -> bool:
     if N < 64:
         return False
     blocks = _blocks_for(N, logits.shape[-1], logits.dtype)
-    return _probe_ok(logits.dtype, N, logits.shape[-1], blocks)
+    _check_compiles(logits.dtype, N, logits.shape[-1], blocks)
+    return True
 
 
 def fused_softmax_ce(logits, labels):

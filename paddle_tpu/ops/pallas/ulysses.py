@@ -28,8 +28,8 @@ from typing import Optional
 
 import jax
 
-from ..._jax_compat import shard_map as _shard_map
-from ..._jax_compat import axis_size as _axis_size
+from jax import shard_map as _shard_map
+from jax.lax import axis_size as _axis_size
 import jax.numpy as jnp
 import numpy as np
 

@@ -225,10 +225,14 @@ class Optimizer:
         operators/optimizers/merged_adam_op): instead of ~n_params small
         per-parameter fusions the compiled step gets a handful of big
         ones, shrinking the optimizer segment's launch overhead.
-        Elementwise math on a concatenated vector is BIT-IDENTICAL per
-        element to the per-parameter loop (pinned by
-        tests/test_fused_opt.py), so the two paths are interchangeable
-        mid-run. Callers with per-leaf sharded state (ZeRO) should keep
+        Elementwise math on a concatenated vector is the per-parameter
+        loop's math per element, so the two paths are interchangeable
+        mid-run: slots come out bit-identical, parameters bit-identical
+        for SGD/Momentum and within a few f32 roundings of the step for
+        the Adam family, whose sqrt/divide line the compiler may emit
+        differently once concatenation moves elements across vector lanes
+        (pinned by tests/test_fused_opt.py). Callers with per-leaf sharded
+        state (ZeRO) should keep
         the default: concatenation would force cross-shard gathers.
         """
         lr = self.get_lr() if lr is None else lr

@@ -5,8 +5,8 @@ on TPU the async dispatch returns in microseconds while the op runs on the
 chip for milliseconds, so host spans alone cannot separate "dispatch-bound"
 from "device-bound". Two attribution modes, recorded alongside each span:
 
-* ``estimate`` (default, works everywhere incl. CPU CI): a roofline bound
-  from the cost model — max(flops / peak_flops, bytes / peak_hbm_bw) for
+* ``estimate`` (default, on a device with published peaks): a roofline
+  bound from the cost model — max(flops / peak_flops, bytes / peak_hbm_bw) for
   the span's op. Clearly labeled an ESTIMATE: cost-analysis numbers are
   cache-oblivious upper bounds, the same provenance bench.py already
   documents for hbm_gb_per_step.
@@ -21,61 +21,83 @@ The full-fidelity third mode lives in `profiler/xplane.py`: a bounded
 host spans (`device_src="xplane"`), replacing the estimate with measured
 backend execution time wherever the correlation lands.
 
-Peaks: TPU `BENCH_PEAK_FLOPS` (default 197e12, v5e bf16) and
-`PADDLE_TPU_PEAK_HBM_GBS` (GB/s, default 819 = v5e); CPU gets deliberately
-conservative defaults (100 GFLOP/s, 20 GB/s) so estimate rows stay
-obviously synthetic there.
+Peaks come from ONE table, `PEAKS`, keyed by the `device_kind` string jax
+reports, each row with its source. A device that is not in the table has no
+roofline: `device_peaks` raises `UnknownDeviceError`, and the estimator
+attributes nothing (a CPU run gets no invented "device time").
 """
 from __future__ import annotations
 
 import os
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 __all__ = ["sync_mode", "estimate_ns", "attribute", "split_rows",
+           "PEAKS", "Peaks", "UnknownDeviceError", "device_peaks",
            "platform_peaks", "reset_peaks"]
 
-_CPU_PEAK_FLOPS = 100e9
-_CPU_PEAK_BW = 20e9
 
-# cache keyed on the env knobs that feed it — a test or bench changing
-# BENCH_PEAK_FLOPS / PADDLE_TPU_PEAK_HBM_GBS mid-process must see fresh
-# peaks, not the first call's (the platform probe alone stays cached: a
-# process cannot change backends)
-_peaks_cache: Optional[Tuple[Tuple[Optional[str], Optional[str]],
-                             Tuple[str, float, float]]] = None
+class Peaks(NamedTuple):
+    bf16_flops: float        # dense bf16 matmul FLOP/s of one chip
+    hbm_bytes_per_s: float   # HBM bandwidth of one chip
+    source: str
 
 
-def _platform() -> str:
-    try:
+#: device_kind (as `jax.devices()[0].device_kind` prints it) -> published
+#: peaks of ONE chip. Add a row, with its source, before benchmarking on a
+#: new part; there is no default.
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(
+        197e12, 819e9,
+        "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, 16 GB "
+        "HBM2e at 819 GB/s per chip"),
+}
+
+
+class UnknownDeviceError(LookupError):
+    """No published peaks for this device_kind: a utilization or a roofline
+    share against it would be a made-up number."""
+
+
+def device_peaks(kind: Optional[str] = None) -> Peaks:
+    """Peaks of `kind` (default: this process's first device)."""
+    if kind is None:
         import jax
-        return jax.devices()[0].platform
-    except Exception:
-        return "cpu"
+        kind = jax.devices()[0].device_kind
+    try:
+        return PEAKS[kind]
+    except KeyError:
+        raise UnknownDeviceError(
+            f"no published peaks for device_kind {kind!r} (known: "
+            f"{sorted(PEAKS)}); add a row with its source to "
+            f"paddle_tpu.profiler.device_time.PEAKS") from None
+
+
+# (platform, Peaks-or-None) of this process's device, probed once: a
+# process cannot change backends (tests that patch PEAKS call reset_peaks)
+_peaks_cache: Optional[Tuple[str, Optional[Peaks]]] = None
+
+
+def _probe() -> Tuple[str, Optional[Peaks]]:
+    global _peaks_cache
+    if _peaks_cache is None:
+        import jax
+        d = jax.devices()[0]
+        _peaks_cache = (d.platform, PEAKS.get(d.device_kind))
+    return _peaks_cache
 
 
 def platform_peaks() -> Tuple[str, float, float]:
-    """(platform, peak_flops/s, peak_bytes/s) used by the estimator."""
-    global _peaks_cache
-    env_key = (os.environ.get("BENCH_PEAK_FLOPS"),
-               os.environ.get("PADDLE_TPU_PEAK_HBM_GBS"))
-    if _peaks_cache is not None and _peaks_cache[0] == env_key:
-        return _peaks_cache[1]
-    plat = _platform() if _peaks_cache is None else _peaks_cache[1][0]
-    if plat == "cpu":
-        peaks = (plat, _CPU_PEAK_FLOPS, _CPU_PEAK_BW)
-    else:
-        from ..utils.envparse import env_float
-        flops = float(env_key[0]) if env_key[0] else 197e12
-        bw = env_float("PADDLE_TPU_PEAK_HBM_GBS", 819.0) * 1e9
-        peaks = (plat, flops, bw)
-    _peaks_cache = (env_key, peaks)
-    return peaks
+    """(platform, peak_flops/s, peak_bytes/s) used by the estimator;
+    raises `UnknownDeviceError` off the table."""
+    plat, peaks = _probe()
+    if peaks is None:
+        peaks = device_peaks()  # raises, naming the kind
+    return plat, peaks.bf16_flops, peaks.hbm_bytes_per_s
 
 
 def reset_peaks():
-    """Drop the cached peaks (including the platform probe) — tests that
-    monkeypatch the backend need this; env-knob changes are picked up
-    automatically."""
+    """Drop the cached device probe — for tests that patch `PEAKS` or the
+    backend."""
     global _peaks_cache
     _peaks_cache = None
 
@@ -95,10 +117,11 @@ def estimate_ns(flops: float, nbytes: float) -> int:
 
 
 def attribute(outs, flops: float, nbytes: float,
-              start_ns: int) -> Tuple[int, str]:
+              start_ns: int) -> Tuple[Optional[int], Optional[str]]:
     """(device_ns, source) for one traced op. In sync mode, waits for the
     op's outputs and reports wall-until-completion as "measured"; otherwise
-    returns the roofline "estimate"."""
+    returns the roofline "estimate" — or (None, None) on a device with no
+    published peaks."""
     if sync_mode():
         try:
             import jax
@@ -107,6 +130,8 @@ def attribute(outs, flops: float, nbytes: float,
             return max(0, now_ns() - start_ns), "measured"
         except Exception:
             pass  # fall through to the estimate
+    if _probe()[1] is None:
+        return None, None  # no published peaks: nothing to estimate from
     return estimate_ns(flops, nbytes), "estimate"
 
 

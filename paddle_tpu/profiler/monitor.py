@@ -19,7 +19,6 @@ trajectory carries per-window observability from this PR on.
 from __future__ import annotations
 
 import json
-import os
 import time
 from typing import Callable, List, Optional
 
@@ -44,7 +43,16 @@ STEP_RECORD_OPTIONAL = {
 }
 STEP_RECORD_FIELDS = set(STEP_RECORD_REQUIRED) | set(STEP_RECORD_OPTIONAL)
 
-_DEFAULT_PEAK_FLOPS = float(os.environ.get("BENCH_PEAK_FLOPS", 197e12))
+
+def _table_peak_flops() -> Optional[float]:
+    """bf16 peak of this process's device from the one peaks table
+    (profiler/device_time.PEAKS); None where it has no row, and then no
+    MFU estimate is made."""
+    from . import device_time
+    try:
+        return device_time.device_peaks().bf16_flops
+    except device_time.UnknownDeviceError:
+        return None
 
 
 def make_step_record(*, step: int, window_steps: int, window_time_s: float,
@@ -62,9 +70,9 @@ def make_step_record(*, step: int, window_steps: int, window_time_s: float,
     steps_per_sec = window_steps / window_time_s if window_time_s > 0 else 0.0
     ips = (float(samples) / window_time_s
            if samples and window_time_s > 0 else None)
-    peak = peak_flops if peak_flops else _DEFAULT_PEAK_FLOPS
+    peak = peak_flops if peak_flops else _table_peak_flops()
     mfu = (float(flops_per_step) * steps_per_sec / peak
-           if flops_per_step and steps_per_sec > 0 and peak > 0 else None)
+           if flops_per_step and steps_per_sec > 0 and peak else None)
     return {
         "ts": time.time(),
         "step": int(step),
@@ -256,7 +264,7 @@ class ThroughputMonitor:
         self.flops_per_sample = flops_per_sample
         self.flops_per_step = flops_per_step
         self.samples_per_step = samples_per_step
-        self.peak_flops = peak_flops or _DEFAULT_PEAK_FLOPS
+        self.peak_flops = peak_flops
         self.records: List[dict] = []
         self.diagnose = bool(diagnose)
         self.diagnoses: List[dict] = []

@@ -132,10 +132,15 @@ class ObservabilityServer:
     /snapshot fleet-aware; without one they serve this process only."""
 
     def __init__(self, registry=None, aggregator=None,
-                 stall_after: Optional[float] = None):
+                 stall_after: Optional[float] = None,
+                 owns_devices: bool = True):
         self.registry = registry or _metrics_mod.default_registry()
         self.aggregator = aggregator
         self.stall_after = stall_after
+        # False in a supervisor: sampling device memory calls
+        # jax.devices(), and a scrape must not make the supervisor open
+        # the chip its trainer child needs (one process per chip)
+        self.owns_devices = owns_devices
         self._httpd: Optional[ThreadingHTTPServer] = None
         self._thread: Optional[threading.Thread] = None
         self.port: Optional[int] = None
@@ -172,7 +177,8 @@ class ObservabilityServer:
         self._collect_fleet()
         # refresh the device-memory gauges so the snapshot's watermark is
         # scrape-time, not last-step-record time
-        _metrics_mod.update_device_memory_gauges(self.registry)
+        if self.owns_devices:
+            _metrics_mod.update_device_memory_gauges(self.registry)
         snap = {
             "metrics": self.registry.snapshot(),
             "watchdog": get_watchdog().snapshot(),
@@ -605,7 +611,8 @@ def maybe_start_server(role: str = "trainer",
             aggregator.start_polling()
         except Exception:
             pass
-    server = ObservabilityServer(aggregator=aggregator)
+    server = ObservabilityServer(aggregator=aggregator,
+                                 owns_devices=role != "supervisor")
     try:
         bound = server.start(port)
     except OSError as e:

@@ -51,3 +51,16 @@ def _seed_all():
     yield
     from paddle_tpu.framework import tape
     tape.reset_tape()
+
+
+@pytest.fixture
+def peaks_row_for_this_device(monkeypatch):
+    """The roofline estimator and bench's MFU need a row of published
+    peaks for the device (profiler/device_time.PEAKS); the CPU has none,
+    by design. Tests of that machinery's STRUCTURE give it a row."""
+    from paddle_tpu.profiler import device_time
+    monkeypatch.setitem(device_time.PEAKS, jax.devices()[0].device_kind,
+                        device_time.Peaks(100e9, 20e9, "test row"))
+    device_time.reset_peaks()
+    yield
+    device_time.reset_peaks()

@@ -92,13 +92,11 @@ class TestDonationCheck:
 
 class TestDtypeCheck:
     def test_f64_upcast_fires_high(self):
-        from jax.experimental import enable_x64
-
         def step(x):
             with jax.named_scope("bad_layer"):
                 return (x.astype(jnp.float64) * 2).sum()
 
-        with enable_x64():
+        with jax.enable_x64(True):
             rep = audit_program(step, (jnp.ones((8, 8), jnp.float32),),
                                 name="f64", emit=False)
         f = [x for x in rep.findings if x.code == "f64-compute"]
@@ -172,7 +170,7 @@ class TestShardingCheck:
 
     def test_collective_budget_fires(self, monkeypatch):
         monkeypatch.setenv("PADDLE_TPU_AUDIT_COLLECTIVE_BUDGET_MB", "1")
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         mesh = Mesh(np.array(jax.devices()[:1]), ("i",))
         f = shard_map(lambda x: jax.lax.psum(x, "i"), mesh=mesh,

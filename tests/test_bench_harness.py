@@ -1,17 +1,27 @@
-"""The bench harness itself must be unkillable (round-3 lesson: one backend
-failure produced rc=1 and no JSON, losing the whole round's perf record).
+"""The bench harness always prints its one JSON line, and fails LOUDLY:
 
-These tests pin the harness's degradation contract without any real device:
-- backend-init failure → one JSON line with an `error` field, rc 0;
-- any single config raising → structured per-config error, others intact;
-- flagship failure → JSON still printed, `value: null` + `error`.
+- backend-init failure → one JSON line with an `error` field, exit code 1;
+- any single non-flagship config raising → structured per-config error,
+  others intact, exit code 0;
+- flagship failure → JSON still printed, `value: null` + `error`, exit
+  code 1.
 """
 import importlib.util
 import json
 import os
 import sys
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(autouse=True)
+def _no_cache_placement(monkeypatch):
+    """bench.main() points the compile cache at <repo>/.jax_cache before
+    its first compile; a test process must not be repointed."""
+    from paddle_tpu.framework import flags
+    monkeypatch.setattr(flags, "place_caches", lambda checkout: None)
 
 
 def _load_bench():
@@ -22,8 +32,8 @@ def _load_bench():
     return mod
 
 
-def _run_main(bench, capsys):
-    bench.main()
+def _run_main(bench, capsys, rc=0):
+    assert bench.main() == rc
     out = capsys.readouterr().out.strip().splitlines()
     assert len(out) == 1, f"bench must print exactly ONE line, got {out}"
     return json.loads(out[0])
@@ -33,7 +43,7 @@ def test_backend_init_failure_emits_error_json(capsys, monkeypatch):
     bench = _load_bench()
     monkeypatch.setattr(bench, "_init_backend_with_retry",
                         lambda: "RuntimeError: TPU is wedged")
-    rec = _run_main(bench, capsys)
+    rec = _run_main(bench, capsys, rc=1)
     assert "TPU is wedged" in rec["error"]
     assert rec["value"] is None
     assert rec["metric"]  # schema intact for the driver
@@ -72,13 +82,14 @@ def test_flagship_failure_still_prints_json(capsys, monkeypatch):
         monkeypatch.setattr(
             bench, name,
             lambda: (_ for _ in ()).throw(RuntimeError("all dead")))
-    rec = _run_main(bench, capsys)
+    rec = _run_main(bench, capsys, rc=1)
     assert rec["value"] is None
     assert "flagship" in rec["error"]
     assert "all dead" in rec["configs"]["gpt2_small"]["error"]
 
 
-def test_bench_json_includes_observability_snapshot(capsys, monkeypatch):
+def test_bench_json_includes_observability_snapshot(capsys, monkeypatch,
+                                                   peaks_row_for_this_device):
     """PR 2: the bench line must carry the metrics snapshot + retrace
     summary + schema-valid step records under `observability`."""
     from paddle_tpu.profiler.monitor import (make_step_record,
@@ -119,7 +130,7 @@ def test_bench_json_includes_observability_snapshot(capsys, monkeypatch):
         validate_event(ev)
 
 
-def test_run_config_emits_step_record(monkeypatch):
+def test_run_config_emits_step_record(monkeypatch, peaks_row_for_this_device):
     """bench._run_config appends a schema-valid step record per timed run
     (exercised with a stub compiled step — no device needed)."""
     from paddle_tpu.profiler.monitor import validate_step_record
@@ -183,7 +194,8 @@ def test_import_paddle_tpu_does_not_init_backend():
     assert r.returncode == 0 and "LAZY_OK" in r.stdout, r.stderr[-2000:]
 
 
-def test_profile_steps_captures_compiled_run(monkeypatch, tmp_path):
+def test_profile_steps_captures_compiled_run(monkeypatch, tmp_path,
+                                             peaks_row_for_this_device):
     """--profile-steps: _run_config with a profile label runs a bounded
     xplane capture of the compiled step and records a measured-vs-estimate
     result under _PROFILE_RESULTS (stub executable, CPU-fast)."""
@@ -249,7 +261,8 @@ def test_main_rejects_unknown_args_only_from_cli():
         sys.argv = old
 
 
-def test_device_time_probe_xplane_mode(monkeypatch, tmp_path):
+def test_device_time_probe_xplane_mode(monkeypatch, tmp_path,
+                                       peaks_row_for_this_device):
     """With --profile-steps set, the bench's eager device-time probe runs
     inside a capture session: rows carry src="xplane" and the correlation
     block reports the measured-vs-estimate delta per op."""

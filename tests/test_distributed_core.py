@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from paddle_tpu._jax_compat import shard_map
+from jax import shard_map
 
 import paddle_tpu as paddle
 import paddle_tpu.distributed as dist

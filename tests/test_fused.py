@@ -295,7 +295,7 @@ class TestPallasFlashAttention:
             fa.flash_attention(q, k, v, causal=True) ** 2).sum(),
             argnums=(0, 1, 2))(q, k, v)
         assert fa._stats["pallas"] > before["pallas"], fa._stats
-        assert not fa._use_small_path(640, 640, 2, 64)
+        assert not fa._use_small_path(640, 640, 2, 64, jnp.float32)
         gx = jax.grad(lambda q, k, v: (
             fa.flash_attention_xla(q, k, v, causal=True) ** 2).sum(),
             argnums=(0, 1, 2))(q, k, v)

@@ -25,10 +25,8 @@ def interpret_mode():
     """Run the Pallas kernels in the interpreter (kernel path on CPU)."""
     old = fb._INTERPRET
     fb._INTERPRET = True
-    fb._probe_status.clear()
     yield
     fb._INTERPRET = old
-    fb._probe_status.clear()
 
 
 def _ref(x, z, g, b, act="relu"):

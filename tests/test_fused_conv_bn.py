@@ -30,13 +30,9 @@ def interpret_mode(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_AUTOTUNE", "0")
     old_f, old_b = fcb._INTERPRET, fb._INTERPRET
     fcb._INTERPRET = fb._INTERPRET = True
-    fcb._probe_status.clear()
-    fb._probe_status.clear()
     autotune.reset_for_tests()
     yield
     fcb._INTERPRET, fb._INTERPRET = old_f, old_b
-    fcb._probe_status.clear()
-    fb._probe_status.clear()
     autotune.reset_for_tests()
 
 

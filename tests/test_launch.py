@@ -116,3 +116,15 @@ class TestSpawn:
         assert r.returncode == 0, r.stderr
         assert (tmp_path / "spawn.0").exists()
         assert (tmp_path / "spawn.1").exists()
+
+
+def test_nproc_gt1_refused_unless_cpu_simulation(monkeypatch):
+    """A chip belongs to one process: several workers per TPU host would
+    each open every chip (libtpu reads no *_VISIBLE_DEVICES variable)."""
+    from paddle_tpu.distributed.launch.main import Pod, parse_args
+    args = parse_args(["--nproc_per_node", "2", "worker.py"])
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(ValueError, match="ONE controller"):
+        Pod(args)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert Pod(args).world_size == 2

@@ -237,7 +237,7 @@ class TestRelaunchAndCompileAttribution:
 
 
 class TestDeviceTimeAttribution:
-    def test_spans_carry_estimate_split(self):
+    def test_spans_carry_estimate_split(self, peaks_row_for_this_device):
         from paddle_tpu.profiler.recorder import get_recorder
         rec = get_recorder()
         rec.clear()
@@ -253,7 +253,7 @@ class TestDeviceTimeAttribution:
         s = spans[-1]
         assert s.device_ns is not None and s.device_ns > 0
         assert s.device_src == "estimate"
-        # roofline sanity: 2*64^3 flops at the CPU peak
+        # roofline sanity: 2*64^3 flops at the table row's peak
         assert s.device_ns >= device_time.estimate_ns(2 * 64 ** 3, 0)
 
     def test_sync_mode_measures(self, monkeypatch):
@@ -283,7 +283,7 @@ class TestDeviceTimeAttribution:
             [HostSpan(name="op_a", start_ns=0, end_ns=1000, tid=1)]))
         assert "Dev(ms)" not in plain
 
-    def test_chrome_export_includes_device_args(self, tmp_path):
+    def test_chrome_export_includes_device_args(self, tmp_path, peaks_row_for_this_device):
         from paddle_tpu import profiler as prof_mod
         p = prof_mod.Profiler()
         with p:
@@ -296,7 +296,7 @@ class TestDeviceTimeAttribution:
         assert ops
         assert ops[0]["args"]["device_src"] in ("estimate", "measured")
 
-    def test_bench_device_probe_shape(self):
+    def test_bench_device_probe_shape(self, peaks_row_for_this_device):
         import bench
         probe = bench._device_time_probe()
         assert probe["mode"] == "estimate"
@@ -490,6 +490,14 @@ class TestSupervisorRole:
             assert status == 200
             # no master env -> process-local only, no crash
             assert s.aggregator is None
+            # a scrape must not make the supervisor open the chip its
+            # trainer child needs: /snapshot never samples device memory
+            calls = []
+            monkeypatch.setattr(
+                server_mod._metrics_mod, "update_device_memory_gauges",
+                lambda reg=None: calls.append(1) or {})
+            status, _, _ = _get(s.port, "/snapshot")
+            assert status == 200 and not calls
         finally:
             server_mod.stop_server()
 

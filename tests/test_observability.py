@@ -366,7 +366,7 @@ class TestCollectiveMetrics:
         """An all_reduce on a TRACER (inside shard_map/pjit) must NOT hit
         the counters — it executes per compiled run, not per Python call,
         so counting the trace would be meaningless."""
-        from paddle_tpu._jax_compat import shard_map
+        from jax import shard_map
         reg = metrics.default_registry()
         before = reg.counter("collective_calls_total").total()
 

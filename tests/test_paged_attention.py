@@ -54,7 +54,10 @@ def _rand_pool(rng, B, H, D, page_size, num_pages, pages_per_seq):
 
 
 class TestKernelParity:
-    def test_pallas_matches_dense_reference(self, interp):
+    def test_pallas_matches_dense_reference(self, interp, monkeypatch):
+        # H=12 has one legal head block, so the XLA gather would be the
+        # second candidate timed — and the interpreter always loses to it
+        monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_MAX_CONFIGS", "1")
         rng = np.random.default_rng(0)
         q, kp, vp, bt = _rand_pool(rng, 3, 12, 64, 8, 10, 4)
         cl = jnp.asarray(np.array([13, 5, 32], np.int32))
@@ -96,12 +99,13 @@ class TestKernelParity:
         """Every heads candidate regroups grid programs only — outputs
         are identical across head-block choices."""
         rng = np.random.default_rng(3)
-        q, kp, vp, bt = _rand_pool(rng, 2, 8, 64, 8, 8, 3)
+        q, kp, vp, bt = _rand_pool(rng, 2, 16, 64, 8, 8, 3)
         cl = jnp.asarray(np.array([20, 9], np.int32))
         outs = [
             np.asarray(pa._paged_attn_pallas(q, kp, vp, bt, cl,
                                              1.0 / 8.0, bh, interpret=True))
-            for bh in (2, 4, 8)]
+            for bh in pa._head_candidates(16)]
+        assert len(outs) == 2
         for o in outs[1:]:
             np.testing.assert_array_equal(outs[0], o)
 
@@ -195,13 +199,14 @@ class TestAutotunePagedAttn:
 
         monkeypatch.setattr(autotune, "get_config", spy)
         rng = np.random.default_rng(5)
-        q, kp, vp, bt = _rand_pool(rng, 1, 8, 64, 8, 4, 2)
+        q, kp, vp, bt = _rand_pool(rng, 1, 16, 64, 8, 4, 2)
         pa.paged_attention(q, kp, vp, bt, jnp.asarray(np.array([9],
                                                               np.int32)))
         impls = {c["impl"] for c in seen["cands"]}
         assert impls == {0, 1}
         heads = {c["heads"] for c in seen["cands"] if c["impl"] == 1}
-        assert 8 in heads and len(heads) > 1
+        assert heads == {8, 16}  # multiples of 8 or whole-H: Mosaic's rule
+        assert pa._head_candidates(12) == [12]
 
     def test_tuned_log_names_the_op(self, interp):
         rng = np.random.default_rng(6)

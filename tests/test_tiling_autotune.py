@@ -416,9 +416,7 @@ class TestTunedDispatch:
         # shrink the small-path crossover so a CI-sized seq takes the
         # GRID path (the one with tunable blocks)
         monkeypatch.setattr(fa, "_SMALL_MAX_L", 64)
-        fa._pallas_fa_status.clear()
         yield
-        fa._pallas_fa_status.clear()
 
     def test_flash_dispatch_tunes_then_matches_static(
             self, tuner, monkeypatch, fa_interpret):
@@ -439,7 +437,6 @@ class TestTunedDispatch:
         # kill switch: same dispatch, static picks — numerics must agree
         monkeypatch.setenv("PADDLE_TPU_AUTOTUNE", "0")
         autotune.reset_for_tests()
-        fa._pallas_fa_status.clear()
         p1 = fa._stats["pallas"]
         out_static = fa.flash_attention(q, k, v, causal=True)
         assert fa._stats["pallas"] == p1 + 1
@@ -451,7 +448,6 @@ class TestTunedDispatch:
             self, tuner, monkeypatch):
         monkeypatch.setattr(sce, "_INTERPRET", True)
         monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_MAX_CONFIGS", "2")
-        sce._status.clear()
         rng = np.random.default_rng(5)
         N, V = 64, 4096
         lg = jnp.asarray(rng.normal(size=(N, V)).astype("float32"))
@@ -461,12 +457,10 @@ class TestTunedDispatch:
         assert autotune._M_TUNES.value(op="softmax_ce") >= 1
         monkeypatch.setenv("PADDLE_TPU_AUTOTUNE", "0")
         autotune.reset_for_tests()
-        sce._status.clear()
         nll_static = sce.fused_softmax_ce(lg, lb)
         np.testing.assert_allclose(np.asarray(nll_tuned),
                                    np.asarray(nll_static),
                                    rtol=1e-6, atol=1e-6)
-        sce._status.clear()
 
     def test_layer_norm_resolver_static_when_not_forced(self, monkeypatch):
         # default mode on CPU: resolver returns the static pick and the
@@ -474,7 +468,6 @@ class TestTunedDispatch:
         monkeypatch.setattr(ln, "_INTERPRET", True)
         monkeypatch.delenv("PADDLE_TPU_AUTOTUNE", raising=False)
         autotune.reset_for_tests()
-        ln._pallas_ln_status.clear()
         rng = np.random.default_rng(6)
         x = jnp.asarray(rng.normal(size=(256, 128)).astype("float32"))
         g = jnp.asarray(rng.normal(size=(128,)).astype("float32"))
@@ -489,13 +482,11 @@ class TestTunedDispatch:
             np.asarray(b)
         np.testing.assert_allclose(np.asarray(y), ref, rtol=1e-4,
                                    atol=1e-4)
-        ln._pallas_ln_status.clear()
         autotune.reset_for_tests()
 
     def test_fused_bn_tuned_path_matches_static(self, tuner, monkeypatch):
         monkeypatch.setattr(fb, "_INTERPRET", True)
         monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_MAX_CONFIGS", "2")
-        fb._probe_status.clear()
         rng = np.random.default_rng(7)
         x = jnp.asarray(rng.normal(size=(2, 16, 8, 128)).astype("float32"))
         g = jnp.asarray(rng.normal(size=(128,)).astype("float32"))
@@ -506,12 +497,10 @@ class TestTunedDispatch:
         assert autotune._M_TUNES.value(op="fused_bn") >= 1
         monkeypatch.setenv("PADDLE_TPU_AUTOTUNE", "0")
         autotune.reset_for_tests()
-        fb._probe_status.clear()
         y_static, m2, v2 = fb.fused_bn_relu(x, g, b, data_format="NHWC")
         # row-block regrouping only: the fused fwd is bit-compatible
         assert np.array_equal(np.asarray(y_tuned), np.asarray(y_static))
         assert np.array_equal(np.asarray(m1), np.asarray(m2))
-        fb._probe_status.clear()
 
 
 _CONV_BN_CHILD = """

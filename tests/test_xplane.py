@@ -280,38 +280,27 @@ class TestProfileCapture:
         assert "train_step" in summary["summary_table"]
 
 
-class TestPeaksCacheRegression:
-    def test_platform_peaks_follow_env_changes(self, monkeypatch):
-        """Satellite regression: _peaks_cache was computed once per
-        process, so changing BENCH_PEAK_FLOPS / PADDLE_TPU_PEAK_HBM_GBS
-        mid-process silently kept the old peaks."""
-        monkeypatch.setattr(device_time, "_platform", lambda: "tpu")
-        device_time.reset_peaks()
-        try:
-            monkeypatch.setenv("BENCH_PEAK_FLOPS", "100e12")
-            monkeypatch.setenv("PADDLE_TPU_PEAK_HBM_GBS", "500")
-            plat, flops, bw = device_time.platform_peaks()
-            assert flops == 100e12 and bw == 500e9
-            monkeypatch.setenv("BENCH_PEAK_FLOPS", "200e12")
-            _, flops2, _ = device_time.platform_peaks()
-            assert flops2 == 200e12, "stale peaks served after env change"
-            monkeypatch.delenv("BENCH_PEAK_FLOPS")
-            monkeypatch.delenv("PADDLE_TPU_PEAK_HBM_GBS")
-            _, flops3, bw3 = device_time.platform_peaks()
-            assert flops3 == 197e12 and bw3 == 819e9
-        finally:
-            device_time.reset_peaks()
+class TestPeaksTable:
+    def test_known_kind_has_a_sourced_row(self):
+        row = device_time.device_peaks("TPU v5 lite")
+        assert row.bf16_flops == 197e12 and row.hbm_bytes_per_s == 819e9
+        assert "Google Cloud" in row.source
 
-    def test_reset_peaks_reprobes_platform(self, monkeypatch):
+    def test_unknown_kind_raises_and_estimates_nothing(self):
+        """No default peak: a device off the table gets no roofline, and
+        the per-op estimator attributes nothing rather than inventing
+        CPU "device time"."""
         device_time.reset_peaks()
-        monkeypatch.setattr(device_time, "_platform", lambda: "cpu")
-        assert device_time.platform_peaks()[0] == "cpu"
-        monkeypatch.setattr(device_time, "_platform", lambda: "tpu")
-        # cached platform survives env-key-identical calls...
-        assert device_time.platform_peaks()[0] == "cpu"
-        device_time.reset_peaks()  # ...until an explicit reset
-        assert device_time.platform_peaks()[0] == "tpu"
-        device_time.reset_peaks()
+        with pytest.raises(device_time.UnknownDeviceError, match="cpu"):
+            device_time.device_peaks()
+        with pytest.raises(device_time.UnknownDeviceError):
+            device_time.platform_peaks()
+        assert device_time.attribute([], 1e9, 1e6, 0) == (None, None)
+
+    def test_reset_peaks_reprobes(self, peaks_row_for_this_device):
+        plat, flops, bw = device_time.platform_peaks()
+        assert (plat, flops, bw) == ("cpu", 100e9, 20e9)
+        assert device_time.attribute([], 1e9, 0, 0) == (int(1e7), "estimate")
 
 
 class TestCompileCacheWiring:

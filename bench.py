@@ -236,6 +236,7 @@ def _device_time_probe():
     import paddle_tpu as paddle
     from paddle_tpu.profiler import device_time
     from paddle_tpu.profiler.recorder import get_recorder
+    from paddle_tpu.profiler.utils import RecordEvent
 
     rng = np.random.default_rng(0)
     a = paddle.to_tensor(rng.normal(size=(256, 256)).astype("float32"))
@@ -243,9 +244,13 @@ def _device_time_probe():
 
     def run_ops():
         for _ in range(3):  # first pass compiles; later passes are steady
-            c = paddle.matmul(a, b)
-            d = paddle.nn.functional.softmax(c)
-            (d + c).mean()
+            # one span to a pass, closed once the result is back on the
+            # host: its device work lies inside it, which asynchronous
+            # dispatch does not promise for the per-op spans
+            with RecordEvent("probe_pass"):
+                c = paddle.matmul(a, b)
+                d = paddle.nn.functional.softmax(c)
+                (d + c).mean().numpy()
 
     correlation = None
     if _PROFILE_STEPS > 0:

@@ -79,11 +79,21 @@ from ..profiler import events as _events
 from ..profiler import metrics as _metrics
 from ..profiler import reqtrace as _reqtrace
 from ..profiler import slo as _slo
+from ..profiler.utils import SPAN_PREFIX, RecordEvent
 from ..utils.envparse import env_float, env_int
 from .sampling import SamplingParams, sample_logits
 
 __all__ = ["Request", "PageAllocator", "SamplingParams", "ServingEngine",
            "EngineSuspended", "current_engine", "live_engines"]
+
+
+def _span(name: str, **args) -> RecordEvent:
+    """A span of the engine, `pt.engine.<name>`: in any profiler trace
+    being taken (and the host recorder while that is on). The names and
+    arguments are an interface: `tests/test_program_spans.py` pins them,
+    the benchmark's per-layer metrics read them (PERF.md section 3)."""
+    return RecordEvent(SPAN_PREFIX + "engine." + name, **args)
+
 
 #: live engines, newest last — how the ObservabilityServer's /requests,
 #: /slo and /generate endpoints find the engine without plumbing a
@@ -824,23 +834,27 @@ class ServingEngine:
                sampling: Optional[SamplingParams] = None) -> Request:
         req = self.make_request(prompt, max_new_tokens, eos_id,
                                 sampling=sampling)
-        with self._lock:
-            # re-check under the lock: a close() racing this submit has
-            # already drained the queue, and a request appended after
-            # that drain would never complete (result() hangs forever)
-            if self._closed:
-                raise RuntimeError("engine is closed")
-            if self.queue_limit is not None \
-                    and len(self._queue) >= self.queue_limit:
-                # controller shed: sustained SLO breach capped the queue
-                raise RuntimeError(
-                    f"queue at shed cap ({self.queue_limit}); "
-                    f"engine {self.name!r} is shedding load")
-            self._queue.append(req)
-            depth = len(self._queue)
-        req.trace_id = self.tracer.submit(req.rid)
-        if _metrics.enabled():
-            _M_QUEUE.set(depth, model=self.name)
+        with _span("submit", rid=req.rid, prompt_tokens=len(req.prompt),
+                   queue_depth=len(self._queue)):
+            with self._lock:
+                # re-check under the lock: a close() racing this submit
+                # has already drained the queue, and a request appended
+                # after that drain would never complete (result() hangs
+                # forever)
+                if self._closed:
+                    raise RuntimeError("engine is closed")
+                if self.queue_limit is not None \
+                        and len(self._queue) >= self.queue_limit:
+                    # controller shed: sustained SLO breach capped the
+                    # queue
+                    raise RuntimeError(
+                        f"queue at shed cap ({self.queue_limit}); "
+                        f"engine {self.name!r} is shedding load")
+                self._queue.append(req)
+                depth = len(self._queue)
+            req.trace_id = self.tracer.submit(req.rid)
+            if _metrics.enabled():
+                _M_QUEUE.set(depth, model=self.name)
         return req
 
     def queue_depth(self) -> int:
@@ -863,6 +877,10 @@ class ServingEngine:
         shared page about to be written (copy-on-write), preempting the
         youngest on pool exhaustion, then one fused decode dispatch.
         Returns the number of tokens generated (0 = engine idle)."""
+        with _span("step", iteration=self.stats["iterations"]):
+            return self._iterate()
+
+    def _iterate(self) -> int:
         # chaos: an armed `serving.wedge=N:delay` stalls the loop HERE,
         # before any progress is made — `wedged()` flips once the stall
         # outlives the liveness window (the watchdog-restart drill)
@@ -877,20 +895,21 @@ class ServingEngine:
             self._apply_pending_swap()
         if self.handoff_source is not None:
             self._drain_handoff_source()
-        self._admit()
+        with _span("admit"):
+            self._admit()
         active_slots = [i for i, r in enumerate(self._slots)
                         if r is not None]
         if _metrics.enabled():
             _M_OCC.set(len(active_slots), model=self.name)
         if not active_slots:
             return 0
-        self._ensure_capacity(active_slots)
+        with _span("capacity", active=len(active_slots)):
+            self._ensure_capacity(active_slots)
         active_slots = [i for i, r in enumerate(self._slots)
                         if r is not None]  # capacity may have preempted
         if not active_slots:
             return 0
         produced = self._decode_iteration(active_slots)
-        self._note_introspection(len(active_slots))
         self._last_progress = time.monotonic()
         return produced
 
@@ -1316,7 +1335,6 @@ class ServingEngine:
         resident (prefix cache hit) FORKS the matching pages instead of
         allocating + recomputing them; prefill then skips the K/V
         scatter below the shared length."""
-        import jax.numpy as jnp
         while True:
             with self._lock:
                 if not self._queue:
@@ -1344,67 +1362,84 @@ class ServingEngine:
                 req.shared_tokens = shared_len
                 self._slots[slot] = req
                 depth = len(self._queue)
-            if shared_len:
-                self.stats["shared_admissions"] += 1
-                self.stats["prefix_hit_tokens"] += shared_len
-            self._note_pool_watermark()
-            bucket = self._bucket_for(len(tokens))
-            requeue = req.preemptions > 0
+            # stamped right after the pop: queue wait ends here, and the
+            # prefill span below opens on the same instant
             if req.admitted_ts is None:
                 req.admitted_ts = time.monotonic()
                 self.slo.observe("queue_wait",
                                  req.admitted_ts - req.submitted_ts)
-            self.tracer.admitted(req.rid, bucket=bucket,
-                                 prompt_tokens=len(tokens),
-                                 shared_tokens=shared_len,
-                                 requeue=requeue)
+            bucket = self._bucket_for(len(tokens))
+            requeue = req.preemptions > 0
+            with _span("prefill", rid=req.rid, trace_id=req.trace_id or 0,
+                       bucket=bucket, prompt_tokens=len(tokens),
+                       shared_tokens=shared_len, requeue=int(requeue),
+                       queue_wait_us=int(
+                           1e6 * (req.admitted_ts - req.submitted_ts))):
+                self._prefill(req, slot, tokens, pages, shared_len, bucket,
+                              requeue)
+            if _metrics.enabled():
+                _M_QUEUE.set(depth, model=self.name)
+            if req.state != "running":
+                continue  # single-token request finished at prefill
+            self._cur_tokens[slot] = req.generated[-1]
+
+    def _prefill(self, req: Request, slot: int, tokens: List[int],
+                 pages: List[int], shared_len: int, bucket: int,
+                 requeue: bool):
+        """One admission past the queue: block-table row and padded ids
+        on the host, the bucketed prefill program, the first token."""
+        import jax.numpy as jnp
+        if shared_len:
+            self.stats["shared_admissions"] += 1
+            self.stats["prefix_hit_tokens"] += shared_len
+        self._note_pool_watermark()
+        self.tracer.admitted(req.rid, bucket=bucket,
+                             prompt_tokens=len(tokens),
+                             shared_tokens=shared_len,
+                             requeue=requeue)
+        with _span("prefill.build"):
             bt = self.cache.block_tables
             row = np.zeros((self.cache.pages_per_seq,), np.int32)
             row[:len(pages)] = pages
             self.cache.block_tables = bt.at[slot].set(jnp.asarray(row))
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :len(tokens)] = tokens
-            self._observe_site(f"prefill:{self.name}", [ids])
-            from ..profiler import compile_watch as _cw
-            prev = _cw.push_entry("to_static",
-                                  f"serving_prefill:{self.name}")
-            sp = req.sampling
-            try:
-                # dispatch lock: a concurrent canary evaluation rebinds
-                # the model's parameter state while it traces — never
-                # interleave that with a prefill/decode trace
-                with self._dispatch_lock:
-                    nxt, self.cache = self._prefill_jit(
-                        self._params, self._buffers, self.cache,
-                        jnp.asarray(ids), np.int32(slot),
-                        np.int32(len(tokens)), np.int32(shared_len),
-                        jnp.full((1,), sp.temperature, jnp.float32),
-                        jnp.full((1,), sp.top_k, jnp.int32),
-                        jnp.full((1,), sp.top_p, jnp.float32),
-                        jnp.full((1,), req.seed, jnp.int32),
-                        jnp.full((1,), len(req.generated), jnp.int32))
-            finally:
-                _cw.pop_entry(prev)
-            self.stats["prefills"] += 1
-            if self.share_prefix:
-                self._prefix.register(tokens, pages)
+        self._observe_site(f"prefill:{self.name}", [ids])
+        from ..profiler import compile_watch as _cw
+        prev = _cw.push_entry("to_static", f"serving_prefill:{self.name}")
+        sp = req.sampling
+        try:
+            # dispatch lock: a concurrent canary evaluation rebinds the
+            # model's parameter state while it traces — never interleave
+            # that with a prefill/decode trace
+            with _span("prefill.dispatch"), self._dispatch_lock:
+                nxt, self.cache = self._prefill_jit(
+                    self._params, self._buffers, self.cache,
+                    jnp.asarray(ids), np.int32(slot),
+                    np.int32(len(tokens)), np.int32(shared_len),
+                    jnp.full((1,), sp.temperature, jnp.float32),
+                    jnp.full((1,), sp.top_k, jnp.int32),
+                    jnp.full((1,), sp.top_p, jnp.float32),
+                    jnp.full((1,), req.seed, jnp.int32),
+                    jnp.full((1,), len(req.generated), jnp.int32))
+        finally:
+            _cw.pop_entry(prev)
+        self.stats["prefills"] += 1
+        if self.share_prefix:
+            self._prefix.register(tokens, pages)
+        with _span("prefill.fetch"):
             tok = int(np.asarray(nxt)[0])
-            self.tracer.prefill_done(req.rid)
-            now = time.monotonic()
-            if req.first_token_ts is None:
-                req.first_token_ts = now
-                if _metrics.enabled() and req.ttft_s is not None:
-                    _M_TTFT.observe(req.ttft_s, model=self.name,
-                                    path=self.decode_mode)
-                if req.ttft_s is not None:
-                    self.slo.observe("ttft", req.ttft_s)
-            self._emit_admission(req, bucket, len(tokens))
-            self._record_token(req, tok)
-            if _metrics.enabled():
-                _M_QUEUE.set(depth, model=self.name)
-            if req.state != "running":
-                continue  # single-token request finished at prefill
-            self._cur_tokens[slot] = tok
+        self.tracer.prefill_done(req.rid)
+        now = time.monotonic()
+        if req.first_token_ts is None:
+            req.first_token_ts = now
+            if _metrics.enabled() and req.ttft_s is not None:
+                _M_TTFT.observe(req.ttft_s, model=self.name,
+                                path=self.decode_mode)
+            if req.ttft_s is not None:
+                self.slo.observe("ttft", req.ttft_s)
+        self._emit_admission(req, bucket, len(tokens))
+        self._record_token(req, tok)
 
     def _alloc_one_or_preempt(self, req: Request) -> Optional[int]:
         """One fresh page for `req`, preempting the youngest runner on a
@@ -1520,21 +1555,25 @@ class ServingEngine:
             _fault_site("serving.decode")
         except Exception:
             pass  # only delay/no-op kinds make sense here; ignore others
-        (W, tokens, slot_map, lane_active, temp, top_k, top_p, seeds,
-         steps) = self._lane_arrays(active_slots)
+        with _span("lanes", lanes=self._decode_bucket(len(active_slots)),
+                   active=len(active_slots)):
+            (W, tokens, slot_map, lane_active, temp, top_k, top_p, seeds,
+             steps) = self._lane_arrays(active_slots)
         # per-bucket watchdog site: ONE signature per lane width is the
         # zero-retrace steady-state contract
         self._observe_site(f"decode:{self.name}:w{W}", [tokens])
         from ..profiler import compile_watch as _cw
         prev = _cw.push_entry("to_static", f"serving_decode:{self.name}")
         t0 = time.perf_counter()
-        args = (self._params, self._buffers, self.cache,
-                jnp.asarray(tokens), jnp.asarray(slot_map),
-                jnp.asarray(lane_active), jnp.asarray(temp),
-                jnp.asarray(top_k), jnp.asarray(top_p),
-                jnp.asarray(seeds), jnp.asarray(steps))
+        with _span("upload"):
+            args = (self._params, self._buffers, self.cache,
+                    jnp.asarray(tokens), jnp.asarray(slot_map),
+                    jnp.asarray(lane_active), jnp.asarray(temp),
+                    jnp.asarray(top_k), jnp.asarray(top_p),
+                    jnp.asarray(seeds), jnp.asarray(steps))
         try:
-            with self._dispatch_lock:  # see _admit: canary serialization
+            # see _prefill: canary serialization
+            with _span("dispatch"), self._dispatch_lock:
                 if self.decode_mode == "fused":
                     nxt, self.cache = self._fused_jit(*args)
                 else:
@@ -1542,27 +1581,30 @@ class ServingEngine:
                     nxt, self.cache = self._fused_step_fn(*args)
         finally:
             _cw.pop_entry(prev)
-        nxt_np = np.asarray(nxt)  # device sync: the iteration boundary
+        with _span("fetch"):
+            nxt_np = np.asarray(nxt)  # device sync: the iteration boundary
         self.stats["decode_wall_s"] += time.perf_counter() - t0
         self.stats["iterations"] += 1
-        produced = 0
-        for i, slot in enumerate(active_slots[:W]):
-            req = self._slots[slot]
-            if req is None:
-                continue
-            tok = int(nxt_np[i])
-            self.tracer.decode_iteration(req.rid, bucket=W,
-                                         path=self.decode_mode)
-            self._record_token(req, tok)
-            produced += 1
-            if req.state == "running":
-                self._cur_tokens[slot] = tok
-        self.stats["decode_tokens"] += produced
-        if _metrics.enabled():
-            # re-publish occupancy AFTER completions so a drained batch
-            # reads 0 even when no further step() runs
-            _M_OCC.set(sum(r is not None for r in self._slots),
-                       model=self.name)
+        with _span("bookkeep", lanes=W):
+            produced = 0
+            for i, slot in enumerate(active_slots[:W]):
+                req = self._slots[slot]
+                if req is None:
+                    continue
+                tok = int(nxt_np[i])
+                self.tracer.decode_iteration(req.rid, bucket=W,
+                                             path=self.decode_mode)
+                self._record_token(req, tok)
+                produced += 1
+                if req.state == "running":
+                    self._cur_tokens[slot] = tok
+            self.stats["decode_tokens"] += produced
+            if _metrics.enabled():
+                # re-publish occupancy AFTER completions so a drained
+                # batch reads 0 even when no further step() runs
+                _M_OCC.set(sum(r is not None for r in self._slots),
+                           model=self.name)
+            self._note_introspection(len(active_slots))
         return produced
 
     def _record_token(self, req: Request, tok: int):

@@ -29,6 +29,7 @@ from ..framework import tape as tape_mod
 from ..framework.tensor import Tensor
 from ..nn.layer import Layer
 from ..profiler import compile_watch as _compile_watch
+from ..profiler.utils import SPAN_PREFIX, RecordEvent
 from ..profiler.watchdog import get_watchdog as _get_watchdog
 
 
@@ -484,40 +485,48 @@ class TrainStep:
             name=self._wd_name, entry="train_step", emit=emit)
 
     def __call__(self, *batch):
+        """One optimizer step. Spans (an interface: pinned by
+        `tests/test_program_spans.py`, read by the benchmark's per-layer
+        metrics): `pt.train.call` around all of it, `pt.train.prepare`
+        for what the host does before the program is called,
+        `pt.train.dispatch` for that call, `pt.train.health` when the
+        probe's vector is fetched."""
         self._t += 1
-        rng = random_mod.default_generator().split()
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        arrs = _tree_to_arrays(batch)
-        # a new batch signature recompiles the WHOLE fused step — the most
-        # expensive retrace in the system; always worth an event
-        _get_watchdog().observe("train_step", self._wd_name,
-                                jax.tree_util.tree_leaves(arrs))
-        if _analysis_enabled("train_step"):
-            from .. import analysis
-            # batch args stay UNflattened: the audit must trace the same
-            # signature the real self._step(..., *arrs) call compiles
-            analysis.maybe_audit(
-                "train_step", self._wd_name, self._step_raw,
-                (self.params, self.buffers, self.opt_state,
-                 jax.random.PRNGKey(0), lr, self._t) + tuple(arrs),
-                donate_argnums=self._donate_argnums)
+        with RecordEvent(SPAN_PREFIX + "train.call", t=self._t):
+            return self._call(batch)
+
+    def _call(self, batch):
+        with RecordEvent(SPAN_PREFIX + "train.prepare"):
+            rng = random_mod.default_generator().split()
+            lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
+            arrs = _tree_to_arrays(batch)
+            # a new batch signature recompiles the WHOLE fused step — the
+            # most expensive retrace in the system; always worth an event
+            _get_watchdog().observe("train_step", self._wd_name,
+                                    jax.tree_util.tree_leaves(arrs))
+            if _analysis_enabled("train_step"):
+                from .. import analysis
+                # batch args stay UNflattened: the audit must trace the
+                # same signature the real self._step(..., *arrs) call
+                # compiles
+                analysis.maybe_audit(
+                    "train_step", self._wd_name, self._step_raw,
+                    (self.params, self.buffers, self.opt_state,
+                     jax.random.PRNGKey(0), lr, self._t) + tuple(arrs),
+                    donate_argnums=self._donate_argnums)
         _cw_prev = _compile_watch.push_entry("train_step", self._wd_name)
         try:
-            if self._health_probe is None:
-                loss, self.params, self.buffers, self.opt_state = self._step(
-                    self.params, self.buffers, self.opt_state, rng, lr,
-                    self._t, *arrs)
-            else:
-                (loss, self.params, self.buffers, self.opt_state,
-                 hvec) = self._step(
-                    self.params, self.buffers, self.opt_state, rng, lr,
-                    self._t, *arrs)
+            with RecordEvent(SPAN_PREFIX + "train.dispatch"):
+                out = self._step(self.params, self.buffers, self.opt_state,
+                                 rng, lr, self._t, *arrs)
         finally:
             _compile_watch.pop_entry(_cw_prev)
+        loss, self.params, self.buffers, self.opt_state = out[:4]
         if self._health_probe is not None:
             self._last_batch = arrs
             if self._t % self._health_interval == 0:
-                self._note_health(hvec)
+                with RecordEvent(SPAN_PREFIX + "train.health"):
+                    self._note_health(out[4])
         return Tensor(loss)
 
     def _note_health(self, hvec):

@@ -265,7 +265,12 @@ def test_device_time_probe_xplane_mode(monkeypatch, tmp_path,
                                        peaks_row_for_this_device):
     """With --profile-steps set, the bench's eager device-time probe runs
     inside a capture session: rows carry src="xplane" and the correlation
-    block reports the measured-vs-estimate delta per op."""
+    block reports measured device time. What a capture promises is the
+    span that waits for its result (`probe_pass`): an eager op's own span
+    closes when the op is enqueued, and whether its work overlaps it is
+    up to asynchronous dispatch, so of the ops only the annotations are
+    asserted."""
+    from paddle_tpu.profiler import xplane
     bench = _load_bench()
     monkeypatch.setenv("PADDLE_TPU_PROFILE_DIR", str(tmp_path))
     monkeypatch.setattr(bench, "_PROFILE_STEPS", 1)
@@ -274,8 +279,12 @@ def test_device_time_probe_xplane_mode(monkeypatch, tmp_path,
     assert any(r["src"] == "xplane" for r in probe["rows"])
     assert probe["correlation"]["correlated"] >= 1
     by_op = {r["op"]: r for r in probe["correlation"]["by_op"]}
-    assert "matmul" in by_op
-    assert by_op["matmul"]["xplane_ms"] > 0
+    assert by_op["probe_pass"]["calls"] == 3
+    assert by_op["probe_pass"]["xplane_ms"] > 0
+    trace = xplane.load_trace(xplane.find_trace_file(
+        os.path.join(bench._profile_root(), "eager_probe")))
+    names = [e.get("name") for e in trace["traceEvents"]]
+    assert names.count("matmul") == 3 and names.count("probe_pass") == 3
 
 
 import pytest

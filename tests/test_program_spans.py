@@ -1,0 +1,324 @@
+"""The program's own spans (`pt.*`, profiler/utils.RecordEvent) inside
+`ServingEngine.step` and `TrainStep.__call__`, read back from real
+profiler captures (`jax.profiler.start_trace` + `ProfileData`) taken the
+way the benchmark takes its traced window. Names, nesting and arguments
+are an interface: `benchmark/program_trace.py` and the per-layer metrics
+in `BENCHMARK.json` read them (PERF.md section 3). Presence, nesting and
+counts only; durations are judged on the chip.
+"""
+import glob
+import os
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import jit as jit_mod
+from paddle_tpu import optimizer
+from paddle_tpu.inference import serving
+from paddle_tpu.models import GPT, GPTConfig
+from paddle_tpu.nn import functional as F
+from paddle_tpu.profiler.recorder import get_recorder
+from paddle_tpu.profiler.utils import SPAN_PREFIX, RecordEvent
+
+# span -> (the pt.* span it sits directly inside, its arguments)
+ENGINE_SPANS = {
+    "pt.engine.submit": (None, {"rid", "prompt_tokens", "queue_depth"}),
+    "pt.engine.step": (None, {"iteration"}),
+    "pt.engine.admit": ("pt.engine.step", set()),
+    "pt.engine.prefill": ("pt.engine.admit", {
+        "rid", "trace_id", "bucket", "prompt_tokens", "shared_tokens",
+        "requeue", "queue_wait_us"}),
+    "pt.engine.prefill.build": ("pt.engine.prefill", set()),
+    "pt.engine.prefill.dispatch": ("pt.engine.prefill", set()),
+    "pt.engine.prefill.fetch": ("pt.engine.prefill", set()),
+    "pt.engine.capacity": ("pt.engine.step", {"active"}),
+    "pt.engine.lanes": ("pt.engine.step", {"lanes", "active"}),
+    "pt.engine.upload": ("pt.engine.step", set()),
+    "pt.engine.dispatch": ("pt.engine.step", set()),
+    "pt.engine.fetch": ("pt.engine.step", set()),
+    "pt.engine.bookkeep": ("pt.engine.step", {"lanes"}),
+}
+TRAIN_SPANS = {
+    "pt.train.call": (None, {"t"}),
+    "pt.train.prepare": ("pt.train.call", set()),
+    "pt.train.dispatch": ("pt.train.call", set()),
+    "pt.train.health": ("pt.train.call", set()),
+}
+
+
+class _NoSpan:
+    """RecordEvent patched out: what the program does without its spans."""
+
+    def __init__(self, *a, **kw):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def capture(directory, fn):
+    """Run `fn` under a trace taken with the benchmark's options and
+    return (fn's result, every host event as a dict)."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(directory), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(directory), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    events = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            events.extend({"name": e.name, "line": line.name,
+                           "start": e.start_ns,
+                           "end": e.start_ns + e.duration_ns,
+                           "args": dict(e.stats)} for e in line.events)
+    return out, events
+
+
+def parent(span, events):
+    """The innermost `pt.*` event of the same thread that encloses `span`."""
+    around = [e for e in events
+              if e is not span and e["line"] == span["line"]
+              and e["name"].startswith(SPAN_PREFIX)
+              and e["start"] <= span["start"] and span["end"] <= e["end"]]
+    return max(around, key=lambda e: e["start"])["name"] if around else None
+
+
+def named(events, name):
+    return [e for e in events if e["name"] == name]
+
+
+def serve(directory=None):
+    """A tiny engine with more requests than lanes, warmed outside the
+    trace; returns what the traced (or untraced) round produced."""
+    paddle.seed(0)
+    model = GPT(GPTConfig.tiny())
+    model.eval()
+    eng = serving.ServingEngine(model, max_batch=2, max_len=64,
+                                page_size=8, eos_id=-1)
+    rng = np.random.default_rng(0)
+
+    def round_of(n):
+        reqs = [eng.submit(rng.integers(1, 1000, (5 + 3 * i,)).tolist(),
+                           max_new_tokens=3 + i % 2) for i in range(n)]
+        eng.run_until_idle()
+        return reqs
+
+    round_of(5)
+    before = dict(eng.stats)
+    if directory is None:
+        reqs, events = round_of(5), []
+    else:
+        reqs, events = capture(directory, lambda: round_of(5))
+    programs = {m.name for exe in jax.devices()[0].client.live_executables()
+                for m in exe.hlo_modules()}
+    delta = {k: eng.stats[k] - before[k]
+             for k in ("iterations", "prefills", "completed")}
+    eng.close()
+    return {"reqs": reqs, "events": events, "delta": delta,
+            "programs": programs}
+
+
+def train(directory=None, calls=3):
+    paddle.seed(0)
+    model = GPT(GPTConfig.tiny())
+    opt = optimizer.AdamW(parameters=model.parameters(), learning_rate=1e-3)
+    step = jit_mod.TrainStep(model, F.cross_entropy, opt, health=True)
+    rows = np.random.default_rng(0).integers(1, 1000, (calls + 1, 2, 17))
+
+    def call(i):
+        return float(step(paddle.to_tensor(rows[i][:, :-1]),
+                          paddle.to_tensor(rows[i][:, 1:])).data)
+
+    first = call(0)  # compiles, outside the trace
+    if directory is None:
+        return {"losses": [first] + [call(i + 1) for i in range(calls)],
+                "events": []}
+    losses, events = capture(
+        directory, lambda: [call(i + 1) for i in range(calls)])
+    return {"losses": [first] + losses, "events": events}
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    return serve(tmp_path_factory.mktemp("serve_trace"))
+
+
+@pytest.fixture(scope="module")
+def trained(tmp_path_factory):
+    return train(tmp_path_factory.mktemp("train_trace"))
+
+
+@pytest.mark.parametrize("name", sorted(ENGINE_SPANS))
+def test_engine_span_is_in_the_trace_nested_with_its_arguments(served, name):
+    inside, args = ENGINE_SPANS[name]
+    spans = named(served["events"], name)
+    assert spans, f"no {name} in the trace"
+    for s in spans:
+        assert parent(s, served["events"]) == inside
+        assert set(s["args"]) == args
+        assert all(isinstance(v, int) for v in s["args"].values())
+
+
+def test_engine_span_counts_follow_the_engines_counters(served):
+    ev, delta = served["events"], served["delta"]
+    assert delta["prefills"] == len(served["reqs"]) == 5
+    for name in ("pt.engine.submit", "pt.engine.prefill",
+                 "pt.engine.prefill.build", "pt.engine.prefill.dispatch",
+                 "pt.engine.prefill.fetch"):
+        assert len(named(ev, name)) == delta["prefills"], name
+    for name in ("pt.engine.lanes", "pt.engine.upload", "pt.engine.dispatch",
+                 "pt.engine.fetch", "pt.engine.bookkeep"):
+        assert len(named(ev, name)) == delta["iterations"], name
+    steps = named(ev, "pt.engine.step")
+    assert len(steps) == len(named(ev, "pt.engine.admit")) >= len(
+        named(ev, "pt.engine.capacity")) >= delta["iterations"]
+    # `iteration` counts decode iterations done before the step
+    its = [s["args"]["iteration"] for s in steps]
+    assert its == sorted(its) and its[-1] - its[0] == delta["iterations"] - 1
+    # nothing else is named with the program's prefix
+    assert {e["name"] for e in ev if e["name"].startswith(SPAN_PREFIX)} \
+        == set(ENGINE_SPANS)
+
+
+def test_prefill_spans_carry_the_requests_they_admitted(served):
+    reqs = {r.rid: r for r in served["reqs"]}
+    prefills = named(served["events"], "pt.engine.prefill")
+    assert sorted(p["args"]["rid"] for p in prefills) == sorted(reqs)
+    for p in prefills:
+        a, r = p["args"], reqs[p["args"]["rid"]]
+        assert a["trace_id"] == r.trace_id
+        assert a["prompt_tokens"] == len(r.prompt)
+        assert a["bucket"] >= a["prompt_tokens"]
+        assert a["shared_tokens"] == 0 and a["requeue"] == 0
+        assert a["queue_wait_us"] == int(
+            1e6 * (r.admitted_ts - r.submitted_ts))
+    # two lanes, five requests at once: some had to wait for a lane
+    assert max(p["args"]["queue_wait_us"] for p in prefills) > min(
+        p["args"]["queue_wait_us"] for p in prefills)
+    submits = named(served["events"], "pt.engine.submit")
+    assert [s["args"]["rid"] for s in submits] == sorted(reqs)
+    assert [s["args"]["queue_depth"] for s in submits] == [0, 1, 2, 3, 4]
+
+
+def test_engine_programs_are_named_as_the_metrics_expect(served):
+    """`serve_prefill_device_pct` and the ledger's breakdown find the two
+    programs by these names (a chip trace's `XLA Modules` events carry
+    the executable's name; the CPU's trace shows the jitted function)."""
+    assert {"jit__prefill_fn", "jit__fused_step_fn"} <= served["programs"]
+    for call, span in (("PjitFunction(_prefill_fn)",
+                        "pt.engine.prefill.dispatch"),
+                       ("PjitFunction(_fused_step_fn)",
+                        "pt.engine.dispatch")):
+        calls = named(served["events"], call)
+        assert calls
+        assert {parent(c, served["events"]) for c in calls} == {span}
+
+
+@pytest.mark.parametrize("name", sorted(TRAIN_SPANS))
+def test_train_span_is_in_the_trace_nested_with_its_arguments(trained, name):
+    inside, args = TRAIN_SPANS[name]
+    spans = named(trained["events"], name)
+    assert len(spans) == 3, f"{name}: one to a traced call"
+    for s in spans:
+        assert parent(s, trained["events"]) == inside
+        assert set(s["args"]) == args
+    if name == "pt.train.call":
+        assert [s["args"]["t"] for s in spans] == [2, 3, 4]
+
+
+def test_train_spans_follow_one_another_inside_the_call(trained):
+    ev = trained["events"]
+    assert {e["name"] for e in ev if e["name"].startswith(SPAN_PREFIX)} \
+        == set(TRAIN_SPANS)
+    for call in named(ev, "pt.train.call"):
+        inner = sorted((e for e in ev if e["name"] in TRAIN_SPANS
+                        and e is not call and call["start"] <= e["start"]
+                        and e["end"] <= call["end"]),
+                       key=lambda e: e["start"])
+        assert [e["name"] for e in inner] == [
+            "pt.train.prepare", "pt.train.dispatch", "pt.train.health"]
+        assert all(a["end"] <= b["start"] for a, b in zip(inner, inner[1:]))
+        jitted = [e for e in named(ev, "PjitFunction(step)")
+                  if call["start"] <= e["start"] <= call["end"]]
+        assert {parent(e, ev) for e in jitted} == {"pt.train.dispatch"}
+
+
+def test_health_span_only_when_the_probe_is_on(tmp_path):
+    paddle.seed(0)
+    model = GPT(GPTConfig.tiny())
+    opt = optimizer.AdamW(parameters=model.parameters(), learning_rate=1e-3)
+    step = jit_mod.TrainStep(model, F.cross_entropy, opt, health=False)
+    ids = paddle.to_tensor(np.ones((2, 16), np.int32))
+    step(ids, ids)
+    _, ev = capture(tmp_path, lambda: float(step(ids, ids).data))
+    assert len(named(ev, "pt.train.call")) == 1
+    assert not named(ev, "pt.train.health")
+
+
+@pytest.mark.parametrize("what", ["tokens", "losses"])
+def test_outputs_are_the_same_without_the_spans(served, trained, what,
+                                                monkeypatch):
+    monkeypatch.setattr(serving, "RecordEvent", _NoSpan)
+    monkeypatch.setattr(jit_mod, "RecordEvent", _NoSpan)
+    if what == "tokens":
+        bare = serve()
+        assert [r.generated for r in bare["reqs"]] == [
+            r.generated for r in served["reqs"]]
+        assert all(len(r.generated) == r.max_new_tokens
+                   for r in bare["reqs"])
+    else:
+        assert train()["losses"] == trained["losses"]
+
+
+def test_record_event_annotates_with_the_recorder_off(tmp_path):
+    """A trace somebody else started (the benchmark's window, an
+    operator's `start_trace`) sees the span and its arguments; the
+    program's own recorder, being off, gets nothing."""
+    rec = get_recorder()
+    assert not rec.enabled
+    rec.clear()
+
+    def spans():
+        with RecordEvent("pt.test.outer", rid=7, label="x"):
+            with RecordEvent("pt.test.inner"):
+                pass
+
+    _, ev = capture(tmp_path, spans)
+    outer, = named(ev, "pt.test.outer")
+    inner, = named(ev, "pt.test.inner")
+    assert outer["args"] == {"rid": 7, "label": "x"}
+    assert parent(inner, ev) == "pt.test.outer"
+    assert rec.collect() == [] and rec.span_stack() == []
+
+
+def test_record_event_pushes_its_arguments_with_the_recorder_on():
+    rec = get_recorder()
+    rec.clear()
+    rec.enabled = True
+    try:
+        with RecordEvent("pt.test.args", rid=3):
+            pass
+        with RecordEvent("pt.test.bare"):
+            pass
+    finally:
+        rec.enabled = False
+    spans = {s.name: s for s in rec.collect()}
+    assert spans["pt.test.args"].args == {"rid": 3}
+    assert spans["pt.test.bare"].args is None
+
+
+def test_record_event_lets_an_error_from_jax_through():
+    with pytest.raises(TypeError):  # a name jax's annotation refuses
+        RecordEvent(None).begin()

@@ -15,6 +15,7 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.profiler import device_time, xplane
 from paddle_tpu.profiler.recorder import HostSpan, get_recorder
+from paddle_tpu.profiler.utils import RecordEvent
 
 
 def _ev(name, ts, dur, pid=1, tid=1, ph="X", args=None):
@@ -146,6 +147,15 @@ class TestCorrelate:
         assert "Dev(ms)" in table and "xplane" in table
 
 
+def _eager_pass(a):
+    """Eager ops inside a span that waits for the result: the one span
+    whose device work is sure to lie inside it (an op's own span closes
+    when the op is enqueued; whether its work overlaps it is up to
+    asynchronous dispatch)."""
+    with RecordEvent("eager_pass"):
+        paddle.nn.functional.softmax(paddle.matmul(a, a)).numpy()
+
+
 class TestCaptureSessionLive:
     def test_capture_correlates_eager_ops_on_cpu(self, tmp_path):
         """The acceptance path: a capture session over real eager ops on
@@ -156,7 +166,7 @@ class TestCaptureSessionLive:
         try:
             a = paddle.to_tensor(np.ones((96, 96), np.float32))
             for _ in range(3):
-                paddle.nn.functional.softmax(paddle.matmul(a, a))
+                _eager_pass(a)
         finally:
             summary = sess.stop(steps=3)
         assert summary["status"] == "complete"
@@ -183,7 +193,7 @@ class TestCaptureSessionLive:
         with p:
             a = paddle.to_tensor(np.ones((96, 96), np.float32))
             for _ in range(3):
-                paddle.nn.functional.softmax(paddle.matmul(a, a))
+                _eager_pass(a)
         assert p.xplane_stats is not None
         assert p.xplane_stats["correlated"] >= 1
         assert any(s.device_src == "xplane" for s in p._spans)
@@ -208,7 +218,11 @@ class TestProfileCapture:
         step = 0
         while cap.state != "idle":
             step += 1
-            paddle.matmul(a, a)
+            # the result is read back before the step is noted, so the
+            # step's work lies inside its `train_step` span: overlap in
+            # time is all `correlate` has, and asynchronous dispatch
+            # promises none to a step that does not wait
+            paddle.matmul(a, a).numpy()
             cap.on_step(step)
             assert step < 10, "capture never finalized"
         summary = cap.wait(1)

@@ -231,10 +231,11 @@ def phase_kernels(shapes: dict, interpret: bool = False) -> dict:
            * g.astype(jnp.float32) + b.astype(jnp.float32))
     _check(errs, "layer_norm_fwd", y, ref, TOL_LN_BF16)
 
-    # ---- paged decode attention, f32 as the serving engine stores K/V
+    # ---- paged decode attention, f32 and folded [pages, page, H*D] as the
+    # serving engine stores K/V
     s = shapes["paged"]
     num_pages = 1 + s["B"] * s["pages_per_seq"]
-    pool = (num_pages, s["page_size"], s["H"], s["D"])
+    pool = (num_pages, s["page_size"], s["H"] * s["D"])
     qd = randn((s["B"], s["H"], s["D"]), jnp.float32)
     kp, vp = randn(pool, jnp.float32), randn(pool, jnp.float32)
     bt = jnp.asarray(rng.integers(
@@ -408,8 +409,8 @@ def phase_serve(cfg, *, max_batch: int, max_len: int, page_size: int,
 
 
 def _tp_shards(eng, mesh) -> dict:
-    """Every mesh device must hold 1/N of each K/V pool's heads and a
-    full replica of each weight."""
+    """Every mesh device must hold 1/N of each K/V pool's folded head
+    axis (whole heads) and a full replica of each weight."""
     devs = set(mesh.devices.flat)
     n = len(devs)
     for kp in list(eng.cache.k_pages) + list(eng.cache.v_pages):

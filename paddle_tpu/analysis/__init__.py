@@ -11,11 +11,13 @@ Operator surfaces: `tools/program_audit.py` (offline CLI, CI gate via
 `analysis_finding` event / `analysis_*` metric families.
 """
 from .auditor import (AUDIT_ENV, audit_collectives_by_link, audit_program,
-                      audit_sharding, enabled, maybe_audit, reset_seen)
+                      audit_sharding, enabled, maybe_audit,
+                      pool_relayout_report, reset_seen)
 from .findings import (CHECKS, SEVERITIES, AuditReport, Finding,
                        recent_reports)
 
 __all__ = ["AUDIT_ENV", "audit_program", "audit_collectives_by_link",
-           "audit_sharding", "enabled", "maybe_audit", "reset_seen",
+           "audit_sharding", "pool_relayout_report", "enabled",
+           "maybe_audit", "reset_seen",
            "AuditReport", "Finding", "CHECKS", "SEVERITIES",
            "recent_reports"]

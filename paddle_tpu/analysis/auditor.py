@@ -30,9 +30,15 @@ Checks (see findings.py for severity semantics):
 4. **bloat** — oversized constants baked into the program (host arrays
    captured by closure instead of passed as args,
    ``PADDLE_TPU_AUDIT_CONST_MIN_BYTES``) and retrace-risk static args.
+5. **relayout** (only where the caller names the buffers, `relayout_of`;
+   it COMPILES) — whole-buffer ``copy`` instructions of a donated
+   buffer's shape in the optimized HLO: the device's default layout for
+   the shape is not the one the program works in, so the buffer is
+   re-laid out on the way in and out of every call although donation
+   "succeeded" (:func:`pool_relayout_report`).
 
-Nothing here compiles or runs device code — it is trace-time analysis
-that works on CPU CI, which is the point: every compiled TrainStep and
+Apart from that check nothing here compiles or runs device code — it is
+trace-time analysis that works on CPU CI, which is the point: every compiled TrainStep and
 serving executable is vetted before a single device step. Runtime
 integration is opt-in via ``PADDLE_TPU_AUDIT`` (``1``/``on`` audits the
 compiled entry points — TrainStep, to_static, serving; ``all`` adds the
@@ -52,7 +58,8 @@ from ..utils.envparse import env_float, env_int, env_str
 from .findings import AuditReport, Finding
 
 __all__ = ["audit_program", "audit_collectives_by_link", "audit_sharding",
-           "maybe_audit", "enabled", "AUDIT_ENV", "reset_seen"]
+           "pool_relayout_report", "maybe_audit", "enabled", "AUDIT_ENV",
+           "reset_seen"]
 
 AUDIT_ENV = "PADDLE_TPU_AUDIT"
 
@@ -448,11 +455,65 @@ def _check_bloat(report: AuditReport, consts, static_args=None):
                          "value space"))
 
 
+#: HLO's names of the dtypes a K/V pool is stored in
+_HLO_DTYPE = {"float32": "f32", "bfloat16": "bf16", "float16": "f16"}
+
+
+def pool_relayout_report(compiled, pools: Sequence) -> Dict[str, int]:
+    """What a COMPILED program does to buffers it should update in place.
+
+    `pools` are arrays (or anything with `.shape`, `.dtype` and
+    optionally `.sharding`) such as a serving engine's K/V page pools.
+    Returns `pool_relayout_copies`, the number of `copy` instructions in
+    the optimized HLO whose result has a pool's per-device shape and
+    dtype, with `temp_size_in_bytes` of the program and `pool_bytes` of
+    the largest pool on one device. A program that holds the pools in
+    one layout from argument to result has no such copy, and a `temp`
+    smaller than one pool. Works on a program compiled for a described
+    topology (no chip) as on a live one."""
+    shapes, pool_bytes = set(), 0
+    for p in pools:
+        shape = tuple(p.shape)
+        sharding = getattr(p, "sharding", None)
+        if sharding is not None:
+            shape = tuple(sharding.shard_shape(shape))
+        dt = np.dtype(p.dtype)
+        shapes.add(f"{_HLO_DTYPE.get(dt.name, dt.name)}"
+                   f"[{','.join(map(str, shape))}]")
+        pool_bytes = max(pool_bytes, math.prod(shape) * dt.itemsize)
+    text = compiled.as_text()
+    copies = sum(len(re.findall(
+        r"= %s(?:\{[^}]*\})? copy\(" % re.escape(s), text)) for s in shapes)
+    return {"pool_relayout_copies": copies,
+            "temp_size_in_bytes": int(
+                compiled.memory_analysis().temp_size_in_bytes),
+            "pool_bytes": int(pool_bytes)}
+
+
+def _check_relayout(report: AuditReport, compiled, pools):
+    found = pool_relayout_report(compiled, pools)
+    report.pool_relayout_copies = found["pool_relayout_copies"]
+    report.temp_size_in_bytes = found["temp_size_in_bytes"]
+    if not report.pool_relayout_copies:
+        return
+    report.add(Finding(
+        check="donation", severity="high", code="pool-relayout-copy",
+        message=(f"the compiled program copies a whole donated pool "
+                 f"({found['pool_bytes'] >> 20} MiB) "
+                 f"{report.pool_relayout_copies} time(s) per call: its "
+                 f"default device layout is not the layout the program "
+                 f"works in (temp {report.temp_size_in_bytes >> 20} MiB)"),
+        nbytes=found["pool_bytes"],
+        fix_hint="store the buffer in a shape whose default layout is "
+                 "row-major (fold the trailing dims to a multiple of 128)"))
+
+
 # -- entry points ------------------------------------------------------------
 
 def audit_program(fn, args: Sequence, kwargs: Optional[dict] = None, *,
                   donate_argnums: Sequence[int] = (),
                   static_args: Optional[dict] = None,
+                  relayout_of: Optional[Sequence] = None,
                   name: str = "program", entry: str = "offline",
                   emit: bool = True) -> AuditReport:
     """Trace `fn(*args, **kwargs)` and audit the program statically.
@@ -461,6 +522,9 @@ def audit_program(fn, args: Sequence, kwargs: Optional[dict] = None, *,
     donates (exactly what it passes to jax.jit) — the auditor compares
     them against the aliasing table XLA accepted. Findings are emitted
     to events/metrics unless `emit=False`. Never executes the program.
+    `relayout_of` names buffers the program should update in place (a
+    serving engine's page pools): the program is then also COMPILED and
+    the report carries `pool_relayout_copies` and `temp_size_in_bytes`.
     """
     import jax
 
@@ -491,6 +555,10 @@ def audit_program(fn, args: Sequence, kwargs: Optional[dict] = None, *,
     _check_dtype(report, closed.jaxpr)
     _check_collectives(report, closed.jaxpr)
     _check_bloat(report, closed.consts, static_args)
+    if relayout_of:
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")
+            _check_relayout(report, lowered.compile(), relayout_of)
 
     if emit:
         report.emit()

@@ -112,6 +112,10 @@ class AuditReport:
     name: str                      # program label (e.g. "GPT#1")
     entry: str                     # jit entry audited (train_step, ...)
     findings: List[Finding] = field(default_factory=list)
+    # the compiled-program check (auditor.pool_relayout_report): None
+    # where the audit did not compile
+    pool_relayout_copies: Optional[int] = None
+    temp_size_in_bytes: Optional[int] = None
 
     def add(self, finding: Finding):
         self.findings.append(finding)
@@ -138,9 +142,13 @@ class AuditReport:
             key=lambda f: -SEVERITIES.index(f.severity))
         if max_findings is not None:
             ranked = ranked[:max_findings]
-        return {"name": self.name, "entry": self.entry,
-                "counts": self.counts(),
-                "findings": [f.to_dict() for f in ranked]}
+        d = {"name": self.name, "entry": self.entry,
+             "counts": self.counts(),
+             "findings": [f.to_dict() for f in ranked]}
+        if self.pool_relayout_copies is not None:
+            d["pool_relayout_copies"] = self.pool_relayout_copies
+            d["temp_size_in_bytes"] = self.temp_size_in_bytes
+        return d
 
     def emit(self):
         """Land this report on the observability plane: one

@@ -97,7 +97,8 @@ def _pow2_pad(n: int) -> int:
 
 def _extract_pages_impl(k_pages, v_pages, page_ids):
     """Gather the per-layer pages a prefill just wrote into a dense
-    payload [P_pad, page_size, H, D]. Padding ids are the null page 0 —
+    payload [P_pad, page_size, H*D] (a page as the pools store it, heads
+    folded). Padding ids are the null page 0 —
     its garbage rows scatter back onto page 0 at the decode side."""
     return ([kp[page_ids] for kp in k_pages],
             [vp[page_ids] for vp in v_pages])

@@ -721,12 +721,18 @@ class ServingEngine:
         """Statically audit the fused decode step (smallest lane bucket)
         and the (smallest-bucket) prefill executable for perf hazards —
         donation/aliasing of the page pools, dtype hygiene, baked
-        constants. Trace + lower only; nothing executes and the live
-        cache is untouched. Returns [decode_report, prefill_report]
-        (+ a per-link collective-bytes report when TP decode is on)."""
+        constants — and COMPILE both to see what "donation accepted"
+        cannot: each report's `pool_relayout_copies` counts the `copy`
+        instructions of a pool's shape in the optimized HLO (the device's
+        default layout for the pool's shape differing from the one the
+        program works in, PERF.md section 5) beside the program's
+        `temp_size_in_bytes`. Nothing executes and the live cache is
+        untouched. Returns [decode_report, prefill_report] (+ a per-link
+        collective-bytes report when TP decode is on)."""
         import jax.numpy as jnp
         from .. import analysis
         W = self.decode_buckets[0]
+        pools = self.cache.k_pages[:1] + self.cache.v_pages[:1]
         lane_args = (jnp.zeros((W,), jnp.int32),           # tokens
                      jnp.full((W,), self.max_batch, jnp.int32),  # slot_map
                      jnp.zeros((W,), bool),                # lane_active
@@ -738,7 +744,7 @@ class ServingEngine:
         decode = analysis.audit_program(
             self._fused_step_fn,
             (self._params, self._buffers, self.cache) + lane_args,
-            donate_argnums=(2,),
+            donate_argnums=(2,), relayout_of=pools,
             name=f"serving_decode:{self.name}", entry="serving_decode",
             emit=emit)
         bucket = self.prefill_buckets[0]
@@ -750,7 +756,7 @@ class ServingEngine:
             self._prefill_fn,
             (self._params, self._buffers, self.cache, ids,
              np.int32(0), np.int32(1), np.int32(0)) + one,
-            donate_argnums=(2,),
+            donate_argnums=(2,), relayout_of=pools,
             name=f"serving_prefill:{self.name}", entry="serving_prefill",
             emit=emit)
         reports = [decode, prefill]

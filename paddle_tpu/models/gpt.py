@@ -22,20 +22,30 @@ class PagedKVCache:
     """Paged decode KV cache: per-layer page pools + per-sequence block
     tables (ops/pallas/paged_attention.py layout).
 
-    ``k_pages[l]`` / ``v_pages[l]`` are ``[num_pages, page_size, H, D]``;
-    ``block_tables`` is ``[max_batch, pages_per_seq]`` int32 and
+    ``k_pages[l]`` / ``v_pages[l]`` are ``[num_pages, page_size, H*D]``,
+    the heads FOLDED into the minor axis (head h in lanes [h*D, (h+1)*D);
+    ``num_heads`` / ``head_dim`` say how). Folded, because a jitted
+    program holds its arguments and results to the device's default
+    layout for their shape: the TPU lays ``[.., H*D]`` out row-major
+    whenever H*D is a multiple of 128, but puts the PAGES of a 4-D
+    ``[.., 12, 64]`` pool in the lanes, and then every decode and prefill
+    program re-lays out every pool on the way in and on the way out
+    (ops/pallas/paged_attention.py says how to check a new shape ahead of
+    time). ``block_tables`` is ``[max_batch, pages_per_seq]`` int32 and
     ``context_lens`` ``[max_batch]`` int32. Page 0 is the NULL page: idle
     batch slots point at it and their decode-step writes land there (see
     the serving allocator). Registered as a pytree so a whole serving
     decode step jits over it with the pools donated."""
 
     def __init__(self, k_pages, v_pages, block_tables, context_lens,
-                 page_size: int):
+                 page_size: int, num_heads: int, head_dim: int):
         self.k_pages = list(k_pages)
         self.v_pages = list(v_pages)
         self.block_tables = block_tables
         self.context_lens = context_lens
         self.page_size = int(page_size)
+        self.num_heads = int(num_heads)
+        self.head_dim = int(head_dim)
 
     @property
     def num_pages(self) -> int:
@@ -51,12 +61,12 @@ class PagedKVCache:
 
     def tree_flatten(self):
         return ((self.k_pages, self.v_pages, self.block_tables,
-                 self.context_lens), (self.page_size,))
+                 self.context_lens),
+                (self.page_size, self.num_heads, self.head_dim))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        k_pages, v_pages, block_tables, context_lens = children
-        return cls(k_pages, v_pages, block_tables, context_lens, aux[0])
+        return cls(*children, *aux)
 
 
 def _register_cache_pytree():
@@ -274,7 +284,8 @@ class GPT(nn.Layer):
         deployment may pass less and rely on allocator preemption.
 
         With a TP mesh armed (`set_tp_mesh`) the pools allocate SHARDED
-        over the head axis — each device holds 1/N of every layer's pool,
+        over the folded axis (heads are contiguous in it, so a shard holds
+        whole heads) — each device holds 1/N of every layer's pool,
         which is the N×-larger-model capacity claim — while block tables
         and context lens replicate (they are host-updated control state).
         `sharded=False` builds a plain single-device cache regardless
@@ -291,7 +302,7 @@ class GPT(nn.Layer):
         if dtype is None:
             dtype = self.wte.weight.dtype
         H, D = self.cfg.num_heads, self.cfg.hidden_size // self.cfg.num_heads
-        shape = (num_pages, page_size, H, D)
+        shape = (num_pages, page_size, H * D)
         mesh = self.tp_mesh() if sharded else None
         if mesh is None:
             k_pages = [jnp.zeros(shape, dtype) for _ in self.blocks]
@@ -300,8 +311,7 @@ class GPT(nn.Layer):
             cl = jnp.zeros((max_batch,), jnp.int32)
         else:
             from jax.sharding import NamedSharding, PartitionSpec as P
-            pool_sh = NamedSharding(mesh, P(None, None, self._tp_axis,
-                                            None))
+            pool_sh = NamedSharding(mesh, P(None, None, self._tp_axis))
             rep_sh = NamedSharding(mesh, P())
             # allocate THROUGH the sharding: each device materializes
             # only its pool shard — the whole point of TP decode is that
@@ -313,14 +323,16 @@ class GPT(nn.Layer):
             bt = jax.device_put(
                 jnp.zeros((max_batch, pages_per_seq), jnp.int32), rep_sh)
             cl = jax.device_put(jnp.zeros((max_batch,), jnp.int32), rep_sh)
-        return PagedKVCache(k_pages, v_pages, bt, cl, page_size)
+        return PagedKVCache(k_pages, v_pages, bt, cl, page_size, H, D)
 
     def _block_qkv(self, blk, x):
-        """(q, k, v) raw arrays [B, L, H, D] from one block's qkv proj."""
-        B, L, _ = x.shape
-        qkv = blk.attn.qkv(x)
-        qkv = reshape(qkv, [B, L, 3, blk.attn.num_heads, blk.attn.head_dim])
-        return qkv[:, :, 0].data, qkv[:, :, 1].data, qkv[:, :, 2].data
+        """(q, k, v) raw arrays [B, L, H*D] from one block's qkv proj:
+        three slices of its lanes, heads left folded as the pools store
+        them (what wants [.., H, D] — flash attention, the decode query —
+        reshapes its own operand)."""
+        h = self.cfg.hidden_size
+        qkv = blk.attn.qkv(x).data
+        return qkv[..., :h], qkv[..., h:2 * h], qkv[..., 2 * h:]
 
     def forward_prefill(self, input_ids, cache: PagedKVCache, slot,
                         length, write_start=0, use_tp: bool = True):
@@ -351,6 +363,7 @@ class GPT(nn.Layer):
         length = jnp.asarray(length, jnp.int32)
         write_start = jnp.asarray(write_start, jnp.int32)
         page_row = jnp.take(cache.block_tables, slot, axis=0)
+        per_head = (B, L, cache.num_heads, cache.head_dim)
         mesh = self.tp_mesh() if use_tp else None
         # under a TP mesh the prefill program is multi-device: the Pallas
         # dispatch sites run per shard, heads over the TP axis
@@ -359,7 +372,7 @@ class GPT(nn.Layer):
                 with jax.named_scope("ln"):
                     h = blk.ln1(x)
                 with jax.named_scope("attention"):
-                    q, k, v = self._block_qkv(blk, h)
+                    q, k, v = self._block_qkv(blk, h)   # [1, L, H*D]
                     if mesh is not None:
                         cache.k_pages[li], cache.v_pages[li] = \
                             _pa.prefill_append_tp(
@@ -371,9 +384,10 @@ class GPT(nn.Layer):
                             _pa.prefill_append(
                                 cache.k_pages[li], cache.v_pages[li], k[0],
                                 v[0], page_row, length, start=write_start)
+                    q, k, v = (Tensor(t.reshape(per_head))
+                               for t in (q, k, v))
                     out = F.scaled_dot_product_attention(
-                        Tensor(q), Tensor(k), Tensor(v), is_causal=True,
-                        training=False)
+                        q, k, v, is_causal=True, training=False)
                     out = reshape(out, [B, L, self.cfg.hidden_size])
                     x = x + blk.attn.proj(out)
                 with jax.named_scope("ln"):
@@ -435,14 +449,15 @@ class GPT(nn.Layer):
             with jax.named_scope("ln"):
                 h = blk.ln1(x)
             with jax.named_scope("attention"):
-                q, k, v = self._block_qkv(blk, h)      # [B, 1, H, D]
+                q, k, v = self._block_qkv(blk, h)      # [B, 1, H*D]
+                q = q.reshape(B, cache.num_heads, cache.head_dim)
                 if mesh is not None:
                     # TP: per-shard append + attention on the local head
                     # slice; `out` comes back REPLICATED so the proj
                     # contraction below never splits (bit-exactness)
                     out, cache.k_pages[li], cache.v_pages[li] = \
                         _pa.decode_step_tp(
-                            q[:, 0], k[:, 0], v[:, 0], cache.k_pages[li],
+                            q, k[:, 0], v[:, 0], cache.k_pages[li],
                             cache.v_pages[li], bt, ctx, active, mesh,
                             axis=self._tp_axis)
                 else:
@@ -451,7 +466,7 @@ class GPT(nn.Layer):
                             cache.k_pages[li], cache.v_pages[li],
                             k[:, 0], v[:, 0], bt, ctx, active)
                     out = _pa.paged_attention(
-                        q[:, 0], cache.k_pages[li], cache.v_pages[li], bt,
+                        q, cache.k_pages[li], cache.v_pages[li], bt,
                         # the new token is part of its own context
                         jnp.where(active, ctx + 1, 0))
                 out = reshape(Tensor(out), [B, 1, self.cfg.hidden_size])

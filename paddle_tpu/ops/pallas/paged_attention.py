@@ -6,8 +6,9 @@ per-sequence KV cache `[B, max_len, H, D]` wastes HBM on short sequences
 and forces whole-cache reallocation as sequences grow; following vLLM
 (Kwon et al., 2023), K/V live in a shared pool of fixed-size PAGES
 
-    k_pages, v_pages: [num_pages, page_size, num_heads, head_dim]
+    k_pages, v_pages: [num_pages, page_size, num_heads * head_dim]
 
+(the heads FOLDED into the minor axis, see "Why the pool is folded" below)
 and each sequence owns a BLOCK TABLE of page indices
 
     block_tables: [B, pages_per_seq] int32   (unused slots -> page 0)
@@ -26,10 +27,11 @@ PR-10 autotune layer (op ``"paged_attn"``, same pattern as ``conv_bn``):
 * ``impl=1`` — the Pallas kernel: grid ``(B, head-blocks, pages)`` under
   a :class:`PrefetchScalarGridSpec` whose scalar-prefetched block table
   drives the k/v BlockSpec index maps, so each grid step DMAs exactly
-  ONE page from wherever it lives in the pool into VMEM (the pipeline
-  double-buffers page fetches against compute); online softmax carried
-  across the page walk in VMEM scratch. The ``heads`` candidate axis
-  splits the head dim across grid-parallel programs.
+  ONE folded page ``[page_size, heads * head_dim]`` from wherever it
+  lives in the pool into VMEM (the pipeline double-buffers page fetches
+  against compute); online softmax carried across the page walk in VMEM
+  scratch, per-head sums taken by masked lane reductions. The ``heads``
+  candidate axis splits the folded axis across grid-parallel programs.
 * ``impl=0`` — the XLA composition: gather pages via
   ``k_pages[block_tables]``, mask past ``context_lens``, dense softmax.
   This is also the CPU fallback and the CI parity reference.
@@ -40,7 +42,28 @@ updates the (potentially multi-GB) pool in place instead of copying it
 per token.
 
 Layout convention (paddle): q is [batch, heads, head_dim] (ONE decode
-token per sequence); pages carry [page_size, heads, head_dim] tokens.
+token per sequence); a page carries [page_size, heads * head_dim], one
+token a row, head h in lanes [h * head_dim, (h + 1) * head_dim).
+
+Why the pool is folded (PERF.md section 5, PR 26). The arguments and
+results of a jitted program are held to the device's DEFAULT layout for
+their shape. For a 4-D f32 array whose last two dimensions do not fill an
+(8, 128) tile, e.g. GPT-2's (12, 64), the TPU's default puts the largest
+dimension, the pages, in the lanes (`{0,3,2,1}`), while the scatter and
+the kernel need row-major: donation aliases the buffers and the compiler
+still copies every pool once on the way in and once on the way out of
+every decode and prefill program (83 % of the device time when serving
+GPT-2 small). `[pages, page_size, H*D]` is laid out row-major and
+unpadded whenever `H*D` is a multiple of 128, so the programs update the
+pools in place. Shapes whose tiles are filled exactly (`H % 8 == 0` and
+`D % 128 == 0` in f32) never had the copies; folded and 4-D are the same
+bytes in the same tiles there. To check a new shape ahead of time, without
+a chip: `analysis.pool_relayout_report` on the program compiled for a
+described topology, as `tests/test_paged_attention.py::TestPoolLayout`
+does (it wants 0 copies of pool shape and a `temp` smaller than one pool).
+The entry points here also take 4-D pools and 3-D new rows (tests, eager
+callers off the hot path): scatters work on any trailing shape, attention
+folds by a reshape.
 """
 from __future__ import annotations
 
@@ -56,16 +79,30 @@ from . import tiling as _tiling
 from .tiling import on_tpu as _on_tpu
 
 _NEG = -1e30
-_CARRY_LANES = 128  # m/l scratch lane width (f32 native lane tile)
+_LANE = _tiling.LANE
 
 # dispatch decisions, counted at trace time (reset freely in tests)
 # ("xla_measured" counts the XLA dispatches that were the autotuner's
-# measured impl=0 choice, as opposed to a shape/platform gate)
-_stats = {"pallas": 0, "xla": 0, "xla_measured": 0, "append": 0, "cow": 0}
+# measured impl=0 choice, as opposed to a shape/platform gate; "folded"
+# counts the dispatches to the kernel that reads the folded page block:
+# all of "pallas" since PR 26, absent before it)
+_stats = {"pallas": 0, "folded": 0, "xla": 0, "xla_measured": 0,
+          "append": 0, "cow": 0}
 
 # tests set True: the kernel runs in the Pallas interpreter on CPU, so
 # the real gather/online-softmax logic is exercised without a TPU
 _INTERPRET = False
+
+
+def _folded(pool):
+    """A pool as the kernels and the engine hold it: [pages, page, H*D]."""
+    return pool if pool.ndim == 3 else pool.reshape(*pool.shape[:2], -1)
+
+
+def _rows_like(pool, new):
+    """New K/V rows [N, H, D] or [N, H*D] in the pool's own trailing
+    shape, so that one scatter serves folded and 4-D pools."""
+    return new.reshape(new.shape[0], *pool.shape[2:]).astype(pool.dtype)
 
 
 # --------------------------- XLA reference (impl=0) --------------------------
@@ -75,12 +112,15 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
                         scale=None):
     """Dense gather reference: correct for every shape, the CPU path, and
     the ``impl=0`` autotune candidate. A sequence with ``context_lens==0``
-    (idle serving slot) outputs exactly zero."""
+    (idle serving slot) outputs exactly zero. It unfolds the pool itself
+    (a copy on the TPU, which this path can afford)."""
     B, H, D = q.shape
     page_size = k_pages.shape[1]
     n_pages = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / np.sqrt(D)
+    k_pages = k_pages.reshape(*k_pages.shape[:2], H, D)
+    v_pages = v_pages.reshape(*v_pages.shape[:2], H, D)
     # [B, n_pages, page_size, H, D] -> [B, L_max, H, D]
     k = k_pages[block_tables].reshape(B, n_pages * page_size, H, D)
     v = v_pages[block_tables].reshape(B, n_pages * page_size, H, D)
@@ -101,12 +141,51 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
 # --------------------------- Pallas kernel (impl=1) --------------------------
 
 
+def _lane_groups(width: int, D: int):
+    """Lane ranges of a folded block that are worked on together: one
+    128-lane tile holding 128/D whole heads (the last tile of a block may
+    be narrower) or, where D > 128, one head."""
+    step = max(D, _LANE)
+    return [(lo, min(lo + step, width)) for lo in range(0, width, step)]
+
+
+def _head_sums(x, D: int):
+    """x [rows, w] (one lane group) -> the same shape, every lane holding
+    the sum over the D lanes of its own head. Lane reductions under lane
+    masks on the XLU: the MXU would load a 128 x 128 tile of a 0/1 matrix
+    for each 16 rows of a page."""
+    w = x.shape[1]
+    if D >= w:
+        if w > _LANE:  # one head over several tiles: add the tiles first
+            t = x[:, :_LANE]
+            for lo in range(_LANE, w, _LANE):
+                t = t + x[:, lo:lo + _LANE]
+            x = t
+        return jnp.sum(x, axis=-1, keepdims=True)       # broadcasts back
+    head = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) // D
+    out = None
+    for j in range(w // D):
+        mine = head == j
+        t = jnp.sum(jnp.where(mine, x, 0.0), axis=-1, keepdims=True)
+        out = jnp.broadcast_to(t, x.shape) if out is None \
+            else jnp.where(mine, t, out)
+    return out
+
+
 def _paged_attn_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
-                       acc_ref, m_ref, l_ref, *, page_size, scale, n_pages):
+                       acc_ref, m_ref, l_ref, *, page_size, scale, n_pages,
+                       D):
     """Grid (B, head-blocks, pages); the page axis is the minormost,
     sequentially-executed dim carrying the online-softmax state. The
     block table itself picked which page this step's k/v blocks were
-    DMA'd from (see the BlockSpec index maps in `_paged_attn_pallas`)."""
+    DMA'd from (see the BlockSpec index maps in `_paged_attn_pallas`).
+
+    A block is a FOLDED page [page, heads * D]: a row is one token, a
+    head is D lanes of it. One query token per head makes the scores a
+    matrix-VECTOR product per head, so the products run on the VPU at the
+    page's own layout. The softmax state is kept per lane (every lane of
+    a head carries that head's max and sum): everything but the per-head
+    sum of q * k is elementwise work and reductions over rows."""
     from jax.experimental import pallas as pl
 
     b = pl.program_id(0)
@@ -125,82 +204,76 @@ def _paged_attn_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
     # table slots all point at the null page)
     @pl.when(i * page_size < ctx)
     def _compute():
-        # One query token per head makes this a matrix-VECTOR product per
-        # head: nothing for the MXU to amortise, and Mosaic's matmul wants
-        # batch dims leading, which a [page, head, D] page is not. So the
-        # products run on the VPU at the page's own layout — reduce over
-        # lanes (D) for the scores, over the major axis (positions) for
-        # the softmax sums and the weighted values; no relayout anywhere.
-        qb = q_ref[...].astype(jnp.float32)[None]       # [1, bh, D]
-        kb = k_ref[...].astype(jnp.float32)             # [page, bh, D]
-        vb = v_ref[...].astype(jnp.float32)
-        s = jnp.sum(qb * kb, axis=-1, keepdims=True) * scale  # [page,bh,1]
-        pos = i * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        s = jnp.where(pos < ctx, s, _NEG)
-        m_prev = m_ref[...][:, :1][None]                # [1, bh, 1]
-        l_prev = l_ref[...][:, :1][None]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        p = jnp.exp(s - m_new)
-        # a page whose every position is past ctx never reaches here, but
-        # the LAST live page's tail positions sit at the floor: zero them
-        # (exp(_NEG - m) underflows only when m is real)
-        p = jnp.where(s > 0.5 * _NEG, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=0, keepdims=True)
-        l_ref[...] = jnp.broadcast_to(l_new[0], l_ref.shape)
-        acc_ref[...] = acc_ref[...] * corr[0] + jnp.sum(p * vb, axis=0)
-        m_ref[...] = jnp.broadcast_to(m_new[0], m_ref.shape)
+        for lo, hi in _lane_groups(k_ref.shape[-1], D):
+            qb = q_ref[:, lo:hi].astype(jnp.float32)        # [1, w]
+            kb = k_ref[:, lo:hi].astype(jnp.float32)        # [page, w]
+            vb = v_ref[:, lo:hi].astype(jnp.float32)
+            s = _head_sums(qb * kb, D) * scale
+            pos = i * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, kb.shape, 0)
+            live = pos < ctx
+            s = jnp.where(live, s, _NEG)
+            m_prev = m_ref[:, lo:hi]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            # the last live page's tail rows are masked, not underflowed:
+            # exp(_NEG - m) is 0 only where m is real
+            p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[:, lo:hi] = l_ref[:, lo:hi] * corr + jnp.sum(
+                p, axis=0, keepdims=True)
+            acc_ref[:, lo:hi] = acc_ref[:, lo:hi] * corr + jnp.sum(
+                p * vb, axis=0, keepdims=True)
+            m_ref[:, lo:hi] = m_new
 
     @pl.when(i == n_pages - 1)
     def _finalize():
-        # ctx == 0 (idle slot): acc/l still zero -> output exactly zero,
-        # matching the XLA reference
-        o_ref[...] = (acc_ref[...]
-                      / jnp.maximum(l_ref[...][:, :1], 1e-30)
+        # ctx == 0 (idle slot): acc and l still zero -> exactly zero
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                       ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "block_h", "interpret"))
 def _paged_attn_pallas(q, k_pages, v_pages, block_tables, context_lens,
                        scale, block_h, interpret=False):
+    """q [B, H, D] over pools [num_pages, page_size, H * D]; `block_h`
+    heads (block_h * D lanes) to a grid program."""
     from jax.experimental import pallas as pl
 
     B, H, D = q.shape
+    k_pages, v_pages = _folded(k_pages), _folded(v_pages)
     page_size = k_pages.shape[1]
     n_pages = block_tables.shape[1]
-    n_h = pl.cdiv(H, block_h)
-    grid = (B, n_h, n_pages)
+    w = block_h * D
+    grid = (B, pl.cdiv(H, block_h), n_pages)
     # the scalar-prefetched block table drives the page fetch: grid step
     # (b, h, i) DMAs pool page block_tables[b, i] — this is the paged
     # gather, done by the Pallas pipeline's own double-buffered DMA
-    kspec = pl.BlockSpec((None, page_size, block_h, D),
-                         lambda b, h, i, bt, cl: (bt[b, i], 0, h, 0))
-    qspec = pl.BlockSpec((None, block_h, D),
-                         lambda b, h, i, bt, cl: (b, h, 0))
+    kspec = pl.BlockSpec((None, page_size, w),
+                         lambda b, h, i, bt, cl: (bt[b, i], 0, h))
+    # q and the output ride as [B, 1, H*D]: Mosaic refuses a (1, w) block
+    # of a [B, H*D] array
+    qspec = pl.BlockSpec((None, 1, w), lambda b, h, i, bt, cl: (b, 0, h))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[qspec, kspec, kspec],
         out_specs=qspec,
-        scratch_shapes=[pltpu.VMEM((block_h, D), jnp.float32),
-                        pltpu.VMEM((block_h, _CARRY_LANES), jnp.float32),
-                        pltpu.VMEM((block_h, _CARRY_LANES), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, w), jnp.float32)] * 3,
     )
-    if interpret:
-        params = None
-    else:
-        # the page axis carries the softmax carry state -> ARBITRARY;
-        # batch and head blocks are embarrassingly parallel
-        params = pltpu.CompilerParams(dimension_semantics=(
-            pltpu.PARALLEL, pltpu.PARALLEL, pltpu.ARBITRARY))
-    return pl.pallas_call(
+    # the page axis carries the softmax carry state -> ARBITRARY; batch
+    # and head blocks are embarrassingly parallel
+    params = None if interpret else pltpu.CompilerParams(
+        dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
+                             pltpu.ARBITRARY))
+    out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, page_size=page_size,
-                          scale=scale, n_pages=n_pages),
+                          scale=scale, n_pages=n_pages, D=D),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, 1, H * D), q.dtype),
         compiler_params=params,
         interpret=interpret,
-    )(block_tables, context_lens, q, k_pages, v_pages)
+    )(block_tables, context_lens, q.reshape(B, 1, H * D), k_pages, v_pages)
+    return out.reshape(B, H, D)
 
 
 # ------------------- autotuned impl/heads decision ---------------------------
@@ -210,7 +283,7 @@ def _vmem_bytes(cfg, page_size: int, D: int, itemsize: int) -> int:
     bh = cfg["heads"]
     b = 2 * 2 * page_size * bh * D * itemsize   # double-buffered k/v pages
     b += 2 * bh * D * itemsize                  # q in / o out
-    b += bh * D * 4 + 2 * bh * _CARRY_LANES * 4  # acc/m/l scratch
+    b += 3 * bh * D * 4                         # acc/m/l scratch, per lane
     return b
 
 
@@ -218,10 +291,11 @@ _cfg_memo = _autotune.register_memo({})
 
 
 def _head_candidates(H: int):
-    """Head-block extents Mosaic accepts: the head axis is the blocks'
-    second-minor dim, so an extent is a multiple of 8 or the whole H —
-    and a divisor of H (a non-divisor would need head tail-masking the
-    kernel doesn't carry). GPT-2's H=12 has only whole-H."""
+    """Head-block extents Mosaic accepts: a block's lanes are a multiple
+    of 128, which 8 heads are for every head size the kernel takes down
+    to 16, or the whole H — and a divisor of H (a non-divisor would need
+    head tail-masking the kernel doesn't carry). GPT-2's H=12 has only
+    whole-H."""
     return [h for h in (8, 16, 32) if h < H and H % h == 0] + [H]
 
 
@@ -256,7 +330,7 @@ def _resolve_cfg(dtype, H: int, D: int, page_size: int, n_pages: int):
             buf["q"] = jnp.asarray(
                 rng.normal(size=(Bp, H, D)).astype(np.float32)).astype(dtype)
             buf["kp"] = jnp.asarray(rng.normal(
-                size=(max(n_pages, 2), page_size, H, D)
+                size=(max(n_pages, 2), page_size, H * D)
             ).astype(np.float32)).astype(dtype)
             buf["bt"] = jnp.asarray(
                 rng.integers(0, max(n_pages, 2), (Bp, n_pages)
@@ -268,7 +342,7 @@ def _resolve_cfg(dtype, H: int, D: int, page_size: int, n_pages: int):
         qa, kp, bt, cl = _args()
         if cfg["impl"] == 1:
             out = _paged_attn_pallas(qa, kp, kp, bt, cl, sc, cfg["heads"],
-                                     interpret=interpret)
+                                    interpret=interpret)
         else:
             out = jax.jit(paged_attention_xla, static_argnames=("scale",))(
                 qa, kp, kp, bt, cl, scale=sc)
@@ -288,11 +362,11 @@ def _check_compiles(dtype, H: int, D: int, page_size: int, n_pages: int,
     (`autotune.compile_check`)."""
     def run():
         q = jnp.ones((2, H, D), dtype)
-        kp = jnp.ones((max(n_pages, 2), page_size, H, D), dtype)
+        kp = jnp.ones((max(n_pages, 2), page_size, H * D), dtype)
         bt = jnp.zeros((2, n_pages), jnp.int32)
         cl = jnp.full((2,), page_size, jnp.int32)
         return _paged_attn_pallas(q, kp, kp, bt, cl, float(1.0 / np.sqrt(D)),
-                                  heads, interpret=_INTERPRET)
+                                 heads, interpret=_INTERPRET)
 
     _autotune.compile_check(
         "paged_attn", run, dtype=jnp.dtype(dtype).name, heads=H, head_dim=D,
@@ -304,10 +378,11 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
                     scale=None):
     """Single-token decode attention over a paged KV pool.
 
-    q [B, H, D]; k_pages/v_pages [num_pages, page_size, H, D];
-    block_tables [B, pages_per_seq] int32 (unused slots MUST index a
-    valid page — the serving layer points them at the null page 0);
-    context_lens [B] int32. Returns [B, H, D].
+    q [B, H, D]; k_pages/v_pages [num_pages, page_size, H * D] (a 4-D
+    [.., H, D] pool is folded by a reshape); block_tables
+    [B, pages_per_seq] int32 (unused slots MUST index a valid page — the
+    serving layer points them at the null page 0); context_lens [B]
+    int32. Returns [B, H, D].
 
     Dispatch mirrors `flash_attention`: the per-shape impl (Pallas page
     walk vs XLA gather) is resolved on the autotune layer, then the
@@ -323,15 +398,19 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     eligible = ((_on_tpu() or _INTERPRET)
                 and q.dtype == k_pages.dtype == v_pages.dtype
                 and q.dtype != jnp.dtype(jnp.float16)
-                and isinstance(H, int))
+                and isinstance(H, int)
+                # a head is a whole number of lane tiles or a whole
+                # fraction of one (`_lane_groups`)
+                and (D % _LANE == 0 or _LANE % D == 0))
     if eligible:
         cfg = _resolve_cfg(q.dtype, H, D, page_size, n_pages)
         if cfg["impl"] == 1:
             _check_compiles(q.dtype, H, D, page_size, n_pages, cfg["heads"])
             _stats["pallas"] += 1
+            _stats["folded"] += 1
             return _paged_attn_pallas(q, k_pages, v_pages, block_tables,
-                                      context_lens, float(scale),
-                                      cfg["heads"], interpret=_INTERPRET)
+                                     context_lens, float(scale),
+                                     cfg["heads"], interpret=_INTERPRET)
         _stats["xla_measured"] += 1
     _stats["xla"] += 1
     return paged_attention_xla(q, k_pages, v_pages, block_tables,
@@ -342,7 +421,8 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 #
 # Decode attention is embarrassingly parallel over HEADS: each head's
 # page gather, online softmax and weighted sum touch only that head's
-# slice of the pools. Sharding the pools' head axis over a mesh axis
+# slice of the pools. Sharding the pools' folded axis over a mesh axis
+# (heads are contiguous in it, so a shard holds whole heads)
 # therefore needs NO cross-device math — every shard runs the normal
 # single-chip dispatch on its local head slice (the Pallas page walk or
 # the XLA gather, resolved per LOCAL shape by the same autotune layer),
@@ -374,11 +454,11 @@ def decode_step_tp(q, k_new, v_new, k_pages, v_pages, block_tables,
     replicate), then the attention output is gathered back to replicated
     so the caller's proj matmul never splits a contraction.
 
-    q/k_new/v_new are [B, H, D]; pools [num_pages, page_size, H, D]
-    sharded (or shardable) over `axis` on the head dim. Returns
-    (out [B, H, D] replicated, k_pages, v_pages head-sharded). H must
-    divide by the mesh axis size. Traceable — the serving engine's fused
-    step jits over it with the pools donated."""
+    q is [B, H, D], k_new/v_new [B, H*D] (or [B, H, D]); pools
+    [num_pages, page_size, H*D] sharded (or shardable) over `axis` on the
+    folded dim. Returns (out [B, H, D] replicated, k_pages, v_pages
+    head-sharded). H must divide by the mesh axis size. Traceable — the
+    serving engine's fused step jits over it with the pools donated."""
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
@@ -399,21 +479,22 @@ def decode_step_tp(q, k_new, v_new, k_pages, v_pages, block_tables,
         return out, kp_s, vp_s
 
     head = P(None, axis, None)
-    pool = P(None, None, axis, None)
+    rows = P(None, axis)
+    pool = P(None, None, axis)
     rep = P()
     out, k_pages, v_pages = shard_map(
         body, mesh=mesh,
-        in_specs=(head, head, head, pool, pool, rep, rep, rep),
+        in_specs=(head, rows, rows, pool, pool, rep, rep, rep),
         out_specs=(head, pool, pool), check_vma=False)(
-            q, k_new, v_new, k_pages, v_pages, block_tables,
-            context_lens, active)
+            q, k_new.reshape(B, H * D), v_new.reshape(B, H * D), k_pages,
+            v_pages, block_tables, context_lens, active)
     return _rep_put(out, mesh), k_pages, v_pages
 
 
 def prefill_append_tp(k_pages, v_pages, k_seq, v_seq, page_ids, length,
                       mesh, axis="tp", start=0):
     """`prefill_append` on head-sharded pools: each shard scatters its
-    own head slice of the prompt K/V [L, H, D] into its pool slice. The
+    own head slice of the prompt K/V [L, H*D] into its pool slice. The
     scatter indices (page ids, offsets) are head-independent, so this is
     the identical write per shard — no communication at all."""
     import jax.numpy as jnp
@@ -424,14 +505,16 @@ def prefill_append_tp(k_pages, v_pages, k_seq, v_seq, page_ids, length,
     def body(kp_s, vp_s, ks_s, vs_s, pid, ln, st):
         return prefill_append(kp_s, vp_s, ks_s, vs_s, pid, ln, start=st)
 
-    pool = P(None, None, axis, None)
-    seq = P(None, axis, None)
+    pool = P(None, None, axis)
+    seq = P(None, axis)
     rep = P()
+    L = k_seq.shape[0]
     return shard_map(
         body, mesh=mesh,
         in_specs=(pool, pool, seq, seq, rep, rep, rep),
         out_specs=(pool, pool), check_vma=False)(
-            k_pages, v_pages, k_seq, v_seq, page_ids,
+            k_pages, v_pages, k_seq.reshape(L, -1), v_seq.reshape(L, -1),
+            page_ids,
             jnp.asarray(length, jnp.int32), jnp.asarray(start, jnp.int32))
 
 
@@ -450,8 +533,8 @@ def _append_impl(k_pages, v_pages, k_new, v_new, block_tables,
     off = context_lens % page_size
     slot = jnp.where(active, slot, 0)
     off = jnp.where(active, off, 0)
-    k_pages = k_pages.at[slot, off].set(k_new.astype(k_pages.dtype))
-    v_pages = v_pages.at[slot, off].set(v_new.astype(v_pages.dtype))
+    k_pages = k_pages.at[slot, off].set(_rows_like(k_pages, k_new))
+    v_pages = v_pages.at[slot, off].set(_rows_like(v_pages, v_new))
     return k_pages, v_pages
 
 
@@ -460,8 +543,8 @@ _append_jit = jax.jit(_append_impl, donate_argnums=(0, 1))
 
 def cache_append(k_pages, v_pages, k_new, v_new, block_tables,
                  context_lens, active=None):
-    """Append k_new/v_new [B, H, D] at position context_lens[b] of each
-    active sequence. Returns the updated pools.
+    """Append k_new/v_new [B, H*D] (or [B, H, D]) at position
+    context_lens[b] of each active sequence. Returns the updated pools.
 
     Eagerly this routes through a jitted scatter whose page pools are
     DONATED, so XLA updates the buffers in place — the decode loop never
@@ -482,24 +565,37 @@ def cache_append(k_pages, v_pages, k_new, v_new, block_tables,
 
 def prefill_append(k_pages, v_pages, k_seq, v_seq, page_ids, length,
                    start=0):
-    """Scatter a whole prompt's K/V [L, H, D] into the pages of ONE
-    sequence: position i lands in page_ids[i // page_size] at offset
-    i % page_size. Positions at/past `length` (bucket padding) go to the
-    null page 0, and so do positions below `start` — the copy-on-write
-    shared-prefix path prefills a request whose first `start` tokens'
-    K/V already live in pages FORKED from another request; writing them
-    again would clobber the shared (refcount > 1) pages. `page_ids` is
-    the sequence's block-table row [n_pages]. Traceable (used inside
-    the jitted prefill step)."""
+    """Scatter a whole prompt's K/V [L, H*D] (or [L, H, D]) into the
+    pages of ONE sequence: position i lands in page_ids[i // page_size]
+    at offset i % page_size. Positions at/past `length` (bucket padding)
+    are not written, and neither are positions below `start` — the
+    copy-on-write shared-prefix path prefills a request whose first
+    `start` tokens' K/V already live in pages FORKED from another
+    request; writing them again would clobber the shared (refcount > 1)
+    pages. `page_ids` is the sequence's block-table row [n_pages].
+    Traceable (used inside the jitted prefill step).
+
+    The write is a PAGE at a time: in the folded pool a page is one
+    contiguous block of tiles while a token's row is a sublane strided
+    over H*D/128 of them, and a scatter of L rows took four times as long
+    as the same rows did in a [.., H, D] pool (PERF.md, PR 26). Each page
+    the prompt spans is read, its live rows replaced, and written back;
+    a page with no live row is parked on the null page 0."""
     page_size = k_pages.shape[1]
-    L = k_seq.shape[0]
-    pos = jnp.arange(L, dtype=jnp.int32)
+    n = -(-k_seq.shape[0] // page_size)      # pages the (padded) prompt spans
+    pos = jnp.arange(n * page_size, dtype=jnp.int32).reshape(n, page_size)
     live = (pos >= start) & (pos < length)
-    pages = jnp.where(live, page_ids[pos // page_size], 0)
-    offs = jnp.where(live, pos % page_size, 0)
-    k_pages = k_pages.at[pages, offs].set(k_seq.astype(k_pages.dtype))
-    v_pages = v_pages.at[pages, offs].set(v_seq.astype(v_pages.dtype))
-    return k_pages, v_pages
+    pages = jnp.where(jnp.any(live, axis=1), page_ids[:n], 0)
+
+    def put(pool, seq):
+        rows = _rows_like(pool, seq)
+        rows = jnp.pad(rows, [(0, n * page_size - rows.shape[0])]
+                       + [(0, 0)] * (rows.ndim - 1))
+        rows = rows.reshape(n, page_size, *pool.shape[2:])
+        keep = live.reshape(n, page_size, *[1] * (pool.ndim - 2))
+        return pool.at[pages].set(jnp.where(keep, rows, pool[pages]))
+
+    return put(k_pages, k_seq), put(v_pages, v_seq)
 
 
 # --------------------------- copy-on-write fork -------------------------------

@@ -1,8 +1,11 @@
 """Paged KV-cache decode stack (ops/pallas/paged_attention.py +
 models/gpt.py decode path): kernel parity vs the dense gather reference
-(Pallas interpreter on CPU), cache-append semantics (null page, donated
-eager buffers), the autotune `paged_attn` op (impl axis + cross-process
-disk-cache hit), and greedy-decode parity paged-vs-cacheless.
+(Pallas interpreter on CPU) on folded [pages, page, H*D] and 4-D pools,
+cache-append semantics (null page, donated eager buffers) against a NumPy
+model of the pages, the autotune `paged_attn` op (impl axis +
+cross-process disk-cache hit), greedy-decode parity paged-vs-cacheless,
+and the pools' device layout (compiled ahead of time for a described v5e:
+no pool-shaped copies).
 
 fast-sibling: every class here is tier-1 except the timing probe
 (TestSuperLinear.test_per_token_cost_flat_vs_dense_slow), whose fast
@@ -118,6 +121,125 @@ class TestKernelParity:
         assert pa._stats["xla"] == 1 and pa._stats["pallas"] == 0
 
 
+# (H, D): GPT-2 small's, GPT-3 XL's, one TP shard of GPT-2 small's 12 heads
+# over 4 devices (192 lanes: a tile and a half), and a tile-exact small one
+_FOLDED_SHAPES = [(12, 64), (16, 128), (3, 64), (8, 128)]
+
+
+class TestFoldedKernel:
+    """The kernel reads the FOLDED page block [page, H*D] (PR 26): parity
+    with the dense gather reference at the head shapes the engine serves,
+    in both storage dtypes."""
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("H,D", _FOLDED_SHAPES)
+    def test_folded_pool_matches_reference(self, H, D, dtype):
+        rng = np.random.default_rng(H * 1000 + D)
+        B, S, P, n = 4, 8, 12, 4
+        q, kp, vp, bt = _rand_pool(rng, B, H, D, S, P, n)
+        q, kp, vp = (x.astype(dtype) for x in (q, kp, vp))
+        # idle row, a context ending mid-page, one on a page edge, a full one
+        cl = jnp.asarray(np.array([0, 13, 16, 32], np.int32))
+        fold = lambda x: x.reshape(P, S, H * D)  # noqa: E731
+        out = pa._paged_attn_pallas(q, fold(kp), fold(vp), bt, cl,
+                                    float(1 / np.sqrt(D)), H, interpret=True)
+        assert out.shape == (B, H, D) and out.dtype == q.dtype
+        ref = pa.paged_attention_xla(
+            q.astype(jnp.float32), fold(kp).astype(jnp.float32),
+            fold(vp).astype(jnp.float32), bt, cl)
+        assert np.all(np.asarray(out.astype(jnp.float32))[0] == 0.0)
+        # float32 to rounding of the sums; bfloat16 to its output rounding
+        atol = 2e-6 if dtype == "float32" else 1e-2
+        np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)),
+                                   np.asarray(ref), rtol=0, atol=atol)
+
+    def test_dispatch_counts_the_folded_kernel(self, interp, monkeypatch):
+        monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_MAX_CONFIGS", "1")
+        rng = np.random.default_rng(7)
+        q, kp, vp, bt = _rand_pool(rng, 2, 12, 64, 8, 10, 4)
+        cl = jnp.asarray(np.array([9, 30], np.int32))
+        before = dict(pa._stats)
+        out = pa.paged_attention(q, kp.reshape(10, 8, 768),
+                                 vp.reshape(10, 8, 768), bt, cl)
+        assert pa._stats["folded"] == before["folded"] + 1
+        assert pa._stats["pallas"] == before["pallas"] + 1
+        # a 4-D pool is the same call through a reshape
+        np.testing.assert_array_equal(
+            np.asarray(out), np.asarray(pa.paged_attention(q, kp, vp, bt, cl)))
+
+    def test_head_size_off_the_lane_grid_takes_xla(self, interp):
+        """A head of 96 lanes is neither a fraction nor a multiple of a
+        128-lane tile: the gate sends it to the gather, it does not fail."""
+        rng = np.random.default_rng(8)
+        q, kp, vp, bt = _rand_pool(rng, 1, 2, 96, 8, 4, 2)
+        before = dict(pa._stats)
+        pa.paged_attention(q, kp, vp, bt, jnp.asarray([5], jnp.int32))
+        assert pa._stats["xla"] == before["xla"] + 1
+        assert pa._stats["pallas"] == before["pallas"]
+
+
+def _np_pages(P, S, HD):
+    return np.zeros((P, S, HD), np.float32)
+
+
+class TestFoldedScatter:
+    """`cache_append` / `prefill_append` / `cow_copy_pages` on folded pools
+    against a NumPy model of the pages."""
+
+    def test_cache_append_matches_numpy_model(self):
+        rng = np.random.default_rng(11)
+        P, S, H, D, B = 7, 4, 3, 8, 3
+        model_k, model_v = _np_pages(P, S, H * D), _np_pages(P, S, H * D)
+        kp, vp = jnp.asarray(model_k), jnp.asarray(model_v)
+        bt = np.array([[2, 3], [4, 1], [5, 6]], np.int32)
+        cl = np.array([5, 2, 0], np.int32)
+        active = np.array([True, True, False])
+        for step in range(3):
+            k_new = rng.normal(size=(B, H * D)).astype(np.float32)
+            # (3-D rows too)
+            v_new = rng.normal(size=(B, H, D)).astype(np.float32)
+            kp, vp = pa.cache_append(kp, vp, jnp.asarray(k_new),
+                                     jnp.asarray(v_new), jnp.asarray(bt),
+                                     jnp.asarray(cl), jnp.asarray(active))
+            for b in range(B):
+                page, off = (bt[b, cl[b] // S], cl[b] % S) if active[b] \
+                    else (0, 0)
+                model_k[page, off] = k_new[b]
+                model_v[page, off] = v_new[b].reshape(-1)
+            cl = cl + active
+        assert kp.shape == (P, S, H * D)
+        np.testing.assert_array_equal(np.asarray(kp)[1:], model_k[1:])
+        np.testing.assert_array_equal(np.asarray(vp)[1:], model_v[1:])
+
+    @pytest.mark.parametrize("start", [0, 5])
+    def test_prefill_append_matches_numpy_model(self, start):
+        rng = np.random.default_rng(12)
+        P, S, H, D, L, length = 8, 4, 3, 8, 12, 10
+        model = rng.normal(size=(P, S, H * D)).astype(np.float32)
+        kp, vp = jnp.asarray(model), jnp.asarray(model)
+        page_ids = np.array([2, 5, 7, 0], np.int32)
+        k_seq = rng.normal(size=(L, H * D)).astype(np.float32)
+        kp, vp = pa.prefill_append(kp, vp, jnp.asarray(k_seq),
+                                   jnp.asarray(k_seq.reshape(L, H, D)),
+                                   jnp.asarray(page_ids), jnp.int32(length),
+                                   start=start)
+        for i in range(start, length):    # below start: shared pages, kept
+            model[page_ids[i // S], i % S] = k_seq[i]
+        np.testing.assert_array_equal(np.asarray(kp)[1:], model[1:])
+        np.testing.assert_array_equal(np.asarray(vp)[1:], model[1:])
+
+    def test_cow_copy_matches_numpy_model(self):
+        rng = np.random.default_rng(13)
+        model = [rng.normal(size=(6, 4, 24)).astype(np.float32)
+                 for _ in range(4)]
+        k, v = pa.cow_copy_pages([jnp.asarray(m) for m in model[:2]],
+                                 [jnp.asarray(m) for m in model[2:]], 3, 5)
+        for m in model:
+            m[5] = m[3]
+        for got, want in zip(list(k) + list(v), model):
+            np.testing.assert_array_equal(np.asarray(got), want)
+
+
 class TestCacheAppend:
     def test_append_lands_in_block_table_slot(self):
         page_size = 4
@@ -181,6 +303,81 @@ class TestCacheAppend:
         assert np.all(kp_np[5, 0] == 5.0) and np.all(kp_np[5, 1] == 6.0)
         # padded positions (7, 8, 9) landed on the null page, not page 5
         assert np.all(kp_np[5, 2:] == 0.0)
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One described (not attached) v5e chip to compile for. Described
+    inside a fixture, never at import: see the on-chip-measurement guide."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# the two served configurations' pools and lanes (benchmark/workloads):
+# (heads, head size, pool pages, lanes, pages per sequence)
+_SERVED = {"gpt2_small": (12, 64, 2049, 32, 64),
+           "gpt3_1p3b": (16, 128, 1025, 16, 128)}
+
+
+class TestPoolLayout:
+    """The compiled decode and prefill programs must hold a K/V pool in
+    ONE layout from argument to result. Before PR 26 the 4-D pool of
+    GPT-2 small had its pages in the lanes by default, and every program
+    copied every pool in and out (PERF.md section 5). One layer of each
+    program, compiled ahead of time for a described v5e and counted by the
+    function `ServingEngine.audit()` uses."""
+
+    @staticmethod
+    def _args(chip, H, D, P, B, n, pool_shape):
+        def sds(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+        pool = sds(pool_shape)
+        return pool, dict(
+            q=sds((B, H, D)), rows=sds((B, H * D)), bt=sds((B, n), jnp.int32),
+            cl=sds((B,), jnp.int32), active=sds((B,), jnp.bool_),
+            seq=sds((256, H * D)), page_ids=sds((n,), jnp.int32),
+            scalar=sds((), jnp.int32))
+
+    @staticmethod
+    def _decode_layer(q, k_new, v_new, kp, vp, bt, cl, active):
+        kp, vp = pa._append_impl(kp, vp, k_new, v_new, bt, cl, active)
+        out = pa._paged_attn_pallas(q, kp, vp, bt, jnp.where(active, cl + 1, 0),
+                                    float(1 / np.sqrt(q.shape[-1])),
+                                    q.shape[1])
+        return out, kp, vp
+
+    def _reports(self, chip, H, D, P, B, n, pool_shape):
+        from paddle_tpu.analysis import pool_relayout_report
+        pool, a = self._args(chip, H, D, P, B, n, pool_shape)
+        decode = jax.jit(self._decode_layer, donate_argnums=(3, 4)).lower(
+            a["q"], a["rows"], a["rows"], pool, pool, a["bt"], a["cl"],
+            a["active"]).compile()
+        prefill = jax.jit(pa.prefill_append, donate_argnums=(0, 1)).lower(
+            pool, pool, a["seq"], a["seq"], a["page_ids"], a["scalar"],
+            a["scalar"]).compile()
+        return [pool_relayout_report(c, [pool]) for c in (decode, prefill)]
+
+    @pytest.mark.parametrize("config", sorted(_SERVED))
+    def test_folded_pool_is_updated_in_place(self, v5e_chip, config):
+        H, D, P, B, n = _SERVED[config]
+        for rep in self._reports(v5e_chip, H, D, P, B, n, (P, 16, H * D)):
+            assert rep["pool_relayout_copies"] == 0, rep
+            assert rep["temp_size_in_bytes"] < rep["pool_bytes"], rep
+
+    def test_the_count_sees_the_4d_pool_of_before(self, v5e_chip):
+        """The same one-layer programs on GPT-2 small's pool as it was
+        stored before, [pages, page, 12, 64]: one copy in and one out."""
+        H, D, P, B, n = _SERVED["gpt2_small"]
+        for rep in self._reports(v5e_chip, H, D, P, B, n, (P, 16, H, D)):
+            assert rep["pool_relayout_copies"] >= 2, rep
+            assert rep["temp_size_in_bytes"] > rep["pool_bytes"], rep
 
 
 class TestAutotunePagedAttn:

@@ -296,6 +296,14 @@ class DisaggPipeline:
                  max_worker_restarts: int = 3):
         import jax
 
+        if engine.cache.has_state:
+            from ..models.decode_cache import StateLayersUnsupported
+            d = engine.cache.describe()
+            raise StateLayersUnsupported(
+                "disaggregated prefill/decode (DisaggPipeline)",
+                "a hand-off of the slot's recurrent and convolution state "
+                "beside its K/V pages (KVHandoff carries pages only)",
+                kv_layers=d["kv_layers"], state_layers=d["state_layers"])
         self.engine = engine
         #: per-request dispatch bound: a request whose prefill keeps
         #: losing its worker is failed LOUDLY through result() after

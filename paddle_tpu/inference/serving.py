@@ -494,12 +494,40 @@ def _inject_pages_impl(k_pages, v_pages, k_payload, v_payload, page_ids):
 
 
 class ServingEngine:
-    """Continuous-batching decode engine over one model's paged KV cache.
+    """Continuous-batching decode engine over one model's decode cache.
 
-    `model` must expose the GPT decode protocol (`init_cache`,
-    `forward_prefill`, `forward_decode` — models/gpt.py). Drive it either
-    synchronously (`submit` then `run_until_idle`, tests/bench) or with
-    the background thread (`start()`; `close()` joins it).
+    The decode protocol `model` implements (models/gpt.py and
+    models/olmo_hybrid.py do):
+
+    * ``init_cache(max_batch, max_len, page_size=, num_pages=)`` returns a
+      `models/decode_cache.PagedKVCache`: one pytree that DESCRIBES ITSELF
+      per layer (`layer_kinds`, `describe()`): K/V page pools for the
+      layers that attend over every past token, and for each layer that
+      carries a recurrence a fixed-size state per batch slot. The engine
+      allocates, shares, copies and injects pages of the pools that
+      exist, counts both kinds in `pool_bytes()` / `status()`, and never
+      assumes one K/V pair per model layer;
+    * ``forward_prefill(ids [1, bucket], cache, slot, length,
+      write_start=)`` computes the prompt whole, writes its K/V into the
+      slot's pages from `write_start` on (a shared prefix's pages are
+      already there) and OVERWRITES the slot's recurrent state; positions
+      at or past `length` are bucket padding and must not reach a state;
+      returns (last real position's logits [1, V], cache);
+    * ``forward_decode(tokens [W], cache, active [W], slot_map=[W])``
+      is one token for each lane: lane i works on slot `slot_map[i]`; a
+      padding lane carries the sentinel `max_batch`, gathers clamped and
+      must have every write DROPPED; returns (logits [W, V], cache);
+    * ``wte.weight``, the token embedding, whose dtype is the cache's
+      default;
+    * optionally ``set_tp_mesh(mesh, axis)`` / ``tp_mesh()``: a model
+      without them is refused `mesh=` (tensor-parallel decode) with a
+      ValueError naming the protocol, and a model with recurrent-state
+      layers refuses it, as `inference/disagg.py` does, with
+      `StateLayersUnsupported` naming the protocol that is missing.
+
+    Drive it either synchronously (`submit` then `run_until_idle`,
+    tests/bench) or with the background thread (`start()`; `close()`
+    joins it).
 
     `num_pages` below full backing turns the allocator into a real
     constraint: admission waits for pages and decode preempts when the
@@ -569,9 +597,10 @@ class ServingEngine:
                                       num_pages=num_pages)
         self._budget_capped: Optional[Tuple[int, int]] = None
         if self.mem_budget_bytes > 0:
-            per_page = max(1, self.pool_bytes() // max(1,
-                                                       self.cache.num_pages))
-            fit = int(self.mem_budget_bytes // per_page)
+            # the budget buys pages after the states' fixed cost
+            per_page = max(1, self.cache.describe()["page_bytes"])
+            fit = int(max(0, self.mem_budget_bytes
+                          - self.cache.state_bytes()) // per_page)
             if fit < self.cache.num_pages:
                 capped = max(2, fit)
                 self._budget_capped = (self.cache.num_pages, capped)
@@ -645,6 +674,10 @@ class ServingEngine:
                       "shared_admissions": 0, "swaps": 0, "restarts": 0,
                       "handoffs": 0, "worker_prefills": 0,
                       "min_free_pages": self.allocator.free_pages}
+        # what the cache holds, by kind (constants of the engine's life)
+        desc = self.cache.describe()
+        self.stats.update({k: desc[k] for k in (
+            "kv_layers", "state_layers", "state_bytes_per_slot")})
         # request-scoped observability plane: lifecycle tracer, sliding-
         # window SLO tracker, and a bounded ring of per-iteration
         # introspection snapshots (the /requests endpoint payload tail)
@@ -682,10 +715,17 @@ class ServingEngine:
     def _model_key(self) -> tuple:
         cfg = getattr(self.model, "cfg", None)
         dt = self.cache.k_pages[0].dtype
-        return (getattr(cfg, "num_layers", 0),
-                getattr(cfg, "hidden_size", 0),
-                getattr(cfg, "num_heads", 0),
-                self.page_size, str(np.dtype(dt) if dt is not None else ""))
+        key = (getattr(cfg, "num_layers", 0),
+               getattr(cfg, "hidden_size", 0),
+               getattr(cfg, "num_heads", 0),
+               self.page_size, str(np.dtype(dt) if dt is not None else ""))
+        if self.cache.has_state:
+            # only a cache with state layers adds to the key: a model of
+            # paged layers alone keeps the key its tuned entries carry
+            d = self.cache.describe()
+            key += ("".join(k[0] for k in d["layer_kinds"]),
+                    tuple(d["state_shape"]))
+        return key
 
     # -- jitted model steps ---------------------------------------------------
     # The fused decode step is the tentpole: every layer, the paged-
@@ -723,7 +763,8 @@ class ServingEngine:
         donation/aliasing of the page pools, dtype hygiene, baked
         constants — and COMPILE both to see what "donation accepted"
         cannot: each report's `pool_relayout_copies` counts the `copy`
-        instructions of a pool's shape in the optimized HLO (the device's
+        instructions of a pool's shape (or a per-slot state's, where the
+        model has state layers) in the optimized HLO (the device's
         default layout for the pool's shape differing from the one the
         program works in, PERF.md section 5) beside the program's
         `temp_size_in_bytes`. Nothing executes and the live cache is
@@ -732,7 +773,10 @@ class ServingEngine:
         import jax.numpy as jnp
         from .. import analysis
         W = self.decode_buckets[0]
-        pools = self.cache.k_pages[:1] + self.cache.v_pages[:1]
+        # one buffer of each shape the programs should update in place:
+        # a K and a V pool, and a recurrent and a convolution state
+        pools = (self.cache.k_pages[:1] + self.cache.v_pages[:1]
+                 + self.cache.states[:1] + self.cache.conv_states[:1])
         lane_args = (jnp.zeros((W,), jnp.int32),           # tokens
                      jnp.full((W,), self.max_batch, jnp.int32),  # slot_map
                      jnp.zeros((W,), bool),                # lane_active
@@ -1001,10 +1045,11 @@ class ServingEngine:
 
     # -- self-healing plane: hot-swap / restart / degradation -----------------
     def pool_bytes(self) -> int:
-        """Device bytes held by the KV page pools (all layers, K + V)."""
-        return int(sum(int(k.nbytes) + int(v.nbytes)
-                       for k, v in zip(self.cache.k_pages,
-                                       self.cache.v_pages)))
+        """Device bytes the decode cache holds: the K/V page pools (every
+        paged layer, K + V) and the per-slot recurrent states. Pages are
+        what `mem_budget_bytes` and `shrink_pool` can give back; the
+        states are a fixed cost of `max_batch`."""
+        return self.cache.pool_bytes() + self.cache.state_bytes()
 
     def request_swap(self, params: Dict, buffers: Optional[Dict] = None, *,
                      step: Optional[int] = None, source: str = "manual",
@@ -1709,8 +1754,31 @@ class ServingEngine:
         with self._lock:
             snap["queue_depth"] = len(self._queue)
         snap["occupancy"] = sum(r is not None for r in self._slots)
+        snap["cache"] = self.cache_snapshot()
         snap["introspection"] = self.introspection(n)
         return snap
+
+    def cache_snapshot(self) -> Dict:
+        """The decode cache by kind, for `status()` and `/requests`: the
+        page pools (layers, pages used / free / parked, bytes) and the
+        per-slot recurrent states (layers, bytes a slot, slots in use)."""
+        d = self.cache.describe()
+        free, parked = self.allocator.free_pages, self.allocator.reserved_pages
+        return {
+            "pages": {"layers": d["kv_layers"], "page_size": d["page_size"],
+                      "total": d["num_pages"] - 1, "free": free,
+                      "parked": parked,
+                      "used": d["num_pages"] - 1 - free - parked,
+                      "bytes_per_page": d["page_bytes"],
+                      "bytes": d["pool_bytes"]},
+            "state": {"layers": d["state_layers"], "shape": d["state_shape"],
+                      "conv_shape": d["conv_state_shape"],
+                      "bytes_per_slot": d["state_bytes_per_slot"],
+                      "slots": d["slots"],
+                      "slots_in_use": sum(r is not None
+                                          for r in self._slots),
+                      "bytes": d["state_bytes"]},
+        }
 
     def wedged(self, stall_after: Optional[float] = None) -> bool:
         """True when the engine holds work but has not completed a decode
@@ -1759,6 +1827,7 @@ class ServingEngine:
                 "page_size": self.page_size,
                 "num_pages": self.cache.num_pages,
                 "free_pages": self.allocator.free_pages,
+                "cache": self.cache_snapshot(),
                 "queue_depth": len(self._queue),
                 "occupancy": sum(r is not None for r in self._slots),
                 "prefill_buckets": list(self.prefill_buckets),

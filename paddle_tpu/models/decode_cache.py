@@ -1,0 +1,172 @@
+"""The decode cache a model hands the serving engine: paged K/V for the
+layers that attend over every past token, a fixed-size state per batch
+slot for the layers that carry a recurrence, both in one pytree.
+
+A model's `init_cache` builds it, its `forward_prefill` /
+`forward_decode` take and return it, and `inference/serving.py` reads
+its own description (`layer_kinds`, `describe()`) instead of assuming
+one K and one V pool per model layer.
+"""
+from __future__ import annotations
+
+import math
+from typing import Optional, Sequence
+
+import numpy as np
+
+KV = "kv"          # a layer with a paged K and V pool
+STATE = "state"    # a layer with a recurrent state and a convolution tail
+
+
+class StateLayersUnsupported(NotImplementedError):
+    """A serving path that moves K/V pages and knows no protocol for a
+    per-slot recurrent state was asked to serve a model that has state
+    layers."""
+
+    def __init__(self, path: str, missing: str, *, kv_layers: int,
+                 state_layers: int):
+        super().__init__(
+            f"{path} cannot serve a model with recurrent-state layers "
+            f"({state_layers} state, {kv_layers} paged K/V): missing "
+            f"protocol: {missing}")
+        self.path = path
+        self.missing = missing
+
+
+def _nbytes(x) -> int:
+    """Of an array or of its shape alone (`jax.eval_shape`)."""
+    return math.prod(x.shape) * np.dtype(x.dtype).itemsize
+
+
+class PagedKVCache:
+    """Paged decode KV cache + per-slot recurrent state.
+
+    ``k_pages[i]`` / ``v_pages[i]`` are ``[num_pages, page_size, H*D]``,
+    one pair for each layer whose kind is ``"kv"``, in layer order, the
+    heads FOLDED into the minor axis (head h in lanes [h*D, (h+1)*D);
+    ``num_heads`` / ``head_dim`` say how). Folded, because a jitted
+    program holds its arguments and results to the device's default
+    layout for their shape: the TPU lays ``[.., H*D]`` out row-major
+    whenever H*D is a multiple of 128, but puts the PAGES of a 4-D
+    ``[.., 12, 64]`` pool in the lanes, and then every decode and prefill
+    program re-lays out every pool on the way in and on the way out
+    (ops/pallas/paged_attention.py says how to check a new shape ahead of
+    time). ``block_tables`` is ``[max_batch, pages_per_seq]`` int32 and
+    ``context_lens`` ``[max_batch]`` int32. Page 0 is the NULL page: idle
+    batch slots point at it and their decode-step writes land there (see
+    the serving allocator).
+
+    ``states[j]`` ``[max_batch, ...]`` and ``conv_states[j]``
+    ``[max_batch, K-1, C]`` belong to the j-th layer whose kind is
+    ``"state"``: row b is batch slot b's recurrent state and the last
+    K-1 inputs of its short convolution. They are not paged: their size
+    does not grow with the context. Prefill OVERWRITES a slot's row
+    (whatever a previous request left there), decode updates it in
+    place.
+
+    ``layer_kinds`` names each model layer's kind (default: every layer
+    paged K/V, the GPT case). Registered as a pytree so a whole serving
+    decode step jits over it with pools and states donated."""
+
+    def __init__(self, k_pages, v_pages, block_tables, context_lens,
+                 page_size: int, num_heads: int, head_dim: int,
+                 states: Sequence = (), conv_states: Sequence = (),
+                 layer_kinds: Optional[Sequence[str]] = None):
+        self.k_pages = list(k_pages)
+        self.v_pages = list(v_pages)
+        self.block_tables = block_tables
+        self.context_lens = context_lens
+        self.page_size = int(page_size)
+        self.num_heads = int(num_heads)
+        self.head_dim = int(head_dim)
+        self.states = list(states)
+        self.conv_states = list(conv_states)
+        if layer_kinds is None:
+            layer_kinds = (KV,) * len(self.k_pages)
+        self.layer_kinds = tuple(layer_kinds)
+        # layer index -> index into k_pages/v_pages or states/conv_states
+        counts = {KV: 0, STATE: 0}
+        self._index = []
+        for kind in self.layer_kinds:
+            self._index.append(counts[kind])
+            counts[kind] += 1
+        if counts[KV] != len(self.k_pages) \
+                or counts[STATE] != len(self.states):
+            raise ValueError(
+                f"layer_kinds {self.layer_kinds} names {counts[KV]} paged "
+                f"and {counts[STATE]} state layers; the cache holds "
+                f"{len(self.k_pages)} pools and {len(self.states)} states")
+
+    def index_of(self, layer: int) -> int:
+        """Where model layer `layer` sits in the lists of its own kind."""
+        return self._index[layer]
+
+    @property
+    def num_pages(self) -> int:
+        return self.k_pages[0].shape[0]
+
+    @property
+    def pages_per_seq(self) -> int:
+        return self.block_tables.shape[1]
+
+    @property
+    def max_batch(self) -> int:
+        return self.block_tables.shape[0]
+
+    @property
+    def has_state(self) -> bool:
+        return bool(self.states)
+
+    def pool_bytes(self) -> int:
+        """Device bytes of the K/V page pools (every paged layer, K + V)."""
+        return sum(_nbytes(p) for p in self.k_pages + self.v_pages)
+
+    def state_bytes(self) -> int:
+        """Device bytes of the recurrent and convolution states, all
+        slots."""
+        return sum(_nbytes(s) for s in self.states + self.conv_states)
+
+    def describe(self) -> dict:
+        """What the cache holds, by kind: for status pages, memory
+        accounting and the autotune key."""
+        slots = max(1, self.max_batch)
+        return {
+            "layer_kinds": list(self.layer_kinds),
+            "kv_layers": len(self.k_pages),
+            "state_layers": len(self.states),
+            "num_pages": self.num_pages,
+            "page_size": self.page_size,
+            "page_bytes": self.pool_bytes() // max(1, self.num_pages),
+            "pool_bytes": self.pool_bytes(),
+            "slots": self.max_batch,
+            "state_shape": (list(self.states[0].shape[1:])
+                            if self.states else None),
+            "conv_state_shape": (list(self.conv_states[0].shape[1:])
+                                 if self.conv_states else None),
+            "state_dtype": str(self.states[0].dtype) if self.states else None,
+            "state_bytes_per_slot": self.state_bytes() // slots,
+            "state_bytes": self.state_bytes(),
+        }
+
+    def tree_flatten(self):
+        return ((self.k_pages, self.v_pages, self.block_tables,
+                 self.context_lens, self.states, self.conv_states),
+                (self.page_size, self.num_heads, self.head_dim,
+                 self.layer_kinds))
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        k, v, bt, cl, states, conv = children
+        page_size, num_heads, head_dim, kinds = aux
+        return cls(k, v, bt, cl, page_size, num_heads, head_dim,
+                   states=states, conv_states=conv, layer_kinds=kinds)
+
+
+def _register_cache_pytree():
+    import jax
+    jax.tree_util.register_pytree_node(
+        PagedKVCache, PagedKVCache.tree_flatten,
+        PagedKVCache.tree_unflatten)
+
+
+_register_cache_pytree()

@@ -16,67 +16,7 @@ from .. import nn
 from ..nn import functional as F
 from ..framework.tensor import Tensor
 from ..ops import arange, reshape, transpose
-
-
-class PagedKVCache:
-    """Paged decode KV cache: per-layer page pools + per-sequence block
-    tables (ops/pallas/paged_attention.py layout).
-
-    ``k_pages[l]`` / ``v_pages[l]`` are ``[num_pages, page_size, H*D]``,
-    the heads FOLDED into the minor axis (head h in lanes [h*D, (h+1)*D);
-    ``num_heads`` / ``head_dim`` say how). Folded, because a jitted
-    program holds its arguments and results to the device's default
-    layout for their shape: the TPU lays ``[.., H*D]`` out row-major
-    whenever H*D is a multiple of 128, but puts the PAGES of a 4-D
-    ``[.., 12, 64]`` pool in the lanes, and then every decode and prefill
-    program re-lays out every pool on the way in and on the way out
-    (ops/pallas/paged_attention.py says how to check a new shape ahead of
-    time). ``block_tables`` is ``[max_batch, pages_per_seq]`` int32 and
-    ``context_lens`` ``[max_batch]`` int32. Page 0 is the NULL page: idle
-    batch slots point at it and their decode-step writes land there (see
-    the serving allocator). Registered as a pytree so a whole serving
-    decode step jits over it with the pools donated."""
-
-    def __init__(self, k_pages, v_pages, block_tables, context_lens,
-                 page_size: int, num_heads: int, head_dim: int):
-        self.k_pages = list(k_pages)
-        self.v_pages = list(v_pages)
-        self.block_tables = block_tables
-        self.context_lens = context_lens
-        self.page_size = int(page_size)
-        self.num_heads = int(num_heads)
-        self.head_dim = int(head_dim)
-
-    @property
-    def num_pages(self) -> int:
-        return self.k_pages[0].shape[0]
-
-    @property
-    def pages_per_seq(self) -> int:
-        return self.block_tables.shape[1]
-
-    @property
-    def max_batch(self) -> int:
-        return self.block_tables.shape[0]
-
-    def tree_flatten(self):
-        return ((self.k_pages, self.v_pages, self.block_tables,
-                 self.context_lens),
-                (self.page_size, self.num_heads, self.head_dim))
-
-    @classmethod
-    def tree_unflatten(cls, aux, children):
-        return cls(*children, *aux)
-
-
-def _register_cache_pytree():
-    import jax
-    jax.tree_util.register_pytree_node(
-        PagedKVCache, PagedKVCache.tree_flatten,
-        PagedKVCache.tree_unflatten)
-
-
-_register_cache_pytree()
+from .decode_cache import PagedKVCache  # noqa: F401  (its home until PR 27)
 
 
 @dataclasses.dataclass

@@ -564,7 +564,6 @@ def bench_gpt2():
         "name": f"gpt2-small-124M b{B} s{L} bf16+fp32-master",
         "platform": _platform(),
         "scale": _SCALE,
-        "fused_opt": bool(getattr(step, "fused_opt", False)),
         "tuned_vs_static": tuned_vs_static,
         "program_audit": _program_audit_block(
             lambda: [step.audit(ids, labels)]),

@@ -337,8 +337,7 @@ def phase_train(cfg, *, batch: int, seq: int, steps: int,
     assert _on_platform((step.params, step.opt_state), platform), (
         f"train state is not on a {platform} device")
     return {"losses": [round(x, 4) for x in losses],
-            "call_wall_s": walls,  # first includes trace+tune+compile
-            "fused_opt": bool(step.fused_opt)}
+            "call_wall_s": walls}  # first includes trace+tune+compile
 
 
 # ------------------------------ phase: serve --------------------------------

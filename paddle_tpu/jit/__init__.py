@@ -346,10 +346,13 @@ _to_static_seq = [0]
 # ---------------------------------------------------------------------------
 class TrainStep:
     _seq = 0
+    # the optimizer's update is one fusion per parameter leaf, in place on
+    # the donated buffers. The packed multi-tensor form this attribute
+    # named is gone; benchmark/kinds/train.py still reports it
+    fused_opt = False
 
     def __init__(self, layer: Layer, loss_fn: Callable, optimizer,
-                 donate: bool = True, amp_dtype=None, health=None,
-                 fused_opt=None):
+                 donate: bool = True, amp_dtype=None, health=None):
         """amp_dtype: e.g. jnp.bfloat16 enables O2 mixed precision — fp32
         master weights and optimizer slots, parameters cast to amp_dtype for
         the forward/backward compute (reference AMP level O2, master-weight
@@ -364,14 +367,6 @@ class TrainStep:
         default) follows PADDLE_TPU_HEALTH=1 / FLAGS_check_nan_inf; a
         sentinel trip triggers a one-shot eager replay of the last batch
         with the per-op NaN checks armed (first-NaN attribution).
-
-        fused_opt: run the optimizer update as ONE grouped multi-tensor
-        apply over the flattened parameter leaves (bit-identical to the
-        sequential per-parameter loop — Optimizer.apply_fn(fused=True))
-        instead of ~n_params small fused loops. None (the default)
-        follows PADDLE_TPU_FUSED_OPT (on unless set to 0); either way it
-        only engages when the optimizer's update is elementwise
-        (optimizer.fused_update_supported).
 
         NOTE on recompute: a whole-forward jax.checkpoint here is a
         measured no-op for peak memory (XLA already frees residuals as the
@@ -421,14 +416,6 @@ class TrainStep:
                          if jnp.issubdtype(a.dtype, jnp.floating) else a
                          for a in batch)
 
-        if fused_opt is None:
-            fused_opt = str(os.environ.get(
-                "PADDLE_TPU_FUSED_OPT", "1")).strip().lower() \
-                not in ("0", "false", "off", "no")
-        fused_opt = bool(fused_opt) and getattr(
-            optimizer, "fused_update_supported", False)
-        self.fused_opt = fused_opt
-
         def step(params, buffers, opt_state, rng, lr, t, *batch):
             batch = cast_inputs(batch[:-1]) + (batch[-1],)
             def loss_of(p):
@@ -442,15 +429,8 @@ class TrainStep:
                 return (loss.data if isinstance(loss, Tensor) else loss), new_buffers
             (loss, new_buffers), grads = jax.value_and_grad(loss_of, has_aux=True)(params)
             with jax.named_scope("optimizer"):
-                # pass the kwarg only when fusing: duck-typed optimizers
-                # implementing the pre-r06 apply_fn(params, grads, state,
-                # lr, t) protocol must keep working unchanged
-                if fused_opt:
-                    new_params, new_opt = optimizer.apply_fn(
-                        params, grads, opt_state, lr=lr, t=t, fused=True)
-                else:
-                    new_params, new_opt = optimizer.apply_fn(
-                        params, grads, opt_state, lr=lr, t=t)
+                new_params, new_opt = optimizer.apply_fn(
+                    params, grads, opt_state, lr=lr, t=t)
             if health_probe is None:
                 return loss, new_params, new_buffers, new_opt
             # in-graph sentinel: a handful of tiny fused reductions, one

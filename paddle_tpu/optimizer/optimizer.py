@@ -4,9 +4,9 @@ Reference: `python/paddle/optimizer/optimizer.py:50` + the device optimizer
 kernels (`/root/reference/paddle/fluid/operators/optimizers/`). Each
 optimizer defines a pure per-parameter update `_update(p, g, slots, lr, t)`;
 the eager `step()` walks parameters, while `apply_fn()` exposes the same
-update as a jit-compatible pytree transform (the TPU equivalent of the
-reference's fused `merged_adam` multi-tensor kernels — XLA fuses the whole
-tree update into a couple of kernels).
+update as a jit-compatible pytree transform: one `_update` per leaf, which
+XLA compiles to one fusion per leaf (where the reference packs the leaves
+for its `merged_adam` multi-tensor kernels).
 """
 from __future__ import annotations
 
@@ -22,14 +22,6 @@ from .lr import LRScheduler
 
 
 class Optimizer:
-    # True on subclasses whose `_update` is purely ELEMENTWISE in the
-    # parameter (every output element depends only on the same element of
-    # p/g/slots plus scalars): such updates are value-identical on a
-    # concatenated flat vector, which is what makes the fused multi-tensor
-    # apply (`apply_fn(fused=True)`) bit-exact. Optimizers with per-param
-    # reductions (Lamb trust ratio, LARS local lr) must keep this False.
-    _fusable = False
-
     def __init__(self, learning_rate=0.001, parameters=None, weight_decay=None,
                  grad_clip=None, name=None):
         self._learning_rate = learning_rate
@@ -209,100 +201,32 @@ class Optimizer:
             return self._init_slots(fake)
         return jax.tree_util.tree_map(mk, params_tree)
 
-    @property
-    def fused_update_supported(self) -> bool:
-        """May `apply_fn(fused=True)` group this optimizer's update?"""
-        return bool(type(self)._fusable)
-
-    def apply_fn(self, params_tree, grads_tree, state_tree, lr=None, t=1,
-                 fused=False):
+    def apply_fn(self, params_tree, grads_tree, state_tree, lr=None, t=1):
         """Pure update: (params, grads, slots) -> (new_params, new_slots).
 
-        ``fused=True`` (elementwise optimizers only, see ``_fusable``)
-        runs ONE ``_update`` per (dtype, static-kw, slot-layout) group
-        over flattened+concatenated leaves — the merged_adam /
-        multi-tensor-apply form (reference
-        operators/optimizers/merged_adam_op): instead of ~n_params small
-        per-parameter fusions the compiled step gets a handful of big
-        ones, shrinking the optimizer segment's launch overhead.
-        Elementwise math on a concatenated vector is the per-parameter
-        loop's math per element, so the two paths are interchangeable
-        mid-run: slots come out bit-identical, parameters bit-identical
-        for SGD/Momentum and within a few f32 roundings of the step for
-        the Adam family, whose sqrt/divide line the compiler may emit
-        differently once concatenation moves elements across vector lanes
-        (pinned by tests/test_fused_opt.py). Callers with per-leaf sharded
-        state (ZeRO) should keep
-        the default: concatenation would force cross-shard gathers.
+        One ``_update`` per leaf. Inside a compiled step each leaf's
+        parameter, gradient and slots are then read once and written
+        once by that leaf's own fusion, in place on the donated buffers
+        (pinned by tests/test_trainstep_optimizer.py). Packing the leaves
+        into flat vectors (the merged_adam form of the reference) costs
+        full copies in and out of an XLA program and was measured at a
+        third to a half of GPT-2 small's step on a v5e (PERF.md, PR 28).
         """
         lr = self.get_lr() if lr is None else lr
         if self._grad_clip is not None and hasattr(self._grad_clip, "clip_fn"):
             grads_tree = self._grad_clip.clip_fn(grads_tree)
         flat_kp, treedef = jax.tree_util.tree_flatten_with_path(params_tree)
-        names = [jax.tree_util.keystr(kp) for kp, _ in flat_kp]
-        flat_p = [p for _, p in flat_kp]
         flat_g = jax.tree_util.tree_flatten(grads_tree)[0]
         flat_s = treedef.flatten_up_to(state_tree)
-        if fused and self.fused_update_supported and len(flat_p) > 1:
-            new_p, new_s = self._apply_fused(names, flat_p, flat_g, flat_s,
-                                             lr, t)
-        else:
-            new_p, new_s = [], []
-            for name, p, g, s in zip(names, flat_p, flat_g, flat_s):
-                np_, ns_ = self._update(
-                    p, g.astype(jnp.float32) if g.dtype != p.dtype else g,
-                    s, lr, t, **self._param_kw(name))
-                new_p.append(np_.astype(p.dtype))
-                new_s.append(ns_)
+        new_p, new_s = [], []
+        for (kp, p), g, s in zip(flat_kp, flat_g, flat_s):
+            np_, ns_ = self._update(
+                p, g.astype(jnp.float32) if g.dtype != p.dtype else g,
+                s, lr, t, **self._param_kw(jax.tree_util.keystr(kp)))
+            new_p.append(np_.astype(p.dtype))
+            new_s.append(ns_)
         return (jax.tree_util.tree_unflatten(treedef, new_p),
                 jax.tree_util.tree_unflatten(treedef, new_s))
-
-    def _apply_fused(self, names, flat_p, flat_g, flat_s, lr, t):
-        """Grouped multi-tensor update (see apply_fn). A leaf only joins a
-        group when every slot is an array of the param's shape (a loaded
-        legacy state_dict could hold anything); odd leaves fall back to
-        the per-parameter update within the same traced program."""
-        flat_g = [g.astype(jnp.float32) if g.dtype != p.dtype else g
-                  for p, g in zip(flat_p, flat_g)]
-        groups: dict = {}
-        for i, (name, p, g, s) in enumerate(zip(names, flat_p, flat_g,
-                                                flat_s)):
-            kw_key = tuple(sorted(self._param_kw(name).items()))
-            slots_ok = all(
-                hasattr(v, "shape") and tuple(v.shape) == tuple(p.shape)
-                for v in s.values())
-            key = (str(p.dtype), str(g.dtype), kw_key,
-                   tuple(sorted((k, str(v.dtype)) for k, v in s.items()))) \
-                if slots_ok else ("solo", i)
-            groups.setdefault(key, []).append(i)
-        new_p = [None] * len(flat_p)
-        new_s = [None] * len(flat_p)
-        for key, idxs in groups.items():
-            if key[0] == "solo" or len(idxs) == 1:
-                for i in idxs:
-                    np_, ns_ = self._update(flat_p[i], flat_g[i], flat_s[i],
-                                            lr, t,
-                                            **self._param_kw(names[i]))
-                    new_p[i] = np_.astype(flat_p[i].dtype)
-                    new_s[i] = ns_
-                continue
-            kw = dict(key[2])
-            sizes = [int(flat_p[i].size) for i in idxs]
-            p_vec = jnp.concatenate([flat_p[i].reshape(-1) for i in idxs])
-            g_vec = jnp.concatenate([flat_g[i].reshape(-1) for i in idxs])
-            s_vec = {k: jnp.concatenate([flat_s[i][k].reshape(-1)
-                                         for i in idxs])
-                     for k in flat_s[idxs[0]]}
-            np_vec, ns_vec = self._update(p_vec, g_vec, s_vec, lr, t, **kw)
-            offs = np.cumsum(sizes)[:-1]
-            p_parts = jnp.split(np_vec, offs)
-            s_parts = {k: jnp.split(v, offs) for k, v in ns_vec.items()}
-            for j, i in enumerate(idxs):
-                shape = flat_p[i].shape
-                new_p[i] = p_parts[j].reshape(shape).astype(flat_p[i].dtype)
-                new_s[i] = {k: s_parts[k][j].reshape(shape)
-                            for k in s_parts}
-        return new_p, new_s
 
     # -- checkpointing -------------------------------------------------------
     def state_dict(self):

@@ -3,7 +3,7 @@
 Reference kernels: `/root/reference/paddle/fluid/operators/optimizers/`
 (sgd_op, momentum_op, adam_op, adamw_op, lamb_op, adagrad_op, rmsprop_op,
 adadelta_op, adamax_op, lars_momentum_op). Updates are fp32 master-math on
-arrays; XLA fuses the whole per-tree update (merged_adam equivalent).
+arrays; in a compiled step XLA makes one fusion of each leaf's update.
 """
 from __future__ import annotations
 
@@ -13,14 +13,12 @@ from .optimizer import Optimizer
 
 
 class SGD(Optimizer):
-    _fusable = True  # p - lr*g is elementwise
     def _update(self, p, g, slots, lr, t, **kw):
         g = self._decay_grad(p, g)
         return p - lr * g, slots
 
 
 class Momentum(Optimizer):
-    _fusable = True
     def __init__(self, learning_rate=0.001, momentum=0.9, parameters=None,
                  use_nesterov=False, weight_decay=None, grad_clip=None, name=None):
         super().__init__(learning_rate, parameters, weight_decay, grad_clip)
@@ -41,7 +39,6 @@ class Momentum(Optimizer):
 
 
 class Adam(Optimizer):
-    _fusable = True  # AdamW inherits this
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999,
                  epsilon=1e-8, parameters=None, weight_decay=None,
                  grad_clip=None, lazy_mode=False, multi_precision=False,
@@ -91,7 +88,6 @@ class AdamW(Adam):
 
 
 class Adamax(Optimizer):
-    _fusable = True
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999,
                  epsilon=1e-8, parameters=None, weight_decay=None,
                  grad_clip=None, name=None):
@@ -111,7 +107,6 @@ class Adamax(Optimizer):
 
 
 class Adagrad(Optimizer):
-    _fusable = True
     def __init__(self, learning_rate, epsilon=1e-6, parameters=None,
                  weight_decay=None, grad_clip=None, initial_accumulator_value=0.0,
                  name=None):
@@ -130,7 +125,6 @@ class Adagrad(Optimizer):
 
 
 class RMSProp(Optimizer):
-    _fusable = True
     def __init__(self, learning_rate, rho=0.95, epsilon=1e-6, momentum=0.0,
                  centered=False, parameters=None, weight_decay=None,
                  grad_clip=None, name=None):
@@ -163,7 +157,6 @@ class RMSProp(Optimizer):
 
 
 class Adadelta(Optimizer):
-    _fusable = True
     def __init__(self, learning_rate=0.001, epsilon=1e-6, rho=0.95,
                  parameters=None, weight_decay=None, grad_clip=None, name=None):
         super().__init__(learning_rate, parameters, weight_decay, grad_clip)
@@ -183,7 +176,6 @@ class Adadelta(Optimizer):
 
 
 class Lamb(Optimizer):
-    _fusable = False  # per-param trust-ratio norms
     def __init__(self, learning_rate=0.001, lamb_weight_decay=0.01, beta1=0.9,
                  beta2=0.999, epsilon=1e-6, parameters=None, grad_clip=None,
                  exclude_from_weight_decay_fn=None, name=None):
@@ -214,7 +206,6 @@ class Lamb(Optimizer):
 
 
 class LarsMomentum(Momentum):
-    _fusable = False  # per-param LARS local lr
     def __init__(self, learning_rate=0.001, momentum=0.9, lars_coeff=0.001,
                  lars_weight_decay=0.0005, parameters=None, grad_clip=None,
                  epsilon=1e-9, name=None):

@@ -15,9 +15,8 @@ round-over-round.
 
 Measured attribution (--profile-steps) is ON by default so BENCH rounds
 report xplane-measured device time, not just cost-model estimates; opt
-out with --no-profile-steps. Each config also carries an `autotune` block
-(kernel-autotuner cache events + tuned configs for that run) and the
-GPT-2 config a `flops_accounting` block pinning down why hw_flops_util
+out with --no-profile-steps. The GPT-2 config carries a
+`flops_accounting` block pinning down why hw_flops_util
 can sit below mfu (Pallas custom-call flops are invisible to XLA
 cost_analysis).
 """
@@ -209,11 +208,6 @@ def _observability_snapshot():
         out["device_time_error"] = f"{type(e).__name__}: {e}"
     if _HEALTH_BLOCK:
         out["health"] = dict(_HEALTH_BLOCK)
-    try:
-        from paddle_tpu.ops.pallas import autotune as _at
-        out["autotune"] = _at.summary()
-    except Exception as e:
-        out["autotune_error"] = f"{type(e).__name__}: {e}"
     try:
         from paddle_tpu.profiler import events as _events
         out["events_tail"] = _events.recent(20)
@@ -458,48 +452,6 @@ def _program_audit_block(reports_fn):
         return {"error": f"{type(e).__name__}: {e}"}
 
 
-def _tuned_vs_static_probe(build_step, args, iters=6, warmup=2):
-    """Autotune tuned-vs-static comparison, measured in-round: one short
-    timed window with the tuner in its current mode, one with the
-    PADDLE_TPU_AUTOTUNE=0 kill switch (the pre-autotune static picks,
-    fresh trace so block resolution actually re-runs). On TPU this is the
-    `tuned >= static` acceptance check; on CPU both sides resolve static
-    and the ratio reads ~1. Never raises."""
-    import os as _os
-
-    def timed():
-        step = build_step()
-        sec, _, _, _ = _run_config(step, args, iters=iters, warmup=warmup)
-        return 1000.0 * sec
-
-    try:
-        from paddle_tpu.ops.pallas import autotune as _at
-        mode = _at.mode()
-        t_cur = timed()
-        prev = _os.environ.get("PADDLE_TPU_AUTOTUNE")
-        _os.environ["PADDLE_TPU_AUTOTUNE"] = "0"
-        try:
-            t_static = timed()
-        finally:
-            if prev is None:
-                _os.environ.pop("PADDLE_TPU_AUTOTUNE", None)
-            else:
-                _os.environ["PADDLE_TPU_AUTOTUNE"] = prev
-        return {
-            "mode": mode,
-            "probe_ms_tuned": round(t_cur, 2),
-            "probe_ms_static": round(t_static, 2),
-            "tuned_speedup_vs_static": (round(t_static / t_cur, 3)
-                                        if t_cur > 0 else None),
-            "note": ("probe-vs-probe, fresh TrainStep each side; "
-                     "'tuned' side uses the live autotune mode (static "
-                     "resolution off-TPU), 'static' forces the "
-                     "kill-switch picks"),
-        }
-    except Exception as e:
-        return {"error": f"{type(e).__name__}: {e}"}
-
-
 def bench_gpt2():
     import numpy as np
     import jax.numpy as jnp
@@ -547,14 +499,6 @@ def bench_gpt2():
             warmup=_scaled(2, 1)))
     except Exception as e:
         _HEALTH_BLOCK.update({"error": f"{type(e).__name__}: {e}"})
-    # autotune tuned-vs-static, measured on THIS config's shapes
-    def _mk_step():
-        o = optimizer.AdamW(learning_rate=1e-4,
-                            parameters=model.parameters(),
-                            weight_decay=0.01)
-        return TrainStep(model, F.cross_entropy, o, amp_dtype=jnp.bfloat16)
-    tuned_vs_static = _tuned_vs_static_probe(
-        _mk_step, (ids, labels), iters=_scaled(6, 3), warmup=1)
     n_params = sum(int(np.prod(p.shape)) for p in model.parameters())
     # model-FLOPs MFU: 6*N per token (fwd+bwd) + attention 12*L*D_model*T
     attn_flops = 12 * cfg.num_layers * B * L * L * cfg.hidden_size
@@ -564,7 +508,6 @@ def bench_gpt2():
         "name": f"gpt2-small-124M b{B} s{L} bf16+fp32-master",
         "platform": _platform(),
         "scale": _SCALE,
-        "tuned_vs_static": tuned_vs_static,
         "program_audit": _program_audit_block(
             lambda: [step.audit(ids, labels)]),
         "tokens_per_sec_chip": round(B * L / sec, 1),
@@ -1201,9 +1144,6 @@ def bench_resnet50(B=None, hw=None, depth=50, probe_iters=None):
         })
     except Exception as e:
         conv_fusion["error"] = f"{type(e).__name__}: {e}"
-    tuned_vs_static = _tuned_vs_static_probe(
-        lambda: build(best_rc, best_df, True), (imgs[best_df], labels),
-        iters=probe_iters, warmup=2)
     # unfused comparison at the winning layout/remat (compiled in this same
     # run; probe-length timing is enough for the ratio)
     unfused = probes.get((best_rc, best_df, False))
@@ -1225,7 +1165,6 @@ def bench_resnet50(B=None, hw=None, depth=50, probe_iters=None):
         "platform": _platform(),
         "scale": _SCALE,
         "conv_fusion": conv_fusion,
-        "tuned_vs_static": tuned_vs_static,
         "program_audit": _program_audit_block(
             lambda: [step.audit(imgs[best_df], labels)]),
         "samples_per_sec_chip": round(B / sec, 1),
@@ -1697,14 +1636,10 @@ def main(argv=None):
             os._exit(1)
         return 1
     result["platform"] = _platform()
-    # before the first compile: one fixed compile-cache directory (and the
-    # autotuner's entries beside it) unless the environment placed it
+    # before the first compile: one fixed compile-cache directory unless
+    # the environment placed it
     from paddle_tpu.framework.flags import place_caches
     place_caches(os.path.dirname(os.path.abspath(__file__)))
-    try:
-        from paddle_tpu.ops.pallas import autotune as _at
-    except Exception:
-        _at = None
     # EVERY config — including the flagship — inside the guard: one failure
     # must not sink the whole bench (the round-3 lesson).
     for fn, key in ((bench_gpt2, "gpt2_small"),
@@ -1713,30 +1648,12 @@ def main(argv=None):
                     (bench_bert_base, "bert_base_seq128"),
                     (bench_wide_deep_ps, "wide_deep_ps"),
                     (bench_wide_deep_ps_tpu, "wide_deep_ps_tpu")):
-        ev0 = _at.events_snapshot() if _at is not None else {}
-        n_tuned0 = len(_at.tuned_log()) if _at is not None else 0
         try:
             configs[key] = fn()
         except Exception as e:
             import traceback
             configs[key] = {"error": f"{type(e).__name__}: {e}",
                             "traceback": traceback.format_exc(limit=6)}
-        # kernel-autotune activity attributed to THIS config's run (event
-        # deltas + the tune/disk-hit log slice), validated by
-        # tools/check_bench_result.py
-        if _at is not None and isinstance(configs.get(key), dict):
-            try:
-                ev1 = _at.events_snapshot()
-                configs[key]["autotune"] = {
-                    "enabled": _at.enabled(),
-                    "mode": _at.mode(),
-                    "cache_dir": _at.cache_dir() or None,
-                    "events": {k: ev1[k] - ev0.get(k, 0.0) for k in ev1
-                               if ev1[k] - ev0.get(k, 0.0) > 0},
-                    "tuned": _at.tuned_log()[n_tuned0:],
-                }
-            except Exception:
-                pass
     # measured-device-time capture results per config (--profile-steps)
     for key, prof in _PROFILE_RESULTS.items():
         if key in configs and isinstance(configs[key], dict):

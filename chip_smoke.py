@@ -8,8 +8,7 @@ width and depth of GPT-2 small with seeded random weights:
 
 * kernels — every Pallas family the repo turns on by default, compiled by
   Mosaic (``interpret=False``) at the shapes its model uses and compared with
-  its XLA reference. Called directly, so an autotuner's measured choice of
-  the XLA implementation cannot skip a kernel;
+  its XLA reference. Called directly, past the dispatch gates;
 * train   — ``jit.TrainStep`` (bf16 compute, fp32 master, AdamW), b8 x s1024;
 * serve   — ``ServingEngine`` (b32, max_len 1024, page 16) answering a few
   requests of different prompt lengths;
@@ -116,12 +115,10 @@ def kernel_stats() -> dict:
 
 
 def which_path(before: dict, after: dict) -> dict:
-    """Per family: Pallas and XLA dispatches since `before`, and why XLA.
-    Only paged_attention (and conv_bn, unused by GPT-2) can reach XLA by the
-    autotuner's measured impl=0 choice — it counts those apart; every other
-    XLA dispatch is a shape/dtype gate (short rows or sequences, dropout,
-    the kernel being off by default). There is no third reason: a kernel
-    the compiler refuses raises."""
+    """Per family: Pallas and XLA dispatches since `before`. An XLA
+    dispatch is a shape/dtype gate (short rows or sequences, dropout, the
+    kernel being off by default). There is no other reason: a kernel the
+    compiler refuses raises."""
     out = {}
     for fam, now in after.items():
         d = {k: now[k] - before[fam].get(k, 0) for k in now}
@@ -130,12 +127,7 @@ def which_path(before: dict, after: dict) -> dict:
         xla = d.get("xla", d.get("xla_fwd", 0))
         if not (pallas or xla):
             continue
-        row = {"pallas": pallas, "xla": xla}
-        if xla:
-            measured = d.get("xla_measured", 0)
-            row["xla_reason"] = {"shape_gate": xla - measured,
-                                 "measured_choice": measured}
-        out[fam] = row
+        out[fam] = {"pallas": pallas, "xla": xla}
     return out
 
 
@@ -194,9 +186,7 @@ def phase_kernels(shapes: dict, interpret: bool = False) -> dict:
         return jnp.asarray((rng.normal(size=shape) * scale).astype(np.float32)
                            ).astype(dtype)
 
-    # ---- flash attention fwd + bwd (static default blocks: what an
-    # untuned process runs; the autotuner's other candidates are compiled
-    # and timed by the train phase)
+    # ---- flash attention fwd + bwd (blocks=None: `_static_blocks`)
     s = shapes["flash"]
     q, k, v = (randn((s["B"], s["L"], s["H"], s["D"]), jnp.bfloat16)
                for _ in range(3))
@@ -576,10 +566,6 @@ def run_phases(sizes: dict, platform: str) -> dict:
         report["phases"]["four_chips"] = {
             "ok": True, "not_run": f"{len(jax.devices())} device"}
 
-    from paddle_tpu.ops.pallas import autotune
-    report["autotune"] = {"tuned": autotune.tuned_log(),
-                          "refused": autotune.refused_log(),
-                          "cache_dir": autotune.cache_dir()}
     report["compile_total"] = compile_counters()
     report["ok"] = all(p["ok"] for p in report["phases"].values())
     return report

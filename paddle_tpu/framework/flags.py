@@ -137,32 +137,25 @@ def _apply_compile_cache_dir(path):
 
 
 def place_caches(checkout: str) -> str:
-    """Place the compile cache and, beside it, the kernel autotuner's
-    entries, for an entry point that compiles for the chip (chip_smoke.py,
-    bench.py); call before the first compile. Returns the cache root:
-    ``$JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``
-    — one fixed path, because the path is part of jax's cache key and a
-    directory that moves never hits. Autotuned block shapes are part of
-    the compiled program, so a cache that hits needs the same winners next
-    time: unless ``PADDLE_TPU_AUTOTUNE_CACHE_DIR`` says otherwise they
-    live in ``<root>/autotune``.
+    """Place the compile cache for an entry point that compiles for the
+    chip (chip_smoke.py, bench.py); call before the first compile. Returns
+    the cache root: ``$JAX_COMPILATION_CACHE_DIR`` when set, else
+    ``<checkout>/.jax_cache`` — one fixed path, because the path is part
+    of jax's cache key and a directory that moves never hits.
 
     It also keeps the Python call stack out of op locations. jax strips
     locations before hashing a program, but not from inside a Mosaic
-    kernel's serialized body, and a kernel is traced once per process —
-    under the autotuner in a process that tunes, under the compile check
-    in one that loads the winners. With full stacks in there the two
-    processes' train step and serving programs never shared a cache key
-    (chip run, PR 21); file:line locations are the same in both."""
+    kernel's serialized body, and a kernel is traced once per process,
+    under whichever call reaches its compile check first. With full
+    stacks in there two processes' train step and serving programs never
+    shared a cache key (chip run, PR 21); file:line locations are the
+    same in both."""
     import jax
     jax.config.update("jax_include_full_tracebacks_in_locations", False)
     root = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not root:
         root = os.path.join(os.path.abspath(checkout), ".jax_cache")
         set_flags({"FLAGS_compile_cache_dir": root})
-    if not os.environ.get("PADDLE_TPU_AUTOTUNE_CACHE_DIR"):
-        set_flags({"FLAGS_autotune_cache_dir":
-                   os.path.join(root, "autotune")})
     return root
 
 
@@ -211,23 +204,6 @@ define_flag("FLAGS_compile_cache_dir",
             "hits/misses land in xla_compile_cache_events_total. "
             "Set via PADDLE_TPU_COMPILE_CACHE_DIR or set_flags; empty "
             "disables")
-# Pallas kernel autotuner (ops/pallas/autotune.py). The env vars are read
-# LIVE by the autotuner and take precedence; these flags are the set_flags-
-# able fallback when the env is unset. PADDLE_TPU_AUTOTUNE supports the
-# extra value "force" (tune even in interpret mode / on CPU — the CI
-# path), which only the env var can express.
-define_flag("FLAGS_autotune",
-            os.environ.get("PADDLE_TPU_AUTOTUNE", "1").lower() not in
-            ("0", "false", "off", "no"),
-            "benchmark Pallas kernel block-shape candidates at first real "
-            "shape encounter and use the measured winner; off = every "
-            "kernel keeps its static default pick (PADDLE_TPU_AUTOTUNE=0)")
-define_flag("FLAGS_autotune_cache_dir",
-            os.environ.get("PADDLE_TPU_AUTOTUNE_CACHE_DIR", ""),
-            "persistent kernel-autotune cache directory: tuned block "
-            "configs keyed (op, shape-bucket, dtype, chip) as CRC'd JSON; "
-            "a fleet sharing the dir tunes once "
-            "(PADDLE_TPU_AUTOTUNE_CACHE_DIR); empty disables persistence")
 
 if os.environ.get("FLAGS_check_nan_inf"):
     _on_flag_set("FLAGS_check_nan_inf", flag("FLAGS_check_nan_inf"))

@@ -22,8 +22,8 @@ KV cache (ops/pallas/paged_attention.py) —
   context-length bump fuse into a single dispatch with the page pools
   DONATED (the multi-GB pool updates in place per token). Active slots
   gather into ``W`` lanes (``W`` = smallest power-of-two bucket
-  covering the active count, per the ``fused_decode_step`` autotune
-  op), so a mostly-idle batch runs a narrow executable;
+  covering the active count), so a mostly-idle batch runs a narrow
+  executable;
   ``decode_mode="eager"`` keeps the per-op dispatch path alive as the
   measured A/B baseline (``path`` label on the latency histograms);
 * **pages, not slabs**: each sequence owns block-table pages from a
@@ -436,49 +436,6 @@ def _pow2_buckets(lo: int, hi: int) -> List[int]:
     return out
 
 
-#: cross-engine memo for the fused-step autotune decision (cleared by
-#: autotune.reset_for_tests with every other kernel memo)
-def _register_step_memo():
-    from ..ops.pallas import autotune as _autotune
-    return _autotune.register_memo({})
-
-
-_step_cfg_memo = None
-
-
-def _resolve_step_cfg(model_key: tuple, max_batch: int):
-    """The ``fused_decode_step`` autotune decision: lane-bucketed
-    (impl=1, one executable per power-of-two active-lane bucket from
-    ``min_lanes`` up) vs full-width (impl=0, one max_batch-wide
-    executable regardless of occupancy). Persisted per (op, model
-    shape, chip) like every autotuned kernel. On CPU (no measured
-    probe) the static default is lane-bucketed with min_lanes=1 — the
-    narrow executable is the TPOT lever at low occupancy."""
-    global _step_cfg_memo
-    from ..ops.pallas import autotune as _autotune
-    from ..ops.pallas import tiling as _tiling
-    if _step_cfg_memo is None:
-        _step_cfg_memo = _register_step_memo()
-    key = model_key + (max_batch,)
-    memo_key = (key, _autotune.mode())
-    hit = _step_cfg_memo.get(memo_key)
-    if hit is not None:
-        return hit
-    default = _tiling.make_config(impl=1, min_lanes=1)
-    floors = sorted({1, max(1, max_batch // 2)})
-    cands = _tiling.candidate_configs(
-        ("impl", "min_lanes"), [(1,), floors], default)
-    cands = cands + [_tiling.make_config(impl=0, min_lanes=max_batch)]
-    # no bench closure: a representative probe needs live traffic at a
-    # given occupancy; fleets override via PADDLE_TPU_AUTOTUNE_CACHE_DIR
-    # entries measured by the serving bench (tools/check_bench_result
-    # fused_vs_eager block)
-    cfg = _autotune.get_config("fused_decode_step", key, candidates=cands,
-                               default=default, bench=None)
-    _step_cfg_memo[memo_key] = cfg
-    return cfg
-
-
 def _inject_pages_impl(k_pages, v_pages, k_payload, v_payload, page_ids):
     """Scatter a prefill worker's per-layer KV page payload into the
     decode pools (disaggregated handoff). The pools are DONATED — the
@@ -615,16 +572,8 @@ class ServingEngine:
         self.prefill_buckets = sorted(set(int(b) for b in prefill_buckets))
         if self.prefill_buckets[-1] < max_len:
             self.prefill_buckets.append(max_len)
-        # fused-step lane buckets from the autotune decision: impl=1 ->
-        # one executable per pow2 bucket in [min_lanes, max_batch];
-        # impl=0 -> the single full-width executable
-        cfg = _resolve_step_cfg(self._model_key(), self.max_batch)
-        self.step_impl = cfg["impl"]
-        if self.step_impl == 0:
-            self.decode_buckets = [self.max_batch]
-        else:
-            self.decode_buckets = _pow2_buckets(
-                min(cfg["min_lanes"], self.max_batch), self.max_batch)
+        # one fused-step executable per power-of-two lane bucket
+        self.decode_buckets = _pow2_buckets(1, self.max_batch)
 
         self._params = {k: p.data for k, p in model.named_parameters()}
         self._buffers = {k: b.data for k, b in model.named_buffers()}
@@ -711,21 +660,6 @@ class ServingEngine:
         """Shards the KV pools split over (1 = single-chip)."""
         return int(self.mesh.shape[self.tp_axis]) if self.mesh is not None \
             else 1
-
-    def _model_key(self) -> tuple:
-        cfg = getattr(self.model, "cfg", None)
-        dt = self.cache.k_pages[0].dtype
-        key = (getattr(cfg, "num_layers", 0),
-               getattr(cfg, "hidden_size", 0),
-               getattr(cfg, "num_heads", 0),
-               self.page_size, str(np.dtype(dt) if dt is not None else ""))
-        if self.cache.has_state:
-            # only a cache with state layers adds to the key: a model of
-            # paged layers alone keeps the key its tuned entries carry
-            d = self.cache.describe()
-            key += ("".join(k[0] for k in d["layer_kinds"]),
-                    tuple(d["state_shape"]))
-        return key
 
     # -- jitted model steps ---------------------------------------------------
     # The fused decode step is the tentpole: every layer, the paged-

@@ -127,8 +127,8 @@ class PagedKVCache:
         return sum(_nbytes(s) for s in self.states + self.conv_states)
 
     def describe(self) -> dict:
-        """What the cache holds, by kind: for status pages, memory
-        accounting and the autotune key."""
+        """What the cache holds, by kind: for status pages and
+        memory accounting."""
         slots = max(1, self.max_batch)
         return {
             "layer_kinds": list(self.layer_kinds),

@@ -5,8 +5,7 @@ Reference analog: the CUDA `fused/` op tree
 layer (`operators/kernel_primitives/`). Every kernel here has an XLA-composed
 fallback so the op library works on CPU test meshes.
 
-Block-shape selection is shared: `tiling.py` holds the BlockConfig
-vocabulary + candidate generation (VMEM-budgeted, Mosaic-rule-respecting)
-and `autotune.py` the measured search with a persistent
-(op, shape-bucket, dtype, chip) cache — see README "Kernel autotuning".
+Each family picks its block shapes in its own file, from the shapes it is
+called with; `tiling.py` holds what they share (constants, tail masking,
+the eager compile check that raises with the kernel named).
 """

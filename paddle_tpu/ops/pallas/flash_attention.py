@@ -21,7 +21,7 @@ saves softmax-out for bwd, and handles arbitrary attention masks). Here:
   mask inside the fused kernel);
 * dispatch is by shape/dtype eligibility alone: an eligible call takes
   the Pallas path, after one eager compile check at the exact production
-  shapes (`autotune.compile_check`) whose failure RAISES, naming the
+  shapes (`tiling.compile_check`) whose failure RAISES, naming the
   kernel — a kernel Mosaic refuses is a bug, not a route to XLA.
 
 `_stats` counts dispatch decisions at trace time so tests can assert the
@@ -39,7 +39,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.pallas import tpu as pltpu
 
-from . import autotune as _autotune
 from . import tiling as _tiling
 from .tiling import ceil_to as _ceil_to
 from .tiling import on_tpu as _on_tpu
@@ -612,7 +611,6 @@ def _use_small_path(Lq: int, Lk: int, H: int, D: int, dtype,
 
 
 def _static_blocks(Lq: int, Lk: int):
-    # the pre-autotune fixed picks (the PADDLE_TPU_AUTOTUNE=0 behavior);
     # blocks are multiples of 64 (covers f32/bf16 sublane granularity); a
     # block larger than the array is one virtually-padded block whose tail
     # the kernels mask in-register
@@ -621,141 +619,20 @@ def _static_blocks(Lq: int, Lk: int):
 
 
 def _blocks_or_static(blocks, Lq: int, Lk: int):
-    """(block_q, block_k) from a resolved config tuple, static otherwise."""
+    """(block_q, block_k) as given, `_static_blocks` otherwise."""
     return blocks if blocks is not None else _static_blocks(Lq, Lk)
 
 
-# ---- autotuned block selection (tiling/autotune layer) ----------------------
-#
-# Resolution happens at DISPATCH time (like the compile check, and for
-# the same reason: it runs compiled kernels eagerly, which is legal at
-# trace time of a user's outer jit but not inside a pallas body). The
-# resolved (fwd, bwd) configs ride the custom_vjp as a nondiff static arg,
-# so fwd and bwd each use exactly the config they were tuned and probed at.
-
-def _fa_fwd_vmem_bytes(cfg, D: int, itemsize: int, has_mask: bool) -> int:
-    bq, bk = cfg["q"], cfg["k"]
-    b = 2 * (bq * D + 2 * bk * D) * itemsize       # double-buffered q/k/v in
-    b += 2 * (bq * D * itemsize + bq * _STATS_LANES * 4)  # o/lse out
-    b += bq * D * 4 + 2 * bq * _CARRY_LANES * 4    # acc/m/l scratch
-    if has_mask:
-        b += 2 * bq * bk  # worst-case bool mask block, double-buffered
-    return b
-
-
-def _fa_bwd_vmem_bytes(cfg, Lq: int, D: int, itemsize: int,
-                       has_mask: bool, fused: bool) -> int:
-    bq, bk = cfg["q"], cfg["k"]
-    b = 2 * (2 * bq * D + 2 * bk * D) * itemsize   # q/do + k/v in
-    b += 2 * (2 * bq * _STATS_LANES * 4)           # lse/delta in
-    b += 2 * (bq * D + 2 * bk * D) * itemsize      # dq/dk/dv out
-    b += 2 * bk * D * 4                            # dk/dv scratch
-    if fused:
-        b += _ceil_to(Lq, bq) * D * 4              # whole-(b,h) dq scratch
-    else:
-        b += bq * D * 4
-    if has_mask:
-        b += 2 * bq * bk
-    return b
-
-
-# dispatch-time fast path: eager callers resolve per call, so skip the
-# candidate/bench construction once a key is decided (keyed on mode too —
-# a live PADDLE_TPU_AUTOTUNE flip must re-consult the tuner)
-_blocks_memo = _autotune.register_memo({})
-
-
-def _resolve_flash_blocks(q, k, mask, causal):
-    """((fwd_bq, fwd_bk), (bwd_bq, bwd_bk)) for the grid-walk path, or
-    None on the small path (whole-sequence blocks, nothing to tune)."""
+def _resolve_flash_blocks(q, k, mask):
+    """(block_q, block_k) for the grid-walk kernels, forward and backward
+    alike, or None on the small path (whole-sequence blocks). Resolved at
+    dispatch and carried by the custom_vjp as a static argument, so the
+    compile check, the forward and the backward all see one pair."""
     B, Lq, H, D = q.shape
     Lk = k.shape[1]
-    dtype = q.dtype
-    if _use_small_path(Lq, Lk, H, D, dtype, mask):
+    if _use_small_path(Lq, Lk, H, D, q.dtype, mask):
         return None
-    # fused-vs-split bwd selection depends on EXACT Lq, not its bucket —
-    # two lengths sharing a bucket can straddle the threshold, so the
-    # choice is part of the key (the tune op name carries it on disk too)
-    fused_bwd = Lq * D * 4 <= _FUSED_BWD_DQ_BYTES
-    key = (_tiling.shape_bucket(Lq), _tiling.shape_bucket(Lk), H, D,
-           jnp.dtype(dtype).name, bool(causal), _mask_key(mask))
-    memo_key = (key, fused_bwd, _INTERPRET, _autotune.mode())
-    hit = _blocks_memo.get(memo_key)
-    if hit is not None:
-        return hit
-    default = _tiling.make_config(q=_static_blocks(Lq, Lk)[0],
-                                  k=_static_blocks(Lq, Lk)[1])
-    itemsize = jnp.dtype(dtype).itemsize
-    has_mask = mask is not None
-    is_bool = has_mask and mask.dtype == jnp.bool_
-    sc = float(1.0 / np.sqrt(D))
-    # probe arrays: tiny batch/head extent — B and H are grid-PARALLEL
-    # dims, so per-block behavior (what the tune ranks) is B/H-invariant,
-    # while the walked q/k axes keep their REAL lengths
-    Bp, Hp = 2, min(H, 4)
-    buf = {}
-
-    def _args():
-        if not buf:
-            buf["q"] = jnp.ones((Bp, Lq, Hp, D), dtype)
-            buf["k"] = jnp.ones((Bp, Lk, Hp, D), dtype)
-            pm = None
-            if has_mask:
-                shp = tuple(1 if d == 1 else {0: Bp, 1: Hp, 2: Lq,
-                                              3: Lk}[ax]
-                            for ax, d in enumerate(mask.shape))
-                pm = (jnp.ones(shp, jnp.bool_) if is_bool
-                      else jnp.zeros(shp, mask.dtype))
-            buf["m"] = pm
-        return buf["q"], buf["k"], buf["m"]
-
-    def bench_fwd(cfg):
-        qa, ka, pm = _args()
-        out = _fa_fwd_pallas(qa, ka, ka, pm, bool(causal), sc,
-                             mask_is_bool=is_bool, interpret=_INTERPRET,
-                             blocks=(cfg["q"], cfg["k"]))
-        jax.block_until_ready(out)
-
-    bwd_fn = _fa_bwd_fused_pallas if fused_bwd else _fa_bwd_pallas
-
-    def bench_bwd(cfg):
-        qa, ka, pm = _args()
-        if "out" not in buf:
-            # residuals once, at the static fwd config — bwd timing must
-            # not fold a per-candidate forward into the clock
-            buf["out"], buf["lse"] = _fa_fwd_pallas(
-                qa, ka, ka, pm, bool(causal), sc, mask_is_bool=is_bool,
-                interpret=_INTERPRET, blocks=_static_blocks(Lq, Lk))
-        grads = bwd_fn(qa, ka, ka, buf["out"], buf["lse"], qa, pm,
-                       bool(causal), sc, mask_is_bool=is_bool,
-                       interpret=_INTERPRET, blocks=(cfg["q"], cfg["k"]))
-        jax.block_until_ready(grads)
-
-    qs = _tiling.axis_candidates(Lq, (128, 256, 512))
-    ks = _tiling.axis_candidates(Lk, (256, 512, 1024))
-    fwd_cfg = _autotune.get_config(
-        "flash_fwd", key, candidates=_tiling.candidate_configs(
-            ("q", "k"), [qs, ks], default,
-            vmem_bytes=lambda c: _fa_fwd_vmem_bytes(c, D, itemsize,
-                                                    has_mask)),
-        default=default, bench=bench_fwd, interpret=_INTERPRET)
-    # bwd candidate space is WIDER than fwd (perf-round r06): the backward
-    # walks q and k in both loop orders and re-reads residuals per block,
-    # so its block-efficiency optimum sits elsewhere — small q blocks cut
-    # dq re-accumulation traffic, large k blocks amortize the residual
-    # streams. The r05 GPT-2 attention-bwd segment is the measured target.
-    qs_bwd = _tiling.axis_candidates(Lq, (64, 128, 256, 512))
-    ks_bwd = _tiling.axis_candidates(Lk, (128, 256, 512, 1024))
-    bwd_cfg = _autotune.get_config(
-        "flash_bwd_fused" if fused_bwd else "flash_bwd_split", key,
-        candidates=_tiling.candidate_configs(
-            ("q", "k"), [qs_bwd, ks_bwd], default,
-            vmem_bytes=lambda c: _fa_bwd_vmem_bytes(c, Lq, D, itemsize,
-                                                    has_mask, fused_bwd)),
-        default=default, bench=bench_bwd, interpret=_INTERPRET)
-    result = ((fwd_cfg["q"], fwd_cfg["k"]), (bwd_cfg["q"], bwd_cfg["k"]))
-    _blocks_memo[memo_key] = result
-    return result
+    return _static_blocks(Lq, Lk)
 
 
 def _mask_spec(mask, block_q, block_k, *, q_axis, k_axis):
@@ -804,7 +681,7 @@ def _compiler_params(interpret, n_arbitrary=1):
 def _fa_fwd_pallas(q, k, v, mask, causal, scale, mask_is_bool=False,
                    interpret=False, blocks=None):
     """Returns (out [B,L,H,D], lse [B,H,Lq] f32). mask may be None.
-    `blocks` is the resolved (block_q, block_k); None = static picks."""
+    `blocks` is (block_q, block_k); None = `_static_blocks`."""
     from jax.experimental import pallas as pl
 
     B, Lq, H, D = q.shape
@@ -1093,7 +970,7 @@ def _fwd_any(q, k, v, mask, causal, scale, mask_is_bool, interpret,
                                     interpret=interpret)
     return _fa_fwd_pallas(q, k, v, mask, causal, scale,
                           mask_is_bool=mask_is_bool, interpret=interpret,
-                          blocks=blocks[0] if blocks else None)
+                          blocks=blocks)
 
 
 def _bwd_any(q, k, v, out, lse, do, mask, causal, scale, mask_is_bool,
@@ -1109,7 +986,7 @@ def _bwd_any(q, k, v, out, lse, do, mask, causal, scale, mask_is_bool,
         f = _fa_bwd_pallas        # very long seq: split dq / dkv walks
     return f(q, k, v, out, lse, do, mask, causal, scale,
              mask_is_bool=mask_is_bool, interpret=interpret,
-             blocks=blocks[1] if blocks else None)
+             blocks=blocks)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
@@ -1162,7 +1039,7 @@ def _mask_key(mask):
 
 
 def _check_compiles(dtype, Lq, Lk, H, D, causal, mask=None, blocks=None):
-    """Eager fwd+bwd compile check (`autotune.compile_check`) at the exact
+    """Eager fwd+bwd compile check (`tiling.compile_check`) at the exact
     production (L, H, D) shapes AND the exact resolved block config —
     including the BACKWARD kernels, so the custom_vjp path is known-good
     under value_and_grad before it is staged into the user's jit. H is
@@ -1187,10 +1064,10 @@ def _check_compiles(dtype, Lq, Lk, H, D, causal, mask=None, blocks=None):
 
         return jax.grad(f, argnums=(0, 1, 2))(q, k, k)
 
-    _autotune.compile_check(
+    _tiling.compile_check(
         "flash_attention", run, dtype=jnp.dtype(dtype).name,
         q=(2, Lq, H, D), k=(2, Lk, H, D), causal=bool(causal),
-        mask=_mask_key(mask), blocks_fwd_bwd=blocks or "whole-sequence",
+        mask=_mask_key(mask), blocks=blocks or "whole-sequence",
         interpret=_INTERPRET)
 
 
@@ -1277,9 +1154,7 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
         return _flash_per_shard(km, q, k, v, mask, causal, scale)
     if _pallas_eligible(q, k, v, mask, causal):
         B, Lq, H, D = q.shape
-        # blocks resolve BEFORE the compile check: it must compile exactly
-        # the (possibly autotuned) config production runs
-        blocks = _resolve_flash_blocks(q, k, mask, causal)
+        blocks = _resolve_flash_blocks(q, k, mask)
         _check_compiles(q.dtype, Lq, k.shape[1], H, D, causal, mask, blocks)
         _stats["pallas"] += 1
         is_bool = mask is not None and mask.dtype == jnp.bool_

@@ -27,11 +27,12 @@ another single multiply-add over per-channel constants:
 The Pallas path runs on TPU (or under the interpreter in tests, so CPU CI
 exercises the kernels); elsewhere an identical XLA composition is used —
 `layer_norm.py` idiom: `_on_tpu()` + shape gate, then an eager compile
-check that raises (`autotune.compile_check`) — no fallback behind it.
+check that raises (`tiling.compile_check`) — no fallback behind it.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +41,6 @@ from jax.experimental.pallas import tpu as pltpu
 # shared with the unfused path in nn/functional: running-stat parity
 # requires the statistics formulation to be THE SAME code
 from .._bn_common import _bn_axes, _bn_stats
-from . import autotune as _autotune
 from . import tiling as _tiling
 from .tiling import on_tpu as _on_tpu
 
@@ -49,14 +49,9 @@ _INTERPRET = False  # tests flip this to run the kernels in the interpreter
 
 _stats = {"pallas_fwd": 0, "pallas_bwd": 0, "xla_fwd": 0, "xla_bwd": 0}
 
-_DEF_BLOCK_ROWS = 256  # static pick (the PADDLE_TPU_AUTOTUNE=0 behavior);
-                       # also the eligibility floor: R below this stays XLA
+_DEF_BLOCK_ROWS = 256  # also the eligibility floor: R below this stays XLA
 _MAX_PALLAS_C = 2048  # three (256, C) fp32 buffers must fit VMEM
 _SUBLANES = 8       # fp32 sublane count — reduction outputs are (8, C)
-
-# autotune probes cap their synthetic row count: the kernels are pure
-# row-block streams, so candidate ranking at a bounded R ranks any R
-_BENCH_MAX_ROWS = 65536
 
 
 # ----------------------------- shared math ----------------------------------
@@ -218,71 +213,22 @@ def _bn_bwd_dx_pallas(x2d, y2d, dy2d, a, b, c0, act, has_add,
 # -------------------- block selection + compile check -----------------------
 
 
-def _bn_vmem_bytes(cfg, C: int, itemsize: int, has_add: bool) -> int:
-    # worst pass is bwd dx: three double-buffered (br, C) inputs
-    # (x/y/dy), the dx output — plus dz for the residual-add family —
-    # and the fp32 x/g compute intermediates
-    br = cfg["rows"]
-    n_out = 2 if has_add else 1
-    return (3 + n_out) * (2 * br * C * itemsize) + 2 * br * C * 4
-
-
-_blocks_memo = _autotune.register_memo({})
-
-
-def _block_rows_for(dtype, R: int, C: int, has_add: bool) -> int:
-    """Autotuned row-block extent shared by all three kernels of this
-    family (fwd, bwd-reduce, bwd-dx) — one tune times the full chain, the
-    shapes a training step actually runs. Static _DEF_BLOCK_ROWS when
-    tuning is off for this mode/platform. (A tuned extent larger than a
-    bucket-aliased smaller R is fine here: the reduce kernel masks the
-    `R % br` tail and the elementwise passes clip on write.)"""
-    memo_key = (_tiling.shape_bucket(R, floor=_DEF_BLOCK_ROWS), C,
-                jnp.dtype(dtype).name, has_add, _INTERPRET,
-                _autotune.mode())
-    hit = _blocks_memo.get(memo_key)
-    if hit is not None:
-        return hit
-    default = _tiling.make_config(rows=_DEF_BLOCK_ROWS)
-    itemsize = jnp.dtype(dtype).itemsize
-    cands = _tiling.candidate_configs(
-        ("rows",),
-        [_tiling.axis_candidates(R, (128, 256, 512, 1024),
-                                 grain=_tiling.sublane(dtype))],
-        default, vmem_bytes=lambda c: _bn_vmem_bytes(c, C, itemsize,
-                                                     has_add))
-    rb = min(_tiling.shape_bucket(R, floor=_DEF_BLOCK_ROWS), _BENCH_MAX_ROWS)
-    buf = {}
-
-    def bench(cfg):
-        if not buf:
-            buf["x"] = jnp.ones((rb, C), dtype)
-            buf["v"] = jnp.ones((C,), jnp.float32)
-        x, v = buf["x"], buf["v"]
-        br = cfg["rows"]
-        y = _bn_act_fwd_pallas(x, x if has_add else None, v, v, act="relu",
-                               has_add=has_add, interpret=_INTERPRET,
-                               block_rows=br)
-        db, dg = _bn_bwd_reduce_pallas(x, y, x, v, v, act="relu",
-                                       interpret=_INTERPRET, block_rows=br)
-        outs = _bn_bwd_dx_pallas(x, y, x, v, v, v, act="relu",
-                                 has_add=has_add, interpret=_INTERPRET,
-                                 block_rows=br)
-        jax.block_until_ready((y, db, dg, outs))
-
-    cfg = _autotune.get_config(
-        "fused_bn", key=memo_key[:4],
-        candidates=cands, default=default, bench=bench,
-        interpret=_INTERPRET)
-    _blocks_memo[memo_key] = cfg["rows"]
-    return cfg["rows"]
+def _block_rows_for(R: int, C: int) -> Optional[int]:
+    """Row-block extent shared by the family's three kernels (fwd,
+    bwd-reduce, bwd-dx) over an [R, C] view, or None where the shape
+    stays on XLA: fewer rows than one block, rows off the sublane grain,
+    or channels off the lane grain or too wide for VMEM."""
+    if isinstance(R, int) and R >= _DEF_BLOCK_ROWS and R % _SUBLANES == 0 \
+            and C % 128 == 0 and C <= _MAX_PALLAS_C:
+        return _DEF_BLOCK_ROWS
+    return None
 
 
 def _check_compiles(dtype, C: int, has_add: bool, block_rows: int,
                     tail: bool):
     """Per-(dtype, channels, block-rows, tail?) eager compile check of the
     whole fwd / bwd-reduce / bwd-dx chain at the exact block shape
-    production uses (`autotune.compile_check`). `tail` selects the
+    production uses (`tiling.compile_check`). `tail` selects the
     `R % br` masked-reduce variant (a different Mosaic program, gated by
     `if R % br:` in the kernel): production shapes with a partial last
     block must check THAT variant, so the array gets one extra sublane
@@ -301,7 +247,7 @@ def _check_compiles(dtype, C: int, has_add: bool, block_rows: int,
                                  block_rows=block_rows)
         return y, db, dg, outs
 
-    _autotune.compile_check(
+    _tiling.compile_check(
         "fused_bn", run, dtype=jnp.dtype(dtype).name, channels=C,
         has_add=has_add, block_rows=block_rows, tail=tail,
         interpret=_INTERPRET)
@@ -316,13 +262,11 @@ def _pallas_eligible(x, data_format: str, has_add: bool) -> bool:
     R = 1
     for d in x.shape[:-1]:
         R *= d
-    if not isinstance(R, int) or R < _DEF_BLOCK_ROWS or R % _SUBLANES:
-        return False
-    if C % 128 or C > _MAX_PALLAS_C:
+    br = _block_rows_for(R, C)
+    if br is None:
         return False
     if x.dtype not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
         return False
-    br = _block_rows_for(x.dtype, R, C, has_add)
     _check_compiles(x.dtype, C, has_add, br, tail=R % br != 0)
     return True
 
@@ -340,7 +284,7 @@ def _fwd_common(x, z, gamma, beta, eps, data_format, act):
         C = x.shape[-1]
         x2d = x.reshape(-1, C)
         z2d = z.reshape(-1, C) if has_add else None
-        br = _block_rows_for(x.dtype, x2d.shape[0], C, has_add)
+        br = _block_rows_for(x2d.shape[0], C)
         y = _bn_act_fwd_pallas(x2d, z2d, k, c, act=act, has_add=has_add,
                                interpret=_INTERPRET,
                                block_rows=br).reshape(x.shape)
@@ -368,7 +312,7 @@ def _bwd_common(res, cots, eps, data_format, act, has_add):
         _stats["pallas_bwd"] += 1
         C = x.shape[-1]
         x2d, y2d, dy2d = (t.reshape(-1, C) for t in (x, y, dy))
-        br = _block_rows_for(x.dtype, x2d.shape[0], C, has_add)
+        br = _block_rows_for(x2d.shape[0], C)
         db, dg = _bn_bwd_reduce_pallas(x2d, y2d, dy2d, mean, inv, act=act,
                                        interpret=_INTERPRET, block_rows=br)
     else:

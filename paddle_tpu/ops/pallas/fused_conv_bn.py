@@ -21,15 +21,10 @@ full-activation stats read):
     composed:  conv writes y; stats read y; apply reads y, writes out
     fused:     conv writes y + tiny (2, C) stats; apply reads y, writes out
 
-Per-shape implementation choice is MEASURED, not hand-picked: the autotune
-candidate space (registered on :mod:`.tiling`/:mod:`.autotune` as op
-``"conv_bn"``) carries an ``impl`` axis — ``impl=1`` candidates are Pallas
-block shapes, ``impl=0`` is the XLA-composed rewrite (matmul + fused
-stats + elementwise epilogue in one XLA program, no custom-call boundary) —
-and the tuner's timed probe of the full fwd+bwd chain decides per
-(shape-bucket, dtype, chip). Non-1x1 / strided / grouped convolutions are
-out of scope here and keep the existing conv -> ``F.batch_norm(act=)``
-composition (``nn.functional.conv2d_bn`` routes).
+Only a stride-1, unpadded, ungrouped 1x1 convolution whose shape
+:func:`_blocks_for` takes runs here (:func:`eligible`); every other
+convolution keeps the conv -> ``F.batch_norm(act=)`` composition
+(``nn.functional.conv2d_bn`` routes).
 
 Interpret-mode runs the kernels under the Pallas interpreter so CPU CI
 exercises the kernel path itself (same contract as ``fused_bn``; the
@@ -39,28 +34,24 @@ the shared apply/backward kernels).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
-from .._bn_common import _bn_stats
-from . import autotune as _autotune
 from . import fused_bn as _fused_bn
 from . import tiling as _tiling
 from .tiling import on_tpu as _on_tpu
 
 _INTERPRET = False  # tests flip this (with fused_bn._INTERPRET) for CPU CI
 
-_stats = {"pallas_fwd": 0, "xla_fwd": 0, "pallas_bwd": 0, "xla_bwd": 0}
+_stats = {"pallas_fwd": 0, "pallas_bwd": 0}
 
 _SUBLANES = 8           # fp32 sublane count — stats accumulators are (8, C)
 _DEF_BLOCK_ROWS = 256
 _DEF_BLOCK_COLS = 256
 _MAX_CIN = 2048         # full Cin stripe of x and w must sit in VMEM
-# autotune probes cap their synthetic row count (pure row-stream kernels:
-# ranking at a bounded R ranks any R — same contract as fused_bn)
-_BENCH_MAX_ROWS = 32768
 
 
 def _interp() -> bool:
@@ -138,80 +129,27 @@ def _stats_from_sums(s, ss, R: int):
     return mean, var
 
 
-# --------------------- candidate space + impl decision ----------------------
+# ---------------------- block pick + compile check --------------------------
 
-def _vmem_bytes(cfg, Cin: int, itemsize: int) -> int:
-    br, bc = cfg["rows"], cfg["cols"]
-    # double-buffered x block + w stripe + y block, two fp32 accumulator
-    # tiles, and the fp32 matmul intermediate
-    return (2 * br * Cin * itemsize + 2 * Cin * bc * itemsize
-            + 2 * br * bc * itemsize + 2 * _SUBLANES * bc * 4
-            + br * bc * 4)
-
-
-_cfg_memo = _autotune.register_memo({})
-
-
-def _resolve_cfg(dtype, R: int, Cin: int, Cout: int,
-                 has_add: bool) -> _tiling.BlockConfig:
-    """The measured per-shape decision: Pallas block shape OR the
-    XLA-composed rewrite (impl=0). Candidates time the full fused
-    fwd+bwd chain; the persistent autotune cache (op "conv_bn") makes the
-    decision once per (shape-bucket, dtype, chip) fleet-wide."""
-    interpret = _interp()
-    memo_key = (_tiling.shape_bucket(R, floor=_DEF_BLOCK_ROWS), Cin, Cout,
-                jnp.dtype(dtype).name, has_add, interpret, _autotune.mode())
-    hit = _cfg_memo.get(memo_key)
-    if hit is not None:
-        return hit
-    itemsize = jnp.dtype(dtype).itemsize
-    default = _tiling.make_config(impl=1, rows=_DEF_BLOCK_ROWS,
-                                  cols=min(_DEF_BLOCK_COLS, Cout))
-    grain = _tiling.sublane(dtype)
-    pallas_cands = _tiling.candidate_configs(
-        ("impl", "rows", "cols"),
-        [(1,),
-         _tiling.axis_candidates(R, (128, 256, 512), grain=grain),
-         _tiling.axis_candidates(Cout, (128, 256, 512), grain=_tiling.LANE)],
-        default,
-        vmem_bytes=lambda c: _vmem_bytes(c, Cin, itemsize))
-    # the XLA-composed rewrite is a first-class candidate: "decided by
-    # measured probe, not by taste"
-    cands = pallas_cands + [_tiling.make_config(impl=0, rows=0, cols=0)]
-
-    rb = min(_tiling.shape_bucket(R, floor=_DEF_BLOCK_ROWS), _BENCH_MAX_ROWS)
-    buf = {}
-
-    def bench(cfg):
-        if not buf:
-            buf["x"] = jnp.ones((rb, Cin), dtype)
-            buf["w"] = jnp.ones((Cin, Cout), dtype)
-            buf["g"] = jnp.ones((Cout,), jnp.float32)
-            buf["z"] = jnp.ones((rb, Cout), dtype) if has_add else None
-        x, w, g, z = buf["x"], buf["w"], buf["g"], buf["z"]
-
-        def run(xx):
-            args = (xx,) + ((z,) if has_add else ()) + (w, g, g)
-            out = _op(has_add)(*args, 1e-5, "relu", cfg)
-            return out[0].astype(jnp.float32).sum()
-
-        val, grads = jax.value_and_grad(run)(x)
-        jax.block_until_ready((val, grads))
-
-    cfg = _autotune.get_config(
-        "conv_bn", key=memo_key[:5], candidates=cands, default=default,
-        bench=bench, interpret=interpret)
-    _cfg_memo[memo_key] = cfg
-    return cfg
+def _blocks_for(R: int, Cin: int, Cout: int) -> Optional[tuple]:
+    """(block_rows, block_cols) of the matmul + statistics kernel over
+    x [R, Cin] @ w [Cin, Cout], or None where the shape stays on the
+    unfused composition: channels off the lane grain, a Cin stripe too
+    wide for VMEM, fewer rows than one block or rows off the sublane
+    grain."""
+    if Cin % _tiling.LANE or Cout % _tiling.LANE:
+        return None
+    if Cin > _MAX_CIN or Cout > _MAX_CIN:
+        return None
+    if R < _DEF_BLOCK_ROWS or R % _SUBLANES:
+        return None
+    return _DEF_BLOCK_ROWS, min(_DEF_BLOCK_COLS, Cout)
 
 
-def _check_compiles(dtype, R: int, Cin: int, Cout: int, cfg):
-    """Eager compile check at the exact resolved block shape
-    (`autotune.compile_check`); the tail-masked variant when R % rows.
-    The XLA rewrite (impl=0) has nothing to check."""
-    if cfg["impl"] == 0:
-        return
-    br, bc = cfg["rows"], cfg["cols"]
+def _check_compiles(dtype, R: int, Cin: int, Cout: int, blocks):
+    """Eager compile check at the exact block shape
+    (`tiling.compile_check`); the tail-masked variant when R % rows."""
+    br, bc = blocks
 
     def run():
         rows = br + (_SUBLANES if R % br else 0)
@@ -220,7 +158,7 @@ def _check_compiles(dtype, R: int, Cin: int, Cout: int, cfg):
         return _conv1x1_stats_pallas(x, w, interpret=_interp(),
                                      block_rows=br, block_cols=bc)
 
-    _autotune.compile_check(
+    _tiling.compile_check(
         "conv_bn", run, dtype=jnp.dtype(dtype).name, cin=Cin, cout=Cout,
         block_rows=br, block_cols=bc, tail=bool(R % br),
         interpret=_interp())
@@ -228,9 +166,8 @@ def _check_compiles(dtype, R: int, Cin: int, Cout: int, cfg):
 
 def eligible(x_shape, w_shape, stride, padding, dilation, groups,
              data_format: str, dtype) -> bool:
-    """Can this conv+BN run the fused 1x1 path at all? (The impl choice
-    within the path — Pallas kernel vs XLA rewrite — is then measured.)
-    w_shape is the conv layer layout (O, I, kh, kw)."""
+    """Can this conv+BN run the fused 1x1 path? w_shape is the conv
+    layer layout (O, I, kh, kw)."""
     if not (_on_tpu() or _interp()):
         return False
     if data_format.startswith("NC") or len(x_shape) != 4:
@@ -255,58 +192,33 @@ def eligible(x_shape, w_shape, stride, padding, dilation, groups,
     R = int(x_shape[0]) * int(x_shape[1]) * int(x_shape[2])
     if int(x_shape[3]) != Cin:
         return False
-    if Cin % _tiling.LANE or Cout % _tiling.LANE:
-        return False
-    if Cin > _MAX_CIN or Cout > _MAX_CIN:
-        return False
-    if R < _DEF_BLOCK_ROWS or R % _SUBLANES:
+    blocks = _blocks_for(R, Cin, Cout)
+    if blocks is None:
         return False
     if jnp.dtype(dtype) not in (jnp.dtype(jnp.float32),
                                 jnp.dtype(jnp.bfloat16)):
         return False
-    cfg = _resolve_cfg(dtype, R, Cin, Cout, has_add=False)
-    _check_compiles(dtype, R, Cin, Cout, cfg)
+    _check_compiles(dtype, R, Cin, Cout, blocks)
     return True
 
 
 # ----------------------------- fwd/bwd common -------------------------------
 
-def _conv_fwd(x2d, w2d, cfg):
-    """(y_conv, mean, var) via the resolved impl."""
-    R = x2d.shape[0]
-    if cfg["impl"] == 1:
-        _stats["pallas_fwd"] += 1
-        y, s, ss = _conv1x1_stats_pallas(x2d, w2d, interpret=_interp(),
-                                         block_rows=cfg["rows"],
-                                         block_cols=cfg["cols"])
-        mean, var = _stats_from_sums(s, ss, R)
-    else:
-        _stats["xla_fwd"] += 1
-        y = jnp.dot(x2d, w2d, preferred_element_type=jnp.float32) \
-            .astype(x2d.dtype)
-        mean, var = _bn_stats(y, axes=(0,))
-    return y, mean, var
-
-
-def _fwd_common(x2d, z2d, w2d, gamma, beta, epsilon, act, cfg):
-    """Conv (+stats) then normalize(+add)+act. The Pallas impl reuses the
-    PR-1 fused-BN elementwise kernel for the epilogue; the XLA impl stays
-    custom-call-free so the whole matmul->stats->epilogue chain can fuse
-    in one XLA program."""
-    y_conv, mean, var = _conv_fwd(x2d, w2d, cfg)
+def _fwd_common(x2d, z2d, w2d, gamma, beta, epsilon, act):
+    """Conv (+stats) then normalize(+add)+act; the epilogue is the PR-1
+    fused-BN elementwise kernel where fused_bn's own gate takes it."""
+    _stats["pallas_fwd"] += 1
+    br, bc = _blocks_for(x2d.shape[0], *w2d.shape)
+    y_conv, s, ss = _conv1x1_stats_pallas(x2d, w2d, interpret=_interp(),
+                                          block_rows=br, block_cols=bc)
+    mean, var = _stats_from_sums(s, ss, x2d.shape[0])
     inv = jax.lax.rsqrt(var + epsilon)
     k, c = _fused_bn._fold_affine(gamma, beta, mean, inv)
     has_add = z2d is not None
-    use_pallas_apply = (cfg["impl"] == 1
-                        and _fused_bn._pallas_eligible(y_conv, "NHWC",
-                                                       has_add))
-    if use_pallas_apply:
-        br = _fused_bn._block_rows_for(y_conv.dtype, y_conv.shape[0],
-                                       y_conv.shape[1], has_add)
-        y = _fused_bn._bn_act_fwd_pallas(y_conv, z2d, k, c, act=act,
-                                         has_add=has_add,
-                                         interpret=_interp(),
-                                         block_rows=br)
+    if _fused_bn._pallas_eligible(y_conv, "NHWC", has_add):
+        y = _fused_bn._bn_act_fwd_pallas(
+            y_conv, z2d, k, c, act=act, has_add=has_add, interpret=_interp(),
+            block_rows=_fused_bn._block_rows_for(*y_conv.shape))
     else:
         yf = y_conv.astype(jnp.float32) * k + c
         if has_add:
@@ -317,12 +229,9 @@ def _fwd_common(x2d, z2d, w2d, gamma, beta, epsilon, act, cfg):
     return y, mean, var, inv, y_conv
 
 
-def _bwd_common(res, cots, epsilon, act, has_add, cfg):
+def _bwd_common(res, cots, epsilon, act, has_add):
     x2d, w2d, gamma, beta, mean, inv, y_conv, y_out = res
-    if cfg["impl"] == 1:
-        _stats["pallas_bwd"] += 1
-    else:
-        _stats["xla_bwd"] += 1
+    _stats["pallas_bwd"] += 1
     # BN(+add)+act backward over the conv output — the PR-1 single-pass
     # reduce + dx kernels (or their XLA twin, fused_bn's own gates decide)
     d_yconv, dz, dgamma, dbeta = _fused_bn._bwd_common(
@@ -339,55 +248,51 @@ def _bwd_common(res, cots, epsilon, act, has_add, cfg):
 
 # ----------------------------- custom-vjp ops -------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _conv_bn_act(x2d, w2d, gamma, beta, epsilon, act, cfg):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _conv_bn_act(x2d, w2d, gamma, beta, epsilon, act):
     y, mean, var, _, _ = _fwd_common(x2d, None, w2d, gamma, beta, epsilon,
-                                     act, cfg)
+                                     act)
     return y, mean, var
 
 
-def _conv_bn_act_fwd(x2d, w2d, gamma, beta, epsilon, act, cfg):
+def _conv_bn_act_fwd(x2d, w2d, gamma, beta, epsilon, act):
     y, mean, var, inv, y_conv = _fwd_common(x2d, None, w2d, gamma, beta,
-                                            epsilon, act, cfg)
+                                            epsilon, act)
     # residuals: x2d/w2d live anyway; y_conv is the fused op's one extra
     # saved activation (the composed path saves it too — it is BN's input);
     # y_out doubles as the ReLU mask
     return (y, mean, var), (x2d, w2d, gamma, beta, mean, inv, y_conv, y)
 
 
-def _conv_bn_act_bwd(epsilon, act, cfg, res, cots):
+def _conv_bn_act_bwd(epsilon, act, res, cots):
     dx, dw, dgamma, dbeta, _ = _bwd_common(res, cots, epsilon, act,
-                                           has_add=False, cfg=cfg)
+                                           has_add=False)
     return dx, dw, dgamma, dbeta
 
 
 _conv_bn_act.defvjp(_conv_bn_act_fwd, _conv_bn_act_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _conv_bn_add_act(x2d, z2d, w2d, gamma, beta, epsilon, act, cfg):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _conv_bn_add_act(x2d, z2d, w2d, gamma, beta, epsilon, act):
     y, mean, var, _, _ = _fwd_common(x2d, z2d, w2d, gamma, beta, epsilon,
-                                     act, cfg)
+                                     act)
     return y, mean, var
 
 
-def _conv_bn_add_act_fwd(x2d, z2d, w2d, gamma, beta, epsilon, act, cfg):
+def _conv_bn_add_act_fwd(x2d, z2d, w2d, gamma, beta, epsilon, act):
     y, mean, var, inv, y_conv = _fwd_common(x2d, z2d, w2d, gamma, beta,
-                                            epsilon, act, cfg)
+                                            epsilon, act)
     return (y, mean, var), (x2d, w2d, gamma, beta, mean, inv, y_conv, y)
 
 
-def _conv_bn_add_act_bwd(epsilon, act, cfg, res, cots):
+def _conv_bn_add_act_bwd(epsilon, act, res, cots):
     dx, dw, dgamma, dbeta, dz = _bwd_common(res, cots, epsilon, act,
-                                            has_add=True, cfg=cfg)
+                                            has_add=True)
     return dx, dz, dw, dgamma, dbeta
 
 
 _conv_bn_add_act.defvjp(_conv_bn_add_act_fwd, _conv_bn_add_act_bwd)
-
-
-def _op(has_add: bool):
-    return _conv_bn_add_act if has_add else _conv_bn_act
 
 
 # ----------------------------- public API -----------------------------------
@@ -407,13 +312,10 @@ def fused_conv1x1_bn_act(x, w, gamma, beta, *, residual=None, epsilon=1e-5,
     w2d = w.reshape(Cout, -1).T.astype(x.dtype)  # (Cin, Cout)
     N, H, W, Cin = x.shape
     x2d = x.reshape(-1, Cin)
-    cfg = _resolve_cfg(x.dtype, x2d.shape[0], Cin, Cout,
-                       has_add=residual is not None)
     if residual is not None:
         z2d = residual.reshape(-1, Cout)
         y, mean, var = _conv_bn_add_act(x2d, z2d, w2d, gamma, beta,
-                                        epsilon, act, cfg)
+                                        epsilon, act)
     else:
-        y, mean, var = _conv_bn_act(x2d, w2d, gamma, beta, epsilon, act,
-                                    cfg)
+        y, mean, var = _conv_bn_act(x2d, w2d, gamma, beta, epsilon, act)
     return y.reshape(N, H, W, Cout), mean, var

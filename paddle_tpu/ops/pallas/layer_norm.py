@@ -19,7 +19,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from . import autotune as _autotune
 from . import tiling as _tiling
 from .tiling import on_tpu as _on_tpu
 
@@ -30,7 +29,7 @@ _INTERPRET = False  # tests flip this: kernel runs in the Pallas interpreter
 # flash_attention._stats)
 _stats = {"pallas": 0, "xla": 0}
 
-_DEF_BLOCK_ROWS = 256  # static pick (the PADDLE_TPU_AUTOTUNE=0 behavior)
+_DEF_BLOCK_ROWS = 256
 
 
 # ----------------------------- forward --------------------------------------
@@ -63,10 +62,9 @@ def _ln_fwd_pallas(x2d, gamma, beta, eps: float = 1e-5,
         y = xhat * g_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
         o_ref[...] = y.astype(o_ref.dtype)
 
-    br = block_rows  # STATIC block shape — the compile check compiled
-    # exactly (block_rows, N); the autotuner resolves br BEFORE dispatch
-    # (memory-cached per shape bucket), so no unchecked Mosaic variant
-    # runs inside the user's jit (callers gate on R >= _DEF_BLOCK_ROWS)
+    br = block_rows  # STATIC block shape: the compile check compiled
+    # exactly (block_rows, N), so no unchecked Mosaic variant runs inside
+    # the user's jit (callers gate on R >= block_rows)
     grid = (pl.cdiv(R, br),)  # cover ALL rows; the edge block is masked
     return pl.pallas_call(
         kernel,
@@ -84,68 +82,28 @@ def _ln_fwd_pallas(x2d, gamma, beta, eps: float = 1e-5,
 
 _MAX_PALLAS_N = 4096  # block (256, N) must fit VMEM with fp32 intermediates
 
-# probe arrays are capped: per-row independence makes timing linear in R,
-# so ranking at a bounded row count ranks the full array too
-_BENCH_MAX_ROWS = 65536
 
-
-def _ln_vmem_bytes(cfg, N: int, itemsize: int) -> int:
-    br = cfg["rows"]
-    # double-buffered in/out blocks + the fp32 compute intermediate
-    return 2 * (2 * br * N * itemsize) + br * N * 4
-
-
-_blocks_memo = _autotune.register_memo({})
-
-
-def _block_rows_for(R: int, N: int, dtype) -> int:
-    """Autotuned row-block extent (static _DEF_BLOCK_ROWS when tuning is
-    off for this mode/platform). Keyed by (R-bucket, N, dtype, chip)."""
-    memo_key = (_tiling.shape_bucket(R, floor=_DEF_BLOCK_ROWS), N,
-                jnp.dtype(dtype).name, _INTERPRET, _autotune.mode())
-    hit = _blocks_memo.get(memo_key)
-    if hit is None:
-        default = _tiling.make_config(rows=_DEF_BLOCK_ROWS)
-        itemsize = jnp.dtype(dtype).itemsize
-        cands = _tiling.candidate_configs(
-            ("rows",),
-            [_tiling.axis_candidates(R, (128, 256, 512, 1024),
-                                     grain=_tiling.sublane(dtype))],
-            default, vmem_bytes=lambda c: _ln_vmem_bytes(c, N, itemsize))
-        rb = min(_tiling.shape_bucket(R, floor=_DEF_BLOCK_ROWS),
-                 _BENCH_MAX_ROWS)
-        buf = {}
-
-        def bench(cfg):
-            if not buf:
-                buf["x"] = jnp.ones((rb, N), dtype)
-                buf["g"] = jnp.ones((N,), dtype)
-            jax.block_until_ready(_ln_fwd_pallas(
-                buf["x"], buf["g"], buf["g"], eps=1e-5,
-                block_rows=cfg["rows"], interpret=_INTERPRET))
-
-        cfg = _autotune.get_config(
-            "layer_norm_fwd", key=memo_key[:3],
-            candidates=cands, default=default, bench=bench,
-            interpret=_INTERPRET)
-        hit = _blocks_memo[memo_key] = cfg["rows"]
-    # shape buckets alias: a config tuned at the bucket's top can exceed a
-    # smaller R in the same bucket — an extent that was never a candidate
-    # (and may be Mosaic-illegal) — so fall back to the static pick, which
-    # the eligibility floor (R >= _DEF_BLOCK_ROWS) keeps legal
-    return hit if hit <= R else _DEF_BLOCK_ROWS
+def _block_rows_for(R: int, N: int) -> Optional[int]:
+    """Row-block extent of the Pallas forward over an [R, N] input, or
+    None where the shape stays on XLA: fewer rows than one block (the
+    decode widths), rows off the sublane grain, or a hidden size that is
+    off the lane grain or too wide for VMEM."""
+    if isinstance(R, int) and R >= _DEF_BLOCK_ROWS and R % 8 == 0 \
+            and N % 128 == 0 and N <= _MAX_PALLAS_N:
+        return _DEF_BLOCK_ROWS
+    return None
 
 
 def _check_compiles(dtype, N: int, block_rows: int):
     """Per-(dtype, hidden-size, block-rows) eager compile check at the
-    exact kernel shape production uses (`autotune.compile_check`)."""
+    exact kernel shape production uses (`tiling.compile_check`)."""
     def run():
         probe = jnp.ones((block_rows, N), dtype)
         g = jnp.ones((N,), dtype)
         return _ln_fwd_pallas(probe, g, g, eps=1e-5, block_rows=block_rows,
                               interpret=_INTERPRET)
 
-    _autotune.compile_check(
+    _tiling.compile_check(
         "layer_norm_fwd", run, dtype=jnp.dtype(dtype).name,
         x=(block_rows, N), block_rows=block_rows, interpret=_INTERPRET)
 
@@ -164,10 +122,9 @@ def _ln_fwd(x2d, gamma, beta, eps):
             km, lambda x, g, b: _ln_fwd(x, g, b, eps),
             (rows, P(), P()), rows)(x2d, gamma, beta)
     R, N = x2d.shape
-    if isinstance(R, int) and R >= _DEF_BLOCK_ROWS and R % 8 == 0 \
-            and N % 128 == 0 and x2d.dtype == gamma.dtype \
-            and (_on_tpu() or _INTERPRET) and N <= _MAX_PALLAS_N:
-        br = _block_rows_for(R, N, x2d.dtype)
+    br = _block_rows_for(R, N)
+    if br is not None and x2d.dtype == gamma.dtype \
+            and (_on_tpu() or _INTERPRET):
         _check_compiles(x2d.dtype, N, br)
         _stats["pallas"] += 1
         return _ln_fwd_pallas(x2d, gamma, beta, eps=eps, block_rows=br,

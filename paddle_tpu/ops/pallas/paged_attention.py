@@ -21,20 +21,20 @@ block-table entry at it, and the cache-append scatter parks dead slots'
 writes there.
 
 Decode attention (one query token per sequence) gathers the scattered
-pages. Two implementations, chosen per shape by a MEASURED probe on the
-PR-10 autotune layer (op ``"paged_attn"``, same pattern as ``conv_bn``):
+pages:
 
-* ``impl=1`` — the Pallas kernel: grid ``(B, head-blocks, pages)`` under
-  a :class:`PrefetchScalarGridSpec` whose scalar-prefetched block table
+* the Pallas kernel: grid ``(B, head-blocks, pages)`` under a
+  :class:`PrefetchScalarGridSpec` whose scalar-prefetched block table
   drives the k/v BlockSpec index maps, so each grid step DMAs exactly
   ONE folded page ``[page_size, heads * head_dim]`` from wherever it
   lives in the pool into VMEM (the pipeline double-buffers page fetches
   against compute); online softmax carried across the page walk in VMEM
-  scratch, per-head sums taken by masked lane reductions. The ``heads``
-  candidate axis splits the folded axis across grid-parallel programs.
-* ``impl=0`` — the XLA composition: gather pages via
-  ``k_pages[block_tables]``, mask past ``context_lens``, dense softmax.
-  This is also the CPU fallback and the CI parity reference.
+  scratch, per-head sums taken by masked lane reductions. Dispatch gives
+  one program all the heads.
+* ``paged_attention_xla``: gather pages via ``k_pages[block_tables]``,
+  mask past ``context_lens``, dense softmax. The path off the TPU, for
+  fp16 and for head sizes the kernel's lane groups do not take, and the
+  CI parity reference.
 
 `cache_append` is the matching single-token K/V scatter; its eager form
 is jitted with the page pools DONATED, so the steady-state decode loop
@@ -74,7 +74,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.pallas import tpu as pltpu
 
-from . import autotune as _autotune
 from . import tiling as _tiling
 from .tiling import on_tpu as _on_tpu
 
@@ -82,12 +81,9 @@ _NEG = -1e30
 _LANE = _tiling.LANE
 
 # dispatch decisions, counted at trace time (reset freely in tests)
-# ("xla_measured" counts the XLA dispatches that were the autotuner's
-# measured impl=0 choice, as opposed to a shape/platform gate; "folded"
-# counts the dispatches to the kernel that reads the folded page block:
-# all of "pallas" since PR 26, absent before it)
-_stats = {"pallas": 0, "folded": 0, "xla": 0, "xla_measured": 0,
-          "append": 0, "cow": 0}
+# ("folded" counts the dispatches to the kernel that reads the folded
+# page block: all of "pallas" since PR 26, absent before it)
+_stats = {"pallas": 0, "folded": 0, "xla": 0, "append": 0, "cow": 0}
 
 # tests set True: the kernel runs in the Pallas interpreter on CPU, so
 # the real gather/online-softmax logic is exercised without a TPU
@@ -105,14 +101,14 @@ def _rows_like(pool, new):
     return new.reshape(new.shape[0], *pool.shape[2:]).astype(pool.dtype)
 
 
-# --------------------------- XLA reference (impl=0) --------------------------
+# ------------------------------ XLA reference --------------------------------
 
 
 def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
                         scale=None):
-    """Dense gather reference: correct for every shape, the CPU path, and
-    the ``impl=0`` autotune candidate. A sequence with ``context_lens==0``
-    (idle serving slot) outputs exactly zero. It unfolds the pool itself
+    """Dense gather reference: correct for every shape, and the CPU
+    path. A sequence with ``context_lens==0`` (idle serving slot) outputs
+    exactly zero. It unfolds the pool itself
     (a copy on the TPU, which this path can afford)."""
     B, H, D = q.shape
     page_size = k_pages.shape[1]
@@ -138,7 +134,7 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
     return out.astype(q.dtype)
 
 
-# --------------------------- Pallas kernel (impl=1) --------------------------
+# ------------------------------ Pallas kernel --------------------------------
 
 
 def _lane_groups(width: int, D: int):
@@ -276,101 +272,20 @@ def _paged_attn_pallas(q, k_pages, v_pages, block_tables, context_lens,
     return out.reshape(B, H, D)
 
 
-# ------------------- autotuned impl/heads decision ---------------------------
-
-
-def _vmem_bytes(cfg, page_size: int, D: int, itemsize: int) -> int:
-    bh = cfg["heads"]
-    b = 2 * 2 * page_size * bh * D * itemsize   # double-buffered k/v pages
-    b += 2 * bh * D * itemsize                  # q in / o out
-    b += 3 * bh * D * 4                         # acc/m/l scratch, per lane
-    return b
-
-
-_cfg_memo = _autotune.register_memo({})
-
-
-def _head_candidates(H: int):
-    """Head-block extents Mosaic accepts: a block's lanes are a multiple
-    of 128, which 8 heads are for every head size the kernel takes down
-    to 16, or the whole H — and a divisor of H (a non-divisor would need
-    head tail-masking the kernel doesn't carry). GPT-2's H=12 has only
-    whole-H."""
-    return [h for h in (8, 16, 32) if h < H and H % h == 0] + [H]
-
-
-def _resolve_cfg(dtype, H: int, D: int, page_size: int, n_pages: int):
-    """The measured per-shape decision: Pallas head-block shape or the
-    XLA gather (impl=0). Persisted per (op, shape-bucket, dtype, chip)
-    like every autotuned kernel, so a serving fleet sharing
-    PADDLE_TPU_AUTOTUNE_CACHE_DIR decides once."""
-    interpret = _INTERPRET
-    key = (H, D, page_size, _tiling.shape_bucket(n_pages, floor=1),
-           jnp.dtype(dtype).name)
-    memo_key = (key, interpret, _autotune.mode())
-    hit = _cfg_memo.get(memo_key)
-    if hit is not None:
-        return hit
-    itemsize = jnp.dtype(dtype).itemsize
-    default = _tiling.make_config(impl=1, heads=H)
-    cands = _tiling.candidate_configs(
-        ("impl", "heads"), [(1,), _head_candidates(H)], default,
-        vmem_bytes=lambda c: _vmem_bytes(c, page_size, D, itemsize))
-    # the XLA gather is a first-class candidate: measured, not assumed
-    cands = cands + [_tiling.make_config(impl=0, heads=0)]
-
-    sc = float(1.0 / np.sqrt(D))
-    buf = {}
-
-    def _args():
-        if not buf:
-            # B is a grid-parallel dim (probe small); page count real
-            rng = np.random.default_rng(0)
-            Bp = 2
-            buf["q"] = jnp.asarray(
-                rng.normal(size=(Bp, H, D)).astype(np.float32)).astype(dtype)
-            buf["kp"] = jnp.asarray(rng.normal(
-                size=(max(n_pages, 2), page_size, H * D)
-            ).astype(np.float32)).astype(dtype)
-            buf["bt"] = jnp.asarray(
-                rng.integers(0, max(n_pages, 2), (Bp, n_pages)
-                             ).astype(np.int32))
-            buf["cl"] = jnp.full((Bp,), n_pages * page_size, jnp.int32)
-        return buf["q"], buf["kp"], buf["bt"], buf["cl"]
-
-    def bench(cfg):
-        qa, kp, bt, cl = _args()
-        if cfg["impl"] == 1:
-            out = _paged_attn_pallas(qa, kp, kp, bt, cl, sc, cfg["heads"],
-                                    interpret=interpret)
-        else:
-            out = jax.jit(paged_attention_xla, static_argnames=("scale",))(
-                qa, kp, kp, bt, cl, scale=sc)
-        jax.block_until_ready(out)
-
-    tune_bench = bench if (_on_tpu() or interpret) else None
-    cfg = _autotune.get_config("paged_attn", key, candidates=cands,
-                               default=default, bench=tune_bench,
-                               interpret=interpret)
-    _cfg_memo[memo_key] = cfg
-    return cfg
-
-
-def _check_compiles(dtype, H: int, D: int, page_size: int, n_pages: int,
-                    heads: int):
-    """Eager compile check at the exact resolved head block
-    (`autotune.compile_check`)."""
+def _check_compiles(dtype, H: int, D: int, page_size: int, n_pages: int):
+    """Eager compile check at the head block dispatch uses, all H
+    (`tiling.compile_check`)."""
     def run():
         q = jnp.ones((2, H, D), dtype)
         kp = jnp.ones((max(n_pages, 2), page_size, H * D), dtype)
         bt = jnp.zeros((2, n_pages), jnp.int32)
         cl = jnp.full((2,), page_size, jnp.int32)
         return _paged_attn_pallas(q, kp, kp, bt, cl, float(1.0 / np.sqrt(D)),
-                                 heads, interpret=_INTERPRET)
+                                 H, interpret=_INTERPRET)
 
-    _autotune.compile_check(
+    _tiling.compile_check(
         "paged_attn", run, dtype=jnp.dtype(dtype).name, heads=H, head_dim=D,
-        page_size=page_size, pages_per_seq=n_pages, block_heads=heads,
+        page_size=page_size, pages_per_seq=n_pages, block_heads=H,
         interpret=_INTERPRET)
 
 
@@ -384,12 +299,12 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     serving layer points them at the null page 0); context_lens [B]
     int32. Returns [B, H, D].
 
-    Dispatch mirrors `flash_attention`: the per-shape impl (Pallas page
-    walk vs XLA gather) is resolved on the autotune layer, then the
-    resolved Pallas config gets one eager compile check that raises; CPU
-    without interpret mode always takes the XLA path. Safe to call at trace time
-    of an outer jit (resolution runs eagerly at trace, like every kernel
-    in this package)."""
+    Dispatch mirrors `flash_attention`: an eligible call takes the Pallas
+    page walk, all heads to a program, after one eager compile check that
+    raises; anything else (off the TPU without interpret mode, fp16, a
+    head size off the lane groups) takes the XLA gather. Safe to call at
+    trace time of an outer jit (the check runs eagerly at trace, like
+    every kernel in this package)."""
     B, H, D = q.shape
     page_size = k_pages.shape[1]
     n_pages = block_tables.shape[1]
@@ -403,15 +318,12 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
                 # fraction of one (`_lane_groups`)
                 and (D % _LANE == 0 or _LANE % D == 0))
     if eligible:
-        cfg = _resolve_cfg(q.dtype, H, D, page_size, n_pages)
-        if cfg["impl"] == 1:
-            _check_compiles(q.dtype, H, D, page_size, n_pages, cfg["heads"])
-            _stats["pallas"] += 1
-            _stats["folded"] += 1
-            return _paged_attn_pallas(q, k_pages, v_pages, block_tables,
-                                     context_lens, float(scale),
-                                     cfg["heads"], interpret=_INTERPRET)
-        _stats["xla_measured"] += 1
+        _check_compiles(q.dtype, H, D, page_size, n_pages)
+        _stats["pallas"] += 1
+        _stats["folded"] += 1
+        return _paged_attn_pallas(q, k_pages, v_pages, block_tables,
+                                 context_lens, float(scale), H,
+                                 interpret=_INTERPRET)
     _stats["xla"] += 1
     return paged_attention_xla(q, k_pages, v_pages, block_tables,
                                context_lens, scale=scale)
@@ -425,8 +337,8 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 # (heads are contiguous in it, so a shard holds whole heads)
 # therefore needs NO cross-device math — every shard runs the normal
 # single-chip dispatch on its local head slice (the Pallas page walk or
-# the XLA gather, resolved per LOCAL shape by the same autotune layer),
-# and concatenating shard outputs reproduces the single-chip result
+# the XLA gather, by the LOCAL shape), and concatenating shard outputs
+# reproduces the single-chip result
 # BIT-EXACTLY because no floating-point reduction ever crosses the
 # shard boundary. The serving layer replicates the attention output
 # before the proj matmul (see models/gpt.py) so the contraction that

@@ -34,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.pallas import tpu as pltpu
 
-from . import autotune as _autotune
 from . import tiling as _tiling
 from .tiling import ceil_to as _ceil_to
 from .tiling import on_tpu as _on_tpu
@@ -51,69 +50,11 @@ _CARRY_LANES = 128  # m/l scratch lane width
 _DEF_BLOCK_N = 256
 _DEF_BLOCK_V = 2048
 
-# autotune probe row cap: rows are independent (grid-parallel), so a
-# bounded-N probe ranks candidates for any N; V is walked in full — the
-# vocab-block choice is exactly what is being tuned
-_BENCH_MAX_N = 4096
-
 
 def _static_blocks(N: int, V: int):
-    """The pre-autotune fixed picks (the PADDLE_TPU_AUTOTUNE=0 behavior)."""
+    """(block_n, block_v) for [N, V] logits."""
     return (min(_DEF_BLOCK_N, _ceil_to(N, 64)),
             min(_DEF_BLOCK_V, _ceil_to(V, 128)))
-
-
-def _ce_vmem_bytes(cfg, itemsize: int) -> int:
-    bn, bv = cfg["n"], cfg["v"]
-    # double-buffered logits block + (bwd) dlogits out block + fp32
-    # compute intermediate + carry scratch
-    return 2 * bn * bv * itemsize * 2 + bn * bv * 4 + 3 * bn * _CARRY_LANES * 4
-
-
-_blocks_memo = _autotune.register_memo({})
-
-
-def _blocks_for(N: int, V: int, dtype):
-    """Autotuned (block_n, block_v): one tune per (N-bucket, V, dtype,
-    chip) times the fwd+bwd chain at the real vocab width. Static picks
-    when tuning is off for this mode/platform."""
-    memo_key = (_tiling.shape_bucket(N), V, jnp.dtype(dtype).name,
-                _INTERPRET, _autotune.mode())
-    hit = _blocks_memo.get(memo_key)
-    if hit is not None:
-        return hit
-    default = _tiling.make_config(n=_static_blocks(N, V)[0],
-                                  v=_static_blocks(N, V)[1])
-    itemsize = jnp.dtype(dtype).itemsize
-    cands = _tiling.candidate_configs(
-        ("n", "v"),
-        [_tiling.axis_candidates(N, (128, 256, 512), grain=64),
-         _tiling.axis_candidates(V, (1024, 2048, 4096, 8192),
-                                 grain=_tiling.LANE)],
-        default, vmem_bytes=lambda c: _ce_vmem_bytes(c, itemsize))
-    nb = min(_tiling.shape_bucket(N), _BENCH_MAX_N)
-    buf = {}
-
-    def bench(cfg):
-        if not buf:
-            buf["lg"] = jnp.ones((nb, V), dtype)
-            buf["lb"] = jnp.zeros((nb,), jnp.int32)
-            buf["dn"] = jnp.ones((nb,), jnp.float32)
-        lg, lb, dn = buf["lg"], buf["lb"], buf["dn"]
-        blocks = (cfg["n"], cfg["v"])
-        nll, lse = _ce_fwd_pallas(lg, lb, blocks=blocks,
-                                  interpret=_INTERPRET)
-        dl = _ce_bwd_pallas(lg, lb, lse, dn, blocks=blocks,
-                            interpret=_INTERPRET)
-        jax.block_until_ready((nll, dl))
-
-    cfg = _autotune.get_config(
-        "softmax_ce",
-        key=(_tiling.shape_bucket(N), V, jnp.dtype(dtype).name),
-        candidates=cands, default=default, bench=bench,
-        interpret=_INTERPRET)
-    _blocks_memo[memo_key] = (cfg["n"], cfg["v"])
-    return cfg["n"], cfg["v"]
 
 
 def _ce_fwd_kernel(logits_ref, label_ref, nll_ref, lse_ref, m_ref, l_ref,
@@ -191,7 +132,7 @@ def _ce_bwd_kernel(logits_ref, label_ref, lse_ref, dnll_ref, dlogits_ref, *,
 @functools.partial(jax.jit, static_argnames=("blocks", "interpret"))
 def _ce_fwd_pallas(logits, labels, blocks=None, interpret=False):
     """logits [N, V], labels [N] int32 -> (nll [N] f32, lse [N] f32).
-    `blocks` is the resolved (block_n, block_v); None = static picks."""
+    `blocks` is (block_n, block_v); None = `_static_blocks`."""
     from jax.experimental import pallas as pl
 
     N, V = logits.shape
@@ -283,16 +224,15 @@ _fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
 
 
 def _check_compiles(dtype, N, V, blocks=None):
-    """Eager fwd+bwd compile check (`autotune.compile_check`) at the
-    RESOLVED block config — checking static picks while production runs
-    tuned ones would validate a kernel production never executes."""
+    """Eager fwd+bwd compile check (`tiling.compile_check`) at the block
+    shape production runs."""
     def run():
         lg = jnp.ones((N, V), dtype)
         lb = jnp.zeros((N,), jnp.int32)
         return jax.grad(lambda x: _fused_ce(x, lb, _INTERPRET,
                                             blocks).sum())(lg)
 
-    _autotune.compile_check(
+    _tiling.compile_check(
         "softmax_ce", run, dtype=jnp.dtype(dtype).name, logits=(N, V),
         blocks_n_v=blocks or "static", interpret=_INTERPRET)
 
@@ -319,7 +259,7 @@ def fused_softmax_ce_eligible(logits, labels) -> bool:
     N = int(np.prod(logits.shape[:-1])) if logits.ndim > 1 else 1
     if N < 64:
         return False
-    blocks = _blocks_for(N, logits.shape[-1], logits.dtype)
+    blocks = _static_blocks(N, logits.shape[-1])
     _check_compiles(logits.dtype, N, logits.shape[-1], blocks)
     return True
 
@@ -338,5 +278,5 @@ def fused_softmax_ce(logits, labels):
     flat = logits.reshape((-1, V))
     flab = labels.reshape((-1,))
     _stats["pallas"] += 1
-    blocks = _blocks_for(flat.shape[0], V, flat.dtype)
+    blocks = _static_blocks(flat.shape[0], V)
     return _fused_ce(flat, flab, _INTERPRET, blocks).reshape(shape)

@@ -6,7 +6,6 @@ import os
 import subprocess
 import sys
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -16,9 +15,10 @@ sys.path.insert(0, REPO)
 
 import chip_smoke  # noqa: E402
 from paddle_tpu.models.gpt import GPTConfig  # noqa: E402
-from paddle_tpu.ops.pallas import (autotune, flash_attention as fa,  # noqa: E402
+from paddle_tpu.ops.pallas import (flash_attention as fa,  # noqa: E402
                                    fused_bn as fbn, fused_conv_bn as fcb,
-                                   layer_norm as ln, paged_attention as pa)
+                                   layer_norm as ln, paged_attention as pa,
+                                   tiling)
 
 TINY_KERNEL_SHAPES = {
     "flash": dict(B=1, L=128, H=2, D=64),
@@ -43,11 +43,11 @@ def tiny_sizes():
 @pytest.fixture
 def interpreted(monkeypatch):
     """Every kernel family's dispatch under the Pallas interpreter."""
-    autotune.reset_for_tests()
+    tiling.reset_compile_checks()
     for mod in (fa, ln, pa, fbn, fcb):
         monkeypatch.setattr(mod, "_INTERPRET", True)
     yield
-    autotune.reset_for_tests()
+    tiling.reset_compile_checks()
 
 
 def test_command_refuses_to_run_off_the_chip():
@@ -85,7 +85,6 @@ def test_a_refused_kernel_fails_the_run_not_the_xla_path(interpreted,
 
     # dispatch: the compile check's exception propagates, named
     monkeypatch.setattr(pa, "_paged_attn_pallas", refused)
-    monkeypatch.setenv("PADDLE_TPU_AUTOTUNE", "0")
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(1, 4, 64)).astype(np.float32))
     kp = jnp.asarray(rng.normal(size=(4, 8, 4, 64)).astype(np.float32))
@@ -98,13 +97,6 @@ def test_a_refused_kernel_fails_the_run_not_the_xla_path(interpreted,
     assert any("paged_attn" in n and "block_heads=4" in n
                for n in exc.value.__notes__)
 
-    # the autotuner: a refused DEFAULT raises, a refused candidate is
-    # skipped and counted
-    monkeypatch.setenv("PADDLE_TPU_AUTOTUNE", "force")
-    autotune.reset_for_tests()
-    with pytest.raises(RuntimeError, match="boom"):
-        pa.paged_attention(q, kp, kp, bt, cl)
-
     # the smoke: one failing phase fails the run
     sizes = tiny_sizes()
     for name in ("phase_train", "phase_serve", "phase_four_chips"):
@@ -114,49 +106,6 @@ def test_a_refused_kernel_fails_the_run_not_the_xla_path(interpreted,
     assert not report["phases"]["kernels"]["ok"]
     assert "boom" in report["phases"]["kernels"]["error"]
     assert report["phases"]["train"]["ok"]
-
-
-def test_refused_candidate_is_skipped_and_counted(monkeypatch):
-    from paddle_tpu.ops.pallas import tiling
-    autotune.reset_for_tests()
-    monkeypatch.setenv("PADDLE_TPU_AUTOTUNE", "force")
-    monkeypatch.delenv("PADDLE_TPU_AUTOTUNE_CACHE_DIR", raising=False)
-    good, bad = tiling.make_config(rows=256), tiling.make_config(rows=512)
-
-    def bench(cfg):
-        if cfg == bad:
-            raise ValueError("block shape refused")
-
-    assert autotune.get_config("toy", ("k",), [good, bad], good, bench,
-                               interpret=True) == good
-    assert [(r["op"], r["config"]) for r in autotune.refused_log()] == \
-        [("toy", "rows512")]
-    autotune.reset_for_tests()
-
-
-def test_tune_inside_a_jit_trace_runs_the_probe(monkeypatch):
-    """Resolution happens at trace time of the user's jit, where jax stages
-    calls instead of running them: the probe must still execute on real
-    arrays, or the tuner ranks tracing noise."""
-    from paddle_tpu.ops.pallas import tiling
-    autotune.reset_for_tests()
-    monkeypatch.setenv("PADDLE_TPU_AUTOTUNE", "force")
-    monkeypatch.delenv("PADDLE_TPU_AUTOTUNE_CACHE_DIR", raising=False)
-    cfg = tiling.make_config(rows=256)
-    seen = []
-
-    def bench(c):
-        seen.append(isinstance(jnp.ones((2,)) * 2, jax.core.Tracer))
-
-    @jax.jit
-    def f(x):
-        autotune.get_config("toy_traced", ("k",), [cfg], cfg, bench,
-                            interpret=True)
-        return x + 1
-
-    f(jnp.zeros(2))
-    assert seen and not any(seen)
-    autotune.reset_for_tests()
 
 
 _CACHE_CHILD = """
@@ -169,9 +118,7 @@ root = flags.place_caches(sys.argv[1])
 seen.append(jax.config.jax_compilation_cache_dir)
 flags.set_flags({"FLAGS_compile_cache_dir": "/somewhere/else"})
 seen.append(jax.config.jax_compilation_cache_dir)
-from paddle_tpu.ops.pallas import autotune
 print(json.dumps({"root": root, "seen": seen,
-                  "autotune": autotune.cache_dir(),
                   "stacks": jax.config.jax_include_full_tracebacks_in_locations}))
 """
 
@@ -185,8 +132,7 @@ def test_compile_cache_is_placed_from_outside_or_at_one_fixed_path(tmp_path):
     def child(env_dir, cwd):
         env = {k: v for k, v in os.environ.items()
                if k not in ("JAX_COMPILATION_CACHE_DIR",
-                            "PADDLE_TPU_COMPILE_CACHE_DIR",
-                            "PADDLE_TPU_AUTOTUNE_CACHE_DIR")}
+                            "PADDLE_TPU_COMPILE_CACHE_DIR")}
         env["PYTHONPATH"] = REPO
         if env_dir:
             env["JAX_COMPILATION_CACHE_DIR"] = env_dir
@@ -203,15 +149,13 @@ def test_compile_cache_is_placed_from_outside_or_at_one_fixed_path(tmp_path):
         assert p.returncode == 0
         docs.append(json.loads(out.strip().splitlines()[-1]))
     placed, a, b = docs
-    # call stacks stay out of kernel payloads, or a tuning process and a
-    # winner-loading one never share a cache key
+    # call stacks stay out of kernel payloads, or two processes that
+    # first trace a kernel from different calls never share a cache key
     assert not any(d["stacks"] for d in docs)
     assert placed["root"] == outside
     assert placed["seen"] == [outside] * 3
-    assert placed["autotune"] == os.path.join(outside, "autotune")
     fixed = str(tmp_path / "checkout" / ".jax_cache")
     assert a["root"] == b["root"] == fixed
     assert a["seen"][1] == b["seen"][1] == fixed
-    assert a["autotune"] == b["autotune"] == os.path.join(fixed, "autotune")
     # (unset, set_flags still moves it: the ~15 fixtures that call it work)
     assert a["seen"][2] == "/somewhere/else"

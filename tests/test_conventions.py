@@ -103,8 +103,8 @@ class TestEnvParseLint:
         assert "PADDLE_TPU_STORE_TIMEOUT" in knobs
 
     def test_collect_env_knobs_sees_aliased_helper_import(self, tmp_path):
-        """`from ...envparse import env_int as _int_knob` (the autotune/
-        controller pattern) must still feed the knob-docs lint."""
+        """`from ...envparse import env_int as _int_knob` (the controller
+        pattern) must still feed the knob-docs lint."""
         root = _write_pkg(tmp_path, """
             from paddle_tpu.utils.envparse import env_int as _int_knob
             from ...utils.envparse import env_float as _env_float

@@ -155,13 +155,6 @@ class TestOffenderRegressions:
         assert pol.max_attempts == 5
         assert pol.base_delay == 0.2
 
-    def test_autotune_budget_knobs(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_MAX_CONFIGS", "all")
-        monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_BUDGET_S", "unbounded")
-        from paddle_tpu.ops.pallas.autotune import _float_knob, _int_knob
-        assert _int_knob("PADDLE_TPU_AUTOTUNE_MAX_CONFIGS", 8) == 8
-        assert _float_knob("PADDLE_TPU_AUTOTUNE_BUDGET_S", 20.0) == 20.0
-
     def test_supervisor_metrics_port(self, monkeypatch):
         monkeypatch.setenv("PADDLE_TPU_SUPERVISOR_METRICS_PORT", "auto")
         assert env_int("PADDLE_TPU_SUPERVISOR_METRICS_PORT", 8081) == 8081

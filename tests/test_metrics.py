@@ -212,7 +212,6 @@ class TestMetricNamingLint:
         import paddle_tpu.io.dataloader  # noqa: F401
         import paddle_tpu.io.worker  # noqa: F401
         import paddle_tpu.ops._dispatch  # noqa: F401
-        import paddle_tpu.ops.pallas.autotune  # noqa: F401
         import paddle_tpu.profiler.compile_watch  # noqa: F401
         import paddle_tpu.profiler.health  # noqa: F401
         import paddle_tpu.profiler.reqtrace  # noqa: F401
@@ -265,14 +264,6 @@ class TestMetricNamingLint:
         import paddle_tpu.amp as _amp
         _amp._M_FOUND_INF.inc()
         _amp._M_LOSS_SCALE.set(32768.0)
-        # kernel-autotuner families: cache events (event=, op=), tune
-        # counter (op=), probe histogram (op=), chosen-config gauge
-        # (op=, config=)
-        from paddle_tpu.ops.pallas import autotune as _at
-        _at._M_EVENTS.inc(event="hit", op="lint_op")
-        _at._M_TUNES.inc(op="lint_op")
-        _at._M_PROBE_SECONDS.observe(0.001, op="lint_op")
-        _at._M_CHOSEN.set(1.0, op="lint_op", config="q256-k512")
         # self-driving fleet controller families: decisions (policy=,
         # outcome=), per-action counters (host=), relaunch-to-first-step
         # gauge (policy=)
@@ -295,8 +286,7 @@ class TestMetricNamingLint:
         _dis._M_W_RESTARTS.inc()
         _dis._M_REQUEUE.inc(reason="worker_dead")
         # continuous-batching serving families (model=, latency split by
-        # decode path=) + the paged-KV decode kernel's autotune op riding
-        # the existing families
+        # decode path=)
         from paddle_tpu.inference import serving as _srv
         _srv._M_QUEUE.set(2, model="gpt")
         _srv._M_OCC.set(1, model="gpt")
@@ -320,9 +310,6 @@ class TestMetricNamingLint:
         _srv._M_HANDOFF_BYTES.inc(4096, model="gpt")
         _srv._M_STAGE_OCC.set(1, model="gpt", stage="prefill")
         _srv._M_STAGE_OCC.set(2, model="gpt", stage="decode")
-        _at._M_EVENTS.inc(event="hit", op="paged_attn")
-        _at._M_TUNES.inc(op="paged_attn")
-        _at._M_CHOSEN.set(1.0, op="paged_attn", config="impl1-heads12")
         # request-trace lifecycle histograms (model=) + SLO plane
         # families (model=, signal=)
         from paddle_tpu.profiler import reqtrace as _rt

@@ -69,6 +69,19 @@ def _serve(eng, prompts, max_new=6, sampling=None):
     return [r.result(timeout=10) for r in reqs]
 
 
+@pytest.mark.parametrize("max_batch", [1, 3, 16, 32])
+def test_decode_buckets(max_batch):
+    """One fused-step executable per power-of-two lane count up to
+    max_batch, and max_batch itself (the benchmark cells run 16 and 32)."""
+    m, cfg = _model()
+    eng = ServingEngine(m, max_batch=max_batch, max_len=32, page_size=8,
+                        name=f"buckets{max_batch}")
+    want = [b for b in (1, 2, 4, 8, 16, 32) if b < max_batch] + [max_batch]
+    assert eng.decode_buckets == want
+    assert eng.status()["decode_buckets"] == want
+    eng.close()
+
+
 class TestSamplingPolicies:
     """sample_logits: the traceable policy kernel inside the fused step."""
 

@@ -737,127 +737,6 @@ class TestObsTailErrorPaths:
         assert seqs == [0, 1, 2, 3]  # the racing append is NOT lost
 
 
-class TestAutotuneGate:
-    """`autotune_*` metric families and the per-config / observability
-    `autotune` blocks must validate (kernel-autotuner satellite)."""
-
-    @staticmethod
-    def _doc_with_metrics(metrics):
-        doc = TestObservabilitySchemaGate._good_doc()
-        doc["observability"]["metrics"] = metrics
-        return doc
-
-    @staticmethod
-    def _good_metrics():
-        return {
-            "autotune_cache_events_total": {
-                "kind": "counter", "help": "h",
-                "values": [{"labels": {"event": "hit", "op": "flash_fwd"},
-                            "value": 3.0}]},
-            "autotune_tunes_total": {
-                "kind": "counter", "help": "h",
-                "values": [{"labels": {"op": "flash_fwd"}, "value": 1.0}]},
-            "autotune_probe_seconds": {
-                "kind": "histogram", "help": "h",
-                "values": [{"labels": {"op": "flash_fwd"},
-                            "buckets": {"0.1": 1, "+Inf": 1},
-                            "sum": 0.05, "count": 1}]},
-            "autotune_chosen_config": {
-                "kind": "gauge", "help": "h",
-                "values": [{"labels": {"op": "flash_fwd",
-                                       "config": "q256-k512"},
-                            "value": 1.25}]},
-        }
-
-    @staticmethod
-    def _good_block():
-        return {
-            "enabled": True, "mode": "on", "cache_dir": "/tmp/at",
-            "events": {"miss": 1, "persist": 1},
-            "tuned": [{"op": "flash_fwd", "key": [1024, 1024],
-                       "chip": "v5e", "config": "q256-k512",
-                       "probe_ms": 1.25, "source": "tuned"}],
-        }
-
-    def test_good_families_and_blocks_pass(self):
-        doc = self._doc_with_metrics(self._good_metrics())
-        doc["observability"]["autotune"] = self._good_block()
-        doc["configs"]["gpt"]["autotune"] = self._good_block()
-        assert gate.validate_observability(doc) == []
-
-    def test_live_registry_snapshot_validates(self):
-        # the REAL families the autotuner registers must pass the gate
-        from paddle_tpu.ops.pallas import autotune as at
-        at._M_EVENTS.inc(event="miss", op="gate_op")
-        at._M_TUNES.inc(op="gate_op")
-        at._M_PROBE_SECONDS.observe(0.01, op="gate_op")
-        at._M_CHOSEN.set(0.5, op="gate_op", config="rows256")
-        from paddle_tpu.profiler.metrics import default_registry
-        snap = {k: v for k, v in default_registry().snapshot().items()
-                if k.startswith("autotune_")}
-        assert set(snap) == {"autotune_cache_events_total",
-                             "autotune_tunes_total",
-                             "autotune_probe_seconds",
-                             "autotune_chosen_config"}
-        assert gate.validate_observability(
-            self._doc_with_metrics(snap)) == []
-
-    def test_live_summary_block_validates(self):
-        from paddle_tpu.ops.pallas import autotune as at
-        doc = TestObservabilitySchemaGate._good_doc()
-        doc["observability"]["autotune"] = at.summary()
-        assert gate.validate_observability(doc) == []
-
-    def test_wrong_kind_and_unknown_family_named(self):
-        m = self._good_metrics()
-        m["autotune_tunes_total"]["kind"] = "gauge"
-        m["autotune_best_ms"] = {"kind": "gauge", "values": []}
-        problems = gate.validate_observability(self._doc_with_metrics(m))
-        assert any("autotune_tunes_total" in p and "counter" in p
-                   for p in problems)
-        assert any("autotune_best_ms" in p and "unknown" in p
-                   for p in problems)
-
-    def test_negative_value_and_missing_label_named(self):
-        m = self._good_metrics()
-        m["autotune_cache_events_total"]["values"][0]["value"] = -1
-        m["autotune_chosen_config"]["values"][0]["labels"] = {"op": "x"}
-        problems = gate.validate_observability(self._doc_with_metrics(m))
-        assert any("autotune_cache_events_total" in p and "non-negative" in p
-                   for p in problems)
-        assert any("autotune_chosen_config" in p and "config" in p
-                   for p in problems)
-
-    def test_inconsistent_histogram_named(self):
-        m = self._good_metrics()
-        m["autotune_probe_seconds"]["values"][0]["buckets"]["+Inf"] = 7
-        problems = gate.validate_observability(self._doc_with_metrics(m))
-        assert any("autotune_probe_seconds" in p and "inconsistent" in p
-                   for p in problems)
-
-    def test_bad_config_block_named(self):
-        doc = TestObservabilitySchemaGate._good_doc()
-        doc["configs"]["gpt"]["autotune"] = {
-            "enabled": "yes",                      # not a bool
-            "mode": "sometimes",                   # unknown mode
-            "events": {"miss": -2},                # negative count
-            "tuned": [{"op": "", "config": 7, "probe_ms": -1.0}],
-        }
-        problems = gate.validate_observability(doc)
-        joined = "\n".join(problems)
-        assert "configs.gpt.autotune.enabled" in joined
-        assert "configs.gpt.autotune.mode" in joined
-        assert "events['miss']" in joined or "events" in joined
-        assert any("tuned[0]" in p for p in problems)
-
-    def test_malformed_blocks_reported_not_crash(self):
-        doc = TestObservabilitySchemaGate._good_doc()
-        for bad in ("garbage", [1], {"tuned": "x"}, {"events": [1]}):
-            doc["configs"]["gpt"]["autotune"] = bad
-            problems = gate.validate_observability(doc)
-            assert problems, f"autotune={bad!r} produced no violation"
-
-
 class TestPlatformAwareGate:
     """r06: cross-platform rounds/configs read 'incomparable', never
     'regressed' — a CPU dev-box round vs a TPU driver round is not a

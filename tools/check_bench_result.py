@@ -369,72 +369,6 @@ def _validate_health_block(where: str, h: dict) -> List[str]:
     return problems
 
 
-# kernel-autotuner metric families: name -> (kind, required labels).
-# Every value must be a finite non-negative number (probe seconds,
-# event/tune counts, chosen-config probe-ms gauges — none may go negative).
-_AUTOTUNE_FAMILIES = {
-    "autotune_cache_events_total": ("counter", ("event", "op")),
-    "autotune_tunes_total": ("counter", ("op",)),
-    "autotune_probe_seconds": ("histogram", ("op",)),
-    "autotune_chosen_config": ("gauge", ("op", "config")),
-}
-
-
-def _validate_autotune_metrics(where: str, metrics: dict) -> List[str]:
-    """`autotune_*` families must be the documented kind, carry their
-    required labels, and hold non-negative values (histograms: consistent
-    buckets/sum/count) — the autotuner's observability contract."""
-    problems = []
-    for name, fam in metrics.items():
-        if not name.startswith("autotune_"):
-            continue
-        spec = _AUTOTUNE_FAMILIES.get(name)
-        if spec is None:
-            problems.append(f"{where}.metrics.{name}: unknown autotune "
-                            f"family (expected one of "
-                            f"{sorted(_AUTOTUNE_FAMILIES)})")
-            continue
-        kind, req_labels = spec
-        if not isinstance(fam, dict) or fam.get("kind") != kind:
-            problems.append(f"{where}.metrics.{name}: kind "
-                            f"{fam.get('kind') if isinstance(fam, dict) else fam!r}"
-                            f", expected {kind}")
-            continue
-        values = fam.get("values") or []
-        if not isinstance(values, list):
-            problems.append(f"{where}.metrics.{name}.values is not a list")
-            continue
-        for i, v in enumerate(values):
-            if not isinstance(v, dict):
-                problems.append(f"{where}.metrics.{name}[{i}] is not a "
-                                f"series object")
-                continue
-            if kind == "histogram":
-                buckets, cnt = v.get("buckets"), v.get("count")
-                if not isinstance(buckets, dict) or \
-                        not isinstance(cnt, (int, float)) or \
-                        not isinstance(v.get("sum"), (int, float)):
-                    problems.append(f"{where}.metrics.{name}[{i}]: "
-                                    f"histogram needs buckets/sum/count")
-                elif buckets.get("+Inf") != cnt or v["sum"] < 0 or cnt < 0:
-                    problems.append(
-                        f"{where}.metrics.{name}[{i}]: inconsistent "
-                        f"histogram (+Inf bucket {buckets.get('+Inf')} != "
-                        f"count {cnt}, or negative sum)")
-            else:
-                val = v.get("value")
-                if not isinstance(val, (int, float)) or \
-                        isinstance(val, bool) or val != val or val < 0:
-                    problems.append(f"{where}.metrics.{name}[{i}]: value "
-                                    f"{val!r} is not a non-negative number")
-            labels = v.get("labels") or {}
-            for lk in req_labels:
-                if lk not in labels:
-                    problems.append(f"{where}.metrics.{name}[{i}]: series "
-                                    f"missing the {lk!r} label")
-    return problems
-
-
 # continuous-batching serving metric families: name -> (kind, required
 # labels). All values non-negative. The latency histograms additionally
 # carry a `path` label since serving v2 (fused|eager decode) — optional
@@ -1057,57 +991,6 @@ def _validate_controller_decision(where: str, ev: dict) -> List[str]:
     return problems
 
 
-def _validate_autotune_block(where: str, at: dict) -> List[str]:
-    """A bench `autotune` block (per config, and the summary under
-    `observability.autotune`): enabled flag, event-count deltas, and the
-    tuned/disk-hit log — each tuned entry names its op and config and
-    carries a non-negative (or null) probe_ms."""
-    problems = []
-    if not isinstance(at, dict):
-        return [f"{where} is not an object"]
-    if "enabled" in at and not isinstance(at["enabled"], bool):
-        problems.append(f"{where}.enabled {at['enabled']!r} is not a bool")
-    mode = at.get("mode")
-    if mode is not None and mode not in ("off", "on", "force"):
-        problems.append(f"{where}.mode {mode!r} not in (off, on, force)")
-    cd = at.get("cache_dir")
-    if cd is not None and not isinstance(cd, str):
-        problems.append(f"{where}.cache_dir {cd!r} is not a string or null")
-    events = at.get("events")
-    if events is not None:
-        if not isinstance(events, dict):
-            problems.append(f"{where}.events is not an object")
-        else:
-            for ev, n in events.items():
-                if not isinstance(n, (int, float)) or isinstance(n, bool) \
-                        or n != n or n < 0:
-                    problems.append(f"{where}.events[{ev!r}] {n!r} is not "
-                                    f"a non-negative number")
-    tuned = at.get("tuned")
-    if tuned is not None:
-        if not isinstance(tuned, list):
-            problems.append(f"{where}.tuned is not a list")
-        else:
-            for i, t in enumerate(tuned):
-                if not isinstance(t, dict):
-                    problems.append(f"{where}.tuned[{i}] is not an object")
-                    continue
-                if not isinstance(t.get("op"), str) or not t.get("op"):
-                    problems.append(f"{where}.tuned[{i}].op {t.get('op')!r} "
-                                    f"is not a non-empty string")
-                if not isinstance(t.get("config"), (str, dict)):
-                    problems.append(f"{where}.tuned[{i}].config "
-                                    f"{t.get('config')!r} is not a string "
-                                    f"or object")
-                pm = t.get("probe_ms")
-                if pm is not None and (not isinstance(pm, (int, float))
-                                       or isinstance(pm, bool)
-                                       or pm != pm or pm < 0):
-                    problems.append(f"{where}.tuned[{i}].probe_ms {pm!r} "
-                                    f"is not a non-negative number or null")
-    return problems
-
-
 def _nonneg_num(v) -> bool:
     return (isinstance(v, (int, float)) and not isinstance(v, bool)
             and v == v and v >= 0)
@@ -1369,7 +1252,7 @@ def validate_observability(doc: dict) -> List[str]:
     events/events_tail to the event contract (`controller_decision`
     events additionally to the decision contract: policy/action/legal
     outcome/decision id), `checkpoint_async_*` / `device_memory_*` /
-    `health_*` / `amp_*` / `autotune_*` / `controller_*` / `disagg_*` /
+    `health_*` / `amp_*` / `controller_*` / `disagg_*` /
     `serving_*` / `slo_*` / `analysis_*` metric families to their
     kind/label/shape
     contracts, `reqtrace`/`slo` observability blocks to the request-trace
@@ -1382,22 +1265,17 @@ def validate_observability(doc: dict) -> List[str]:
     contract (TTFT/TPOT percentiles, goodput fields, A/B rows),
     `device_time` blocks to
     the per-op row shape with a known provenance label (estimate /
-    measured / xplane), `health` blocks to the sentinel-overhead shape,
-    and `autotune` blocks (per config and the observability summary) to
-    the tuner's event/tuned-log shape; a missing section is fine (old
-    rounds), a malformed one is not."""
+    measured / xplane) and `health` blocks to the sentinel-overhead
+    shape; a missing section is fine (old rounds), a malformed one is
+    not."""
     from paddle_tpu.profiler.events import validate_event
     from paddle_tpu.profiler.monitor import validate_step_record
     problems = []
-    # per-config `autotune`/`profile`/`conv_fusion` blocks sit beside
-    # (not inside) observability
+    # per-config `profile`/`conv_fusion` blocks sit beside (not inside)
+    # observability
     for name, cfg in (doc.get("configs") or {}).items():
         if not isinstance(cfg, dict):
             continue
-        at = cfg.get("autotune")
-        if at is not None:
-            problems.extend(_validate_autotune_block(
-                f"configs.{name}.autotune", at))
         prof = cfg.get("profile")
         if isinstance(prof, dict) and prof.get("segments") is not None:
             problems.extend(_validate_segments(
@@ -1418,7 +1296,6 @@ def validate_observability(doc: dict) -> List[str]:
             problems.extend(_validate_async_ckpt_metrics(where, metrics))
             problems.extend(_validate_device_memory_metrics(where, metrics))
             problems.extend(_validate_health_metrics(where, metrics))
-            problems.extend(_validate_autotune_metrics(where, metrics))
             problems.extend(_validate_controller_metrics(where, metrics))
             problems.extend(_validate_disagg_metrics(where, metrics))
             problems.extend(_validate_serving_metrics(where, metrics))
@@ -1431,10 +1308,6 @@ def validate_observability(doc: dict) -> List[str]:
         slo_blk = obs.get("slo")
         if slo_blk is not None:
             problems.extend(_validate_slo_block(f"{where}.slo", slo_blk))
-        at = obs.get("autotune")
-        if at is not None:
-            problems.extend(_validate_autotune_block(f"{where}.autotune",
-                                                     at))
         dt = obs.get("device_time")
         if dt is not None:
             problems.extend(_validate_device_time(where, dt))

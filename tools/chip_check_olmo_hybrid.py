@@ -25,7 +25,6 @@ import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
-os.environ.setdefault("PADDLE_TPU_AUTOTUNE", "0")   # as the cell runs
 
 
 def main(argv=None) -> int:

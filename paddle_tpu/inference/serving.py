@@ -463,7 +463,18 @@ class ServingEngine:
       carries a recurrence a fixed-size state per batch slot. The engine
       allocates, shares, copies and injects pages of the pools that
       exist, counts both kinds in `pool_bytes()` / `status()`, and never
-      assumes one K/V pair per model layer;
+      assumes one K/V pair per model layer. Its `block_tables` and
+      `context_lens` are the ENGINE's: the source of truth is a pair of
+      NumPy arrays on the host (`_block_tables`, `_context_lens`), every
+      admission, page growth, copy-on-write repoint, release and
+      hand-off writes those, and the cache's fields are copies the
+      engine re-sends by one plain transfer right before a dispatch,
+      and only if a row changed since the last (`stats["table_refreshes"]`).
+      The programs keep the lengths current themselves (prefill sets
+      its slot's, decode adds one for each active lane, the host does
+      the same to its copy), so a length travels only after a hand-off.
+      A released slot's row is zeroed on the host alone: no lane names
+      an idle slot, and the next prefill overwrites its length;
     * ``forward_prefill(ids [1, bucket], cache, slot, length,
       write_start=)`` computes the prompt whole, writes its K/V into the
       slot's pages from `write_start` on (a shared prefix's pages are
@@ -473,7 +484,9 @@ class ServingEngine:
     * ``forward_decode(tokens [W], cache, active [W], slot_map=[W])``
       is one token for each lane: lane i works on slot `slot_map[i]`; a
       padding lane carries the sentinel `max_batch`, gathers clamped and
-      must have every write DROPPED; returns (logits [W, V], cache);
+      must have every write DROPPED; it reads the tables and lengths of
+      the slots `slot_map` names and of no other; returns
+      (logits [W, V], cache);
     * ``wte.weight``, the token embedding, whose dtype is the cache's
       default;
     * optionally ``set_tp_mesh(mesh, axis)`` / ``tp_mesh()``: a model
@@ -497,6 +510,15 @@ class ServingEngine:
     (unjitted) — the measured baseline the `path` metric label and the
     bench's fused_vs_eager A/B compare against. Both modes produce
     bit-identical tokens.
+
+    What `step()` launches and sends in steady state: one decode program
+    an iteration and one prefill program an admission (`cow_copy_pages`
+    when a shared page is written, the injection of a hand-off), and
+    nothing else. The decode program's per-lane arguments travel as two
+    packed arrays, the prefill's as ids and two small vectors, all NumPy
+    handed to the call; with the table refresh that is at most 3
+    transfers an iteration and 4 an admission (`stats["h2d_transfers"]`,
+    `transfers` on the `pt.engine.upload` and `.prefill.dispatch` spans).
 
     `share_prefix` (default True) admits requests whose prompt prefix
     is already resident (page-aligned prefix chains; exact-duplicate
@@ -567,6 +589,7 @@ class ServingEngine:
         self._prefix = _PrefixCache(page_size)
         self.allocator = PageAllocator(self.cache.num_pages,
                                        on_release=self._prefix.drop_page)
+        self._reset_tables()
         if prefill_buckets is None:
             prefill_buckets = _pow2_buckets(min(16, max_len), max_len)
         self.prefill_buckets = sorted(set(int(b) for b in prefill_buckets))
@@ -622,6 +645,7 @@ class ServingEngine:
                       "cow_copies": 0, "prefix_hit_tokens": 0,
                       "shared_admissions": 0, "swaps": 0, "restarts": 0,
                       "handoffs": 0, "worker_prefills": 0,
+                      "table_refreshes": 0, "h2d_transfers": 0,
                       "min_free_pages": self.allocator.free_pages}
         # what the cache holds, by kind (constants of the engine's life)
         desc = self.cache.describe()
@@ -656,6 +680,62 @@ class ServingEngine:
         from jax.sharding import NamedSharding, PartitionSpec
         return NamedSharding(self.mesh, PartitionSpec())
 
+    # -- the scheduling state the device reads --------------------------------
+    # The HOST owns it: `_block_tables` [max_batch, pages_per_seq] and
+    # `_context_lens` [max_batch] are NumPy arrays, written on the
+    # engine's thread only (the discipline `_slots` has), and the
+    # cache's `block_tables` / `context_lens` are copies made by plain
+    # transfers right before a dispatch. Nothing edits the device's copy
+    # from Python: an eager `.at[].set` is a program launch of its own,
+    # and the device waits through every one of them.
+
+    def _reset_tables(self):
+        """Host tables for a cache fresh from `init_cache`, whose own
+        tables are zeros too: nothing to send."""
+        self._block_tables = np.zeros(
+            (self.cache.max_batch, self.cache.pages_per_seq), np.int32)
+        self._context_lens = np.zeros((self.cache.max_batch,), np.int32)
+        self._tables_dirty = False
+        self._lens_dirty = False
+
+    def _put(self, host):
+        """One host-to-device transfer of a NumPy array nobody writes to
+        afterwards (the CPU backend aliases the host's memory), replicated
+        over the mesh under TP decode."""
+        import jax
+        if self.mesh is not None:
+            return jax.device_put(host, self._rep_sharding())
+        return jax.device_put(host)
+
+    def _point_slot(self, slot: int, pages: Sequence[int] = ()):
+        """The slot's row: `pages`, then the null page."""
+        row = self._block_tables[slot]
+        row[:] = 0
+        row[:len(pages)] = pages
+        self._tables_dirty = True
+
+    def _count_transfers(self, with_call: int) -> int:
+        """Arrays the dispatch about to be made hands to the device:
+        `with_call` NumPy arguments of the call itself plus what
+        `_refresh_tables` will send; counted here, once."""
+        stale = int(self._tables_dirty) + int(self._lens_dirty)
+        self.stats["h2d_transfers"] += with_call + stale
+        self.stats["table_refreshes"] += bool(stale)
+        return with_call + stale
+
+    def _refresh_tables(self):
+        """Before a dispatch: re-send the block tables if a row changed
+        since the last one. The lengths follow the device's own updates
+        (prefill sets a slot's, decode bumps each active lane's, and
+        `_prefill` / `_decode_iteration` do the same to the host's), so
+        they are sent only after a write no program makes: a hand-off."""
+        if self._tables_dirty:
+            self.cache.block_tables = self._put(self._block_tables.copy())
+            self._tables_dirty = False
+        if self._lens_dirty:
+            self.cache.context_lens = self._put(self._context_lens.copy())
+            self._lens_dirty = False
+
     def tp_degree(self) -> int:
         """Shards the KV pools split over (1 = single-chip)."""
         return int(self.mesh.shape[self.tp_axis]) if self.mesh is not None \
@@ -670,19 +750,28 @@ class ServingEngine:
     # surfaces like any other jit site) and compile time is attributed
     # on the compile-watch plane.
 
-    def _fused_step_fn(self, params, buffers, cache, tokens, slot_map,
-                       lane_active, temp, top_k, top_p, seeds, steps):
+    def _fused_step_fn(self, params, buffers, cache, lanes_i, lanes_f):
+        """`lanes_i` int32 [6, W] and `lanes_f` float32 [2, W] are what
+        `_lane_arrays` packed: one transfer each instead of eight."""
         import jax.numpy as jnp
         from ..jit import _swapped_state
+        tokens, slot_map, lane_active, top_k, seeds, steps = lanes_i
+        lane_active = lane_active.astype(bool)
+        temp, top_p = lanes_f
         with tape_mod.no_grad(), _swapped_state(self.model, params, buffers):
             logits, cache = self.model.forward_decode(
                 Tensor(tokens), cache, lane_active, slot_map=slot_map)
         nxt = sample_logits(logits.data, temp, top_k, top_p, seeds, steps)
         return jnp.where(lane_active, nxt, 0), cache
 
-    def _prefill_fn(self, params, buffers, cache, ids, slot, length,
-                    write_start, temp, top_k, top_p, seed, step):
+    def _prefill_fn(self, params, buffers, cache, ids, scalars, floats):
+        """`scalars` int32 [6] is slot, length, write start, top-k, seed,
+        step; `floats` float32 [2] temperature, top-p (`_prefill` packs
+        them on the host)."""
         from ..jit import _swapped_state
+        slot, length, write_start = scalars[0], scalars[1], scalars[2]
+        top_k, seed, step = scalars[3:4], scalars[4:5], scalars[5:6]
+        temp, top_p = floats[0:1], floats[1:2]
         with tape_mod.no_grad(), _swapped_state(self.model, params, buffers):
             logits, cache = self.model.forward_prefill(
                 Tensor(ids), cache, slot, length, write_start=write_start)
@@ -704,21 +793,12 @@ class ServingEngine:
         `temp_size_in_bytes`. Nothing executes and the live cache is
         untouched. Returns [decode_report, prefill_report] (+ a per-link
         collective-bytes report when TP decode is on)."""
-        import jax.numpy as jnp
         from .. import analysis
-        W = self.decode_buckets[0]
         # one buffer of each shape the programs should update in place:
         # a K and a V pool, and a recurrent and a convolution state
         pools = (self.cache.k_pages[:1] + self.cache.v_pages[:1]
                  + self.cache.states[:1] + self.cache.conv_states[:1])
-        lane_args = (jnp.zeros((W,), jnp.int32),           # tokens
-                     jnp.full((W,), self.max_batch, jnp.int32),  # slot_map
-                     jnp.zeros((W,), bool),                # lane_active
-                     jnp.zeros((W,), jnp.float32),         # temperature
-                     jnp.zeros((W,), jnp.int32),           # top_k
-                     jnp.ones((W,), jnp.float32),          # top_p
-                     jnp.zeros((W,), jnp.int32),           # seeds
-                     jnp.zeros((W,), jnp.int32))           # steps
+        lane_args = self._lane_arrays([])[1:]      # every lane padding
         decode = analysis.audit_program(
             self._fused_step_fn,
             (self._params, self._buffers, self.cache) + lane_args,
@@ -726,14 +806,12 @@ class ServingEngine:
             name=f"serving_decode:{self.name}", entry="serving_decode",
             emit=emit)
         bucket = self.prefill_buckets[0]
-        ids = jnp.zeros((1, bucket), jnp.int32)
-        one = (jnp.zeros((1,), jnp.float32), jnp.zeros((1,), jnp.int32),
-               jnp.ones((1,), jnp.float32), jnp.zeros((1,), jnp.int32),
-               jnp.zeros((1,), jnp.int32))
         prefill = analysis.audit_program(
             self._prefill_fn,
-            (self._params, self._buffers, self.cache, ids,
-             np.int32(0), np.int32(1), np.int32(0)) + one,
+            (self._params, self._buffers, self.cache,
+             np.zeros((1, bucket), np.int32),
+             np.array([0, 1, 0, 0, 0, 0], np.int32),  # slot 0, length 1
+             np.array([0.0, 1.0], np.float32)),        # greedy
             donate_argnums=(2,), relayout_of=pools,
             name=f"serving_prefill:{self.name}", entry="serving_prefill",
             emit=emit)
@@ -1160,6 +1238,7 @@ class ServingEngine:
                                            on_release=self._prefix.drop_page)
             if reserved:
                 self.allocator.reserve(reserved)  # keep the shrink in force
+            self._reset_tables()
             self._cur_tokens[:] = 0
             self.stats["restarts"] += 1
             self._last_progress = time.monotonic()
@@ -1224,7 +1303,6 @@ class ServingEngine:
         the payload untouched when no slot or pages are free right now
         (the pipeline retries next tick); True when admitted OR when the
         request already finished at the prefill stage."""
-        import jax.numpy as jnp
         req = handoff.request
         if req.state != "queued":
             return True  # single-token request finished at prefill
@@ -1244,12 +1322,11 @@ class ServingEngine:
             req.slot, req.pages, req.state = slot, list(pages), "running"
             self._slots[slot] = req
         self._note_pool_watermark()
-        row = np.zeros((self.cache.pages_per_seq,), np.int32)
-        row[:n_pages] = pages
-        self.cache.block_tables = self.cache.block_tables.at[slot].set(
-            jnp.asarray(row))
-        self.cache.context_lens = self.cache.context_lens.at[slot].set(
-            jnp.int32(ctx))
+        self._point_slot(slot, pages)
+        # no program of this engine wrote the length: the next refresh
+        # carries it
+        self._context_lens[slot] = ctx
+        self._lens_dirty = True
         # scatter ids padded to the payload's pow2 bucket with page 0
         pad = int(handoff.k_payload[0].shape[0])
         ids = np.zeros((pad,), np.int32)
@@ -1265,7 +1342,7 @@ class ServingEngine:
         with self._dispatch_lock:
             self.cache.k_pages, self.cache.v_pages = self._inject_jit(
                 self.cache.k_pages, self.cache.v_pages,
-                k_payload, v_payload, jnp.asarray(ids))
+                k_payload, v_payload, ids)
         self._cur_tokens[slot] = req.generated[-1]
         if req.admitted_ts is None:
             req.admitted_ts = time.monotonic()
@@ -1373,7 +1450,6 @@ class ServingEngine:
                  requeue: bool):
         """One admission past the queue: block-table row and padded ids
         on the host, the bucketed prefill program, the first token."""
-        import jax.numpy as jnp
         if shared_len:
             self.stats["shared_admissions"] += 1
             self.stats["prefix_hit_tokens"] += shared_len
@@ -1382,33 +1458,32 @@ class ServingEngine:
                              prompt_tokens=len(tokens),
                              shared_tokens=shared_len,
                              requeue=requeue)
+        sp = req.sampling
         with _span("prefill.build"):
-            bt = self.cache.block_tables
-            row = np.zeros((self.cache.pages_per_seq,), np.int32)
-            row[:len(pages)] = pages
-            self.cache.block_tables = bt.at[slot].set(jnp.asarray(row))
+            self._point_slot(slot, pages)
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :len(tokens)] = tokens
-        self._observe_site(f"prefill:{self.name}", [ids])
+            scalars = np.array([slot, len(tokens), shared_len, sp.top_k,
+                                req.seed, len(req.generated)], np.int32)
+            floats = np.array([sp.temperature, sp.top_p], np.float32)
+        self._observe_site(f"prefill:{self.name}", [ids, scalars, floats])
         from ..profiler import compile_watch as _cw
         prev = _cw.push_entry("to_static", f"serving_prefill:{self.name}")
-        sp = req.sampling
+        # the three NumPy arguments travel with the call itself
+        transfers = self._count_transfers(3)
         try:
             # dispatch lock: a concurrent canary evaluation rebinds the
             # model's parameter state while it traces — never interleave
             # that with a prefill/decode trace
-            with _span("prefill.dispatch"), self._dispatch_lock:
+            with _span("prefill.dispatch", transfers=transfers), \
+                    self._dispatch_lock:
+                self._refresh_tables()
                 nxt, self.cache = self._prefill_jit(
-                    self._params, self._buffers, self.cache,
-                    jnp.asarray(ids), np.int32(slot),
-                    np.int32(len(tokens)), np.int32(shared_len),
-                    jnp.full((1,), sp.temperature, jnp.float32),
-                    jnp.full((1,), sp.top_k, jnp.int32),
-                    jnp.full((1,), sp.top_p, jnp.float32),
-                    jnp.full((1,), req.seed, jnp.int32),
-                    jnp.full((1,), len(req.generated), jnp.int32))
+                    self._params, self._buffers, self.cache, ids, scalars,
+                    floats)
         finally:
             _cw.pop_entry(prev)
+        self._context_lens[slot] = len(tokens)   # as the program set it
         self.stats["prefills"] += 1
         if self.share_prefix:
             self._prefix.register(tokens, pages)
@@ -1457,7 +1532,6 @@ class ServingEngine:
         every layer's pools, the block table repoints, and the other
         sharers keep the original. Preempts the youngest request when
         the pool is dry."""
-        import jax.numpy as jnp
         from ..ops.pallas import paged_attention as _pa
         for slot in list(active_slots):
             req = self._slots[slot]
@@ -1472,8 +1546,8 @@ class ServingEngine:
                     dead = True
                     break
                 req.pages.append(page)
-                self.cache.block_tables = self.cache.block_tables.at[
-                    slot, len(req.pages) - 1].set(jnp.int32(page))
+                self._block_tables[slot, len(req.pages) - 1] = page
+                self._tables_dirty = True
             if dead or self._slots[slot] is not req:
                 continue
             # copy-on-write: the page receiving this iteration's K/V
@@ -1489,8 +1563,8 @@ class ServingEngine:
                 continue
             self.cache.k_pages, self.cache.v_pages = _pa.cow_copy_pages(
                 self.cache.k_pages, self.cache.v_pages, old, fresh)
-            self.cache.block_tables = self.cache.block_tables.at[
-                slot, write_idx].set(jnp.int32(fresh))
+            self._block_tables[slot, write_idx] = fresh
+            self._tables_dirty = True
             req.pages[write_idx] = fresh
             self.allocator.free([old])  # drop this holder's shared ref
             self.stats["cow_copies"] += 1
@@ -1503,36 +1577,34 @@ class ServingEngine:
 
     def _lane_arrays(self, active_slots: List[int]):
         """Gather the active slots into W bucketed lanes (W = smallest
-        decode bucket covering the active count). Padding lanes carry
-        the slot sentinel `max_batch` (clamp-gather + drop-scatter in
-        forward_decode) and greedy sampling params (so an all-greedy
-        batch keeps the sampler's argmax fast path)."""
+        decode bucket covering the active count), packed as the decode
+        program takes them: int32 [6, W] (tokens, slot map, lane-active,
+        top-k, seeds, steps) and float32 [2, W] (temperature, top-p).
+        Padding lanes carry the slot sentinel `max_batch` (clamp-gather +
+        drop-scatter in forward_decode) and greedy sampling params (so an
+        all-greedy batch keeps the sampler's argmax fast path)."""
         n = len(active_slots)
         W = self._decode_bucket(n)
-        slot_map = np.full((W,), self.max_batch, np.int32)
-        tokens = np.zeros((W,), np.int32)
-        lane_active = np.zeros((W,), bool)
-        temp = np.zeros((W,), np.float32)
-        top_k = np.zeros((W,), np.int32)
-        top_p = np.ones((W,), np.float32)
-        seeds = np.zeros((W,), np.int32)
-        steps = np.zeros((W,), np.int32)
+        lanes_i = np.zeros((6, W), np.int32)
+        lanes_f = np.zeros((2, W), np.float32)
+        tokens, slot_map, lane_active, top_k, seeds, steps = lanes_i
+        temp, top_p = lanes_f
+        slot_map[:] = self.max_batch
+        top_p[:] = 1.0
         for i, slot in enumerate(active_slots[:W]):
             req = self._slots[slot]
             sp = req.sampling
             slot_map[i] = slot
             tokens[i] = self._cur_tokens[slot]
-            lane_active[i] = True
+            lane_active[i] = 1
             temp[i] = sp.temperature
             top_k[i] = sp.top_k
             top_p[i] = sp.top_p
             seeds[i] = req.seed
             steps[i] = len(req.generated)
-        return (W, tokens, slot_map, lane_active, temp, top_k, top_p,
-                seeds, steps)
+        return W, lanes_i, lanes_f
 
     def _decode_iteration(self, active_slots: List[int]) -> int:
-        import jax.numpy as jnp
         self._maybe_audit_once()
         # chaos: an armed `serving.decode=N:delay` sleeps here, inflating
         # TTFT/TPOT exactly like a slow device would (the SLO-breach drill)
@@ -1542,20 +1614,20 @@ class ServingEngine:
             pass  # only delay/no-op kinds make sense here; ignore others
         with _span("lanes", lanes=self._decode_bucket(len(active_slots)),
                    active=len(active_slots)):
-            (W, tokens, slot_map, lane_active, temp, top_k, top_p, seeds,
-             steps) = self._lane_arrays(active_slots)
+            W, lanes_i, lanes_f = self._lane_arrays(active_slots)
         # per-bucket watchdog site: ONE signature per lane width is the
         # zero-retrace steady-state contract
-        self._observe_site(f"decode:{self.name}:w{W}", [tokens])
+        self._observe_site(f"decode:{self.name}:w{W}", [lanes_i, lanes_f])
         from ..profiler import compile_watch as _cw
         prev = _cw.push_entry("to_static", f"serving_decode:{self.name}")
         t0 = time.perf_counter()
-        with _span("upload"):
-            args = (self._params, self._buffers, self.cache,
-                    jnp.asarray(tokens), jnp.asarray(slot_map),
-                    jnp.asarray(lane_active), jnp.asarray(temp),
-                    jnp.asarray(top_k), jnp.asarray(top_p),
-                    jnp.asarray(seeds), jnp.asarray(steps))
+        # what this iteration hands to the device: the tables if a row
+        # changed, here, and the two lane arrays, which travel as NumPy
+        # with the call itself (cheaper than a `device_put` each)
+        with _span("upload", transfers=self._count_transfers(2)):
+            self._refresh_tables()
+            args = (self._params, self._buffers, self.cache, lanes_i,
+                    lanes_f)
         try:
             # see _prefill: canary serialization
             with _span("dispatch"), self._dispatch_lock:
@@ -1566,6 +1638,8 @@ class ServingEngine:
                     nxt, self.cache = self._fused_step_fn(*args)
         finally:
             _cw.pop_entry(prev)
+        # the program bumped each active lane's length: so does the host
+        self._context_lens[active_slots[:W]] += 1
         with _span("fetch"):
             nxt_np = np.asarray(nxt)  # device sync: the iteration boundary
         self.stats["decode_wall_s"] += time.perf_counter() - t0
@@ -1648,16 +1722,15 @@ class ServingEngine:
         self._emit_eviction(req, "preempted")
 
     def _release_slot(self, req: Request):
-        import jax.numpy as jnp
         slot = req.slot
         if slot is not None and self._slots[slot] is req:
             self._slots[slot] = None
             self._cur_tokens[slot] = 0
-            # point the slot's block table back at the null page and zero
-            # its context so the batched decode masks it out entirely
-            self.cache.block_tables = self.cache.block_tables.at[slot].set(
-                jnp.zeros((self.cache.pages_per_seq,), jnp.int32))
-            self.cache.context_lens = self.cache.context_lens.at[slot].set(0)
+            # host only: the zeroed row rides on the next refresh, and
+            # the device's stale length is never read (no lane names an
+            # idle slot; prefill overwrites it on the next admission)
+            self._point_slot(slot)
+            self._context_lens[slot] = 0
         self.allocator.free(req.pages)
         req.pages = []
 

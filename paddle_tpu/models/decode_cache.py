@@ -54,7 +54,15 @@ class PagedKVCache:
     time). ``block_tables`` is ``[max_batch, pages_per_seq]`` int32 and
     ``context_lens`` ``[max_batch]`` int32. Page 0 is the NULL page: idle
     batch slots point at it and their decode-step writes land there (see
-    the serving allocator).
+    the serving allocator). WHO OWNS THEM: whoever schedules the slots.
+    Under `inference/serving.ServingEngine` that is the host: the engine
+    keeps both as NumPy arrays, writes only those, and assigns fresh
+    device copies into these two fields right before a dispatch when a
+    row changed; the programs pass the tables through and update the
+    lengths (prefill sets a slot's, decode bumps each active lane's).
+    A model's forward never edits a table, and in lane mode it reads
+    only the rows its `slot_map` names, so a row of an idle slot may be
+    stale on the device.
 
     ``states[j]`` ``[max_batch, ...]`` and ``conv_states[j]``
     ``[max_batch, K-1, C]`` belong to the j-th layer whose kind is

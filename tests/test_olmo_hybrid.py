@@ -440,20 +440,20 @@ class TestScopes:
         m = _model()
         eng = ServingEngine(m, max_batch=2, max_len=32, page_size=8,
                             name="olmo_scopes")
+        # the packed arguments of both programs (serving.py: int32 [6, W]
+        # and float32 [2, W]; ids, int32 [6], float32 [2])
         W = 2
-        lane = (jnp.zeros((W,), jnp.int32), jnp.zeros((W,), jnp.int32),
-                jnp.ones((W,), bool), jnp.zeros((W,), jnp.float32),
-                jnp.zeros((W,), jnp.int32), jnp.ones((W,), jnp.float32),
-                jnp.zeros((W,), jnp.int32), jnp.zeros((W,), jnp.int32))
+        lanes_i = np.zeros((6, W), np.int32)
+        lanes_i[2] = 1                                  # both lanes active
+        lanes_f = np.zeros((2, W), np.float32)
+        lanes_f[1] = 1.0                                # top-p
         decode = jax.jit(eng._fused_step_fn).lower(
-            eng._params, eng._buffers, eng.cache, *lane)
-        one = (jnp.zeros((1,), jnp.float32), jnp.zeros((1,), jnp.int32),
-               jnp.ones((1,), jnp.float32), jnp.zeros((1,), jnp.int32),
-               jnp.zeros((1,), jnp.int32))
+            eng._params, eng._buffers, eng.cache, lanes_i, lanes_f)
         prefill = jax.jit(eng._prefill_fn).lower(
             eng._params, eng._buffers, eng.cache,
-            jnp.zeros((1, 16), jnp.int32), np.int32(0), np.int32(5),
-            np.int32(0), *one)
+            np.zeros((1, 16), np.int32),
+            np.array([0, 5, 0, 0, 0, 0], np.int32),     # slot 0, length 5
+            np.array([0.0, 1.0], np.float32))
         eng.close()
         return {"decode": decode.as_text(debug_info=True),
                 "prefill": prefill.as_text(debug_info=True)}

@@ -18,6 +18,7 @@ from paddle_tpu import jit as jit_mod
 from paddle_tpu import optimizer
 from paddle_tpu.inference import serving
 from paddle_tpu.models import GPT, GPTConfig
+from paddle_tpu.models.olmo_hybrid import OlmoHybrid, OlmoHybridConfig
 from paddle_tpu.nn import functional as F
 from paddle_tpu.profiler.recorder import get_recorder
 from paddle_tpu.profiler.utils import SPAN_PREFIX, RecordEvent
@@ -31,11 +32,11 @@ ENGINE_SPANS = {
         "rid", "trace_id", "bucket", "prompt_tokens", "shared_tokens",
         "requeue", "queue_wait_us"}),
     "pt.engine.prefill.build": ("pt.engine.prefill", set()),
-    "pt.engine.prefill.dispatch": ("pt.engine.prefill", set()),
+    "pt.engine.prefill.dispatch": ("pt.engine.prefill", {"transfers"}),
     "pt.engine.prefill.fetch": ("pt.engine.prefill", set()),
     "pt.engine.capacity": ("pt.engine.step", {"active"}),
     "pt.engine.lanes": ("pt.engine.step", {"lanes", "active"}),
-    "pt.engine.upload": ("pt.engine.step", set()),
+    "pt.engine.upload": ("pt.engine.step", {"transfers"}),
     "pt.engine.dispatch": ("pt.engine.step", set()),
     "pt.engine.fetch": ("pt.engine.step", set()),
     "pt.engine.bookkeep": ("pt.engine.step", {"lanes"}),
@@ -99,11 +100,12 @@ def named(events, name):
     return [e for e in events if e["name"] == name]
 
 
-def serve(directory=None):
+def serve(directory=None, state_layers=False):
     """A tiny engine with more requests than lanes, warmed outside the
     trace; returns what the traced (or untraced) round produced."""
     paddle.seed(0)
-    model = GPT(GPTConfig.tiny())
+    model = (OlmoHybrid(OlmoHybridConfig.tiny(1)) if state_layers
+             else GPT(GPTConfig.tiny()))
     model.eval()
     eng = serving.ServingEngine(model, max_batch=2, max_len=64,
                                 page_size=8, eos_id=-1)
@@ -124,7 +126,8 @@ def serve(directory=None):
     programs = {m.name for exe in jax.devices()[0].client.live_executables()
                 for m in exe.hlo_modules()}
     delta = {k: eng.stats[k] - before[k]
-             for k in ("iterations", "prefills", "completed")}
+             for k in ("iterations", "prefills", "completed",
+                       "h2d_transfers", "table_refreshes")}
     eng.close()
     return {"reqs": reqs, "events": events, "delta": delta,
             "programs": programs}
@@ -153,6 +156,12 @@ def train(directory=None, calls=3):
 @pytest.fixture(scope="module")
 def served(tmp_path_factory):
     return serve(tmp_path_factory.mktemp("serve_trace"))
+
+
+@pytest.fixture(scope="module")
+def served_state(tmp_path_factory):
+    return serve(tmp_path_factory.mktemp("serve_state_trace"),
+                 state_layers=True)
 
 
 @pytest.fixture(scope="module")
@@ -224,6 +233,66 @@ def test_engine_programs_are_named_as_the_metrics_expect(served):
         calls = named(served["events"], call)
         assert calls
         assert {parent(c, served["events"]) for c in calls} == {span}
+
+
+# what the CPU client's host trace shows of the device's side: a program
+# launched, and an array handed over (from Python, or as an argument of a
+# jitted call)
+LAUNCH = "PjRtCpuExecutable::Execute"
+TRANSFER = {"DevicePut", "DevicePutWithSharding"}
+
+
+def inside(span, events, names):
+    return [e for e in events if e["name"] in names
+            and e["line"] == span["line"]
+            and span["start"] <= e["start"] and e["end"] <= span["end"]]
+
+
+@pytest.mark.parametrize("engine", ["gpt", "state_layers"])
+def test_a_step_launches_its_two_programs_and_nothing_else(
+        engine, served, served_state):
+    """PR 30: block tables and lengths are the host's, so inside
+    `pt.engine.step` the only executables are one decode program an
+    iteration and one prefill program an admission, and the transfers
+    are the packed arguments plus at most one table refresh."""
+    run = served if engine == "gpt" else served_state
+    ev, delta = run["events"], run["delta"]
+    steps = named(ev, "pt.engine.step")
+    launches = [e for s in steps for e in inside(s, ev, {LAUNCH})]
+    assert launches, "the trace shows no launch: another client's names?"
+    assert len(launches) == delta["iterations"] + delta["prefills"]
+    sites = {"pt.engine.dispatch", "pt.engine.prefill.dispatch"}
+    assert {parent(e, ev) for e in launches} == sites
+    jitted = {e["name"] for s in steps for e in ev
+              if e["name"].startswith("PjitFunction(")
+              and e["line"] == s["line"]
+              and s["start"] <= e["start"] and e["end"] <= s["end"]}
+    assert jitted == {"PjitFunction(_prefill_fn)",
+                      "PjitFunction(_fused_step_fn)"}
+
+    # transfers: as many as the span says, where the span says
+    moved = [e for s in steps for e in inside(s, ev, TRANSFER)]
+    said = 0
+    for step in steps:
+        uploads = inside(step, ev, {"pt.engine.upload"})
+        calls = inside(step, ev, {"pt.engine.dispatch"})
+        assert len(uploads) == len(calls) <= 1
+        for up, call in zip(uploads, calls):
+            n = up["args"]["transfers"]
+            assert 2 <= n <= 3
+            # the tables inside `.upload`, the lane arrays with the call
+            assert len(inside(up, ev, TRANSFER)) == n - 2
+            assert len(inside(call, ev, TRANSFER)) == 2
+            said += n
+        for fill in inside(step, ev, {"pt.engine.prefill.dispatch"}):
+            n = fill["args"]["transfers"]
+            assert 3 <= n <= 4
+            assert len(inside(fill, ev, TRANSFER)) == n
+            said += n
+    assert said == len(moved) == delta["h2d_transfers"]
+    assert delta["h2d_transfers"] == (2 * delta["iterations"]
+                                      + 3 * delta["prefills"]
+                                      + delta["table_refreshes"])
 
 
 @pytest.mark.parametrize("name", sorted(TRAIN_SPANS))

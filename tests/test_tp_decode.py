@@ -116,6 +116,13 @@ class TestTPParity:
         assert st["tp_degree"] == 2 and st["tp_axis"] == "tp"
         # the pools actually shard: each K page pool spans both devices
         assert len(eng.cache.k_pages[0].sharding.device_set) == 2
+        # the tables the host re-sends (PR 30) are replicated over the
+        # mesh, not committed to one device
+        eng._tables_dirty = eng._lens_dirty = True
+        eng._refresh_tables()
+        for t in (eng.cache.block_tables, eng.cache.context_lens):
+            assert t.sharding.is_fully_replicated
+            assert len(t.sharding.device_set) == 2
 
     def test_parity_with_cow_forked_shared_prefix(self, shared):
         """Exact-duplicate prompts admit onto shared pages (partial

@@ -453,14 +453,19 @@ def _inject_pages_impl(k_pages, v_pages, k_payload, v_payload, page_ids):
 class ServingEngine:
     """Continuous-batching decode engine over one model's decode cache.
 
-    The decode protocol `model` implements (models/gpt.py and
-    models/olmo_hybrid.py do):
+    The decode protocol `model` implements (models/gpt.py,
+    models/olmo_hybrid.py and models/nemotron_h.py do):
 
     * ``init_cache(max_batch, max_len, page_size=, num_pages=)`` returns a
       `models/decode_cache.PagedKVCache`: one pytree that DESCRIBES ITSELF
       per layer (`layer_kinds`, `describe()`): K/V page pools for the
-      layers that attend over every past token, and for each layer that
-      carries a recurrence a fixed-size state per batch slot. The engine
+      layers that attend over every past token (at the width of the K/V
+      heads, which grouped heads make narrower than the query's), for
+      each layer that carries a recurrence a fixed-size state per batch
+      slot, nothing for a layer that carries nothing from token to token
+      (an expert block that is a layer of its own), and `counters`, small
+      device arrays the decode step adds to, which ride in the donated
+      cache and are read by `device_counters()` alone. The engine
       allocates, shares, copies and injects pages of the pools that
       exist, counts both kinds in `pool_bytes()` / `status()`, and never
       assumes one K/V pair per model layer. Its `block_tables` and
@@ -1062,6 +1067,16 @@ class ServingEngine:
         what `mem_budget_bytes` and `shrink_pool` can give back; the
         states are a fixed cost of `max_batch`."""
         return self.cache.pool_bytes() + self.cache.state_bytes()
+
+    def device_counters(self) -> Dict:
+        """What the model's layers counted on the device about the work
+        they did (`PagedKVCache.counters`: an expert layer's assignments),
+        fetched now, as NumPy. They ride in the decode step's donated
+        cache and cost the loop nothing; THIS is a device fetch that
+        waits for the step in flight, so call it at the ends of a window
+        (a benchmark, a status page), never once an iteration."""
+        with self._dispatch_lock:
+            return {k: np.asarray(v) for k, v in self.cache.counters.items()}
 
     def request_swap(self, params: Dict, buffers: Optional[Dict] = None, *,
                      step: Optional[int] = None, source: str = "manual",
@@ -1767,12 +1782,15 @@ class ServingEngine:
 
     def cache_snapshot(self) -> Dict:
         """The decode cache by kind, for `status()` and `/requests`: the
-        page pools (layers, pages used / free / parked, bytes) and the
-        per-slot recurrent states (layers, bytes a slot, slots in use)."""
+        page pools (layers, K/V heads, pages used / free / parked, bytes),
+        the per-slot recurrent states (layers, bytes a slot, slots in use)
+        and how many layers hold nothing."""
         d = self.cache.describe()
         free, parked = self.allocator.free_pages, self.allocator.reserved_pages
         return {
-            "pages": {"layers": d["kv_layers"], "page_size": d["page_size"],
+            "cacheless_layers": d["cacheless_layers"],
+            "pages": {"layers": d["kv_layers"], "kv_heads": d["num_kv_heads"],
+                      "page_size": d["page_size"],
                       "total": d["num_pages"] - 1, "free": free,
                       "parked": parked,
                       "used": d["num_pages"] - 1 - free - parked,

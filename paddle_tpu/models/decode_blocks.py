@@ -1,0 +1,147 @@
+"""What the serving methods of the hybrid models share, so that a model
+file writes out only its own layers: matrix products at three bfloat16
+passes, the head under a scope, and the parts of `forward_prefill` /
+`forward_decode` that are the same whatever the layers are (the slot's
+arguments, the lanes' view of the tables, a full-attention layer's append
+and paged attention, the length bump, the last real position).
+`models/olmo_hybrid.py` and `models/nemotron_h.py` call these; the rest of
+ROADMAP D1 (one model runner instead of a copy a model) is a `simplicity`
+issue's.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from .. import nn
+from ..framework.tensor import Tensor
+from ..ops import _dispatch as _d
+
+# Matrix products of float32 weights run in three bfloat16 passes
+# (`Precision.HIGH`), not the TPU's default one: every branch of these
+# models ends in (or starts from) a norm, so the residual stream carries
+# each layer's rounding on at full size, and with single-pass products 8
+# layers put the logits 0.12 from the float32 reference (of a mean size of
+# 0.22; PERF.md, PR 27): the argmax turned in one run of three. A choice of
+# these models', in their own products; the Pallas kernels they share keep
+# their own precision (Mosaic takes DEFAULT or HIGHEST only).
+PRODUCTS = jax.lax.Precision.HIGH
+
+
+@_d.kernel("linear_high")
+def _matmul(x, weight):
+    return jnp.matmul(x, weight, precision=PRODUCTS)
+
+
+# under an inner jit of its own: the op dispatcher stages a shape it has
+# seen twice, and a program meets the head once, so it would be traced
+# bare and carry no scope in the device trace
+head = jax.jit(_matmul)
+
+
+class HighLinear(nn.Linear):
+    """`nn.Linear` without bias, its product at `PRODUCTS`."""
+
+    def __init__(self, n_in: int, n_out: int):
+        super().__init__(n_in, n_out, bias_attr=False)
+
+    def forward(self, x):
+        return _d.call(_matmul, (x, self.weight))
+
+
+# A model whose expert blocks ROUTE on the residual stream keeps the
+# products in front of its routers at `highest`: at three passes the
+# margin between the last expert chosen and the first left out sat up to
+# 4e-5 from the float32 reference's, and 13 prompts of 48 met another
+# expert somewhere in their 256 tokens, after which the two runs are no
+# longer the same computation (PERF.md, PR 31).
+EXACT = jax.lax.Precision.HIGHEST
+
+
+@_d.kernel("linear_highest")
+def _matmul_exact(x, weight):
+    return jnp.matmul(x, weight, precision=EXACT)
+
+
+class ExactLinear(nn.Linear):
+    """`nn.Linear` without bias, its product at `EXACT`."""
+
+    def __init__(self, n_in: int, n_out: int):
+        super().__init__(n_in, n_out, bias_attr=False)
+
+    def forward(self, x):
+        return _d.call(_matmul_exact, (x, self.weight))
+
+
+def pages_for(max_batch: int, max_len: int, page_size: int, num_pages: int):
+    """(pages a sequence, pages of a pool): the default backs every slot
+    in full, +1 for the null page."""
+    pages_per_seq = -(-max_len // page_size)
+    return pages_per_seq, num_pages or 1 + max_batch * pages_per_seq
+
+
+def prefill_args(input_ids, cache, slot, length, write_start):
+    """`forward_prefill`'s scalars as int32 arrays, and the slot's row of
+    the block table."""
+    if input_ids.shape[0] != 1:
+        raise ValueError(f"forward_prefill fills ONE slot; got batch "
+                         f"{input_ids.shape[0]} (serving prefills per "
+                         f"request)")
+    slot = jnp.asarray(slot, jnp.int32)
+    return (slot, jnp.asarray(length, jnp.int32),
+            jnp.asarray(write_start, jnp.int32),
+            jnp.take(cache.block_tables, slot, axis=0))
+
+
+def last_real_position(x, length):
+    """x Tensor [1, L, h] -> Tensor [1, h] at position `length - 1`."""
+    return Tensor(jax.lax.dynamic_index_in_dim(
+        x.data, length - 1, axis=1, keepdims=False))
+
+
+def decode_view(cache, active, slot_map):
+    """The block tables, lengths and activity one decode step works on:
+    in lane mode the rows `slot_map` names (gathered clamped: a padding
+    lane carries the sentinel `max_batch`), else every slot's. Returns
+    ``(slot_map or None, block_tables, context_lens, active)``."""
+    if slot_map is not None:
+        slot_map = jnp.asarray(slot_map, jnp.int32)
+        bt = jnp.take(cache.block_tables, slot_map, axis=0, mode="clip")
+        ctx = jnp.take(cache.context_lens, slot_map, mode="clip")
+        if active is None:
+            active = slot_map < cache.max_batch
+    else:
+        bt, ctx = cache.block_tables, cache.context_lens
+        if active is None:
+            active = jnp.ones((cache.max_batch,), bool)
+    return slot_map, bt, ctx, jnp.asarray(getattr(active, "data", active),
+                                          bool)
+
+
+def bump_lengths(cache, slot_map, ctx, active):
+    """One more token in every active sequence; a padding lane's sentinel
+    is dropped."""
+    if slot_map is not None:
+        cache.context_lens = cache.context_lens.at[slot_map].add(
+            jnp.where(active, 1, 0).astype(jnp.int32), mode="drop")
+    else:
+        cache.context_lens = jnp.where(active, ctx + 1, ctx)
+
+
+def paged_decode_attention(cache, i, q, k, v, bt, ctx, active):
+    """One token of a full-attention layer: append its K/V ``[B, Hkv*D]``
+    to pools `i`, then attend with q ``[B, H, D]`` over the pages (the new
+    token is part of its own context). Returns ``[B, H, D]``."""
+    from ..ops.pallas import paged_attention as _pa
+    cache.k_pages[i], cache.v_pages[i] = _pa.cache_append(
+        cache.k_pages[i], cache.v_pages[i], k, v, bt, ctx, active)
+    return _pa.paged_attention(q, cache.k_pages[i], cache.v_pages[i], bt,
+                               jnp.where(active, ctx + 1, 0))
+
+
+def paged_prefill_append(cache, i, k, v, page_row, length, write_start):
+    """A prompt's K/V ``[L, Hkv*D]`` into the pages of its slot's row."""
+    from ..ops.pallas import paged_attention as _pa
+    cache.k_pages[i], cache.v_pages[i] = _pa.prefill_append(
+        cache.k_pages[i], cache.v_pages[i], k, v, page_row, length,
+        start=write_start)

@@ -16,6 +16,7 @@ import numpy as np
 
 KV = "kv"          # a layer with a paged K and V pool
 STATE = "state"    # a layer with a recurrent state and a convolution tail
+NONE = "none"      # a layer that carries nothing from token to token
 
 
 class StateLayersUnsupported(NotImplementedError):
@@ -41,10 +42,14 @@ def _nbytes(x) -> int:
 class PagedKVCache:
     """Paged decode KV cache + per-slot recurrent state.
 
-    ``k_pages[i]`` / ``v_pages[i]`` are ``[num_pages, page_size, H*D]``,
+    ``k_pages[i]`` / ``v_pages[i]`` are ``[num_pages, page_size, Hkv*D]``,
     one pair for each layer whose kind is ``"kv"``, in layer order, the
-    heads FOLDED into the minor axis (head h in lanes [h*D, (h+1)*D);
-    ``num_heads`` / ``head_dim`` say how). Folded, because a jitted
+    K/V heads FOLDED into the minor axis (head h in lanes [h*D, (h+1)*D);
+    ``num_kv_heads`` / ``head_dim`` say how). ``num_heads`` counts the
+    QUERY heads: where it is a multiple of ``num_kv_heads`` (grouped K/V
+    heads), query head h reads K/V head ``h // (num_heads //
+    num_kv_heads)`` and a K/V head is stored once, never repeated. Folded,
+    because a jitted
     program holds its arguments and results to the device's default
     layout for their shape: the TPU lays ``[.., H*D]`` out row-major
     whenever H*D is a multiple of 128, but puts the PAGES of a 4-D
@@ -73,13 +78,22 @@ class PagedKVCache:
     place.
 
     ``layer_kinds`` names each model layer's kind (default: every layer
-    paged K/V, the GPT case). Registered as a pytree so a whole serving
+    paged K/V, the GPT case); a layer of kind ``"none"`` (a feed-forward
+    or expert block that is a layer of its own) holds nothing here.
+
+    ``counters`` is a dict of small device arrays that the model's decode
+    step adds to (what its layers count about the work they did: an
+    expert layer's assignments); they ride in the donated step like the
+    pools, and whoever wants them reads them when it chooses, never the
+    engine's loop. Registered as a pytree so a whole serving
     decode step jits over it with pools and states donated."""
 
     def __init__(self, k_pages, v_pages, block_tables, context_lens,
                  page_size: int, num_heads: int, head_dim: int,
                  states: Sequence = (), conv_states: Sequence = (),
-                 layer_kinds: Optional[Sequence[str]] = None):
+                 layer_kinds: Optional[Sequence[str]] = None,
+                 num_kv_heads: Optional[int] = None,
+                 counters: Optional[dict] = None):
         self.k_pages = list(k_pages)
         self.v_pages = list(v_pages)
         self.block_tables = block_tables
@@ -87,13 +101,15 @@ class PagedKVCache:
         self.page_size = int(page_size)
         self.num_heads = int(num_heads)
         self.head_dim = int(head_dim)
+        self.num_kv_heads = int(num_kv_heads or num_heads)
+        self.counters = dict(counters or {})
         self.states = list(states)
         self.conv_states = list(conv_states)
         if layer_kinds is None:
             layer_kinds = (KV,) * len(self.k_pages)
         self.layer_kinds = tuple(layer_kinds)
         # layer index -> index into k_pages/v_pages or states/conv_states
-        counts = {KV: 0, STATE: 0}
+        counts = {KV: 0, STATE: 0, NONE: 0}
         self._index = []
         for kind in self.layer_kinds:
             self._index.append(counts[kind])
@@ -142,6 +158,9 @@ class PagedKVCache:
             "layer_kinds": list(self.layer_kinds),
             "kv_layers": len(self.k_pages),
             "state_layers": len(self.states),
+            "cacheless_layers": self.layer_kinds.count(NONE),
+            "num_heads": self.num_heads,
+            "num_kv_heads": self.num_kv_heads,
             "num_pages": self.num_pages,
             "page_size": self.page_size,
             "page_bytes": self.pool_bytes() // max(1, self.num_pages),
@@ -158,16 +177,18 @@ class PagedKVCache:
 
     def tree_flatten(self):
         return ((self.k_pages, self.v_pages, self.block_tables,
-                 self.context_lens, self.states, self.conv_states),
+                 self.context_lens, self.states, self.conv_states,
+                 self.counters),
                 (self.page_size, self.num_heads, self.head_dim,
-                 self.layer_kinds))
+                 self.layer_kinds, self.num_kv_heads))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        k, v, bt, cl, states, conv = children
-        page_size, num_heads, head_dim, kinds = aux
+        k, v, bt, cl, states, conv, counters = children
+        page_size, num_heads, head_dim, kinds, num_kv_heads = aux
         return cls(k, v, bt, cl, page_size, num_heads, head_dim,
-                   states=states, conv_states=conv, layer_kinds=kinds)
+                   states=states, conv_states=conv, layer_kinds=kinds,
+                   num_kv_heads=num_kv_heads, counters=counters)
 
 
 def _register_cache_pytree():

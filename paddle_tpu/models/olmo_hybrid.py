@@ -49,9 +49,10 @@ from .. import nn
 from ..nn import functional as F
 from ..framework.tensor import Tensor
 from ..nn.initializer import Uniform
-from ..ops import _dispatch as _d
 from ..ops import reshape
 from ..ops import linear_attention as _la
+from . import decode_blocks as _blocks
+from .decode_blocks import HighLinear as _Linear
 from .decode_cache import KV, STATE, PagedKVCache, StateLayersUnsupported
 from .gpt import GPT
 
@@ -119,38 +120,6 @@ class OlmoHybridConfig:
             linear_num_key_heads=2, linear_num_value_heads=2,
             linear_key_head_dim=8, linear_value_head_dim=16,
             linear_chunk_size=16)
-
-
-# Matrix products of float32 weights run in three bfloat16 passes
-# (`Precision.HIGH`), not the TPU's default one: every branch of this
-# model ends in a norm, so the residual stream carries each layer's
-# rounding on at full size, and with single-pass products 8 layers put
-# the logits 0.12 from the float32 reference (of a mean size of 0.22;
-# PERF.md, PR 27): the argmax turned in one run of three. A choice of
-# this model's, in its own products; the Pallas kernels it shares keep
-# their own precision (Mosaic takes DEFAULT or HIGHEST only).
-_PRODUCTS = jax.lax.Precision.HIGH
-
-
-@_d.kernel("linear_high")
-def _matmul(x, weight):
-    return jnp.matmul(x, weight, precision=_PRODUCTS)
-
-
-# under an inner jit of its own: the op dispatcher stages a shape it has
-# seen twice, and a program meets the head once, so it would be traced
-# bare and carry no scope in the device trace
-_head = jax.jit(_matmul)
-
-
-class _Linear(nn.Linear):
-    """`nn.Linear` without bias, its product at `_PRODUCTS`."""
-
-    def __init__(self, n_in: int, n_out: int):
-        super().__init__(n_in, n_out, bias_attr=False)
-
-    def forward(self, x):
-        return _d.call(_matmul, (x, self.weight))
 
 
 class OlmoFullAttention(nn.Layer):
@@ -335,7 +304,7 @@ class OlmoHybrid(nn.Layer):
         with jax.named_scope("ln"):
             x = self.norm_f(x)
         with jax.named_scope("logits"):
-            return Tensor(_head(x.data, self.lm_head.weight.data))
+            return Tensor(_blocks.head(x.data, self.lm_head.weight.data))
 
     def _full_attention(self, attn, q, k, v):
         """Causal attention over whole sequences; q, k, v Tensors
@@ -387,9 +356,8 @@ class OlmoHybrid(nn.Layer):
             raise ValueError(
                 f"init_cache: max_len {max_len} exceeds "
                 f"max_position_embeddings {cfg.max_position_embeddings}")
-        pages_per_seq = -(-max_len // page_size)
-        if not num_pages:
-            num_pages = 1 + max_batch * pages_per_seq  # +1: the null page
+        pages_per_seq, num_pages = _blocks.pages_for(
+            max_batch, max_len, page_size, num_pages)
         if dtype is None:
             dtype = self.wte.weight.dtype
         n_full = cfg.layer_types.count(FULL)
@@ -421,15 +389,8 @@ class OlmoHybrid(nn.Layer):
         OVERWRITTEN with the prompt's, computed from a zero state over
         the first `length` positions. Returns (last-position logits
         [1, V], updated cache)."""
-        from ..ops.pallas import paged_attention as _pa
-        B, L = input_ids.shape
-        if B != 1:
-            raise ValueError(f"forward_prefill fills ONE slot; got batch "
-                             f"{B} (serving prefills per request)")
-        slot = jnp.asarray(slot, jnp.int32)
-        length = jnp.asarray(length, jnp.int32)
-        write_start = jnp.asarray(write_start, jnp.int32)
-        page_row = jnp.take(cache.block_tables, slot, axis=0)
+        slot, length, write_start, page_row = _blocks.prefill_args(
+            input_ids, cache, slot, length, write_start)
         x = self._embed(input_ids)
         for li, blk in enumerate(self.blocks):
             i = cache.index_of(li)
@@ -440,16 +401,14 @@ class OlmoHybrid(nn.Layer):
             else:
                 with jax.named_scope("attention"):
                     q, k, v = blk.attn.qkv(x)
-                    cache.k_pages[i], cache.v_pages[i] = _pa.prefill_append(
-                        cache.k_pages[i], cache.v_pages[i], k.data[0],
-                        v.data[0], page_row, length, start=write_start)
+                    _blocks.paged_prefill_append(
+                        cache, i, k.data[0], v.data[0], page_row, length,
+                        write_start)
                     mixed = self._full_attention(blk.attn, q, k, v)
             x = blk.finish(x, mixed)
         cache.context_lens = cache.context_lens.at[slot].set(length)
         # logits of the LAST REAL position only
-        last = Tensor(jax.lax.dynamic_index_in_dim(
-            x.data, length - 1, axis=1, keepdims=False))
-        return self._logits(last), cache
+        return self._logits(_blocks.last_real_position(x, length)), cache
 
     def forward_decode(self, tokens, cache: PagedKVCache, active=None,
                        slot_map=None):
@@ -459,20 +418,9 @@ class OlmoHybrid(nn.Layer):
         rows of their states. In lane mode the rows are gathered with the
         clamped `slot_map` and scattered back with the sentinel of a
         padding lane DROPPED; an inactive lane writes back what it read."""
-        from ..ops.pallas import paged_attention as _pa
         cfg = self.cfg
-        lanes = slot_map is not None
-        if lanes:
-            slot_map = jnp.asarray(slot_map, jnp.int32)
-            bt = jnp.take(cache.block_tables, slot_map, axis=0, mode="clip")
-            ctx = jnp.take(cache.context_lens, slot_map, mode="clip")
-            if active is None:
-                active = slot_map < cache.max_batch
-        else:
-            bt, ctx = cache.block_tables, cache.context_lens
-            if active is None:
-                active = jnp.ones((cache.max_batch,), bool)
-        active = jnp.asarray(getattr(active, "data", active), bool)
+        slot_map, bt, ctx, active = _blocks.decode_view(cache, active,
+                                                        slot_map)
         x = self._embed(tokens)
         B = x.shape[0]
         x = reshape(x, [B, 1, cfg.hidden_size])
@@ -485,21 +433,12 @@ class OlmoHybrid(nn.Layer):
             else:
                 with jax.named_scope("attention"):
                     q, k, v = blk.attn.qkv(x)          # [B, 1, H*D]
-                    cache.k_pages[i], cache.v_pages[i] = _pa.cache_append(
-                        cache.k_pages[i], cache.v_pages[i], k.data[:, 0],
-                        v.data[:, 0], bt, ctx, active)
-                    out = _pa.paged_attention(
-                        q.data.reshape(B, cfg.num_attention_heads,
-                                       cfg.head_dim),
-                        cache.k_pages[i], cache.v_pages[i], bt,
-                        # the new token is part of its own context
-                        jnp.where(active, ctx + 1, 0))
+                    out = _blocks.paged_decode_attention(
+                        cache, i, q.data.reshape(
+                            B, cfg.num_attention_heads, cfg.head_dim),
+                        k.data[:, 0], v.data[:, 0], bt, ctx, active)
                     mixed = blk.attn.o_proj(
                         reshape(Tensor(out), [B, 1, cfg.hidden_size]))
             x = blk.finish(x, mixed)
-        if lanes:
-            cache.context_lens = cache.context_lens.at[slot_map].add(
-                jnp.where(active, 1, 0).astype(jnp.int32), mode="drop")
-        else:
-            cache.context_lens = jnp.where(active, ctx + 1, ctx)
+        _blocks.bump_lengths(cache, slot_map, ctx, active)
         return self._logits(reshape(x, [B, cfg.hidden_size])), cache

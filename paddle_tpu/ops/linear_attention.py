@@ -170,19 +170,22 @@ def gated_delta_rule_step(state, q, k, v, g, beta, active=None):
 
 
 @jax.jit
-def _conv_prefill_impl(x, weight, length):
+def _conv_prefill_impl(x, weight, length, bias=None):
     B, L, C = x.shape
     K = weight.shape[0]
     xp = jnp.pad(x, [(0, 0), (K - 1, 0), (0, 0)])
     y = sum(xp[:, j:j + L] * weight[j] for j in range(K))
+    if bias is not None:
+        y = y + bias
     # the inputs at length-K+1 .. length-1 (zeros before position 0)
     tail = jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(
         row, n, K - 1, axis=0))(xp, length)
     return jax.nn.silu(y), tail
 
 
-def causal_conv_prefill(x, weight, length=None):
-    """Causal depthwise convolution over time, then SiLU. x ``[B, L, C]``,
+def causal_conv_prefill(x, weight, length=None, bias=None):
+    """Causal depthwise convolution over time (plus ``bias [C]`` where the
+    layer has one), then SiLU. x ``[B, L, C]``,
     weight ``[K, C]`` (``weight[K-1]`` meets the current token). Returns
     ``(y [B, L, C], conv_state [B, K-1, C])``: the state is the last K-1
     inputs before position ``length`` (``[B]`` or a scalar; default L),
@@ -193,27 +196,29 @@ def causal_conv_prefill(x, weight, length=None):
         length = jnp.full((B,), L, jnp.int32)
     length = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
     with jax.named_scope("conv"):
-        return _conv_prefill_impl(x, weight, length)
+        return _conv_prefill_impl(x, weight, length, bias)
 
 
 @jax.jit
-def _conv_update_impl(conv_state, x, weight, active):
+def _conv_update_impl(conv_state, x, weight, active, bias=None):
     window = jnp.concatenate(
         [conv_state, x[:, None].astype(conv_state.dtype)], axis=1)
     y = jnp.sum(window * weight[None].astype(window.dtype), axis=1)
+    if bias is not None:
+        y = y + bias.astype(window.dtype)
     return (jax.nn.silu(y).astype(x.dtype),
             jnp.where(active[:, None, None], window[:, 1:], conv_state))
 
 
-def causal_conv_update(conv_state, x, weight, active=None):
+def causal_conv_update(conv_state, x, weight, active=None, bias=None):
     """One token of the same convolution. conv_state ``[B, K-1, C]``, x
-    ``[B, C]``; a row whose ``active`` ``[B]`` is False keeps its state.
+    ``[B, C]``, ``bias [C]`` or none; a row whose ``active`` ``[B]`` is False keeps its state.
     Returns ``(y [B, C], conv_state)``."""
     _stats["conv_update"] += 1
     if active is None:
         active = jnp.ones(conv_state.shape[:1], bool)
     with jax.named_scope("conv"):
-        return _conv_update_impl(conv_state, x, weight, active)
+        return _conv_update_impl(conv_state, x, weight, active, bias)
 
 
 # ------------------------- rows of a per-slot state ---------------------------

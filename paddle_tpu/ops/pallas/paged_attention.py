@@ -83,7 +83,10 @@ _LANE = _tiling.LANE
 # dispatch decisions, counted at trace time (reset freely in tests)
 # ("folded" counts the dispatches to the kernel that reads the folded
 # page block: all of "pallas" since PR 26, absent before it)
-_stats = {"pallas": 0, "folded": 0, "xla": 0, "append": 0, "cow": 0}
+# ("grouped": those of "pallas" that went to the kernel for grouped K/V
+# heads)
+_stats = {"pallas": 0, "folded": 0, "grouped": 0, "xla": 0, "append": 0,
+          "cow": 0}
 
 # tests set True: the kernel runs in the Pallas interpreter on CPU, so
 # the real gather/online-softmax logic is exercised without a TPU
@@ -109,12 +112,17 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
     """Dense gather reference: correct for every shape, and the CPU
     path. A sequence with ``context_lens==0`` (idle serving slot) outputs
     exactly zero. It unfolds the pool itself
-    (a copy on the TPU, which this path can afford)."""
+    (a copy on the TPU, which this path can afford). A pool that holds
+    fewer K/V heads than q has heads (grouped K/V heads) takes
+    `_paged_attention_grouped_xla`."""
     B, H, D = q.shape
     page_size = k_pages.shape[1]
     n_pages = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / np.sqrt(D)
+    if _kv_heads(k_pages, D) != H:
+        return _paged_attention_grouped_xla(q, k_pages, v_pages,
+                                            block_tables, context_lens, scale)
     k_pages = k_pages.reshape(*k_pages.shape[:2], H, D)
     v_pages = v_pages.reshape(*v_pages.shape[:2], H, D)
     # [B, n_pages, page_size, H, D] -> [B, L_max, H, D]
@@ -132,6 +140,49 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
     out = jnp.einsum("bhl,blhd->bhd", p / l, v.astype(jnp.float32))
     # fully-empty sequence: m == _NEG everywhere -> p all zero -> out 0
     return out.astype(q.dtype)
+
+
+def _kv_heads(pool, D: int) -> int:
+    """K/V heads a pool holds: folded [.., Hkv*D] or 4-D [.., Hkv, D]."""
+    return pool.shape[2] // D if pool.ndim == 3 else pool.shape[2]
+
+
+def _paged_attention_grouped_xla(q, k_pages, v_pages, block_tables,
+                                 context_lens, scale):
+    """Grouped K/V heads: q ``[B, H, D]`` over pools of ``Hkv`` heads, H a
+    multiple of Hkv; query head h reads K/V head ``h // (H // Hkv)``. The
+    pages are gathered FOLDED, as the pool stores them, and a K/V head is
+    a slice of their lanes: unfolding the pool first made the compiler
+    re-lay out every pool whole in every decode step (four copies of 100
+    MB a layer in the compiled step of Nemotron-3-Nano's 2 K/V heads of
+    128). The group is an axis of q and of the scores, K/V are never
+    repeated, and the products run at `highest`: a float32 pool is served
+    as float32."""
+    B, H, D = q.shape
+    k_pages, v_pages = _folded(k_pages), _folded(v_pages)
+    Hkv = k_pages.shape[2] // D
+    if H % Hkv:
+        raise ValueError(f"{H} query heads do not divide over {Hkv} K/V "
+                         f"heads")
+    G = H // Hkv
+    hi = jax.lax.Precision.HIGHEST
+    L = block_tables.shape[1] * k_pages.shape[1]
+    k = k_pages[block_tables].reshape(B, L, Hkv * D).astype(jnp.float32)
+    v = v_pages[block_tables].reshape(B, L, Hkv * D).astype(jnp.float32)
+    live = (jnp.arange(L, dtype=jnp.int32)[None, None, :]
+            < context_lens[:, None, None])
+    out = []
+    for h in range(Hkv):
+        lanes = slice(h * D, (h + 1) * D)
+        qh = q[:, h * G:(h + 1) * G].astype(jnp.float32)        # [B, G, D]
+        s = jnp.einsum("bgd,bld->bgl", qh, k[..., lanes],
+                       precision=hi) * scale
+        s = jnp.where(live, s, _NEG)
+        p = jnp.where(live, jnp.exp(s - jnp.max(s, -1, keepdims=True)), 0.0)
+        p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+        out.append(jnp.einsum("bgl,bld->bgd", p, v[..., lanes],
+                              precision=hi))
+    return jnp.concatenate(out, axis=1).astype(q.dtype)
 
 
 # ------------------------------ Pallas kernel --------------------------------
@@ -272,6 +323,145 @@ def _paged_attn_pallas(q, k_pages, v_pages, block_tables, context_lens,
     return out.reshape(B, H, D)
 
 
+# pages a grid step of the grouped kernel walks: one page of 2 K/V heads is
+# 16 KB, and a step that fetched one spent most of its time on its own
+# overhead and on products 16 tokens wide (1.73 ms a call at 64 lanes of
+# Nemotron-3-Nano's cell against 0.25 for the same bytes; PERF.md, PR 31)
+_GROUPED_PAGES = 16
+
+
+def _paged_attn_grouped_kernel(bt_ref, cl_ref, live_ref, q_ref, *refs,
+                               page_size, scale, D, G, P):
+    """Grouped K/V heads. Grid (B, live page groups); a step holds P
+    folded pages [page, Hkv * D] of K and of V (`refs`: P K blocks, P V
+    blocks, the output, three scratches) and ALL the query heads of the
+    sequence, q_ref [H, D] with H = Hkv * G: query heads h*G .. h*G+G-1
+    read K/V head h, the lanes [h*D, (h+1)*D) of a page. G query heads
+    against P pages of one K/V head are a [G, D] x [D, P * page] product,
+    so the scores and the weighted sum run on the MXU (at `highest`:
+    float32 pools are served as float32), and the softmax state is a row
+    a query head (`m_ref`, `l_ref` [H, 128], every lane the same)."""
+    from jax.experimental import pallas as pl
+
+    k_refs, v_refs = refs[:P], refs[P:2 * P]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * P:]
+    b = pl.program_id(0)
+    i = pl.program_id(1)
+    span = P * page_size
+
+    @pl.when(i == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    ctx = cl_ref[b]
+
+    @pl.when(i * span < ctx)
+    def _compute():
+        for h in range(k_refs[0].shape[-1] // D):
+            rows, lanes = slice(h * G, (h + 1) * G), slice(h * D, (h + 1) * D)
+            q = q_ref[rows, :].astype(jnp.float32)              # [G, D]
+            k = jnp.concatenate([r[:, lanes] for r in k_refs],
+                                axis=0).astype(jnp.float32)     # [span, D]
+            v = jnp.concatenate([r[:, lanes] for r in v_refs],
+                                axis=0).astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32) * scale     # [G, span]
+            pos = i * span + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            live = pos < ctx
+            s = jnp.where(live, s, _NEG)
+            m_prev = m_ref[rows, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = l_ref[rows, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[rows, :] = acc_ref[rows, :] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+            m_ref[rows, :] = jnp.broadcast_to(m_new, (G, m_ref.shape[1]))
+            l_ref[rows, :] = jnp.broadcast_to(l_new, (G, l_ref.shape[1]))
+
+    @pl.when(i == live_ref[0] - 1)
+    def _finalize():
+        # ctx == 0 (idle slot): acc and l still zero -> exactly zero
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
+                      ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _paged_attn_grouped_pallas(q, k_pages, v_pages, block_tables,
+                               context_lens, scale, interpret=False):
+    """q [B, H, D] over pools [num_pages, page_size, Hkv * D], H a
+    multiple of Hkv. The page axis of the grid stops at the LONGEST
+    context's last page group (a traced bound, as megablox's tile count
+    is): pages past it hold nothing for any sequence. A step's P pages
+    are P blocks of the same pool, each with its own row of the block
+    table (a slot past the table's end reads its last entry, and is
+    masked by its position)."""
+    from jax.experimental import pallas as pl
+
+    B, H, D = q.shape
+    k_pages, v_pages = _folded(k_pages), _folded(v_pages)
+    page_size, width = k_pages.shape[1:]
+    n_pages = block_tables.shape[1]
+    G = H // (width // D)
+    P = min(_GROUPED_PAGES, n_pages)
+    span = P * page_size
+    n_live = jnp.clip(-(-jnp.max(context_lens) // span), 1,
+                      -(-n_pages // P)).astype(jnp.int32)
+
+    def page(j):
+        return pl.BlockSpec(
+            (None, page_size, width),
+            lambda b, i, bt, cl, nl: (
+                bt[b, jnp.minimum(i * P + j, n_pages - 1)], 0, 0))
+
+    qspec = pl.BlockSpec((None, H, D), lambda b, i, bt, cl, nl: (b, 0, 0))
+    pages = [page(j) for j in range(P)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, n_live),
+        in_specs=[qspec] + pages + pages,
+        out_specs=qspec,
+        scratch_shapes=[pltpu.VMEM((H, D), jnp.float32),
+                        pltpu.VMEM((H, _LANE), jnp.float32),
+                        pltpu.VMEM((H, _LANE), jnp.float32)],
+    )
+    params = None if interpret else pltpu.CompilerParams(
+        dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY))
+    return pl.pallas_call(
+        functools.partial(_paged_attn_grouped_kernel, page_size=page_size,
+                          scale=scale, D=D, G=G, P=P),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        compiler_params=params,
+        interpret=interpret,
+    )(block_tables, context_lens, n_live[None], q,
+      *([k_pages] * P), *([v_pages] * P))
+
+
+def _check_compiles_grouped(dtype, H: int, Hkv: int, D: int, page_size: int,
+                            n_pages: int):
+    """Eager compile check of the grouped kernel (`tiling.compile_check`)."""
+    def run():
+        q = jnp.ones((2, H, D), dtype)
+        kp = jnp.ones((max(n_pages, 2), page_size, Hkv * D), dtype)
+        bt = jnp.zeros((2, n_pages), jnp.int32)
+        cl = jnp.full((2,), page_size, jnp.int32)
+        return _paged_attn_grouped_pallas(q, kp, kp, bt, cl,
+                                          float(1.0 / np.sqrt(D)),
+                                          interpret=_INTERPRET)
+
+    _tiling.compile_check(
+        "paged_attn_grouped", run, dtype=jnp.dtype(dtype).name, heads=H,
+        kv_heads=Hkv, head_dim=D, page_size=page_size, pages_per_seq=n_pages,
+        interpret=_INTERPRET)
+
+
 def _check_compiles(dtype, H: int, D: int, page_size: int, n_pages: int):
     """Eager compile check at the head block dispatch uses, all H
     (`tiling.compile_check`)."""
@@ -293,16 +483,19 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
                     scale=None):
     """Single-token decode attention over a paged KV pool.
 
-    q [B, H, D]; k_pages/v_pages [num_pages, page_size, H * D] (a 4-D
-    [.., H, D] pool is folded by a reshape); block_tables
+    q [B, H, D]; k_pages/v_pages [num_pages, page_size, Hkv * D] (a 4-D
+    [.., Hkv, D] pool is folded by a reshape), Hkv == H or, with grouped
+    K/V heads, a whole fraction of it (query head h reads K/V head
+    h // (H // Hkv): `_paged_attn_grouped_pallas`); block_tables
     [B, pages_per_seq] int32 (unused slots MUST index a valid page — the
     serving layer points them at the null page 0); context_lens [B]
     int32. Returns [B, H, D].
 
     Dispatch mirrors `flash_attention`: an eligible call takes the Pallas
     page walk, all heads to a program, after one eager compile check that
-    raises; anything else (off the TPU without interpret mode, fp16, a
-    head size off the lane groups) takes the XLA gather. Safe to call at
+    raises (grouped K/V heads: the grouped kernel, all query heads of a
+    sequence to a program); anything else (off the TPU without interpret
+    mode, fp16, a head size off the lane groups) takes the XLA gather. Safe to call at
     trace time of an outer jit (the check runs eagerly at trace, like
     every kernel in this package)."""
     B, H, D = q.shape
@@ -310,13 +503,24 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     n_pages = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / np.sqrt(D)
-    eligible = ((_on_tpu() or _INTERPRET)
-                and q.dtype == k_pages.dtype == v_pages.dtype
-                and q.dtype != jnp.dtype(jnp.float16)
-                and isinstance(H, int)
-                # a head is a whole number of lane tiles or a whole
-                # fraction of one (`_lane_groups`)
-                and (D % _LANE == 0 or _LANE % D == 0))
+    Hkv = _kv_heads(k_pages, D)
+    kernels = ((_on_tpu() or _INTERPRET)
+               and q.dtype == k_pages.dtype == v_pages.dtype
+               and q.dtype != jnp.dtype(jnp.float16)
+               and isinstance(H, int))
+    if kernels and Hkv != H and H % Hkv == 0 and (
+            _INTERPRET or (D % _LANE == 0 and (H // Hkv) % 8 == 0)):
+        # grouped K/V heads: a K/V head is a whole number of lane tiles
+        # of the folded page and its query heads whole sublane tiles of q
+        _check_compiles_grouped(q.dtype, H, Hkv, D, page_size, n_pages)
+        _stats["pallas"] += 1
+        _stats["grouped"] += 1
+        return _paged_attn_grouped_pallas(q, k_pages, v_pages, block_tables,
+                                          context_lens, float(scale),
+                                          interpret=_INTERPRET)
+    # a head is a whole number of lane tiles or a whole fraction of one
+    # (`_lane_groups`)
+    eligible = kernels and Hkv == H and (D % _LANE == 0 or _LANE % D == 0)
     if eligible:
         _check_compiles(q.dtype, H, D, page_size, n_pages)
         _stats["pallas"] += 1

@@ -408,8 +408,8 @@ def test_old_knobs_are_not_read(knob, value, monkeypatch, tmp_path):
             monkeypatch.delenv(name)
     plain = _picks_and_paged_stats(monkeypatch)
     assert plain[0] == [pick for _, _, pick in _CELL_PICKS]
-    assert plain[1] == {"pallas": 1, "folded": 1, "xla": 0, "append": 0,
-                        "cow": 0}
+    assert plain[1] == {"pallas": 1, "folded": 1, "grouped": 0, "xla": 0,
+                        "append": 0, "cow": 0}
 
     _write_parent_cache_entry(str(tmp_path), 4, 64, 8, 2)
     on_disk = {n: open(tmp_path / n, "rb").read()

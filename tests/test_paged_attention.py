@@ -498,3 +498,67 @@ class TestSuperLinear:
         assert ab["dense_growth"] > 1.4, ab
         assert ab["paged_growth"] < ab["dense_growth"] / 1.3, ab
         assert ab["speedup_at_max_ctx"] > 1.0, ab
+
+
+# -------------- what PR 31 added, compiled for the chip (this file holds ------
+# -------------- the one described topology of the test suite) -----------------
+
+
+class TestNemotronShapesCompileForTheChip:
+    """`nemotron3_nano_30b`'s pools hold 2 K/V heads of 128 for 32 query
+    heads, and its expert blocks 32 stacked experts of 2688 x 1856: the
+    decode layer must update the pools in place, and the grouped product
+    must read the stacked weights where they lie."""
+
+    H, HKV, D, P, B, N = 32, 2, 128, 6145, 64, 128
+
+    @staticmethod
+    def _decode_layer(q, k_new, v_new, kp, vp, bt, cl, active):
+        kp, vp = pa._append_impl(kp, vp, k_new, v_new, bt, cl, active)
+        out = pa.paged_attention_xla(q, kp, vp, bt,
+                                     jnp.where(active, cl + 1, 0))
+        return out, kp, vp
+
+    def test_grouped_pool_is_updated_in_place(self, v5e_chip):
+        from paddle_tpu.analysis import pool_relayout_report
+
+        def sds(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+        pool = sds((self.P, 16, self.HKV * self.D))
+        rows = sds((self.B, self.HKV * self.D))
+        decode = jax.jit(self._decode_layer, donate_argnums=(3, 4)).lower(
+            sds((self.B, self.H, self.D)), rows, rows, pool, pool,
+            sds((self.B, self.N), jnp.int32), sds((self.B,), jnp.int32),
+            sds((self.B,), jnp.bool_)).compile()
+        prefill = jax.jit(pa.prefill_append, donate_argnums=(0, 1)).lower(
+            pool, pool, sds((256, self.HKV * self.D)),
+            sds((256, self.HKV * self.D)), sds((self.N,), jnp.int32),
+            sds((), jnp.int32), sds((), jnp.int32)).compile()
+        for compiled in (decode, prefill):
+            rep = pool_relayout_report(compiled, [pool])
+            assert rep["pool_relayout_copies"] == 0, rep
+
+    @pytest.mark.parametrize("tokens", [64, 512], ids=["decode", "prefill"])
+    def test_the_grouped_product_reads_the_stacked_weights_in_place(
+            self, v5e_chip, tokens, monkeypatch):
+        """`ops/moe.held_experts` at the cell's widths through the
+        megablox kernel: it compiles at the tiles `_tiles` picks, and no
+        operation of the compiled program has the stacked weights' shape
+        but the two parameters (a re-laid out copy would)."""
+        from paddle_tpu.ops import moe
+        monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+
+        def sds(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+        stacked = sds((32, 1856, 2688))
+        compiled = jax.jit(
+            lambda u, e, w, w1, w2: moe.held_experts(u, e, w, w1, w2)).lower(
+            sds((tokens, 2688)), sds((tokens, 6), jnp.int32),
+            sds((tokens, 6)), stacked, stacked).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= 2
+        made = [line for line in text.splitlines()
+                if " = f32[32,1856,2688]" in line
+                and " parameter(" not in line]
+        assert not made, made[:2]
+        assert compiled.memory_analysis().temp_size_in_bytes < 200e6

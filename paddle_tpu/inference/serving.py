@@ -398,6 +398,9 @@ class Request:
         self.slot: Optional[int] = None
         self.pages: List[int] = []
         self.shared_tokens = 0         # prefix tokens served from shared pages
+        # decode tokens dispatched for this request and not yet read back
+        # (0 or 1: the engine keeps at most one iteration in flight)
+        self.unread = 0
         self._done = threading.Event()
 
     # -- latency accounting ---------------------------------------------------
@@ -525,6 +528,44 @@ class ServingEngine:
     transfers an iteration and 4 an admission (`stats["h2d_transfers"]`,
     `transfers` on the `pt.engine.upload` and `.prefill.dispatch` spans).
 
+    One decode iteration may be IN FLIGHT behind the host. A step builds
+    the lanes, dispatches iteration N, then reads and books iteration
+    N-1 if that is still unread, and then reads N too only if one of N's
+    tokens is a request's last by length (`_may_run_ahead`); else it
+    returns with N unread, and the next step dispatches N+1 first. The
+    device then never waits for the host's read and bookkeeping, and
+    nothing is lost where a slot frees by length: that request is done
+    when the step that dispatched its last token returns, so whoever
+    takes the slot finds the device idle, as with every iteration read at
+    once. What makes N+1 independent of the host's read: the last token
+    sampled for each slot stays on the device (`_last_tokens`, int32
+    [max_batch + 1], donated through the decode program like the cache),
+    and a lane's token travels as -1, "the row's", while the iteration
+    that samples it is unread (a prefill's, a hand-off's and a read
+    iteration's token travel as they did). Positions, sampling step
+    counters and page growth count tokens DISPATCHED (`len(generated) +
+    Request.unread`). The unread record holds the Request of each lane,
+    not its slot. Everything that takes, frees or moves a slot outside
+    that bookkeeping calls `_drain()` first: an admission with a free
+    slot, a hand-off, preemption (a dry pool reads before it picks a
+    victim), a weight swap, `restart`, `close`, `shrink_pool`, `audit`,
+    `device_counters`; `pending()` stays true while an iteration is
+    unread. `stats["iterations"]` counts dispatches, `decode_tokens`
+    tokens recorded; `ahead_iterations` dispatches made with the one
+    before unread, `drained_for_length` iterations read in their own step
+    for a last token, `discarded_tokens` the cost of the one completion
+    the host cannot know ahead:
+
+    * an END OF SEQUENCE sampled in N is seen when N is booked, with N+1
+      already dispatched for the same lane. The request completes then
+      (one step late), N+1's token for it is dropped at bookkeeping by the
+      request's identity, never booked to the slot's next tenant, and
+      N+1's writes are harmless: its K/V went to a page the request owned
+      exclusively at that dispatch (`_ensure_capacity` forks and grows
+      before every dispatch, by tokens dispatched) and its state to the
+      request's own slot, both ahead, in the device's order, of any
+      prefill, page copy or injection that reuses them.
+
     `share_prefix` (default True) admits requests whose prompt prefix
     is already resident (page-aligned prefix chains; exact-duplicate
     prompts additionally share the partial tail page) by FORKING the
@@ -616,7 +657,18 @@ class ServingEngine:
         self._queue: "deque[Request]" = deque()
         self._lock = threading.Lock()
         self._slots: List[Optional[Request]] = [None] * self.max_batch
+        # the token each slot's next lane feeds: one the host knows (a
+        # prefill's, a hand-off's, an iteration's that has been read), or
+        # -1 while the iteration that samples it is unread: the decode
+        # program then takes it from `_last_tokens`, its own row
         self._cur_tokens = np.zeros((self.max_batch,), np.int32)
+        # the one decode iteration dispatched and not read back yet:
+        # (tokens on the device, the Request of each lane, lane bucket,
+        # iteration number), or None. `_step_lock` keeps a drain asked
+        # for from another thread (a governor's `shrink_pool`, a status
+        # page's `device_counters`) out of a step
+        self._inflight: Optional[tuple] = None
+        self._step_lock = threading.RLock()
         self._closed = False
         self._audited = False
         self._thread: Optional[threading.Thread] = None
@@ -651,6 +703,8 @@ class ServingEngine:
                       "shared_admissions": 0, "swaps": 0, "restarts": 0,
                       "handoffs": 0, "worker_prefills": 0,
                       "table_refreshes": 0, "h2d_transfers": 0,
+                      "ahead_iterations": 0, "drained_for_length": 0,
+                      "discarded_tokens": 0,
                       "min_free_pages": self.allocator.free_pages}
         # what the cache holds, by kind (constants of the engine's life)
         desc = self.cache.describe()
@@ -673,7 +727,8 @@ class ServingEngine:
         # fused step compiles exactly one executable per decode-lane
         # bucket and prefill one per prompt bucket — both donate the
         # cache (the page pools update in place)
-        self._fused_jit = jax.jit(self._fused_step_fn, donate_argnums=(2,))
+        self._fused_jit = jax.jit(self._fused_step_fn,
+                                  donate_argnums=(2, 3))
         self._prefill_jit = jax.jit(self._prefill_fn, donate_argnums=(2,))
         # disagg handoff injection: ONE donated executable per pow2
         # page-count bucket scatters a prefill worker's page payload
@@ -696,12 +751,15 @@ class ServingEngine:
 
     def _reset_tables(self):
         """Host tables for a cache fresh from `init_cache`, whose own
-        tables are zeros too: nothing to send."""
+        tables are zeros too: nothing to send but the device's row of
+        each slot's last token, zeros as well."""
         self._block_tables = np.zeros(
             (self.cache.max_batch, self.cache.pages_per_seq), np.int32)
         self._context_lens = np.zeros((self.cache.max_batch,), np.int32)
         self._tables_dirty = False
         self._lens_dirty = False
+        self._last_tokens = self._put(
+            np.zeros((self.cache.max_batch + 1,), np.int32))
 
     def _put(self, host):
         """One host-to-device transfer of a NumPy array nobody writes to
@@ -755,19 +813,27 @@ class ServingEngine:
     # surfaces like any other jit site) and compile time is attributed
     # on the compile-watch plane.
 
-    def _fused_step_fn(self, params, buffers, cache, lanes_i, lanes_f):
+    def _fused_step_fn(self, params, buffers, cache, last_tokens, lanes_i,
+                       lanes_f):
         """`lanes_i` int32 [6, W] and `lanes_f` float32 [2, W] are what
-        `_lane_arrays` packed: one transfer each instead of eight."""
+        `_lane_arrays` packed: one transfer each instead of eight.
+        `last_tokens` int32 [max_batch + 1] is the last token sampled for
+        each slot, kept on the device (donated, like the cache): a lane
+        whose token the host sent as -1 feeds its slot's entry, so the
+        iteration after one the host has not read yet needs nothing from
+        the host. Entry `max_batch` takes the padding lanes' writes."""
         import jax.numpy as jnp
         from ..jit import _swapped_state
         tokens, slot_map, lane_active, top_k, seeds, steps = lanes_i
         lane_active = lane_active.astype(bool)
         temp, top_p = lanes_f
+        tokens = jnp.where(tokens >= 0, tokens, last_tokens[slot_map])
         with tape_mod.no_grad(), _swapped_state(self.model, params, buffers):
             logits, cache = self.model.forward_decode(
                 Tensor(tokens), cache, lane_active, slot_map=slot_map)
         nxt = sample_logits(logits.data, temp, top_k, top_p, seeds, steps)
-        return jnp.where(lane_active, nxt, 0), cache
+        nxt = jnp.where(lane_active, nxt, 0)
+        return nxt, cache, last_tokens.at[slot_map].set(nxt)
 
     def _prefill_fn(self, params, buffers, cache, ids, scalars, floats):
         """`scalars` int32 [6] is slot, length, write start, top-k, seed,
@@ -803,11 +869,13 @@ class ServingEngine:
         # a K and a V pool, and a recurrent and a convolution state
         pools = (self.cache.k_pages[:1] + self.cache.v_pages[:1]
                  + self.cache.states[:1] + self.cache.conv_states[:1])
-        lane_args = self._lane_arrays([])[1:]      # every lane padding
+        self._drain()
+        # the token row, and lane arrays in which every lane is padding
+        lane_args = (self._last_tokens,) + self._lane_arrays([])[1:]
         decode = analysis.audit_program(
             self._fused_step_fn,
             (self._params, self._buffers, self.cache) + lane_args,
-            donate_argnums=(2,), relayout_of=pools,
+            donate_argnums=(2, 3), relayout_of=pools,
             name=f"serving_decode:{self.name}", entry="serving_decode",
             emit=emit)
         bucket = self.prefill_buckets[0]
@@ -830,7 +898,7 @@ class ServingEngine:
             reports.append(analysis.audit_collectives_by_link(
                 self._fused_step_fn,
                 (self._params, self._buffers, self.cache) + lane_args,
-                donate_argnums=(2,),
+                donate_argnums=(2, 3),
                 name=f"serving_decode:{self.name}", emit=emit))
         return reports
 
@@ -932,7 +1000,7 @@ class ServingEngine:
         with self._lock:
             busy = bool(self._queue) or any(
                 r is not None for r in self._slots)
-        if busy:
+        if busy or self._inflight is not None:
             return True
         src = self.handoff_source
         return src is not None and src._handoff_peek() is not None
@@ -942,9 +1010,13 @@ class ServingEngine:
         free slots (bucketed prefill each, shared-prefix pages forked),
         grow pages for sequences crossing a page boundary and fork any
         shared page about to be written (copy-on-write), preempting the
-        youngest on pool exhaustion, then one fused decode dispatch.
-        Returns the number of tokens generated (0 = engine idle)."""
-        with _span("step", iteration=self.stats["iterations"]):
+        youngest on pool exhaustion, then one fused decode dispatch, whose
+        tokens the step reads before it returns only if one of them may
+        end a request (the class docstring has the rule). Returns the
+        number of tokens the dispatched iteration samples (0 = engine
+        idle)."""
+        with self._step_lock, \
+                _span("step", iteration=self.stats["iterations"]):
             return self._iterate()
 
     def _iterate(self) -> int:
@@ -968,26 +1040,28 @@ class ServingEngine:
                         if r is not None]
         if _metrics.enabled():
             _M_OCC.set(len(active_slots), model=self.name)
+        if active_slots:
+            with _span("capacity", active=len(active_slots)):
+                self._ensure_capacity(active_slots)
+            active_slots = [i for i, r in enumerate(self._slots)
+                            if r is not None]  # capacity may have preempted
         if not active_slots:
-            return 0
-        with _span("capacity", active=len(active_slots)):
-            self._ensure_capacity(active_slots)
-        active_slots = [i for i, r in enumerate(self._slots)
-                        if r is not None]  # capacity may have preempted
-        if not active_slots:
+            # an unread iteration can only hold requests that have ended
+            self._drain()
             return 0
         produced = self._decode_iteration(active_slots)
         self._last_progress = time.monotonic()
         return produced
 
-    def _note_introspection(self, active: int):
-        """One bounded-ring snapshot per decode iteration: the live view
-        /requests serves alongside the per-request phase breakdown."""
+    def _note_introspection(self, active: int, iteration: int):
+        """One bounded-ring snapshot per decode iteration, taken when its
+        tokens are read: the live view /requests serves alongside the
+        per-request phase breakdown."""
         with self._lock:
             depth = len(self._queue)
         used = self.cache.num_pages - 1 - self.allocator.free_pages
         self._introspect.append({
-            "iteration": self.stats["iterations"],
+            "iteration": iteration,
             "ts": time.time(),
             "active": active,
             "lanes": self._decode_bucket(active),
@@ -1050,6 +1124,10 @@ class ServingEngine:
         if self._thread is not None:
             self._thread.join(timeout=30)
             self._thread = None
+        try:
+            self._drain()
+        except Exception:  # noqa: BLE001 — a dead device's tokens cannot
+            self._inflight = None  # be read: their requests fail below
         self._fail_outstanding("engine closed")
 
     def _fail_outstanding(self, error: str):
@@ -1057,6 +1135,7 @@ class ServingEngine:
             leftovers = list(self._queue) + [r for r in self._slots
                                              if r is not None]
             self._queue.clear()
+        self._inflight = None  # its requests fail with the rest
         for req in leftovers:
             self._complete(req, "failed", error=error)
 
@@ -1075,6 +1154,7 @@ class ServingEngine:
         cache and cost the loop nothing; THIS is a device fetch that
         waits for the step in flight, so call it at the ends of a window
         (a benchmark, a status page), never once an iteration."""
+        self._drain()
         with self._dispatch_lock:
             return {k: np.asarray(v) for k, v in self.cache.counters.items()}
 
@@ -1136,6 +1216,7 @@ class ServingEngine:
             pend, self._pending_swap = self._pending_swap, None
         if pend is None:
             return None
+        self._drain()
         from_step = self.weights_step
         t0 = time.perf_counter()
         with self._dispatch_lock:
@@ -1239,6 +1320,7 @@ class ServingEngine:
                     raise RuntimeError(
                         f"decode loop did not stop within {join_timeout}s")
                 self._thread = None
+            self._drain()
             requeued = 0
             for req in [r for r in self._slots if r is not None]:
                 self._preempt(req)
@@ -1300,6 +1382,7 @@ class ServingEngine:
         """Park up to `frac` of the pool's pages (taken from the free
         list) out of circulation — the first memory-pressure degradation
         rung. Returns pages actually parked (live pages never move)."""
+        self._drain()   # a request that ended in it gives its pages back
         target = max(1, int((self.cache.num_pages - 1) * frac))
         return self.allocator.reserve(target)
 
@@ -1321,6 +1404,8 @@ class ServingEngine:
         req = handoff.request
         if req.state != "queued":
             return True  # single-token request finished at prefill
+        if None in self._slots:
+            self._drain()   # a slot is taken only with every token read
         # KV covers everything BEFORE the worker's sampled token
         ctx = len(req.prompt) + len(req.generated) - 1
         n_pages = -(-ctx // self.page_size)
@@ -1412,6 +1497,13 @@ class ServingEngine:
         resident (prefix cache hit) FORKS the matching pages instead of
         allocating + recomputing them; prefill then skips the K/V
         scatter below the shared length."""
+        if self._queue and None in self._slots:
+            # somebody can be admitted: read what is in flight first, so
+            # that the prefill's own read waits for nothing else. Only an
+            # end of sequence or an arrival from outside meets an unread
+            # iteration with a free slot: a request's last token by length
+            # is read in the step that dispatched it
+            self._drain()
         while True:
             with self._lock:
                 if not self._queue:
@@ -1525,6 +1617,13 @@ class ServingEngine:
             if got is not None:
                 self._note_pool_watermark()
                 return got[0]
+            if self._inflight is not None:
+                # an end of sequence in the unread iteration frees pages,
+                # and may be `req`'s own
+                self._drain()
+                if req.state != "running":
+                    return None
+                continue
             victim = self._youngest_running()
             running = sum(r is not None for r in self._slots)
             if victim is None or (victim is req and running == 1):
@@ -1552,7 +1651,9 @@ class ServingEngine:
             req = self._slots[slot]
             if req is None:
                 continue
-            ctx = len(req.prompt) + len(req.generated)
+            # by tokens DISPATCHED: an unread iteration has written its
+            # position already
+            ctx = len(req.prompt) + len(req.generated) + req.unread
             need = ctx // self.page_size + 1
             dead = False
             while len(req.pages) < need:
@@ -1593,7 +1694,8 @@ class ServingEngine:
     def _lane_arrays(self, active_slots: List[int]):
         """Gather the active slots into W bucketed lanes (W = smallest
         decode bucket covering the active count), packed as the decode
-        program takes them: int32 [6, W] (tokens, slot map, lane-active,
+        program takes them: int32 [6, W] (tokens, or -1 for "the one the
+        unread iteration sampled for this slot"; slot map, lane-active,
         top-k, seeds, steps) and float32 [2, W] (temperature, top-p).
         Padding lanes carry the slot sentinel `max_batch` (clamp-gather +
         drop-scatter in forward_decode) and greedy sampling params (so an
@@ -1616,7 +1718,7 @@ class ServingEngine:
             top_k[i] = sp.top_k
             top_p[i] = sp.top_p
             seeds[i] = req.seed
-            steps[i] = len(req.generated)
+            steps[i] = len(req.generated) + req.unread
         return W, lanes_i, lanes_f
 
     def _decode_iteration(self, active_slots: List[int]) -> int:
@@ -1641,45 +1743,90 @@ class ServingEngine:
         # with the call itself (cheaper than a `device_put` each)
         with _span("upload", transfers=self._count_transfers(2)):
             self._refresh_tables()
-            args = (self._params, self._buffers, self.cache, lanes_i,
-                    lanes_f)
+            args = (self._params, self._buffers, self.cache,
+                    self._last_tokens, lanes_i, lanes_f)
+        in_flight = self._inflight
         try:
             # see _prefill: canary serialization
-            with _span("dispatch"), self._dispatch_lock:
+            with _span("dispatch", ahead=int(in_flight is not None)), \
+                    self._dispatch_lock:
                 if self.decode_mode == "fused":
-                    nxt, self.cache = self._fused_jit(*args)
+                    nxt, self.cache, self._last_tokens = \
+                        self._fused_jit(*args)
                 else:
                     # eager A/B baseline: identical math, per-op dispatch
-                    nxt, self.cache = self._fused_step_fn(*args)
+                    nxt, self.cache, self._last_tokens = \
+                        self._fused_step_fn(*args)
         finally:
             _cw.pop_entry(prev)
         # the program bumped each active lane's length: so does the host
-        self._context_lens[active_slots[:W]] += 1
-        with _span("fetch"):
+        self._context_lens[active_slots] += 1
+        reqs = [self._slots[slot] for slot in active_slots]
+        for req in reqs:
+            req.unread += 1
+        # until this iteration is read its tokens are the device's alone
+        self._cur_tokens[active_slots] = -1
+        self._inflight = (nxt, reqs, W, self.stats["iterations"])
+        self.stats["iterations"] += 1
+        self.stats["ahead_iterations"] += in_flight is not None
+        self.stats["decode_wall_s"] += time.perf_counter() - t0
+        if in_flight is not None:
+            self._read(in_flight)
+        if not self._may_run_ahead(reqs):
+            self.stats["drained_for_length"] += 1
+            self._drain()
+        return len(reqs)
+
+    @staticmethod
+    def _may_run_ahead(reqs: List[Request]) -> bool:
+        """Whether the iteration just dispatched for `reqs` may stay
+        unread while the next one is dispatched: only if none of its
+        tokens is a request's last by length. That token frees a slot,
+        and whoever takes the slot should find the device idle, not one
+        iteration ahead."""
+        return all(len(r.generated) + r.unread < r.max_new_tokens
+                   for r in reqs if r.state == "running")
+
+    def _drain(self):
+        """Read and book the unread iteration, if there is one. Whatever
+        takes, frees or moves a slot outside `_read` calls this first."""
+        with self._step_lock:
+            in_flight, self._inflight = self._inflight, None
+            if in_flight is not None:
+                self._read(in_flight)
+
+    def _read(self, in_flight: tuple):
+        """Fetch one dispatched iteration's tokens and book each to the
+        REQUEST its lane was dispatched for (the slot may be somebody
+        else's by now). A request that has ended since, by an end of
+        sequence in the iteration before, drops its token."""
+        nxt, reqs, W, iteration = in_flight
+        t0 = time.perf_counter()
+        with _span("fetch", iteration=iteration):
             nxt_np = np.asarray(nxt)  # device sync: the iteration boundary
         self.stats["decode_wall_s"] += time.perf_counter() - t0
-        self.stats["iterations"] += 1
+        newest = self._inflight is None
         with _span("bookkeep", lanes=W):
             produced = 0
-            for i, slot in enumerate(active_slots[:W]):
-                req = self._slots[slot]
-                if req is None:
+            for i, req in enumerate(reqs):
+                req.unread -= 1
+                if req.state != "running":
                     continue
                 tok = int(nxt_np[i])
                 self.tracer.decode_iteration(req.rid, bucket=W,
                                              path=self.decode_mode)
                 self._record_token(req, tok)
                 produced += 1
-                if req.state == "running":
-                    self._cur_tokens[slot] = tok
+                if newest and req.state == "running":
+                    self._cur_tokens[req.slot] = tok
             self.stats["decode_tokens"] += produced
+            self.stats["discarded_tokens"] += len(reqs) - produced
             if _metrics.enabled():
                 # re-publish occupancy AFTER completions so a drained
                 # batch reads 0 even when no further step() runs
                 _M_OCC.set(sum(r is not None for r in self._slots),
                            model=self.name)
-            self._note_introspection(len(active_slots))
-        return produced
+            self._note_introspection(len(reqs), iteration + 1)
 
     def _record_token(self, req: Request, tok: int):
         req.generated.append(tok)
@@ -1716,6 +1863,9 @@ class ServingEngine:
         DECREF — a page another request still references never returns
         to the pool), request requeued with its generated prefix as part
         of the next admission's prompt."""
+        self._drain()   # its unread token is part of that prefix
+        if req.state != "running":
+            return      # it ended in the iteration just read
         self._release_slot(req)
         self.tracer.preempted(req.rid)
         req.state = "queued"

@@ -70,13 +70,14 @@ def prompts(lengths, seed):
 def rebuilt(eng):
     """The tables as the running requests imply them: a slot's row is its
     request's pages then zeros, its length what the cache holds of it
-    (all but the token the next iteration feeds); an idle slot reads 0."""
+    (all but the token the next iteration feeds, counting the token of an
+    iteration dispatched and not read yet, PR 32); an idle slot reads 0."""
     bt = np.zeros((eng.max_batch, eng.cache.pages_per_seq), np.int32)
     cl = np.zeros((eng.max_batch,), np.int32)
     for slot, req in enumerate(eng._slots):
         if req is not None:
             bt[slot, :len(req.pages)] = req.pages
-            cl[slot] = len(req.prompt) + len(req.generated) - 1
+            cl[slot] = len(req.prompt) + len(req.generated) + req.unread - 1
     return bt, cl
 
 
@@ -308,6 +309,32 @@ def test_release_and_capacity_launch_nothing_and_send_nothing():
     assert (eng.cache.block_tables, eng.cache.context_lens,
             eng.stats["h2d_transfers"]) == before
     assert not eng._block_tables.any() and not eng._context_lens.any()
+    eng.close()
+
+
+def test_the_token_row_is_donated_and_costs_no_transfer():
+    """PR 32: each slot's last token stays on the device in a row the
+    decode program takes and returns; the program updates it in place
+    (no `donation-rejected`, no copy of it, in `audit()`), the buffer
+    handed in is gone after the call, and an iteration still hands over
+    its two lane arrays and no more."""
+    eng = ServingEngine(model("gpt"), max_batch=2, max_len=48,
+                        page_size=PAGE, name="ht_row")
+    decode, prefill = eng.audit(emit=False)
+    for rep in (decode, prefill):
+        assert not [f for f in rep.findings if f.check == "donation"], \
+            rep.render()
+    assert eng._last_tokens.shape == (3,)         # a spare for padding
+    reqs = [eng.submit(p, max_new_tokens=6) for p in prompts([5, 9], 10)]
+    eng.step()
+    row, sent = eng._last_tokens, eng.stats["h2d_transfers"]
+    eng.step()
+    assert row.is_deleted() and not eng._last_tokens.is_deleted()
+    assert eng.stats["h2d_transfers"] - sent == 2
+    eng.run_until_idle()
+    # what the row holds for a slot is the last token sampled there
+    np.testing.assert_array_equal(
+        np.asarray(eng._last_tokens)[:2], [r.generated[-1] for r in reqs])
     eng.close()
 
 

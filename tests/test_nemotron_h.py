@@ -506,7 +506,8 @@ class TestScopes:
                   (("ssm", ssm), ("moe", moe))}
         lanes = eng._lane_arrays([])[1:]
         decode = jax.jit(eng._fused_step_fn).lower(
-            eng._params, eng._buffers, eng.cache, *lanes).as_text(
+            eng._params, eng._buffers, eng.cache, eng._last_tokens,
+            *lanes).as_text(
                 debug_info=True)
         prefill = jax.jit(eng._prefill_fn).lower(
             eng._params, eng._buffers, eng.cache,

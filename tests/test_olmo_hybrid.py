@@ -448,7 +448,8 @@ class TestScopes:
         lanes_f = np.zeros((2, W), np.float32)
         lanes_f[1] = 1.0                                # top-p
         decode = jax.jit(eng._fused_step_fn).lower(
-            eng._params, eng._buffers, eng.cache, lanes_i, lanes_f)
+            eng._params, eng._buffers, eng.cache, eng._last_tokens, lanes_i,
+            lanes_f)
         prefill = jax.jit(eng._prefill_fn).lower(
             eng._params, eng._buffers, eng.cache,
             np.zeros((1, 16), np.int32),

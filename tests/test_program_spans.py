@@ -37,8 +37,8 @@ ENGINE_SPANS = {
     "pt.engine.capacity": ("pt.engine.step", {"active"}),
     "pt.engine.lanes": ("pt.engine.step", {"lanes", "active"}),
     "pt.engine.upload": ("pt.engine.step", {"transfers"}),
-    "pt.engine.dispatch": ("pt.engine.step", set()),
-    "pt.engine.fetch": ("pt.engine.step", set()),
+    "pt.engine.dispatch": ("pt.engine.step", {"ahead"}),
+    "pt.engine.fetch": ("pt.engine.step", {"iteration"}),
     "pt.engine.bookkeep": ("pt.engine.step", {"lanes"}),
 }
 TRAIN_SPANS = {
@@ -127,7 +127,8 @@ def serve(directory=None, state_layers=False):
                 for m in exe.hlo_modules()}
     delta = {k: eng.stats[k] - before[k]
              for k in ("iterations", "prefills", "completed",
-                       "h2d_transfers", "table_refreshes")}
+                       "h2d_transfers", "table_refreshes",
+                       "ahead_iterations")}
     eng.close()
     return {"reqs": reqs, "events": events, "delta": delta,
             "programs": programs}
@@ -196,6 +197,21 @@ def test_engine_span_counts_follow_the_engines_counters(served):
     # `iteration` counts decode iterations done before the step
     its = [s["args"]["iteration"] for s in steps]
     assert its == sorted(its) and its[-1] - its[0] == delta["iterations"] - 1
+    # PR 32: `ahead` says the iteration before was unread at the dispatch,
+    # and every iteration is fetched once, under its own number, in the
+    # step that dispatched it or in the next
+    ahead = [d["args"]["ahead"] for d in named(ev, "pt.engine.dispatch")]
+    assert set(ahead) == {0, 1}
+    assert sum(ahead) == delta["ahead_iterations"]
+    read = [f["args"]["iteration"] for f in named(ev, "pt.engine.fetch")]
+    assert read == list(range(read[0], read[0] + delta["iterations"]))
+    late = 0
+    for f in named(ev, "pt.engine.fetch"):
+        step, = [s for s in steps if s["start"] <= f["start"]
+                 and f["end"] <= s["end"]]
+        assert f["args"]["iteration"] - step["args"]["iteration"] in (-1, 0)
+        late += step["args"]["iteration"] - f["args"]["iteration"]
+    assert late == delta["ahead_iterations"]
     # nothing else is named with the program's prefix
     assert {e["name"] for e in ev if e["name"].startswith(SPAN_PREFIX)} \
         == set(ENGINE_SPANS)

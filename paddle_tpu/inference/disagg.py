@@ -304,6 +304,14 @@ class DisaggPipeline:
                 "a hand-off of the slot's recurrent and convolution state "
                 "beside its K/V pages (KVHandoff carries pages only)",
                 kv_layers=d["kv_layers"], state_layers=d["state_layers"])
+        if engine.cache.has_window:
+            from ..models.decode_cache import WindowLayersUnsupported
+            d = engine.cache.describe()
+            raise WindowLayersUnsupported(
+                "disaggregated prefill/decode (DisaggPipeline)",
+                "a hand-off of the slot's window rings beside its K/V "
+                "pages (KVHandoff carries the pages a block table names)",
+                kv_layers=d["kv_layers"], window_layers=d["window_layers"])
         self.engine = engine
         #: per-request dispatch bound: a request whose prefill keeps
         #: losing its worker is failed LOUDLY through result() after

@@ -466,12 +466,20 @@ class ServingEngine:
       heads, which grouped heads make narrower than the query's), for
       each layer that carries a recurrence a fixed-size state per batch
       slot, nothing for a layer that carries nothing from token to token
-      (an expert block that is a layer of its own), and `counters`, small
+      (an expert block that is a layer of its own), for each layer that
+      attends over a sliding window a RING of `window` tokens a batch
+      slot (kind `kv_window`: K/V folded like the pools, never more
+      whatever the context, at a table that is a function of the slot
+      and is computed inside the programs: the engine stores none, sends
+      none, allocates none of its pages), and `counters`, small
       device arrays the decode step adds to, which ride in the donated
       cache and are read by `device_counters()` alone. The engine
       allocates, shares, copies and injects pages of the pools that
-      exist, counts both kinds in `pool_bytes()` / `status()`, and never
-      assumes one K/V pair per model layer. Its `block_tables` and
+      exist, counts every kind in `pool_bytes()` / `status()`, and never
+      assumes one K/V pair per model layer, nor that every K/V layer
+      shares the block table: admission, growth, copy-on-write and a
+      prefix hit count and touch the PAGED layers' pages only; a ring
+      is a fixed cost of `max_batch`, like a state. Its `block_tables` and
       `context_lens` are the ENGINE's: the source of truth is a pair of
       NumPy arrays on the host (`_block_tables`, `_context_lens`), every
       admission, page growth, copy-on-write repoint, release and
@@ -486,8 +494,10 @@ class ServingEngine:
     * ``forward_prefill(ids [1, bucket], cache, slot, length,
       write_start=)`` computes the prompt whole, writes its K/V into the
       slot's pages from `write_start` on (a shared prefix's pages are
-      already there) and OVERWRITES the slot's recurrent state; positions
-      at or past `length` are bucket padding and must not reach a state;
+      already there) and OVERWRITES the slot's recurrent state and its
+      window rings (whatever `write_start` is: the prompt is computed
+      whole); positions at or past `length` are bucket padding and must
+      not reach a state or a ring;
       returns (last real position's logits [1, V], cache);
     * ``forward_decode(tokens [W], cache, active [W], slot_map=[W])``
       is one token for each lane: lane i works on slot `slot_map[i]`; a
@@ -501,7 +511,8 @@ class ServingEngine:
       without them is refused `mesh=` (tensor-parallel decode) with a
       ValueError naming the protocol, and a model with recurrent-state
       layers refuses it, as `inference/disagg.py` does, with
-      `StateLayersUnsupported` naming the protocol that is missing.
+      `StateLayersUnsupported` naming the protocol that is missing (a
+      model with sliding-window layers: `WindowLayersUnsupported`).
 
     Drive it either synchronously (`submit` then `run_until_idle`,
     tests/bench) or with the background thread (`start()`; `close()`
@@ -622,10 +633,12 @@ class ServingEngine:
                                       num_pages=num_pages)
         self._budget_capped: Optional[Tuple[int, int]] = None
         if self.mem_budget_bytes > 0:
-            # the budget buys pages after the states' fixed cost
+            # the budget buys pages after the states' and the window
+            # rings' fixed cost
             per_page = max(1, self.cache.describe()["page_bytes"])
             fit = int(max(0, self.mem_budget_bytes
-                          - self.cache.state_bytes()) // per_page)
+                          - self.cache.state_bytes()
+                          - self.cache.window_bytes()) // per_page)
             if fit < self.cache.num_pages:
                 capped = max(2, fit)
                 self._budget_capped = (self.cache.num_pages, capped)
@@ -709,7 +722,8 @@ class ServingEngine:
         # what the cache holds, by kind (constants of the engine's life)
         desc = self.cache.describe()
         self.stats.update({k: desc[k] for k in (
-            "kv_layers", "state_layers", "state_bytes_per_slot")})
+            "kv_layers", "window_layers", "state_layers",
+            "state_bytes_per_slot")})
         # request-scoped observability plane: lifecycle tracer, sliding-
         # window SLO tracker, and a bounded ring of per-iteration
         # introspection snapshots (the /requests endpoint payload tail)
@@ -866,8 +880,10 @@ class ServingEngine:
         collective-bytes report when TP decode is on)."""
         from .. import analysis
         # one buffer of each shape the programs should update in place:
-        # a K and a V pool, and a recurrent and a convolution state
+        # a K and a V pool, a K ring of a window layer (V's has the
+        # same shape), and a recurrent and a convolution state
         pools = (self.cache.k_pages[:1] + self.cache.v_pages[:1]
+                 + self.cache.window_k[:1]
                  + self.cache.states[:1] + self.cache.conv_states[:1])
         self._drain()
         # the token row, and lane arrays in which every lane is padding
@@ -1142,9 +1158,10 @@ class ServingEngine:
     # -- self-healing plane: hot-swap / restart / degradation -----------------
     def pool_bytes(self) -> int:
         """Device bytes the decode cache holds: the K/V page pools (every
-        paged layer, K + V) and the per-slot recurrent states. Pages are
-        what `mem_budget_bytes` and `shrink_pool` can give back; the
-        states are a fixed cost of `max_batch`."""
+        paged layer, K + V), the window layers' rings and the per-slot
+        recurrent states. Pages are what `mem_budget_bytes` and
+        `shrink_pool` can give back; the rings and the states are a fixed
+        cost of `max_batch`."""
         return self.cache.pool_bytes() + self.cache.state_bytes()
 
     def device_counters(self) -> Dict:
@@ -1945,7 +1962,9 @@ class ServingEngine:
                       "parked": parked,
                       "used": d["num_pages"] - 1 - free - parked,
                       "bytes_per_page": d["page_bytes"],
-                      "bytes": d["pool_bytes"]},
+                      "bytes": d["pool_bytes"] - d["window_bytes"]},
+            "window": {"layers": d["window_layers"], "tokens": d["window"],
+                       "slots": d["slots"], "bytes": d["window_bytes"]},
             "state": {"layers": d["state_layers"], "shape": d["state_shape"],
                       "conv_shape": d["conv_state_shape"],
                       "bytes_per_slot": d["state_bytes_per_slot"],

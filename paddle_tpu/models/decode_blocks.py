@@ -3,10 +3,11 @@ file writes out only its own layers: matrix products at three bfloat16
 passes, the head under a scope, and the parts of `forward_prefill` /
 `forward_decode` that are the same whatever the layers are (the slot's
 arguments, the lanes' view of the tables, a full-attention layer's append
-and paged attention, the length bump, the last real position).
-`models/olmo_hybrid.py` and `models/nemotron_h.py` call these; the rest of
-ROADMAP D1 (one model runner instead of a copy a model) is a `simplicity`
-issue's.
+and paged attention, a sliding-window layer's ring, a prompt's grouped
+attention through the flash kernel, the length bump, the last real
+position). `models/olmo_hybrid.py`, `models/nemotron_h.py` and
+`models/mellum.py` call these; the rest of ROADMAP D1 (one model runner
+instead of a copy a model) is a `simplicity` issue's.
 """
 from __future__ import annotations
 
@@ -16,6 +17,7 @@ import jax.numpy as jnp
 from .. import nn
 from ..framework.tensor import Tensor
 from ..ops import _dispatch as _d
+from .gpt import GPT
 
 # Matrix products of float32 weights run in three bfloat16 passes
 # (`Precision.HIGH`), not the TPU's default one: every branch of these
@@ -71,6 +73,31 @@ class ExactLinear(nn.Linear):
 
     def forward(self, x):
         return _d.call(_matmul_exact, (x, self.weight))
+
+
+class TokensToLogits:
+    """What a hybrid model's class does around its blocks, for a class
+    that holds `wte` (the embedding), `norm_f` (the final RMSNorm) and
+    `lm_head` (the untied head, a `HighLinear`): mixed in before
+    `nn.Layer`."""
+
+    def num_params(self):
+        return sum(p.size for p in self.parameters())
+
+    def _embed(self, ids):
+        with jax.named_scope("embed"):
+            return self.wte(ids)
+
+    def _logits(self, x):
+        with jax.named_scope("ln"):
+            x = self.norm_f(x)
+        with jax.named_scope("logits"):
+            return Tensor(head(x.data, self.lm_head.weight.data))
+
+    def tp_mesh(self):
+        return None
+
+    generate_dense = GPT.generate_dense
 
 
 def pages_for(max_batch: int, max_len: int, page_size: int, num_pages: int):
@@ -145,3 +172,57 @@ def paged_prefill_append(cache, i, k, v, page_row, length, write_start):
     cache.k_pages[i], cache.v_pages[i] = _pa.prefill_append(
         cache.k_pages[i], cache.v_pages[i], k, v, page_row, length,
         start=write_start)
+
+
+# ---- a sliding-window layer's ring (`decode_cache.KV_WINDOW`): the same
+# scatter and the same paged-attention kernel at a table that is a
+# function of the slot, so none is stored or sent
+
+
+def ring_tables(cache, slots):
+    """The ring pages of `slots` ``[B]`` (clamped: a padding lane carries
+    the sentinel `max_batch`, and its writes are dropped by `active`):
+    ``[B, window / page_size]``, slot b owning ``1 + b * n .. + n - 1``."""
+    n = cache.window_pages
+    slots = jnp.minimum(jnp.asarray(slots, jnp.int32), cache.max_batch - 1)
+    return 1 + slots[:, None] * n + jnp.arange(n, dtype=jnp.int32)[None]
+
+
+def ring_decode_attention(cache, i, q, k, v, slots, ctx, active):
+    """One token of a sliding-window layer: write its K/V ``[B, Hkv*D]``
+    at row ``context mod window`` of each lane's ring, then attend with q
+    ``[B, H, D]`` over the ``min(context + 1, window)`` rows the ring
+    holds. Returns ``[B, H, D]``."""
+    from ..ops.pallas import paged_attention as _pa
+    W = cache.window
+    table = ring_tables(cache, slots)
+    cache.window_k[i], cache.window_v[i] = _pa.cache_append(
+        cache.window_k[i], cache.window_v[i], k, v, table, ctx % W, active)
+    return _pa.paged_attention(
+        q, cache.window_k[i], cache.window_v[i], table,
+        jnp.where(active, jnp.minimum(ctx + 1, W), 0))
+
+
+def ring_prefill_write(cache, i, k, v, slot, length):
+    """A prompt's K/V ``[L, Hkv*D]`` into its slot's ring, which is
+    REWRITTEN whole: row r takes the LAST position t < `length` with
+    ``t mod window == r`` (the last `window` tokens of the prompt, each at
+    its own row), and zeros where the prompt is shorter than the ring.
+    A position at or past `length` (bucket padding) is never the last
+    below `length`, so it cannot reach a row, though its row ``t mod
+    window`` holds a live token once the bucket is longer than the
+    window. The slot's pages are contiguous: one in-place update."""
+    W, n = cache.window, cache.window_pages
+    r = jnp.arange(W, dtype=jnp.int32)
+    t = r + W * ((length - 1 - r) // W)           # < length wherever r < length
+    live = (r < length)[:, None]
+    start = 1 + jnp.asarray(slot, jnp.int32) * n
+
+    def put(ring, seq):
+        rows = jnp.take(seq, jnp.clip(t, 0, seq.shape[0] - 1), axis=0)
+        rows = jnp.where(live, rows, 0).astype(ring.dtype)
+        return jax.lax.dynamic_update_slice(
+            ring, rows.reshape(n, cache.page_size, -1), (start, 0, 0))
+
+    cache.window_k[i], cache.window_v[i] = (put(cache.window_k[i], k),
+                                            put(cache.window_v[i], v))

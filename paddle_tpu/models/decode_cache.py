@@ -1,6 +1,7 @@
 """The decode cache a model hands the serving engine: paged K/V for the
-layers that attend over every past token, a fixed-size state per batch
-slot for the layers that carry a recurrence, both in one pytree.
+layers that attend over every past token, a fixed ring of K/V per batch
+slot for the layers that attend over a sliding window, a fixed-size state
+per batch slot for the layers that carry a recurrence, all in one pytree.
 
 A model's `init_cache` builds it, its `forward_prefill` /
 `forward_decode` take and return it, and `inference/serving.py` reads
@@ -15,6 +16,7 @@ from typing import Optional, Sequence
 import numpy as np
 
 KV = "kv"          # a layer with a paged K and V pool
+KV_WINDOW = "kv_window"   # a layer whose K/V is a ring of `window` tokens a slot
 STATE = "state"    # a layer with a recurrent state and a convolution tail
 NONE = "none"      # a layer that carries nothing from token to token
 
@@ -30,6 +32,21 @@ class StateLayersUnsupported(NotImplementedError):
             f"{path} cannot serve a model with recurrent-state layers "
             f"({state_layers} state, {kv_layers} paged K/V): missing "
             f"protocol: {missing}")
+        self.path = path
+        self.missing = missing
+
+
+class WindowLayersUnsupported(NotImplementedError):
+    """A serving path that moves or shards K/V pages by the engine's
+    block table was asked to serve a model with sliding-window layers,
+    whose K/V is a ring a slot that no table names."""
+
+    def __init__(self, path: str, missing: str, *, kv_layers: int,
+                 window_layers: int):
+        super().__init__(
+            f"{path} cannot serve a model with sliding-window layers "
+            f"({window_layers} window rings, {kv_layers} paged K/V): "
+            f"missing protocol: {missing}")
         self.path = path
         self.missing = missing
 
@@ -77,6 +94,23 @@ class PagedKVCache:
     (whatever a previous request left there), decode updates it in
     place.
 
+    ``window_k[j]`` / ``window_v[j]`` belong to the j-th layer whose kind
+    is ``"kv_window"`` (sliding-window attention over the last ``window``
+    tokens): ``[1 + max_batch * window / page_size, page_size, Hkv*D]``,
+    folded like the pools and read by the same kernels, but a RING a
+    slot and never more, whatever the context: slot b owns pages
+    ``1 + b * window / page_size`` onward (page 0 the null page), the
+    token at position t is written at row ``t mod window`` of its slot's
+    ring, and the layer attends over ``min(context, window)`` rows. Keys
+    are stored with their position already in them (rotated), so the
+    order of a ring's rows is immaterial. WHO OWNS ITS TABLE: nobody
+    stores one. It is a function of the slot alone, computed inside the
+    programs from the slot (prefill) or the `slot_map` (decode): the
+    allocator hands out no ring page, admission and growth count the
+    paged layers' pages only, copy-on-write and a prefix hit touch the
+    paged layers only (prefill recomputes the prompt whole and REWRITES
+    the slot's ring, as it overwrites a recurrent state).
+
     ``layer_kinds`` names each model layer's kind (default: every layer
     paged K/V, the GPT case); a layer of kind ``"none"`` (a feed-forward
     or expert block that is a layer of its own) holds nothing here.
@@ -93,7 +127,12 @@ class PagedKVCache:
                  states: Sequence = (), conv_states: Sequence = (),
                  layer_kinds: Optional[Sequence[str]] = None,
                  num_kv_heads: Optional[int] = None,
-                 counters: Optional[dict] = None):
+                 counters: Optional[dict] = None,
+                 window_k: Sequence = (), window_v: Sequence = (),
+                 window: int = 0):
+        self.window_k = list(window_k)
+        self.window_v = list(window_v)
+        self.window = int(window)
         self.k_pages = list(k_pages)
         self.v_pages = list(v_pages)
         self.block_tables = block_tables
@@ -109,17 +148,24 @@ class PagedKVCache:
             layer_kinds = (KV,) * len(self.k_pages)
         self.layer_kinds = tuple(layer_kinds)
         # layer index -> index into k_pages/v_pages or states/conv_states
-        counts = {KV: 0, STATE: 0, NONE: 0}
+        counts = {KV: 0, KV_WINDOW: 0, STATE: 0, NONE: 0}
         self._index = []
         for kind in self.layer_kinds:
             self._index.append(counts[kind])
             counts[kind] += 1
         if counts[KV] != len(self.k_pages) \
-                or counts[STATE] != len(self.states):
+                or counts[STATE] != len(self.states) \
+                or counts[KV_WINDOW] != len(self.window_k):
             raise ValueError(
-                f"layer_kinds {self.layer_kinds} names {counts[KV]} paged "
-                f"and {counts[STATE]} state layers; the cache holds "
-                f"{len(self.k_pages)} pools and {len(self.states)} states")
+                f"layer_kinds {self.layer_kinds} names {counts[KV]} paged, "
+                f"{counts[KV_WINDOW]} window and {counts[STATE]} state "
+                f"layers; the cache holds {len(self.k_pages)} pools, "
+                f"{len(self.window_k)} rings and {len(self.states)} states")
+        if self.window_k and (self.window < 1
+                              or self.window % self.page_size):
+            raise ValueError(
+                f"a window of {self.window} tokens is no whole number of "
+                f"pages of {self.page_size}")
 
     def index_of(self, layer: int) -> int:
         """Where model layer `layer` sits in the lists of its own kind."""
@@ -141,9 +187,26 @@ class PagedKVCache:
     def has_state(self) -> bool:
         return bool(self.states)
 
+    @property
+    def has_window(self) -> bool:
+        return bool(self.window_k)
+
+    @property
+    def window_pages(self) -> int:
+        """Pages of one slot's ring."""
+        return self.window // self.page_size
+
     def pool_bytes(self) -> int:
-        """Device bytes of the K/V page pools (every paged layer, K + V)."""
-        return sum(_nbytes(p) for p in self.k_pages + self.v_pages)
+        """Device bytes of K and V: every paged layer's pools and every
+        window layer's rings."""
+        return self.window_bytes() + sum(
+            _nbytes(p) for p in self.k_pages + self.v_pages)
+
+    def window_bytes(self) -> int:
+        """Device bytes of the window layers' rings, all slots: a fixed
+        cost of `max_batch`, as the states are; no page of it is the
+        allocator's to give."""
+        return sum(_nbytes(p) for p in self.window_k + self.window_v)
 
     def state_bytes(self) -> int:
         """Device bytes of the recurrent and convolution states, all
@@ -157,13 +220,18 @@ class PagedKVCache:
         return {
             "layer_kinds": list(self.layer_kinds),
             "kv_layers": len(self.k_pages),
+            "window_layers": len(self.window_k),
+            "window": self.window,
+            "window_bytes": self.window_bytes(),
             "state_layers": len(self.states),
             "cacheless_layers": self.layer_kinds.count(NONE),
             "num_heads": self.num_heads,
             "num_kv_heads": self.num_kv_heads,
             "num_pages": self.num_pages,
             "page_size": self.page_size,
-            "page_bytes": self.pool_bytes() // max(1, self.num_pages),
+            # of the paged layers: what one page of the allocator costs
+            "page_bytes": ((self.pool_bytes() - self.window_bytes())
+                           // max(1, self.num_pages)),
             "pool_bytes": self.pool_bytes(),
             "slots": self.max_batch,
             "state_shape": (list(self.states[0].shape[1:])
@@ -178,17 +246,18 @@ class PagedKVCache:
     def tree_flatten(self):
         return ((self.k_pages, self.v_pages, self.block_tables,
                  self.context_lens, self.states, self.conv_states,
-                 self.counters),
+                 self.counters, self.window_k, self.window_v),
                 (self.page_size, self.num_heads, self.head_dim,
-                 self.layer_kinds, self.num_kv_heads))
+                 self.layer_kinds, self.num_kv_heads, self.window))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        k, v, bt, cl, states, conv, counters = children
-        page_size, num_heads, head_dim, kinds, num_kv_heads = aux
+        k, v, bt, cl, states, conv, counters, window_k, window_v = children
+        page_size, num_heads, head_dim, kinds, num_kv_heads, window = aux
         return cls(k, v, bt, cl, page_size, num_heads, head_dim,
                    states=states, conv_states=conv, layer_kinds=kinds,
-                   num_kv_heads=num_kv_heads, counters=counters)
+                   num_kv_heads=num_kv_heads, counters=counters,
+                   window_k=window_k, window_v=window_v, window=window)
 
 
 def _register_cache_pytree():

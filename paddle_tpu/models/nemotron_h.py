@@ -63,7 +63,6 @@ from ..ops import ssm as _ssm
 from . import decode_blocks as _blocks
 from .decode_blocks import ExactLinear as _Linear
 from .decode_cache import KV, NONE, STATE, PagedKVCache, StateLayersUnsupported
-from .gpt import GPT
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
 PUBLISHED_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
@@ -383,7 +382,7 @@ class NemotronHBlock(nn.Layer):
             return self.norm(x)
 
 
-class NemotronH(nn.Layer):
+class NemotronH(_blocks.TokensToLogits, nn.Layer):
     def __init__(self, cfg: NemotronHConfig):
         super().__init__()
         self.cfg = cfg
@@ -395,19 +394,6 @@ class NemotronH(nn.Layer):
         self.norm_f = nn.RMSNorm(cfg.hidden_size, cfg.layer_norm_epsilon)
         # the head routes nothing: three passes, as the other hybrid's
         self.lm_head = _blocks.HighLinear(cfg.hidden_size, cfg.vocab_size)
-
-    def num_params(self):
-        return sum(p.size for p in self.parameters())
-
-    def _embed(self, ids):
-        with jax.named_scope("embed"):
-            return self.wte(ids)
-
-    def _logits(self, x):
-        with jax.named_scope("ln"):
-            x = self.norm_f(x)
-        with jax.named_scope("logits"):
-            return Tensor(_blocks.head(x.data, self.lm_head.weight.data))
 
     def _full_attention(self, attn, q, k, v):
         """Causal attention over whole sequences; q [B, L, H*D], k and v
@@ -435,8 +421,6 @@ class NemotronH(nn.Layer):
             x = x + mixed
         return self._logits(x)
 
-    generate_dense = GPT.generate_dense
-
     # ------------------- decode protocol (inference/serving.py) -------------
 
     def _layer_counts(self):
@@ -451,9 +435,6 @@ class NemotronH(nn.Layer):
                 "sharding a per-slot recurrent state and its update over "
                 "the TP axis (set_tp_mesh covers K/V pools only)",
                 kv_layers=n_kv, state_layers=n_state)
-
-    def tp_mesh(self):
-        return None
 
     def init_cache(self, max_batch: int, max_len: int, page_size: int = 16,
                    num_pages: int = 0, dtype=None) -> PagedKVCache:

@@ -54,7 +54,6 @@ from ..ops import linear_attention as _la
 from . import decode_blocks as _blocks
 from .decode_blocks import HighLinear as _Linear
 from .decode_cache import KV, STATE, PagedKVCache, StateLayersUnsupported
-from .gpt import GPT
 
 LINEAR = "linear_attention"
 FULL = "full_attention"
@@ -282,7 +281,7 @@ class OlmoHybridBlock(nn.Layer):
         return self.add_branch(x, self.mlp(x), self.mlp_norm)
 
 
-class OlmoHybrid(nn.Layer):
+class OlmoHybrid(_blocks.TokensToLogits, nn.Layer):
     def __init__(self, cfg: OlmoHybridConfig):
         super().__init__()
         self.cfg = cfg
@@ -292,19 +291,6 @@ class OlmoHybrid(nn.Layer):
             [OlmoHybridBlock(cfg, kind) for kind in cfg.layer_types])
         self.norm_f = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
         self.lm_head = _Linear(cfg.hidden_size, cfg.vocab_size)
-
-    def num_params(self):
-        return sum(p.size for p in self.parameters())
-
-    def _embed(self, ids):
-        with jax.named_scope("embed"):
-            return self.wte(ids)
-
-    def _logits(self, x):
-        with jax.named_scope("ln"):
-            x = self.norm_f(x)
-        with jax.named_scope("logits"):
-            return Tensor(_blocks.head(x.data, self.lm_head.weight.data))
 
     def _full_attention(self, attn, q, k, v):
         """Causal attention over whole sequences; q, k, v Tensors
@@ -328,8 +314,6 @@ class OlmoHybrid(nn.Layer):
             x = blk.finish(x, mixed)
         return self._logits(x)
 
-    generate_dense = GPT.generate_dense
-
     # ------------------- decode protocol (inference/serving.py) -------------
 
     def set_tp_mesh(self, mesh, axis: str = "tp"):
@@ -340,9 +324,6 @@ class OlmoHybrid(nn.Layer):
                 "sharding a per-slot recurrent state and its update over "
                 "the TP axis (set_tp_mesh covers K/V pools only)",
                 kv_layers=kinds.count(FULL), state_layers=kinds.count(LINEAR))
-
-    def tp_mesh(self):
-        return None
 
     def init_cache(self, max_batch: int, max_len: int, page_size: int = 16,
                    num_pages: int = 0, dtype=None) -> PagedKVCache:

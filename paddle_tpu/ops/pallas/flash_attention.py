@@ -24,6 +24,17 @@ saves softmax-out for bwd, and handles arbitrary attention masks). Here:
   shapes (`tiling.compile_check`) whose failure RAISES, naming the
   kernel — a kernel Mosaic refuses is a bug, not a route to XLA.
 
+* FORWARD ONLY (serving's prefill): `window=W` on a causal call keeps key j
+  for query i iff ``i - W < j <= i`` and SKIPS the key blocks outside that
+  band (their grid steps compute nothing and fetch nothing new); K/V may
+  hold fewer heads than q (grouped K/V heads: query head h reads K/V head
+  ``h // (H // Hkv)`` through the block index map, never repeated); and
+  `precision` sets the kernel's matrix products as `ops/moe.py` sets
+  megablox's (`jax.default_matmul_precision` while the kernel is traced:
+  Mosaic takes "default", one bfloat16 pass, or "highest"), for a model
+  whose attention sits in front of a router. The backward pass refuses
+  all three by name.
+
 `_stats` counts dispatch decisions at trace time so tests can assert the
 kernel path is actually exercised (round-1 review found the old fwd-only
 kernel silently dead in training).
@@ -32,6 +43,7 @@ Layout convention (paddle): q/k/v are [batch, seq, heads, head_dim].
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -47,7 +59,9 @@ from .tiling import zero_tail_rows as _zero_tail_rows
 _NEG = -1e30
 
 # dispatch decisions, counted at trace time (reset freely in tests)
-_stats = {"pallas": 0, "pallas_fwd": 0, "pallas_bwd": 0, "xla": 0}
+# ("window": forward-only calls with a `window`, on either path)
+_stats = {"pallas": 0, "pallas_fwd": 0, "pallas_bwd": 0, "xla": 0,
+          "window": 0}
 
 # tests set True: kernels run in the Pallas interpreter on CPU, so the
 # real kernel logic + custom_vjp wiring is exercised without a TPU
@@ -158,7 +172,7 @@ def _dot(a, b):
 
 
 def _apply_mask(s, mask_ref, mask_is_bool, rows, cols, q_len, kv_len,
-                causal, kv_offset, need_tail_q, need_tail_k):
+                causal, kv_offset, need_tail_q, need_tail_k, window=None):
     """Shared score-masking: user mask block, causal triangle, tail blocks.
 
     Returns (s, masked) where `masked` says any position may sit at the
@@ -177,6 +191,8 @@ def _apply_mask(s, mask_ref, mask_is_bool, rows, cols, q_len, kv_len,
         masked = True
     if causal:
         s = jnp.where(rows + kv_offset >= cols, s, _NEG)
+        if window is not None:
+            s = jnp.where(cols > rows + kv_offset - window, s, _NEG)
         masked = True
     if need_tail_q:
         s = jnp.where(rows < q_len, s, _NEG)
@@ -191,8 +207,18 @@ def _apply_mask(s, mask_ref, mask_is_bool, rows, cols, q_len, kv_len,
 # row-blocked kernel in the package)
 
 
+def _band_k_blocks(i, block_q, block_k, kv_offset, window, n_k):
+    """(first, last) key block that holds a key some query of q-block `i`
+    may see under ``row - window < col <= row`` (rows shifted by
+    `kv_offset`); `i` a Python int or a traced one."""
+    first_row = i * block_q + kv_offset
+    lo = jnp.maximum((first_row - window + 1) // block_k, 0)
+    hi = jnp.minimum((first_row + block_q - 1) // block_k, n_k - 1)
+    return lo, hi
+
+
 def _fa_fwd_kernel(*refs, scale, causal, has_mask, mask_is_bool, block_q,
-                   block_k, q_len, kv_len, kv_offset, n_k):
+                   block_k, q_len, kv_len, kv_offset, n_k, window=None):
     """Grid (B, H, q-blocks, k-blocks); online softmax carried in scratch."""
     from jax.experimental import pallas as pl
 
@@ -233,12 +259,15 @@ def _fa_fwd_kernel(*refs, scale, causal, has_mask, mask_is_bool, block_q,
             s, masked = _apply_mask(
                 s, mask_ref, mask_is_bool, rows, cols, q_len, kv_len,
                 causal_band, kv_offset, need_tail_q=q_len % block_q != 0,
-                need_tail_k=kv_len % block_k != 0)
+                need_tail_k=kv_len % block_k != 0, window=window)
         m_prev = m_ref[...][:, :1]            # [bq, 1]
         l_prev = l_ref[...][:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
-        if masked and (has_mask or q_len % block_q or kv_len % block_k):
+        # (a window's first block of a q-block holds rows whose own band
+        # starts in a LATER block: all floor, nothing real before them)
+        if masked and (has_mask or q_len % block_q or kv_len % block_k
+                       or window is not None):
             # a fully-masked row has m_new == s == _NEG -> exp(0) == 1 for
             # every masked column; zero them explicitly. Pure-causal rows
             # never need this: every row's first valid column lives in an
@@ -259,6 +288,14 @@ def _fa_fwd_kernel(*refs, scale, causal, has_mask, mask_is_bool, block_q,
         last_row = first_row + block_q - 1
         active = last_row >= j * block_k
         interior = first_row >= (j + 1) * block_k - 1
+        if window is not None:
+            # key blocks wholly before the band are skipped like those
+            # wholly above the diagonal; a block is interior when its
+            # first key is inside the LAST row's band too
+            lo, _ = _band_k_blocks(i, block_q, block_k, kv_offset, window,
+                                   n_k)
+            active = active & (j >= lo)
+            interior = interior & (j * block_k > last_row - window)
         pl.when(active & interior)(lambda: _compute(False))
         pl.when(active & jnp.logical_not(interior))(lambda: _compute(True))
     else:
@@ -677,15 +714,19 @@ def _compiler_params(interpret, n_arbitrary=1):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "scale", "mask_is_bool", "interpret", "blocks"))
+    "causal", "scale", "mask_is_bool", "interpret", "blocks", "window",
+    "precision"))
 def _fa_fwd_pallas(q, k, v, mask, causal, scale, mask_is_bool=False,
-                   interpret=False, blocks=None):
+                   interpret=False, blocks=None, window=None, precision=None):
     """Returns (out [B,L,H,D], lse [B,H,Lq] f32). mask may be None.
-    `blocks` is (block_q, block_k); None = `_static_blocks`."""
+    `blocks` is (block_q, block_k); None = `_static_blocks`. `window`,
+    `precision` and K/V of fewer heads than q: the module's docstring,
+    "forward only"."""
     from jax.experimental import pallas as pl
 
     B, Lq, H, D = q.shape
     Lk = k.shape[1]
+    G = H // k.shape[2]             # query heads a K/V head
     block_q, block_k = _blocks_or_static(blocks, Lq, Lk)
     qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
     n_q, n_k = pl.cdiv(Lq, block_q), pl.cdiv(Lk, block_k)
@@ -693,18 +734,39 @@ def _fa_fwd_pallas(q, k, v, mask, causal, scale, mask_is_bool=False,
     kernel = functools.partial(
         _fa_fwd_kernel, scale=scale, causal=causal, has_mask=mask is not None,
         mask_is_bool=mask_is_bool, block_q=block_q, block_k=block_k,
-        q_len=Lq, kv_len=Lk, kv_offset=Lk - Lq, n_k=n_k)
+        q_len=Lq, kv_len=Lk, kv_offset=Lk - Lq, n_k=n_k, window=window)
+
+    def kv_index(b, h, i, j):
+        if window is not None:
+            # a step outside the band names the block the band's nearest
+            # step named: the pipeline fetches nothing for it
+            j = jnp.clip(j, *_band_k_blocks(i, block_q, block_k, Lk - Lq,
+                                            window, n_k))
+        return (b, h if G == 1 else h // G, j, 0)
+
     in_specs = [
         pl.BlockSpec((None, None, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
-        pl.BlockSpec((None, None, block_k, D), lambda b, h, i, j: (b, h, j, 0)),
-        pl.BlockSpec((None, None, block_k, D), lambda b, h, i, j: (b, h, j, 0)),
+        pl.BlockSpec((None, None, block_k, D), kv_index),
+        pl.BlockSpec((None, None, block_k, D), kv_index),
     ]
     args = [qt, kt, vt]
     if mask is not None:
         in_specs.insert(0, _mask_spec(mask, block_q, block_k,
                                       q_axis=2, k_axis=3))
         args.insert(0, mask)
-    out, lse = pl.pallas_call(
+    # the kernel's own `dot_general`s name no precision: they take the
+    # default in force while the kernel is traced
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        out, lse = _fa_fwd_call(kernel, grid, in_specs, args, B, H, Lq, D,
+                                block_q, q.dtype, interpret)
+    return jnp.swapaxes(out, 1, 2), lse[..., 0]
+
+
+def _fa_fwd_call(kernel, grid, in_specs, args, B, H, Lq, D, block_q, dtype,
+                 interpret):
+    from jax.experimental import pallas as pl
+    return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
@@ -715,7 +777,7 @@ def _fa_fwd_pallas(q, k, v, mask, causal, scale, mask_is_bool=False,
                          lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Lq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Lq, D), dtype),
             jax.ShapeDtypeStruct((B, H, Lq, _STATS_LANES), jnp.float32),
         ],
         scratch_shapes=[
@@ -726,7 +788,6 @@ def _fa_fwd_pallas(q, k, v, mask, causal, scale, mask_is_bool=False,
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )(*args)
-    return jnp.swapaxes(out, 1, 2), lse[..., 0]
 
 
 def _fa_bwd_fused_kernel(*refs, scale, causal, has_mask, mask_is_bool,
@@ -1108,6 +1169,98 @@ def _pallas_eligible(q, k, v, mask, causal) -> bool:
     return True
 
 
+def _banded_xla(q, k, v, causal, scale, window, precision):
+    """The forward-only call as a masked matrix product: q [B, L, H, D],
+    k and v [B, Lk, Hkv, D], the group an axis of q and of the scores
+    (K/V are not repeated). Off the TPU, and the kernel's parity
+    reference."""
+    B, Lq, H, D = q.shape
+    Lk, Hkv = k.shape[1], k.shape[2]
+    qg = q.reshape(B, Lq, Hkv, H // Hkv, D)
+    rows = jnp.arange(Lq, dtype=jnp.int32)[:, None] + (Lk - Lq)
+    cols = jnp.arange(Lk, dtype=jnp.int32)[None, :]
+    keep = jnp.ones((Lq, Lk), bool)
+    if causal:
+        keep = cols <= rows
+        if window is not None:
+            keep = keep & (cols > rows - window)
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(keep, s, -jnp.inf)
+        out = jnp.einsum("bkgqs,bskd->bqkgd",
+                         jax.nn.softmax(s, axis=-1).astype(v.dtype), v)
+    return out.reshape(B, Lq, H, D).astype(q.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_forward_only(q, k, v, causal, scale, window, precision, interpret,
+                        blocks):
+    return _fa_fwd_pallas(q, k, v, None, causal, scale, interpret=interpret,
+                          blocks=blocks, window=window,
+                          precision=precision)[0]
+
+
+def _forward_only_fwd(q, k, v, causal, scale, window, precision, interpret,
+                      blocks):
+    return _flash_forward_only(q, k, v, causal, scale, window, precision,
+                               interpret, blocks), None
+
+
+def _forward_only_bwd(causal, scale, window, precision, interpret, blocks,
+                      res, do):
+    raise NotImplementedError(
+        "flash_attention: the backward pass takes no `window`, no "
+        "`precision` and no grouped K/V heads (forward only: serving's "
+        "prefill); train through the masked product, or add them to "
+        "_fa_bwd_fused_kernel")
+
+
+_flash_forward_only.defvjp(_forward_only_fwd, _forward_only_bwd)
+
+
+def _forward_only(q, k, v, causal, scale, window, precision):
+    """`flash_attention` with a `window`, a `precision` or grouped K/V
+    heads: the forward kernel alone where it is eligible, the masked
+    product otherwise."""
+    B, Lq, H, D = q.shape
+    Lk, Hkv = k.shape[1], k.shape[2]
+    if window is not None:
+        if not causal or int(window) < 1:
+            raise ValueError("flash_attention: `window` needs causal=True "
+                             f"and window >= 1, got causal={causal}, "
+                             f"window={window}")
+        window = int(window)
+        _stats["window"] += 1
+    if H % Hkv or v.shape != k.shape:
+        raise ValueError(f"flash_attention: {H} query heads do not divide "
+                         f"over K/V of shape {k.shape} / {v.shape}")
+    eligible = ((_on_tpu() or _INTERPRET) and Lq >= 64 and Lk >= 64
+                and q.dtype == k.dtype == v.dtype
+                and q.dtype != jnp.dtype(jnp.float16)
+                and not (causal and Lq > Lk))
+    if not eligible:
+        _stats["xla"] += 1
+        return _banded_xla(q, k, v, causal, scale, window, precision)
+    blocks = _static_blocks(Lq, Lk)
+
+    def run():
+        return _fa_fwd_pallas(
+            jnp.ones((1, Lq, H, D), q.dtype), jnp.ones((1, Lk, Hkv, D), q.dtype),
+            jnp.ones((1, Lk, Hkv, D), q.dtype), None, bool(causal),
+            float(scale), interpret=_INTERPRET, blocks=blocks, window=window,
+            precision=precision)
+
+    _tiling.compile_check(
+        "flash_attention_forward_only", run, dtype=jnp.dtype(q.dtype).name,
+        q=(Lq, H, D), k=(Lk, Hkv, D), causal=bool(causal), window=window,
+        precision=precision, blocks=blocks, interpret=_INTERPRET)
+    _stats["pallas"] += 1
+    return _flash_forward_only(q, k, v, bool(causal), float(scale), window,
+                               precision, _INTERPRET, blocks)
+
+
 def _flash_per_shard(km, q, k, v, mask, causal, scale):
     """The dispatch below, per shard of a declared multi-device program
     (`tiling.kernel_mesh`): batch over the data axes, heads over the
@@ -1132,9 +1285,15 @@ def _flash_per_shard(km, q, k, v, mask, causal, scale):
 
 
 def flash_attention(q, k, v, mask=None, causal=False, scale=None,
-                    dropout_p=0.0, dropout_key=None):
+                    dropout_p=0.0, dropout_key=None, window=None,
+                    precision=None):
     """Dispatch: fused Pallas fwd+bwd on TPU (masks + causal + any seq len
     >= 64, streamed K/V so Lk is HBM-bounded); XLA composition otherwise.
+
+    `window` (causal only: key j for query i iff ``i - window < j <= i``),
+    `precision` ("default" or "highest", of the kernel's products) and K/V
+    of fewer heads than q take the FORWARD-ONLY path (`_forward_only`),
+    which takes no `mask` and no dropout.
 
     `dropout_p > 0` (training-time attention dropout) ALWAYS takes the XLA
     path: the fused kernels do not thread a dropout seed, and weight-level
@@ -1144,6 +1303,12 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     benches and inference run dropout_p == 0 and stay fused."""
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
+    if window is not None or precision is not None \
+            or k.shape[2] != q.shape[2]:
+        if mask is not None or dropout_p > 0.0:
+            raise ValueError("flash_attention: `window`, `precision` and "
+                             "grouped K/V heads take no mask and no dropout")
+        return _forward_only(q, k, v, causal, scale, window, precision)
     if dropout_p > 0.0:
         _stats["xla"] += 1
         return flash_attention_xla(q, k, v, mask=mask, causal=causal,

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.framework import flags
+from paddle_tpu.ops import moe
 from paddle_tpu.ops.pallas import tiling
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import fused_bn as fb
@@ -200,6 +201,10 @@ _PICK = {
     "softmax_ce": lambda mp, N, V: sce._static_blocks(N, V),
     "fused_bn": lambda mp, R, C: fb._block_rows_for(R, C),
     "conv_bn": lambda mp, R, Cin, Cout: fcb._blocks_for(R, Cin, Cout),
+    # the forward-only flash path (window, precision, grouped K/V heads)
+    "flash_forward": lambda mp, L: fa._static_blocks(L, L),
+    # megablox's (rows, k, n) tiles of an [m, k] x [k, n] grouped product
+    "moe_tiles": lambda mp, m, k, n: moe._tiles(m, k, n),
 }
 
 # (family, shape, pick). Flash: (B, L, H, D, dtype), causal, no mask, ->
@@ -242,6 +247,26 @@ _CELL_PICKS = [
     ("flash", (1, 1024, 30, 128, "float32"), ((256, 512), "fused")),
     ("flash", (1, 2048, 30, 128, "float32"), ((256, 512), "fused")),
     ("paged_attn", (30, 128, 16, 128), 30),
+    # nemo3n_serve_closed64: 32 held experts of 2688 x 1856, top-6; the 64
+    # lanes' 384 assignments and a prefill bucket's
+    ("moe_tiles", (384, 2688, 1856), (32, 2688, 128)),
+    ("moe_tiles", (384, 1856, 2688), (32, 512, 896)),
+    ("moe_tiles", (6 * 512, 2688, 1856), (64, 2688, 128)),
+    ("moe_tiles", (6 * 512, 1856, 2688), (64, 512, 896)),
+    # mellum2_serve_closed64_code: 32 query heads on 4 K/V heads of 128,
+    # prefill buckets to 4096 and max_len 5120 through the forward-only
+    # flash path; 32 held SwiGLU experts of 2304 x 896 (gate and up
+    # stacked to 1792), top-8
+    ("flash_forward", (64,), (64, 64)),
+    ("flash_forward", (256,), (256, 256)),
+    ("flash_forward", (1024,), (256, 512)),
+    ("flash_forward", (4096,), (256, 512)),
+    ("flash_forward", (5120,), (256, 512)),
+    ("moe_tiles", (8 * 64, 2304, 1792), (64, 2304, 256)),
+    ("moe_tiles", (8 * 64, 896, 2304), (64, 896, 768)),
+    ("moe_tiles", (8 * 16, 2304, 1792), (32, 2304, 256)),
+    ("moe_tiles", (8 * 4096, 2304, 1792), (64, 2304, 256)),
+    ("moe_tiles", (8 * 4096, 896, 2304), (64, 896, 768)),
     # no cell: a head size off the lane groups takes the XLA gather
     ("paged_attn", (8, 80, 16, 8), "xla"),
     # no cell yet (ROADMAP D4): ResNet-50 b128 NHWC bottleneck stages,

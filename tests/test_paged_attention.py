@@ -562,3 +562,233 @@ class TestNemotronShapesCompileForTheChip:
                 and " parameter(" not in line]
         assert not made, made[:2]
         assert compiled.memory_analysis().temp_size_in_bytes < 200e6
+
+
+# ------------------ a sliding-window layer's ring (PR 33) --------------------
+
+
+def _ring_cache(B, W, page, Hkv, D, H):
+    from paddle_tpu.models.decode_cache import KV_WINDOW, PagedKVCache
+    ring = lambda: jnp.zeros((1 + B * W // page, page, Hkv * D),  # noqa: E731
+                             jnp.float32)
+    return PagedKVCache([], [], jnp.zeros((B, 1), jnp.int32),
+                        jnp.zeros((B,), jnp.int32), page, H, D,
+                        layer_kinds=[KV_WINDOW], num_kv_heads=Hkv,
+                        window_k=[ring()], window_v=[ring()], window=W)
+
+
+class TestRingTable:
+    """`decode_blocks.ring_*`: the scatters and the grouped kernel at a
+    table computed from the slot, `[B, W / page]`, slot b owning pages
+    `1 + b * W / page` onward."""
+
+    @pytest.mark.parametrize("path", ["xla", "grouped"])
+    def test_32_on_4_heads_over_a_ring_equal_the_dense_reference(
+            self, path, monkeypatch):
+        """The cell's head grouping (32 query heads on 4 K/V heads of
+        128) at a window of 64 in pages of 16: lanes at laps 0, 1 and 3 of
+        their rings, one idle, in slots out of order; every lane against
+        `paged_attention_xla` over the LAST min(context, 64) tokens it
+        was given, repeated for every query head."""
+        from paddle_tpu.models import decode_blocks as blocks
+        monkeypatch.setattr(pa, "_INTERPRET", path == "grouped")
+        H, Hkv, D, W, page, B = 32, 4, 128, 64, 16, 4
+        rng = np.random.default_rng(0)
+        cache = _ring_cache(B, W, page, Hkv, D, H)
+        slots = jnp.asarray([2, 0, 3, 1], jnp.int32)
+        ends = [40, 70, 200, 0]                 # context after the last step
+        history = [rng.normal(size=(n, 2, Hkv * D)).astype(np.float32)
+                   for n in ends]
+        for t in range(max(ends)):
+            active = jnp.asarray([t < n for n in ends])
+            k = np.stack([h[min(t, len(h) - 1), 0] if len(h) else
+                          np.zeros(Hkv * D, np.float32) for h in history])
+            v = np.stack([h[min(t, len(h) - 1), 1] if len(h) else
+                          np.zeros(Hkv * D, np.float32) for h in history])
+            ctx = jnp.asarray([min(t, n) for n in ends], jnp.int32)
+            q = rng.normal(size=(B, H, D)).astype(np.float32)
+            before = pa._stats[path]
+            out = blocks.ring_decode_attention(cache, 0, q, k, v, slots,
+                                               ctx, active)
+            if t not in (39, 69, 199):
+                continue
+            assert pa._stats[path] == before + 1
+            for lane, n in enumerate(ends):
+                if t >= n:
+                    continue
+                seen = history[lane][max(0, t + 1 - W):t + 1]
+                pages = -(-len(seen) // page)
+                pad = np.zeros((pages * page, 2, Hkv * D), np.float32)
+                pad[:len(seen)] = seen
+                rep = lambda x: np.repeat(  # noqa: E731
+                    x.reshape(pages, page, Hkv, D), H // Hkv, axis=2
+                ).reshape(pages, page, H * D)
+                want = pa.paged_attention_xla(
+                    q[lane:lane + 1], rep(pad[:, 0]), rep(pad[:, 1]),
+                    jnp.arange(pages, dtype=jnp.int32)[None],
+                    jnp.asarray([len(seen)], jnp.int32))
+                np.testing.assert_allclose(out[lane], want[0], rtol=0,
+                                           atol=2e-5)
+        # the idle lane's slot (1) holds nothing, nor does the null page
+        ring = np.asarray(cache.window_k[0])
+        assert not ring[1 + 1 * 4:1 + 2 * 4].any() and not ring[0].any()
+
+    @pytest.mark.parametrize("length,bucket", [(5, 16), (64, 64), (70, 128),
+                                               (200, 256), (129, 256)])
+    def test_prefill_rewrites_the_ring_with_the_prompts_last_window(
+            self, length, bucket):
+        """Row r takes the last position t < length with t mod 64 == r;
+        rows past a short prompt read zero; what the slot held before is
+        gone; bucket padding (rows at or past `length`, here nonzero)
+        reaches no row; the other slots are untouched."""
+        from paddle_tpu.models import decode_blocks as blocks
+        W, page, B, width = 64, 16, 3, 32
+        cache = _ring_cache(B, W, page, 2, 16, 4)
+        cache.window_k[0] = cache.window_k[0] + 7.0
+        cache.window_v[0] = cache.window_v[0] + 7.0
+        rng = np.random.default_rng(length)
+        k = rng.normal(size=(bucket, width)).astype(np.float32)
+        v = rng.normal(size=(bucket, width)).astype(np.float32)
+        blocks.ring_prefill_write(cache, 0, jnp.asarray(k), jnp.asarray(v),
+                                  jnp.int32(1), jnp.int32(length))
+        for got, seq in ((cache.window_k[0], k), (cache.window_v[0], v)):
+            got = np.asarray(got)
+            want = np.zeros((W, width), np.float32)
+            for t in range(max(0, length - W), length):
+                want[t % W] = seq[t]
+            np.testing.assert_array_equal(
+                got[1 + 4:1 + 8].reshape(W, width), want)
+            assert (got[:1 + 4] == 7.0).all() and (got[1 + 8:] == 7.0).all()
+
+    def test_a_padding_lanes_write_is_dropped(self):
+        """The sentinel slot `max_batch` is clamped for the gather and
+        its write goes to the null page."""
+        from paddle_tpu.models import decode_blocks as blocks
+        cache = _ring_cache(2, 16, 8, 2, 16, 4)
+        table = np.asarray(blocks.ring_tables(cache,
+                                              jnp.asarray([1, 2, 0])))
+        np.testing.assert_array_equal(table, [[3, 4], [3, 4], [1, 2]])
+        ones = jnp.ones((2, 32), jnp.float32)
+        blocks.ring_decode_attention(
+            cache, 0, jnp.ones((2, 4, 16), jnp.float32), ones, ones,
+            jnp.asarray([0, 2]), jnp.asarray([3, 9]),
+            jnp.asarray([True, False]))
+        ring = np.asarray(cache.window_k[0])
+        assert ring[1, 3].all() and not ring[3:].any()
+
+
+class TestMellumShapesCompileForTheChip:
+    """`mellum2_12b_a2p5b`'s pools hold 4 K/V heads of 128 for 32 query
+    heads: 16,385 pages for a full layer, a ring of 1,024 tokens for each
+    of 64 slots for a sliding one; its expert layers stack 32 SwiGLU
+    experts of 2304 x 896. Both programs' layers must update both pool
+    shapes in place, the flash forward kernel must take the window at
+    `highest`, and the grouped products must read the stacked weights
+    where they lie. Compiled for a described v5e, nothing runs."""
+
+    H, HKV, D, B, W, PAGE, POOL, PER_SEQ = 32, 4, 128, 64, 1024, 16, 16385, 320
+
+    def _cache(self, kp, vp, rk, rv, bt, cl):
+        from paddle_tpu.models.decode_cache import (KV, KV_WINDOW,
+                                                    PagedKVCache)
+        return PagedKVCache([kp], [vp], bt, cl, self.PAGE, self.H, self.D,
+                            layer_kinds=[KV, KV_WINDOW],
+                            num_kv_heads=self.HKV, window_k=[rk],
+                            window_v=[rv], window=self.W)
+
+    def test_both_pool_shapes_are_updated_in_place(self, v5e_chip):
+        from paddle_tpu.analysis import pool_relayout_report
+        from paddle_tpu.models import decode_blocks as blocks
+
+        def sds(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+        width = self.HKV * self.D
+        pool = sds((self.POOL, self.PAGE, width))
+        ring = sds((1 + self.B * self.W // self.PAGE, self.PAGE, width))
+        scale = float(1 / np.sqrt(self.D))
+
+        def decode(q, k, v, kp, vp, rk, rv, bt, cl, slots, active):
+            c = self._cache(kp, vp, rk, rv, bt, cl)
+            ctx = jnp.take(cl, slots, mode="clip")
+            rows = jnp.take(bt, slots, axis=0, mode="clip")
+            table = blocks.ring_tables(c, slots)
+            rk, rv = pa._append_impl(rk, rv, k, v, table, ctx % self.W,
+                                     active)
+            a = pa._paged_attn_grouped_pallas(
+                q, rk, rv, table,
+                jnp.where(active, jnp.minimum(ctx + 1, self.W), 0), scale)
+            kp, vp = pa._append_impl(kp, vp, k, v, rows, ctx, active)
+            b = pa._paged_attn_grouped_pallas(
+                q, kp, vp, rows, jnp.where(active, ctx + 1, 0), scale)
+            return a + b, kp, vp, rk, rv
+
+        def prefill(k, v, kp, vp, rk, rv, bt, cl, slot, length):
+            c = self._cache(kp, vp, rk, rv, bt, cl)
+            blocks.ring_prefill_write(c, 0, k, v, slot, length)
+            blocks.paged_prefill_append(c, 0, k, v,
+                                        jnp.take(bt, slot, axis=0), length, 0)
+            return c.k_pages[0], c.v_pages[0], c.window_k[0], c.window_v[0]
+
+        tables = (sds((self.B, self.PER_SEQ), jnp.int32),
+                  sds((self.B,), jnp.int32))
+        rows = sds((self.B, width))
+        compiled = [
+            jax.jit(decode, donate_argnums=(3, 4, 5, 6)).lower(
+                sds((self.B, self.H, self.D)), rows, rows, pool, pool, ring,
+                ring, *tables, sds((self.B,), jnp.int32),
+                sds((self.B,), jnp.bool_)).compile(),
+            jax.jit(prefill, donate_argnums=(2, 3, 4, 5)).lower(
+                sds((4096, width)), sds((4096, width)), pool, pool, ring,
+                ring, *tables, sds((), jnp.int32),
+                sds((), jnp.int32)).compile()]
+        for program in compiled:
+            rep = pool_relayout_report(program, [pool, ring])
+            assert rep["pool_relayout_copies"] == 0, rep
+            assert rep["temp_size_in_bytes"] < 1e6, rep
+
+    @pytest.mark.parametrize("window", [1024, None], ids=["band", "causal"])
+    @pytest.mark.parametrize("L", [64, 4096])
+    def test_the_flash_forward_takes_the_window_at_highest(self, v5e_chip,
+                                                           L, window):
+        from paddle_tpu.ops.pallas import flash_attention as fa
+
+        def sds(heads):
+            return jax.ShapeDtypeStruct((1, L, heads, self.D), jnp.float32,
+                                        sharding=v5e_chip)
+        compiled = jax.jit(lambda q, k, v: fa._fa_fwd_pallas(
+            q, k, v, None, True, float(1 / np.sqrt(self.D)),
+            blocks=fa._static_blocks(L, L), window=window,
+            precision="highest")[0]).lower(
+                sds(self.H), sds(self.HKV), sds(self.HKV)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        # K and V are never repeated: nothing of q's size beside q's own
+        # transposes in and out
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            <= 2.1 * L * self.H * self.D * 4
+
+    @pytest.mark.parametrize("tokens", [64, 4096], ids=["decode", "prefill"])
+    def test_the_swiglu_products_read_the_stacked_weights_in_place(
+            self, v5e_chip, tokens, monkeypatch):
+        from paddle_tpu.ops import moe
+        monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+
+        def sds(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+        assert moe._tiles(tokens * 8, 2304, 1792) == (64, 2304, 256)
+        assert moe._tiles(tokens * 8, 896, 2304) == (64, 896, 768)
+        compiled = jax.jit(
+            lambda u, e, w, w1, w2: moe.held_experts(
+                u, e, w, w1, w2, form="swiglu")).lower(
+            sds((tokens, 2304)), sds((tokens, 8), jnp.int32),
+            sds((tokens, 8)), sds((32, 1792, 2304)),
+            sds((32, 896, 2304))).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= 2
+        made = [line for line in text.splitlines()
+                if (" = f32[32,1792,2304]" in line
+                    or " = f32[32,896,2304]" in line)
+                and " parameter(" not in line]
+        assert not made, made[:2]
+        # rows, the stacked halves and the output of every assignment
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < 5 * tokens * 8 * 2304 * 4 + 1e6

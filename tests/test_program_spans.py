@@ -18,6 +18,7 @@ from paddle_tpu import jit as jit_mod
 from paddle_tpu import optimizer
 from paddle_tpu.inference import serving
 from paddle_tpu.models import GPT, GPTConfig
+from paddle_tpu.models.mellum import Mellum, MellumConfig
 from paddle_tpu.models.olmo_hybrid import OlmoHybrid, OlmoHybridConfig
 from paddle_tpu.nn import functional as F
 from paddle_tpu.profiler.recorder import get_recorder
@@ -100,11 +101,12 @@ def named(events, name):
     return [e for e in events if e["name"] == name]
 
 
-def serve(directory=None, state_layers=False):
+def serve(directory=None, state_layers=False, window_layers=False):
     """A tiny engine with more requests than lanes, warmed outside the
     trace; returns what the traced (or untraced) round produced."""
     paddle.seed(0)
     model = (OlmoHybrid(OlmoHybridConfig.tiny(1)) if state_layers
+             else Mellum(MellumConfig.tiny(vocab_size=1024)) if window_layers
              else GPT(GPTConfig.tiny()))
     model.eval()
     eng = serving.ServingEngine(model, max_batch=2, max_len=64,
@@ -163,6 +165,12 @@ def served(tmp_path_factory):
 def served_state(tmp_path_factory):
     return serve(tmp_path_factory.mktemp("serve_state_trace"),
                  state_layers=True)
+
+
+@pytest.fixture(scope="module")
+def served_window(tmp_path_factory):
+    return serve(tmp_path_factory.mktemp("serve_window_trace"),
+                 window_layers=True)
 
 
 @pytest.fixture(scope="module")
@@ -264,14 +272,18 @@ def inside(span, events, names):
             and span["start"] <= e["start"] and e["end"] <= span["end"]]
 
 
-@pytest.mark.parametrize("engine", ["gpt", "state_layers"])
+@pytest.mark.parametrize("engine", ["gpt", "state_layers", "window_layers"])
 def test_a_step_launches_its_two_programs_and_nothing_else(
-        engine, served, served_state):
+        engine, request):
     """PR 30: block tables and lengths are the host's, so inside
     `pt.engine.step` the only executables are one decode program an
     iteration and one prefill program an admission, and the transfers
-    are the packed arguments plus at most one table refresh."""
-    run = served if engine == "gpt" else served_state
+    are the packed arguments plus at most one table refresh. A window
+    layer's ring adds none (PR 33): its table is computed inside the
+    programs from the slot and never travels."""
+    run = request.getfixturevalue({"gpt": "served",
+                                   "state_layers": "served_state",
+                                   "window_layers": "served_window"}[engine])
     ev, delta = run["events"], run["delta"]
     steps = named(ev, "pt.engine.step")
     launches = [e for s in steps for e in inside(s, ev, {LAUNCH})]

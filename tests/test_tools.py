@@ -1857,7 +1857,9 @@ class TestMetricsDumpRequests:
         srv.start(0)
         try:
             import paddle_tpu.profiler.server as server_mod
-            orig = server_mod.ObservabilityServer._engine
+            # the staticmethod object itself: reading the attribute gives
+            # the bare function, and putting that back would bind it
+            orig = server_mod.ObservabilityServer.__dict__["_engine"]
             server_mod.ObservabilityServer._engine = staticmethod(
                 lambda name=None: _Stub())
             try:

@@ -233,8 +233,11 @@ def phase_kernels(shapes: dict, interpret: bool = False) -> dict:
     cl = jnp.asarray(rng.integers(
         0, s["pages_per_seq"] * s["page_size"] + 1, (s["B"],)
     ).astype(np.int32))
-    got = pa._paged_attn_pallas(qd, kp, vp, bt, cl, 1.0 / math.sqrt(s["D"]),
-                                s["H"], interpret=interpret)
+    got = pa._paged_attn_pallas(
+        qd, kp, vp, bt, cl, 1.0 / math.sqrt(s["D"]), s["H"],
+        pa.pages_per_step(s["H"] * s["D"], s["page_size"],
+                           qd.dtype.itemsize, s["pages_per_seq"]),
+        interpret=interpret)
     with jax.default_matmul_precision("highest"):
         ref = jax.jit(pa.paged_attention_xla)(qd, kp, vp, bt, cl)
     _check(errs, "paged_attention", got, ref, TOL_PAGED_F32)

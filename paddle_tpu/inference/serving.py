@@ -562,9 +562,12 @@ class ServingEngine:
     victim), a weight swap, `restart`, `close`, `shrink_pool`, `audit`,
     `device_counters`; `pending()` stays true while an iteration is
     unread. `stats["iterations"]` counts dispatches, `decode_tokens`
-    tokens recorded; `ahead_iterations` dispatches made with the one
-    before unread, `drained_for_length` iterations read in their own step
-    for a last token, `discarded_tokens` the cost of the one completion
+    tokens recorded; `page_groups_live` / `page_groups_walked` the page
+    groups with a live token and the grid steps made by one layer's
+    full-heads paged-attention walk, summed over dispatches (0 where the
+    model's attention takes the grouped kernel); `ahead_iterations`
+    dispatches made with the one before unread, `drained_for_length`
+    iterations read in their own step for a last token, `discarded_tokens` the cost of the one completion
     the host cannot know ahead:
 
     * an END OF SEQUENCE sampled in N is seen when N is booked, with N+1
@@ -718,8 +721,10 @@ class ServingEngine:
                       "table_refreshes": 0, "h2d_transfers": 0,
                       "ahead_iterations": 0, "drained_for_length": 0,
                       "discarded_tokens": 0,
+                      "page_groups_live": 0, "page_groups_walked": 0,
                       "min_free_pages": self.allocator.free_pages}
         # what the cache holds, by kind (constants of the engine's life)
+        self._walk_span = self._page_walk_span()
         desc = self.cache.describe()
         self.stats.update({k: desc[k] for k in (
             "kv_layers", "window_layers", "state_layers",
@@ -812,6 +817,19 @@ class ServingEngine:
         if self._lens_dirty:
             self.cache.context_lens = self._put(self._context_lens.copy())
             self._lens_dirty = False
+
+    def _page_walk_span(self) -> int:
+        """Tokens one grid step of the full-heads paged-attention walk
+        covers at this cache's shape, or 0 where the model's layers do not
+        go through that walk (none paged, or grouped K/V heads)."""
+        from ..ops.pallas import paged_attention as _pa
+        c = self.cache
+        if not c.k_pages or c.num_kv_heads != c.num_heads:
+            return 0
+        pool = c.k_pages[0]
+        return self.page_size * _pa.pages_per_step(
+            c.num_heads * c.head_dim // self.tp_degree(), self.page_size,
+            pool.dtype.itemsize, c.pages_per_seq)
 
     def tp_degree(self) -> int:
         """Shards the KV pools split over (1 = single-chip)."""
@@ -1786,6 +1804,13 @@ class ServingEngine:
         self._inflight = (nxt, reqs, W, self.stats["iterations"])
         self.stats["iterations"] += 1
         self.stats["ahead_iterations"] += in_flight is not None
+        if self._walk_span:
+            # one layer's walk of this iteration; a padding lane is idle
+            from ..ops.pallas.paged_attention import page_group_counts
+            live, walked = page_group_counts(
+                self._context_lens[active_slots], self._walk_span)
+            self.stats["page_groups_live"] += live
+            self.stats["page_groups_walked"] += walked + W - len(reqs)
         self.stats["decode_wall_s"] += time.perf_counter() - t0
         if in_flight is not None:
             self._read(in_flight)

@@ -23,14 +23,21 @@ writes there.
 Decode attention (one query token per sequence) gathers the scattered
 pages:
 
-* the Pallas kernel: grid ``(B, head-blocks, pages)`` under a
-  :class:`PrefetchScalarGridSpec` whose scalar-prefetched block table
-  drives the k/v BlockSpec index maps, so each grid step DMAs exactly
-  ONE folded page ``[page_size, heads * head_dim]`` from wherever it
-  lives in the pool into VMEM (the pipeline double-buffers page fetches
-  against compute); online softmax carried across the page walk in VMEM
-  scratch, per-head sums taken by masked lane reductions. Dispatch gives
-  one program all the heads.
+* the Pallas kernel: grid ``(head-blocks, items)`` under a
+  :class:`PrefetchScalarGridSpec`. An ITEM is one live page group of one
+  sequence: ``P`` consecutive slots of its block table that hold at least
+  one of its tokens (`page_walk` lists them from the context lengths,
+  inside the same program; the grid's bound is their number, traced, so
+  the walk follows the contexts and not the table's shape: a slot without
+  a live token is no grid step). The scalar-prefetched work list and block
+  table drive the q, k/v and output BlockSpec index maps, so each grid
+  step DMAs ``P`` folded pages ``[page_size, heads * head_dim]`` of K and
+  of V from wherever they live in the pool into VMEM (the pipeline
+  double-buffers page fetches against compute) and makes one
+  online-softmax update over their ``P * page_size`` rows, the state
+  carried across a sequence's items in VMEM scratch, per-head sums taken
+  by masked lane reductions. Dispatch gives one program all the heads and
+  picks ``P`` by the pool's shape (`pages_per_step`).
 * ``paged_attention_xla``: gather pages via ``k_pages[block_tables]``,
   mask past ``context_lens``, dense softmax. The path off the TPU, for
   fp16 and for head sizes the kernel's lane groups do not take, and the
@@ -81,10 +88,10 @@ _NEG = -1e30
 _LANE = _tiling.LANE
 
 # dispatch decisions, counted at trace time (reset freely in tests)
-# ("folded" counts the dispatches to the kernel that reads the folded
-# page block: all of "pallas" since PR 26, absent before it)
-# ("grouped": those of "pallas" that went to the kernel for grouped K/V
-# heads)
+# ("folded": those of "pallas" that went to the full-heads kernel, whose
+# walk visits live page groups only, `pages_per_step` pages a grid step
+# (PR 34); "grouped": those that went to the kernel for grouped K/V heads,
+# whose walk stops at the longest context)
 _stats = {"pallas": 0, "folded": 0, "grouped": 0, "xla": 0, "append": 0,
           "cow": 0}
 
@@ -199,8 +206,9 @@ def _lane_groups(width: int, D: int):
 def _head_sums(x, D: int):
     """x [rows, w] (one lane group) -> the same shape, every lane holding
     the sum over the D lanes of its own head. Lane reductions under lane
-    masks on the XLU: the MXU would load a 128 x 128 tile of a 0/1 matrix
-    for each 16 rows of a page."""
+    masks on the XLU. The same sums as three bfloat16 products with a 0/1
+    matrix on the MXU (exact to float32) took 0-14 % longer a call at the
+    four measured shapes' picks (PERF.md section 6, PR 34)."""
     w = x.shape[1]
     if D >= w:
         if w > _LANE:  # one head over several tiles: add the tiles first
@@ -219,50 +227,114 @@ def _head_sums(x, D: int):
     return out
 
 
-def _paged_attn_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
-                       acc_ref, m_ref, l_ref, *, page_size, scale, n_pages,
-                       D):
-    """Grid (B, head-blocks, pages); the page axis is the minormost,
-    sequentially-executed dim carrying the online-softmax state. The
-    block table itself picked which page this step's k/v blocks were
+# pages a grid step of the full-heads kernel may hold: its blocks (2 pools x
+# 2 buffers x P pages) within 2 MiB and P within 8. Least ms a call of P = 1 /
+# 2 / 4 / 8 at float32, page 16 (PERF.md section 5, PR 34's chain): 0.450 /
+# 0.332 / 0.317 / 0.335 at a folded width of 2048 (P = 4 is 2 MiB), 1.343 /
+# 1.199 / 1.218 / 1.250 at 3840 (P = 2 is 1.9 MiB), 0.294 / 0.194 / 0.161 /
+# 0.152 at 768 and 0.236 / 0.150 / 0.117 / 0.110 at 512; past 8 not measured
+_STEP_BYTES = 2 << 20
+_MAX_PAGES = 8
+
+
+def pages_per_step(width: int, page_size: int, itemsize: int,
+                    n_pages: int) -> int:
+    """P, the pages of K and of V one grid step of the full-heads walk
+    holds, from what a call can observe: the folded width `H*D`, the page
+    size, the pool's bytes an element and the table's width. A step's own
+    cost (its bookkeeping, the softmax state's update) is paid once for P
+    pages, and a lane's last step fetches and computes up to P - 1 pages
+    past its context: P doubles while the step's blocks stay within
+    `_STEP_BYTES` and P within `_MAX_PAGES` and the table
+    (`tests/test_kernel_blocks.py` pins the picks at the cells' shapes)."""
+    page = 2 * 2 * page_size * width * itemsize
+    P = 1
+    while (2 * P <= min(_MAX_PAGES, n_pages)
+           and 2 * P * page <= _STEP_BYTES):
+        P *= 2
+    return P
+
+
+def page_walk(context_lens, span: int, n_groups: int):
+    """The work list of one call of the page walk: one item for every
+    (lane, live page group), ``max(1, ceil(ctx / span))`` items a lane, so
+    that an idle lane (``ctx == 0``) still has the one step that writes
+    its zeros. Returns int32 ``(lane of item, group of item)``, each
+    ``[B * n_groups]`` (the items first, lane by lane and group by group,
+    the rest never visited), and the number of items ``[1]``. Computed
+    inside the program that calls the kernel; every layer of a decode step
+    computes it from the same lengths, and XLA keeps one copy."""
+    B = context_lens.shape[0]
+    per_lane = jnp.clip(-(-context_lens.astype(jnp.int32) // span), 1,
+                        n_groups)
+    ends = jnp.cumsum(per_lane)
+    t = jnp.arange(B * n_groups, dtype=jnp.int32)
+    lane = jnp.minimum(
+        jnp.sum(t[:, None] >= ends[None, :], axis=1, dtype=jnp.int32), B - 1)
+    group = jnp.minimum(t - (ends - per_lane)[lane], n_groups - 1)
+    return lane, group, ends[-1:]
+
+
+def page_group_counts(context_lens, span: int):
+    """(live, walked) page groups of one call over these context lengths
+    (NumPy, on the host: `ServingEngine.stats`): the groups of `span`
+    tokens that hold a live token, and the grid steps `page_walk` makes,
+    which differ by the idle lanes' one step each."""
+    groups = -(-np.asarray(context_lens, np.int64) // span)
+    return int(groups.sum()), int(np.maximum(groups, 1).sum())
+
+
+def _paged_attn_kernel(bt_ref, cl_ref, lane_ref, group_ref, q_ref, *refs,
+                       page_size, scale, D, P):
+    """Grid (head-blocks, items); the item axis is the minormost,
+    sequentially-executed dim and walks `page_walk`'s list: item t is page
+    group `group_ref[t]` of lane `lane_ref[t]`, a lane's items are
+    consecutive and carry its online-softmax state in VMEM scratch. The
+    block table itself picked which pages this step's k/v blocks were
     DMA'd from (see the BlockSpec index maps in `_paged_attn_pallas`).
 
     A block is a FOLDED page [page, heads * D]: a row is one token, a
-    head is D lanes of it. One query token per head makes the scores a
-    matrix-VECTOR product per head, so the products run on the VPU at the
-    page's own layout. The softmax state is kept per lane (every lane of
-    a head carries that head's max and sum): everything but the per-head
-    sum of q * k is elementwise work and reductions over rows."""
+    head is D lanes of it; a step holds P of K and P of V (`refs`: P K
+    blocks, P V blocks, the output, three scratches) and makes ONE
+    softmax update over their P * page rows. One query token per head
+    makes the scores a matrix-VECTOR product per head, so the products run
+    on the VPU at the page's own layout. The softmax state is kept per
+    lane (every lane of a head carries that head's max and sum):
+    everything but the per-head sum of q * k is elementwise work and
+    reductions over rows."""
     from jax.experimental import pallas as pl
 
-    b = pl.program_id(0)
-    i = pl.program_id(2)
+    k_refs, v_refs = refs[:P], refs[P:2 * P]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * P:]
+    t = pl.program_id(1)
+    g = group_ref[t]
+    ctx = cl_ref[lane_ref[t]]
+    span = P * page_size
 
-    @pl.when(i == 0)
+    @pl.when(g == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    ctx = cl_ref[b]
-
-    # pages at/past ceil(ctx/page_size) hold no live tokens: skip their
-    # compute entirely (their DMA cost is already bounded — unused block
-    # table slots all point at the null page)
-    @pl.when(i * page_size < ctx)
+    # false only in an idle lane's one item: the list holds no other
+    # group without a live token
+    @pl.when(g * span < ctx)
     def _compute():
-        for lo, hi in _lane_groups(k_ref.shape[-1], D):
+        for lo, hi in _lane_groups(k_refs[0].shape[-1], D):
             qb = q_ref[:, lo:hi].astype(jnp.float32)        # [1, w]
-            kb = k_ref[:, lo:hi].astype(jnp.float32)        # [page, w]
-            vb = v_ref[:, lo:hi].astype(jnp.float32)
+            kb = jnp.concatenate([r[:, lo:hi] for r in k_refs],
+                                 axis=0).astype(jnp.float32)  # [span, w]
+            vb = jnp.concatenate([r[:, lo:hi] for r in v_refs],
+                                 axis=0).astype(jnp.float32)
             s = _head_sums(qb * kb, D) * scale
-            pos = i * page_size + jax.lax.broadcasted_iota(
+            pos = g * span + jax.lax.broadcasted_iota(
                 jnp.int32, kb.shape, 0)
             live = pos < ctx
             s = jnp.where(live, s, _NEG)
             m_prev = m_ref[:, lo:hi]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-            # the last live page's tail rows are masked, not underflowed:
+            # the last live group's tail rows are masked, not underflowed:
             # exp(_NEG - m) is 0 only where m is real
             p = jnp.where(live, jnp.exp(s - m_new), 0.0)
             corr = jnp.exp(m_prev - m_new)
@@ -272,18 +344,25 @@ def _paged_attn_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
                 p * vb, axis=0, keepdims=True)
             m_ref[:, lo:hi] = m_new
 
-    @pl.when(i == n_pages - 1)
+    @pl.when(g == jnp.maximum(pl.cdiv(ctx, span), 1) - 1)
     def _finalize():
         # ctx == 0 (idle slot): acc and l still zero -> exactly zero
         o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                       ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block_h", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "block_h", "pages",
+                                             "interpret"))
 def _paged_attn_pallas(q, k_pages, v_pages, block_tables, context_lens,
-                       scale, block_h, interpret=False):
+                       scale, block_h, pages, interpret=False):
     """q [B, H, D] over pools [num_pages, page_size, H * D]; `block_h`
-    heads (block_h * D lanes) to a grid program."""
+    heads (block_h * D lanes) and `pages` pages of K and of V to a grid
+    step. The grid's item axis follows the contexts, not the table: its
+    bound is the number of (lane, live page group) items of `page_walk`,
+    traced, as megablox's tile count is, so a page group without a live
+    token costs nothing. A step's pages are `pages` blocks of the same
+    pool, each with its own entry of the block table (a slot past the
+    table's end reads its last entry, and is masked by its position)."""
     from jax.experimental import pallas as pl
 
     B, H, D = q.shape
@@ -291,35 +370,48 @@ def _paged_attn_pallas(q, k_pages, v_pages, block_tables, context_lens,
     page_size = k_pages.shape[1]
     n_pages = block_tables.shape[1]
     w = block_h * D
-    grid = (B, pl.cdiv(H, block_h), n_pages)
-    # the scalar-prefetched block table drives the page fetch: grid step
-    # (b, h, i) DMAs pool page block_tables[b, i] — this is the paged
-    # gather, done by the Pallas pipeline's own double-buffered DMA
-    kspec = pl.BlockSpec((None, page_size, w),
-                         lambda b, h, i, bt, cl: (bt[b, i], 0, h))
+    P = pages
+    lane, group, n_items = page_walk(context_lens, P * page_size,
+                                     pl.cdiv(n_pages, P))
+
+    # the scalar-prefetched work list and block table drive the page
+    # fetch: item t DMAs pool pages block_tables[lane[t], group[t] * P + j]
+    # — this is the paged gather, done by the Pallas pipeline's own
+    # double-buffered DMA
+    def page(j):
+        return pl.BlockSpec(
+            (None, page_size, w),
+            lambda h, t, bt, cl, ln, gr: (
+                bt[ln[t], jnp.minimum(gr[t] * P + j, n_pages - 1)], 0, h))
+
     # q and the output ride as [B, 1, H*D]: Mosaic refuses a (1, w) block
     # of a [B, H*D] array
-    qspec = pl.BlockSpec((None, 1, w), lambda b, h, i, bt, cl: (b, 0, h))
+    qspec = pl.BlockSpec((None, 1, w),
+                         lambda h, t, bt, cl, ln, gr: (ln[t], 0, h))
+    kv = [page(j) for j in range(P)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[qspec, kspec, kspec],
+        num_scalar_prefetch=4,
+        grid=(pl.cdiv(H, block_h), n_items[0]),
+        in_specs=[qspec] + kv + kv,
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((1, w), jnp.float32)] * 3,
     )
-    # the page axis carries the softmax carry state -> ARBITRARY; batch
-    # and head blocks are embarrassingly parallel
+    # the item axis carries the softmax state from one item of a lane to
+    # its next -> ARBITRARY. v5e has one core a chip; a chip with two
+    # would want the list cut in two halves of equal work at a lane's
+    # edge, a leading PARALLEL axis of 2 over them (the head blocks are
+    # PARALLEL already, but dispatch gives one program all the heads)
     params = None if interpret else pltpu.CompilerParams(
-        dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
-                             pltpu.ARBITRARY))
+        dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY))
     out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, page_size=page_size,
-                          scale=scale, n_pages=n_pages, D=D),
+                          scale=scale, D=D, P=P),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, H * D), q.dtype),
         compiler_params=params,
         interpret=interpret,
-    )(block_tables, context_lens, q.reshape(B, 1, H * D), k_pages, v_pages)
+    )(block_tables, context_lens, lane, group, q.reshape(B, 1, H * D),
+      *([k_pages] * P), *([v_pages] * P))
     return out.reshape(B, H, D)
 
 
@@ -462,21 +554,22 @@ def _check_compiles_grouped(dtype, H: int, Hkv: int, D: int, page_size: int,
         interpret=_INTERPRET)
 
 
-def _check_compiles(dtype, H: int, D: int, page_size: int, n_pages: int):
-    """Eager compile check at the head block dispatch uses, all H
-    (`tiling.compile_check`)."""
+def _check_compiles(dtype, H: int, D: int, page_size: int, n_pages: int,
+                    pages: int):
+    """Eager compile check at the blocks dispatch uses, all H to a program
+    and `pages` pages to a step (`tiling.compile_check`)."""
     def run():
         q = jnp.ones((2, H, D), dtype)
         kp = jnp.ones((max(n_pages, 2), page_size, H * D), dtype)
         bt = jnp.zeros((2, n_pages), jnp.int32)
         cl = jnp.full((2,), page_size, jnp.int32)
         return _paged_attn_pallas(q, kp, kp, bt, cl, float(1.0 / np.sqrt(D)),
-                                 H, interpret=_INTERPRET)
+                                 H, pages, interpret=_INTERPRET)
 
     _tiling.compile_check(
         "paged_attn", run, dtype=jnp.dtype(dtype).name, heads=H, head_dim=D,
         page_size=page_size, pages_per_seq=n_pages, block_heads=H,
-        interpret=_INTERPRET)
+        pages_per_step=pages, interpret=_INTERPRET)
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
@@ -522,11 +615,12 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     # (`_lane_groups`)
     eligible = kernels and Hkv == H and (D % _LANE == 0 or _LANE % D == 0)
     if eligible:
-        _check_compiles(q.dtype, H, D, page_size, n_pages)
+        pages = pages_per_step(H * D, page_size, q.dtype.itemsize, n_pages)
+        _check_compiles(q.dtype, H, D, page_size, n_pages, pages)
         _stats["pallas"] += 1
         _stats["folded"] += 1
         return _paged_attn_pallas(q, k_pages, v_pages, block_tables,
-                                 context_lens, float(scale), H,
+                                 context_lens, float(scale), H, pages,
                                  interpret=_INTERPRET)
     _stats["xla"] += 1
     return paged_attention_xla(q, k_pages, v_pages, block_tables,

@@ -4,7 +4,8 @@ Each family maps a shape to its blocks in one function of its own file
 (`flash_attention._resolve_flash_blocks`, `layer_norm._block_rows_for`,
 `softmax_ce._static_blocks`, `fused_bn._block_rows_for`,
 `fused_conv_bn._blocks_for`; `paged_attention` gives one program all the
-heads). `_CELL_PICKS` pins what they return at the shapes the benchmark's
+heads and picks the pages a grid step in `pages_per_step`, PR 34).
+`_CELL_PICKS` pins what they return at the shapes the benchmark's
 cells trace: a PR that moves a pick changes this table in the open.
 
 The pinned values were read off the parent commit (2d1e18f, PR 28), which
@@ -178,19 +179,20 @@ def _flash_pick(monkeypatch, B, L, H, D, dtype):
 
 
 def _paged_pick(monkeypatch, H, D, page_size, n_pages):
-    """Head block the dispatch hands the kernel, or "xla". The kernel and
-    its compile check are stubbed: nothing runs but the XLA gather."""
+    """(head block, pages a grid step) the dispatch hands the kernel, or
+    "xla". The kernel and its compile check are stubbed: nothing runs but
+    the XLA gather."""
     seen = []
     monkeypatch.setattr(pa, "_INTERPRET", True)
     monkeypatch.setattr(pa, "_check_compiles", lambda *a: None)
     monkeypatch.setattr(pa, "_paged_attn_pallas",
-                        lambda q, *a, **kw: seen.append(a[-1]) or q)
+                        lambda q, *a, **kw: seen.append(a[-2:]) or q)
     q = jnp.ones((1, H, D), jnp.float32)
     pool = jnp.ones((2, page_size, H * D), jnp.float32)
     pa.paged_attention(q, pool, pool, jnp.zeros((1, n_pages), jnp.int32),
                        jnp.asarray([3], jnp.int32))
-    (block_h,) = seen or ["xla"]
-    return block_h
+    (pick,) = seen or ["xla"]
+    return pick
 
 
 _PICK = {
@@ -227,7 +229,7 @@ _CELL_PICKS = [
     ("layer_norm", (256, 768), 256),      # the first bucket at the floor
     ("layer_norm", (128, 768), None),     # a prefill bucket under it
     ("layer_norm", (32, 768), None),      # decode, 32 lanes
-    ("paged_attn", (12, 64, 16, 64), 12),
+    ("paged_attn", (12, 64, 16, 64), (12, 8)),
     # gpt3xl_serve_closed16: H16 x D128, max_len 2048
     ("flash", (1, 64, 16, 128, "float32"), ("small", "small")),
     ("flash", (1, 128, 16, 128, "float32"), ((128, 128), "fused")),
@@ -237,7 +239,10 @@ _CELL_PICKS = [
     ("flash", (1, 2048, 16, 128, "float32"), ((256, 512), "fused")),
     ("layer_norm", (2048, 2048), 256),
     ("layer_norm", (16, 2048), None),     # decode, 16 lanes
-    ("paged_attn", (16, 128, 16, 128), 16),
+    ("paged_attn", (16, 128, 16, 128), (16, 4)),
+    # the same model's heads over four chips (`decode_step_tp`): the local
+    # slice of 4 heads
+    ("paged_attn", (4, 128, 16, 128), (4, 8)),
     # olmoh7b_serve_closed32: H30 x D128 in the full-attention layers
     # (RMSNorm, so no layer_norm rows), max_len 2048
     ("flash", (1, 64, 30, 128, "float32"), ((64, 64), "fused")),
@@ -246,7 +251,7 @@ _CELL_PICKS = [
     ("flash", (1, 512, 30, 128, "float32"), ((256, 512), "fused")),
     ("flash", (1, 1024, 30, 128, "float32"), ((256, 512), "fused")),
     ("flash", (1, 2048, 30, 128, "float32"), ((256, 512), "fused")),
-    ("paged_attn", (30, 128, 16, 128), 30),
+    ("paged_attn", (30, 128, 16, 128), (30, 2)),
     # nemo3n_serve_closed64: 32 held experts of 2688 x 1856, top-6; the 64
     # lanes' 384 assignments and a prefill bucket's
     ("moe_tiles", (384, 2688, 1856), (32, 2688, 128)),
@@ -337,17 +342,18 @@ def _run_layer_norm(block_rows, R, N):
                                rtol=1e-5, atol=1e-5)
 
 
-def _run_paged(block_h, D, page_size, n_pages):
-    """`block_h` heads in all (the pick is every head to one program),
-    two sequences."""
-    rng = np.random.default_rng(block_h + D)
+def _run_paged(pick, D, page_size, n_pages):
+    """`pick` = (heads in all: the pick is every head to one program,
+    pages a grid step); two sequences."""
+    block_h, pages = pick
+    rng = np.random.default_rng(block_h + D + pages)
     H, P = block_h, 2 * n_pages + 1
     q = _randn(rng, (2, H, D))
     kp, vp = (_randn(rng, (P, page_size, H * D)) for _ in range(2))
     bt = jnp.asarray(rng.integers(1, P, (2, n_pages)).astype(np.int32))
     cl = jnp.asarray([n_pages * page_size - 3, page_size + 1], jnp.int32)
     sc = float(1.0 / np.sqrt(D))
-    out = pa._paged_attn_pallas(q, kp, vp, bt, cl, sc, block_h,
+    out = pa._paged_attn_pallas(q, kp, vp, bt, cl, sc, block_h, pages,
                                 interpret=True)
     ref = pa.paged_attention_xla(q, kp, vp, bt, cl, scale=sc)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -365,8 +371,12 @@ _RUN = [
     ("flash", _run_flash, ((256, 512), 512, 128)),
     ("layer_norm", _run_layer_norm, (256, 512, 768)),
     ("layer_norm", _run_layer_norm, (256, 256, 2048)),
-    ("paged_attn", _run_paged, (2, 64, 16, 4)),
-    ("paged_attn", _run_paged, (2, 128, 16, 4)),
+    # each paged-attention pick's pages a step, over a table they do not
+    # divide
+    ("paged_attn", _run_paged, ((2, 8), 64, 16, 11)),
+    ("paged_attn", _run_paged, ((2, 4), 128, 16, 11)),
+    ("paged_attn", _run_paged, ((2, 8), 128, 16, 11)),
+    ("paged_attn", _run_paged, ((2, 2), 128, 16, 11)),
 ]
 
 

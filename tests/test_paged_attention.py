@@ -93,7 +93,7 @@ class TestKernelParity:
         cl = jnp.asarray(np.array([20, 9], np.int32))
         outs = [
             np.asarray(pa._paged_attn_pallas(q, kp, vp, bt, cl,
-                                             1.0 / 8.0, bh, interpret=True))
+                                             1.0 / 8.0, bh, 2, interpret=True))
             for bh in (16, 8)]
         for o in outs[1:]:
             np.testing.assert_array_equal(outs[0], o)
@@ -128,7 +128,8 @@ class TestFoldedKernel:
         cl = jnp.asarray(np.array([0, 13, 16, 32], np.int32))
         fold = lambda x: x.reshape(P, S, H * D)  # noqa: E731
         out = pa._paged_attn_pallas(q, fold(kp), fold(vp), bt, cl,
-                                    float(1 / np.sqrt(D)), H, interpret=True)
+                                    float(1 / np.sqrt(D)), H, 1,
+                                    interpret=True)
         assert out.shape == (B, H, D) and out.dtype == q.dtype
         ref = pa.paged_attention_xla(
             q.astype(jnp.float32), fold(kp).astype(jnp.float32),
@@ -161,6 +162,90 @@ class TestFoldedKernel:
         pa.paged_attention(q, kp, vp, bt, jnp.asarray([5], jnp.int32))
         assert pa._stats["xla"] == before["xla"] + 1
         assert pa._stats["pallas"] == before["pallas"]
+
+
+def _walk_case(P, D, dtype, S=8, n=19):
+    """One call that holds every edge of the page walk at `P` pages a
+    grid step: contexts of 0 (idle), 1, span - 1, span, span + 1, the
+    whole table and a tenth of it, over a table of 19 slots (none of 2, 4,
+    8 divides it, so the last group reaches past the table's end). Every
+    slot past a lane's last live GROUP points at a page of NaN."""
+    rng = np.random.default_rng(100 * P + D)
+    H = max(256 // D, 1)
+    span, full = P * S, n * S
+    ctx = np.array([0, 1, span - 1, span, span + 1, full, full // 10],
+                   np.int32)
+    B, nan_page = len(ctx), 1
+    pages = 2 + B * n
+    q = rng.normal(size=(B, H, D)).astype(np.float32)
+    kp = rng.normal(size=(pages, S, H * D)).astype(np.float32)
+    vp = rng.normal(size=(pages, S, H * D)).astype(np.float32)
+    kp[nan_page] = vp[nan_page] = np.nan
+    bt = 2 + np.arange(B * n, dtype=np.int32).reshape(B, n)
+    for b in range(B):
+        bt[b, -(-int(ctx[b]) // span) * P:] = nan_page
+    cast = lambda x: jnp.asarray(x).astype(dtype)  # noqa: E731
+    return (cast(q), cast(kp), cast(vp), jnp.asarray(bt), jnp.asarray(ctx),
+            H, nan_page)
+
+
+class TestPageWalk:
+    """The full-heads kernel's walk (PR 34): P pages a grid step, one
+    grid step for every (lane, live page group) and none for a group
+    without a live token."""
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("D", [64, 128, 256])
+    @pytest.mark.parametrize("P", [1, 2, 4, 8])
+    def test_walk_matches_reference_and_reads_no_dead_group(self, P, D,
+                                                            dtype):
+        q, kp, vp, bt, ctx, H, nan_page = _walk_case(P, D, dtype)
+        out = pa._paged_attn_pallas(q, kp, vp, bt, ctx,
+                                    float(1 / np.sqrt(D)), H, P,
+                                    interpret=True)
+        out = np.asarray(out.astype(jnp.float32))
+        assert out.dtype == np.float32 and np.all(np.isfinite(out)), \
+            "a page past a lane's last live group reached the sum"
+        assert np.all(out[0] == 0.0)            # the idle lane: exact zeros
+        # the gather reads every slot of the table: give it zeros there
+        f32 = lambda x: x.astype(jnp.float32).at[nan_page].set(0.0)  # noqa
+        ref = pa.paged_attention_xla(q.astype(jnp.float32), f32(kp), f32(vp),
+                                     bt, ctx)
+        atol = 2e-6 if dtype == "float32" else 1e-2
+        np.testing.assert_allclose(out, np.asarray(ref), rtol=0, atol=atol)
+
+    @pytest.mark.parametrize("ctx,span,n_groups", [
+        ([0, 1, 15, 16, 17, 64], 16, 4),       # an idle lane, the edges
+        ([700, 70, 7, 2048], 64, 32),          # contexts tenfold apart
+        ([0, 0, 0], 128, 16),                  # every lane idle
+        ([2048] * 5, 128, 16),                 # every lane full
+        ([33], 32, 2),
+    ])
+    def test_work_list_against_a_plain_loop(self, ctx, span, n_groups):
+        lane, group, n_items = pa.page_walk(jnp.asarray(ctx, jnp.int32),
+                                            span, n_groups)
+        want = [(b, g) for b, c in enumerate(ctx)
+                for g in range(max(1, -(-c // span)))]
+        assert lane.shape == group.shape == (len(ctx) * n_groups,)
+        assert lane.dtype == group.dtype == n_items.dtype == jnp.int32
+        n = int(n_items[0])
+        assert list(zip(np.asarray(lane)[:n].tolist(),
+                        np.asarray(group)[:n].tolist())) == want
+        # what is never visited still indexes the table
+        assert 0 <= int(lane.min()) and int(lane.max()) < len(ctx)
+        assert 0 <= int(group.min()) and int(group.max()) < n_groups
+        live, walked = pa.page_group_counts(np.asarray(ctx), span)
+        assert walked == n
+        assert live == n - sum(c == 0 for c in ctx)
+
+    def test_no_idle_lane_walks_live_groups_only(self):
+        """`page_groups_live` of the engine's counter IS the item count
+        where every lane holds a token."""
+        ctx = np.array([700, 70, 7, 2048, 1, 129], np.int32)
+        for span in (16, 32, 64, 128):
+            live, walked = pa.page_group_counts(ctx, span)
+            n_items = pa.page_walk(jnp.asarray(ctx), span, 2048 // span)[2]
+            assert live == walked == int(n_items[0])
 
 
 def _np_pages(P, S, HD):
@@ -333,9 +418,11 @@ class TestPoolLayout:
     @staticmethod
     def _decode_layer(q, k_new, v_new, kp, vp, bt, cl, active):
         kp, vp = pa._append_impl(kp, vp, k_new, v_new, bt, cl, active)
-        out = pa._paged_attn_pallas(q, kp, vp, bt, jnp.where(active, cl + 1, 0),
-                                    float(1 / np.sqrt(q.shape[-1])),
-                                    q.shape[1])
+        out = pa._paged_attn_pallas(
+            q, kp, vp, bt, jnp.where(active, cl + 1, 0),
+            float(1 / np.sqrt(q.shape[-1])), q.shape[1],
+            pa.pages_per_step(kp.shape[2], kp.shape[1], kp.dtype.itemsize,
+                               bt.shape[1]))
         return out, kp, vp
 
     def _reports(self, chip, H, D, P, B, n, pool_shape):
@@ -355,6 +442,44 @@ class TestPoolLayout:
         for rep in self._reports(v5e_chip, H, D, P, B, n, (P, 16, H * D)):
             assert rep["pool_relayout_copies"] == 0, rep
             assert rep["temp_size_in_bytes"] < rep["pool_bytes"], rep
+
+    @pytest.mark.parametrize("config", sorted(_SERVED))
+    def test_the_work_list_is_computed_once_a_step(self, v5e_chip, config):
+        """Every layer's call computes `page_walk` from the same lengths:
+        the compiled step of three layers holds the list's reductions
+        (the cumulative sum, the count of lanes behind an item) as often
+        as the step of one, and still no copy of pool shape."""
+        import re
+        from collections import Counter
+        from paddle_tpu.analysis import pool_relayout_report
+        H, D, P, B, n = _SERVED[config]
+        pool, a = self._args(v5e_chip, H, D, P, B, n, (P, 16, H * D))
+
+        def step(layers):
+            def run(q, k_new, v_new, pools, bt, cl, active):
+                out = []
+                for kp, vp in pools:
+                    q, kp, vp = self._decode_layer(q, k_new, v_new, kp, vp,
+                                                   bt, cl, active)
+                    out.append((kp, vp))
+                return q, out
+            return jax.jit(run, donate_argnums=(3,)).lower(
+                a["q"], a["rows"], a["rows"], [(pool, pool)] * layers,
+                a["bt"], a["cl"], a["active"]).compile()
+
+        def ops(compiled):
+            return Counter(re.findall(r" = \S+ ([a-z\-]+)\(",
+                                      compiled.as_text()))
+
+        one, three = step(1), step(3)
+        n1, n3 = ops(one), ops(three)
+        assert n3["custom-call"] - n1["custom-call"] >= 2   # the kernels
+        assert n1["reduce-window"] + n1["reduce"] >= 2, n1
+        for op in ("reduce-window", "reduce", "iota", "gather", "sort"):
+            assert n3[op] == n1[op], (op, n1[op], n3[op])
+        rep = pool_relayout_report(three, [pool])
+        assert rep["pool_relayout_copies"] == 0, rep
+        assert rep["temp_size_in_bytes"] < rep["pool_bytes"], rep
 
     def test_the_count_sees_the_4d_pool_of_before(self, v5e_chip):
         """The same one-layer programs on GPT-2 small's pool as it was

@@ -190,6 +190,48 @@ def test_counters_follow_the_dispatches_and_a_last_token_is_read_at_once():
     eng.close()
 
 
+@pytest.mark.parametrize("kind", ["gpt", "olmo_hybrid", "nemotron_h"])
+def test_page_walk_counters_follow_the_dispatched_contexts(kind):
+    """`page_groups_live` / `page_groups_walked` (PR 34): what one layer's
+    full-heads paged-attention walk visits, summed over dispatches from
+    the host's own lengths; a padding lane is one idle step; an engine
+    whose attention takes the grouped kernel counts nothing."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    eng = engine(kind, name=f"walk-{kind}", max_batch=4)
+    c = eng.cache
+    if kind == "nemotron_h":
+        assert c.num_kv_heads != c.num_heads and eng._walk_span == 0
+    else:
+        assert eng._walk_span == PAGE * pa.pages_per_step(
+            c.num_heads * c.head_dim, PAGE, 4, c.pages_per_seq)
+    span = eng._walk_span or PAGE
+    want = {"live": 0, "walked": 0, "padding": 0}
+    fused = eng._fused_jit
+
+    def counted(*args):
+        slot_map, active = args[4][1], args[4][2].astype(bool)
+        seen = np.where(active, eng._context_lens[
+            np.minimum(slot_map, eng.max_batch - 1)] + 1, 0)
+        groups = [-(-int(x) // span) for x in seen]
+        want["live"] += sum(groups)
+        want["walked"] += sum(max(g, 1) for g in groups)
+        want["padding"] += int((~active).sum())
+        return fused(*args)
+
+    eng._fused_jit = counted
+    reqs = closed_loop(eng, traffic(7, seed=4, sampled=False), clients=3)
+    assert all(r.state == "done" for r in reqs) and want["padding"] > 0
+    got = (eng.stats["page_groups_live"], eng.stats["page_groups_walked"])
+    if kind == "nemotron_h":
+        assert got == (0, 0)
+    else:
+        assert got == (want["live"], want["walked"])
+        assert got[1] == got[0] + want["padding"]
+    assert {"page_groups_live", "page_groups_walked"} <= set(
+        eng.status()["stats"])
+    eng.close()
+
+
 # ---- (c) an end of sequence is seen one iteration late
 
 def test_an_end_of_sequence_in_flight_drops_one_token_and_frees_once():

@@ -1,0 +1,11 @@
+"""Share of the traced window in which the device was idle, nothing was on
+its way to it, and the host was at its own work: bookkeeping, admission,
+the lane arrays, capacity, the table refresh, `submit`, the step's self
+time, the client loop (`benchmark/launch_trace.py`). With
+`serve_idle_cause_call_pct` and `serve_idle_cause_read_pct` it sums to
+`serve_device_idle_pct`."""
+from benchmark import launch_trace
+
+
+def read(run):
+    return launch_trace.metric("serve_idle_cause_host_pct")

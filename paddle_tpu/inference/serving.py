@@ -561,7 +561,19 @@ class ServingEngine:
     slot, a hand-off, preemption (a dry pool reads before it picks a
     victim), a weight swap, `restart`, `close`, `shrink_pool`, `audit`,
     `device_counters`; `pending()` stays true while an iteration is
-    unread. `stats["iterations"]` counts dispatches, `decode_tokens`
+    unread. Every device program `step()` launches, decode iterations and
+    prefills in one series, carries a launch number: `seq` =
+    `stats["launches"]` read before the call and bumped after it, on the
+    `pt.engine.dispatch` / `.prefill.dispatch` span that made the call and
+    on the `.fetch` / `.prefill.fetch` span that reads its tokens (it rides
+    in the unread record for decode), so a reader of a trace joins each
+    call to its execution on the device, first in first out. Each number
+    is read exactly once, after its dispatch span closed (an unread
+    iteration that `close` or a failure drops is never read); page copies,
+    injections and `eager` decode's per-op calls carry none.
+    `stats["read_wait_s"]` over `stats["step_wall_s"]` is the share of the
+    loop spent inside the two reads: near 1 the device sets the pace.
+    `stats["iterations"]` counts dispatches, `decode_tokens`
     tokens recorded; `page_groups_live` / `page_groups_walked` the page
     groups with a live token and the grid steps made by one layer's
     full-heads paged-attention walk, summed over dispatches (0 where the
@@ -722,6 +734,7 @@ class ServingEngine:
                       "ahead_iterations": 0, "drained_for_length": 0,
                       "discarded_tokens": 0,
                       "page_groups_live": 0, "page_groups_walked": 0,
+                      "launches": 0, "read_wait_s": 0.0, "step_wall_s": 0.0,
                       "min_free_pages": self.allocator.free_pages}
         # what the cache holds, by kind (constants of the engine's life)
         self._walk_span = self._page_walk_span()
@@ -1051,7 +1064,11 @@ class ServingEngine:
         idle)."""
         with self._step_lock, \
                 _span("step", iteration=self.stats["iterations"]):
-            return self._iterate()
+            t0 = time.perf_counter()
+            try:
+                return self._iterate()
+            finally:
+                self.stats["step_wall_s"] += time.perf_counter() - t0
 
     def _iterate(self) -> int:
         # chaos: an armed `serving.wedge=N:delay` stalls the loop HERE,
@@ -1613,11 +1630,12 @@ class ServingEngine:
         prev = _cw.push_entry("to_static", f"serving_prefill:{self.name}")
         # the three NumPy arguments travel with the call itself
         transfers = self._count_transfers(3)
+        seq = self.stats["launches"]
         try:
             # dispatch lock: a concurrent canary evaluation rebinds the
             # model's parameter state while it traces — never interleave
             # that with a prefill/decode trace
-            with _span("prefill.dispatch", transfers=transfers), \
+            with _span("prefill.dispatch", seq=seq, transfers=transfers), \
                     self._dispatch_lock:
                 self._refresh_tables()
                 nxt, self.cache = self._prefill_jit(
@@ -1625,12 +1643,15 @@ class ServingEngine:
                     floats)
         finally:
             _cw.pop_entry(prev)
+        self.stats["launches"] += 1
         self._context_lens[slot] = len(tokens)   # as the program set it
         self.stats["prefills"] += 1
         if self.share_prefix:
             self._prefix.register(tokens, pages)
-        with _span("prefill.fetch"):
+        t0 = time.perf_counter()
+        with _span("prefill.fetch", seq=seq):
             tok = int(np.asarray(nxt)[0])
+        self.stats["read_wait_s"] += time.perf_counter() - t0
         self.tracer.prefill_done(req.rid)
         now = time.monotonic()
         if req.first_token_ts is None:
@@ -1781,9 +1802,12 @@ class ServingEngine:
             args = (self._params, self._buffers, self.cache,
                     self._last_tokens, lanes_i, lanes_f)
         in_flight = self._inflight
+        seq = self.stats["launches"]
         try:
             # see _prefill: canary serialization
-            with _span("dispatch", ahead=int(in_flight is not None)), \
+            with _span("dispatch", seq=seq,
+                       iteration=self.stats["iterations"],
+                       ahead=int(in_flight is not None)), \
                     self._dispatch_lock:
                 if self.decode_mode == "fused":
                     nxt, self.cache, self._last_tokens = \
@@ -1801,8 +1825,9 @@ class ServingEngine:
             req.unread += 1
         # until this iteration is read its tokens are the device's alone
         self._cur_tokens[active_slots] = -1
-        self._inflight = (nxt, reqs, W, self.stats["iterations"])
+        self._inflight = (nxt, reqs, W, self.stats["iterations"], seq)
         self.stats["iterations"] += 1
+        self.stats["launches"] += 1
         self.stats["ahead_iterations"] += in_flight is not None
         if self._walk_span:
             # one layer's walk of this iteration; a padding lane is idle
@@ -1842,11 +1867,13 @@ class ServingEngine:
         REQUEST its lane was dispatched for (the slot may be somebody
         else's by now). A request that has ended since, by an end of
         sequence in the iteration before, drops its token."""
-        nxt, reqs, W, iteration = in_flight
+        nxt, reqs, W, iteration, seq = in_flight
         t0 = time.perf_counter()
-        with _span("fetch", iteration=iteration):
+        with _span("fetch", seq=seq, iteration=iteration):
             nxt_np = np.asarray(nxt)  # device sync: the iteration boundary
-        self.stats["decode_wall_s"] += time.perf_counter() - t0
+        waited = time.perf_counter() - t0
+        self.stats["decode_wall_s"] += waited
+        self.stats["read_wait_s"] += waited
         newest = self._inflight is None
         with _span("bookkeep", lanes=W):
             produced = 0

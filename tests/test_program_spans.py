@@ -33,13 +33,14 @@ ENGINE_SPANS = {
         "rid", "trace_id", "bucket", "prompt_tokens", "shared_tokens",
         "requeue", "queue_wait_us"}),
     "pt.engine.prefill.build": ("pt.engine.prefill", set()),
-    "pt.engine.prefill.dispatch": ("pt.engine.prefill", {"transfers"}),
-    "pt.engine.prefill.fetch": ("pt.engine.prefill", set()),
+    "pt.engine.prefill.dispatch": ("pt.engine.prefill", {"seq",
+                                                         "transfers"}),
+    "pt.engine.prefill.fetch": ("pt.engine.prefill", {"seq"}),
     "pt.engine.capacity": ("pt.engine.step", {"active"}),
     "pt.engine.lanes": ("pt.engine.step", {"lanes", "active"}),
     "pt.engine.upload": ("pt.engine.step", {"transfers"}),
-    "pt.engine.dispatch": ("pt.engine.step", {"ahead"}),
-    "pt.engine.fetch": ("pt.engine.step", {"iteration"}),
+    "pt.engine.dispatch": ("pt.engine.step", {"seq", "iteration", "ahead"}),
+    "pt.engine.fetch": ("pt.engine.step", {"seq", "iteration"}),
     "pt.engine.bookkeep": ("pt.engine.step", {"lanes"}),
 }
 TRAIN_SPANS = {
@@ -130,7 +131,8 @@ def serve(directory=None, state_layers=False, window_layers=False):
     delta = {k: eng.stats[k] - before[k]
              for k in ("iterations", "prefills", "completed",
                        "h2d_transfers", "table_refreshes",
-                       "ahead_iterations")}
+                       "ahead_iterations", "drained_for_length",
+                       "launches", "read_wait_s", "step_wall_s")}
     eng.close()
     return {"reqs": reqs, "events": events, "delta": delta,
             "programs": programs}
@@ -225,6 +227,68 @@ def test_engine_span_counts_follow_the_engines_counters(served):
         == set(ENGINE_SPANS)
 
 
+# the span that launches a program -> the span that reads its tokens
+READ_OF = {"pt.engine.dispatch": "pt.engine.fetch",
+           "pt.engine.prefill.dispatch": "pt.engine.prefill.fetch"}
+
+
+def test_launch_numbers_are_one_series_over_both_programs(served):
+    """PR 35: decode iterations and prefills are numbered in one series,
+    in the order of their calls, by `stats["launches"]`."""
+    ev, delta = served["events"], served["delta"]
+    # the round holds admissions, dispatches made ahead and drains
+    assert delta["prefills"] and delta["ahead_iterations"] \
+        and delta["drained_for_length"]
+    calls = sorted((e for e in ev if e["name"] in READ_OF),
+                   key=lambda e: e["start"])
+    assert len(calls) == delta["launches"] \
+        == delta["iterations"] + delta["prefills"]
+    seqs = [c["args"]["seq"] for c in calls]
+    assert seqs == list(range(seqs[0], seqs[0] + delta["launches"]))
+    assert {c["name"] for c in calls} == set(READ_OF)
+
+
+def test_every_launch_is_read_once_after_its_call_closed(served):
+    ev = served["events"]
+    reads = [e for e in ev if e["name"] in READ_OF.values()]
+    by_seq = {}
+    for r in reads:
+        assert r["args"]["seq"] not in by_seq, "a launch read twice"
+        by_seq[r["args"]["seq"]] = r
+    calls = [e for e in ev if e["name"] in READ_OF]
+    assert sorted(by_seq) == sorted(c["args"]["seq"] for c in calls)
+    for c in calls:
+        r = by_seq[c["args"]["seq"]]
+        assert r["name"] == READ_OF[c["name"]]
+        assert r["start"] >= c["end"]
+        if c["name"] == "pt.engine.dispatch":
+            # the read names the same launch by both of its numbers
+            assert r["args"]["iteration"] == c["args"]["iteration"]
+    # an iteration dispatched ahead is read in a later step than its call
+    steps = named(ev, "pt.engine.step")
+    step_of = lambda e: next(i for i, s in enumerate(steps)  # noqa: E731
+                             if s["start"] <= e["start"]
+                             and e["end"] <= s["end"])
+    behind = sum(step_of(by_seq[c["args"]["seq"]]) - step_of(c)
+                 for c in named(ev, "pt.engine.dispatch"))
+    assert behind == served["delta"]["ahead_iterations"]
+
+
+@pytest.mark.parametrize("counter, spans", [
+    ("read_wait_s", ("pt.engine.fetch", "pt.engine.prefill.fetch")),
+    ("step_wall_s", ("pt.engine.step",))])
+def test_host_counters_time_what_their_spans_time(served, counter, spans):
+    """The two counters an operator without a trace reads: seconds inside
+    the two reads and inside `step()`. The clock calls sit right around
+    (the reads) or right inside (the step) the span, so they differ from
+    it by an annotation's own cost."""
+    timed = [e for e in served["events"] if e["name"] in spans]
+    seconds = sum(e["end"] - e["start"] for e in timed) / 1e9
+    assert served["delta"][counter] == pytest.approx(
+        seconds, rel=0.05, abs=2e-4 * len(timed))
+    assert 0 < served["delta"]["read_wait_s"] < served["delta"]["step_wall_s"]
+
+
 def test_prefill_spans_carry_the_requests_they_admitted(served):
     reqs = {r.rid: r for r in served["reqs"]}
     prefills = named(served["events"], "pt.engine.prefill")
@@ -288,7 +352,8 @@ def test_a_step_launches_its_two_programs_and_nothing_else(
     steps = named(ev, "pt.engine.step")
     launches = [e for s in steps for e in inside(s, ev, {LAUNCH})]
     assert launches, "the trace shows no launch: another client's names?"
-    assert len(launches) == delta["iterations"] + delta["prefills"]
+    assert len(launches) == delta["iterations"] + delta["prefills"] \
+        == delta["launches"]
     sites = {"pt.engine.dispatch", "pt.engine.prefill.dispatch"}
     assert {parent(e, ev) for e in launches} == sites
     jitted = {e["name"] for s in steps for e in ev
@@ -375,6 +440,10 @@ def test_outputs_are_the_same_without_the_spans(served, trained, what,
             r.generated for r in served["reqs"]]
         assert all(len(r.generated) == r.max_new_tokens
                    for r in bare["reqs"])
+        # the launch numbers and the two clocks need no span
+        for k in ("launches", "iterations", "prefills", "ahead_iterations"):
+            assert bare["delta"][k] == served["delta"][k], k
+        assert 0 < bare["delta"]["read_wait_s"] < bare["delta"]["step_wall_s"]
     else:
         assert train()["losses"] == trained["losses"]
 

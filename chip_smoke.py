@@ -163,6 +163,13 @@ FULL_KERNEL_SHAPES = {
     "flash": dict(B=8, L=1024, H=12, D=64),
     "layer_norm": dict(R=8 * 1024, N=768),
     "paged": dict(B=32, H=12, D=64, page_size=16, pages_per_seq=64),
+    # the grouped kernel's three call shapes: Mellum2's full layers and
+    # its sliding layers' rings (64 lanes, 32 query on 4 K/V heads of 128),
+    # Nemotron-3-Nano's attention blocks (32 on 2)
+    "paged_grouped": [
+        dict(B=64, H=32, Hkv=4, D=128, page_size=16, pages_per_seq=320),
+        dict(B=64, H=32, Hkv=4, D=128, page_size=16, pages_per_seq=64),
+        dict(B=64, H=32, Hkv=2, D=128, page_size=16, pages_per_seq=128)],
     "bn": [(128 * 56 * 56, 256), (128 * 28 * 28, 512),
            (128 * 14 * 14, 1024), (128 * 7 * 7, 2048)],
     "conv_bn": [(128 * 14 * 14, 1024, 256), (128 * 28 * 28, 128, 512),
@@ -241,6 +248,30 @@ def phase_kernels(shapes: dict, interpret: bool = False) -> dict:
     with jax.default_matmul_precision("highest"):
         ref = jax.jit(pa.paged_attention_xla)(qd, kp, vp, bt, cl)
     _check(errs, "paged_attention", got, ref, TOL_PAGED_F32)
+
+    # ---- the same over pools of fewer K/V heads than q has heads, at the
+    # grouped kernel's own pick; lanes idle, short and at the table's end
+    for s in shapes["paged_grouped"]:
+        n, width = s["pages_per_seq"], s["Hkv"] * s["D"]
+        num_pages = 1 + s["B"] * n
+        qd = randn((s["B"], s["H"], s["D"]), jnp.float32)
+        kp, vp = (randn((num_pages, s["page_size"], width), jnp.float32)
+                  for _ in range(2))
+        bt = jnp.asarray(1 + rng.permutation(num_pages - 1).reshape(
+            s["B"], n).astype(np.int32))
+        cl = rng.integers(0, n * s["page_size"] + 1, (s["B"],))
+        cl[:3] = 0, 1, n * s["page_size"]
+        cl = jnp.asarray(cl.astype(np.int32))
+        sc = 1.0 / math.sqrt(s["D"])
+        got = pa._paged_attn_grouped_pallas(
+            qd, kp, vp, bt, cl, sc,
+            pa.grouped_pages_per_step(width, s["page_size"],
+                                      qd.dtype.itemsize, n),
+            interpret=interpret)
+        ref = jax.jit(pa._paged_attention_grouped_xla,
+                      static_argnums=5)(qd, kp, vp, bt, cl, sc)
+        _check(errs, f"paged_attention_{s['H']}on{s['Hkv']}_table{n}", got,
+               ref, TOL_PAGED_F32)
 
     # ---- fused BN(+add)+ReLU forward and both backward kernels
     for R, C in shapes["bn"]:

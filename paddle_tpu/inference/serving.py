@@ -575,9 +575,9 @@ class ServingEngine:
     loop spent inside the two reads: near 1 the device sets the pace.
     `stats["iterations"]` counts dispatches, `decode_tokens`
     tokens recorded; `page_groups_live` / `page_groups_walked` the page
-    groups with a live token and the grid steps made by one layer's
-    full-heads paged-attention walk, summed over dispatches (0 where the
-    model's attention takes the grouped kernel); `ahead_iterations`
+    groups with a live token and the grid steps made by one paged layer's
+    paged-attention walk, summed over dispatches (0 where no layer is
+    paged); `ahead_iterations`
     dispatches made with the one before unread, `drained_for_length`
     iterations read in their own step for a last token, `discarded_tokens` the cost of the one completion
     the host cannot know ahead:
@@ -832,17 +832,18 @@ class ServingEngine:
             self._lens_dirty = False
 
     def _page_walk_span(self) -> int:
-        """Tokens one grid step of the full-heads paged-attention walk
-        covers at this cache's shape, or 0 where the model's layers do not
-        go through that walk (none paged, or grouped K/V heads)."""
+        """Tokens one grid step of the paged-attention walk covers at this
+        cache's shape (the full-heads kernel's pick or, with grouped K/V
+        heads, the grouped kernel's), or 0 where no layer is paged."""
         from ..ops.pallas import paged_attention as _pa
         c = self.cache
-        if not c.k_pages or c.num_kv_heads != c.num_heads:
+        if not c.k_pages:
             return 0
-        pool = c.k_pages[0]
-        return self.page_size * _pa.pages_per_step(
-            c.num_heads * c.head_dim // self.tp_degree(), self.page_size,
-            pool.dtype.itemsize, c.pages_per_seq)
+        pick = (_pa.pages_per_step if c.num_kv_heads == c.num_heads
+                else _pa.grouped_pages_per_step)
+        return self.page_size * pick(
+            c.num_kv_heads * c.head_dim // self.tp_degree(), self.page_size,
+            c.k_pages[0].dtype.itemsize, c.pages_per_seq)
 
     def tp_degree(self) -> int:
         """Shards the KV pools split over (1 = single-chip)."""

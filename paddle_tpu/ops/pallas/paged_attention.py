@@ -38,6 +38,12 @@ pages:
   carried across a sequence's items in VMEM scratch, per-head sums taken
   by masked lane reductions. Dispatch gives one program all the heads and
   picks ``P`` by the pool's shape (`pages_per_step`).
+* the kernel for GROUPED K/V heads (``Hkv < H``): the same walk over the
+  same list, all the query heads of a lane to a grid step; the pools stay
+  in HBM and the kernel copies a step's pages itself, one step ahead, and
+  its products run on the MXU as float32's bfloat16 parts, K and V
+  crossing it once a part (`_paged_attn_grouped_kernel`,
+  `grouped_pages_per_step`).
 * ``paged_attention_xla``: gather pages via ``k_pages[block_tables]``,
   mask past ``context_lens``, dense softmax. The path off the TPU, for
   fp16 and for head sizes the kernel's lane groups do not take, and the
@@ -91,7 +97,7 @@ _LANE = _tiling.LANE
 # ("folded": those of "pallas" that went to the full-heads kernel, whose
 # walk visits live page groups only, `pages_per_step` pages a grid step
 # (PR 34); "grouped": those that went to the kernel for grouped K/V heads,
-# whose walk stops at the longest context)
+# which walks the same list, `grouped_pages_per_step` pages a step (PR 36))
 _stats = {"pallas": 0, "folded": 0, "grouped": 0, "xla": 0, "append": 0,
           "cow": 0}
 
@@ -247,10 +253,17 @@ def pages_per_step(width: int, page_size: int, itemsize: int,
     past its context: P doubles while the step's blocks stay within
     `_STEP_BYTES` and P within `_MAX_PAGES` and the table
     (`tests/test_kernel_blocks.py` pins the picks at the cells' shapes)."""
-    page = 2 * 2 * page_size * width * itemsize
+    return _pages_that_fit(2 * 2 * page_size * width * itemsize, n_pages,
+                           _STEP_BYTES, _MAX_PAGES)
+
+
+def _pages_that_fit(page: int, n_pages: int, step_bytes: int,
+                    max_pages: int) -> int:
+    """The largest power of two of pages (`page` bytes each, all of a
+    step's buffers counted) within `step_bytes`, `max_pages` and the
+    table."""
     P = 1
-    while (2 * P <= min(_MAX_PAGES, n_pages)
-           and 2 * P * page <= _STEP_BYTES):
+    while 2 * P <= min(max_pages, n_pages) and 2 * P * page <= step_bytes:
         P *= 2
     return P
 
@@ -415,85 +428,214 @@ def _paged_attn_pallas(q, k_pages, v_pages, block_tables, context_lens,
     return out.reshape(B, H, D)
 
 
-# pages a grid step of the grouped kernel walks: one page of 2 K/V heads is
-# 16 KB, and a step that fetched one spent most of its time on its own
-# overhead and on products 16 tokens wide (1.73 ms a call at 64 lanes of
-# Nemotron-3-Nano's cell against 0.25 for the same bytes; PERF.md, PR 31)
-_GROUPED_PAGES = 16
+# pages a grid step of the grouped kernel holds: its buffers (2 pools x 2
+# slots x P pages) within 4 MiB and P within 32. Least ms a call of P = 4 /
+# 8 / 16 / 32 at float32, page 16, 64 lanes of 32 query heads (PERF.md
+# section 5, PR 36's chain): 2.563 / 1.468 / 1.143 / 1.092 at a folded width
+# of 512 and a table of 320 (contexts 734-4,863), 1.117 / 0.632 / 0.488 /
+# 0.440 at 512 and a ring of 64 pages, 0.557 / 0.337 / 0.283 / 0.294 at 256
+# and a table of 128 (contexts 99-1,332: a step of 512 tokens is half dead
+# there); past 32 not measured. Page copies written out a turn of the
+# kernel's copy loop: 1 / 4 / 8 / 16 / all 32 took 1.235 / 1.115 / 1.092 /
+# 1.080 / 1.045 ms at the first shape, and all 32 cost `mellum2`'s cell 48 s
+# of warm set-up (its decode programs' trace and lowering)
+_GROUPED_STEP_BYTES = 4 << 20
+_GROUPED_MAX_PAGES = 32
+_COPIES_UNROLLED = 8
 
 
-def _paged_attn_grouped_kernel(bt_ref, cl_ref, live_ref, q_ref, *refs,
-                               page_size, scale, D, G, P):
-    """Grouped K/V heads. Grid (B, live page groups); a step holds P
-    folded pages [page, Hkv * D] of K and of V (`refs`: P K blocks, P V
-    blocks, the output, three scratches) and ALL the query heads of the
-    sequence, q_ref [H, D] with H = Hkv * G: query heads h*G .. h*G+G-1
-    read K/V head h, the lanes [h*D, (h+1)*D) of a page. G query heads
-    against P pages of one K/V head are a [G, D] x [D, P * page] product,
-    so the scores and the weighted sum run on the MXU (at `highest`:
-    float32 pools are served as float32), and the softmax state is a row
-    a query head (`m_ref`, `l_ref` [H, 128], every lane the same)."""
+def grouped_pages_per_step(width: int, page_size: int, itemsize: int,
+                           n_pages: int) -> int:
+    """P of the grouped kernel, as `pages_per_step` is the full-heads
+    kernel's: from the folded width `Hkv*D`, the page size, the pool's
+    bytes an element and the table's width. A step pays its own cost (the
+    packed q tile, the softmax state's update, a transpose of the scores)
+    once for P pages, and a lane's last step computes up to P - 1 pages
+    past its context: P doubles while the two slots of both pools stay
+    within `_GROUPED_STEP_BYTES` and P within `_GROUPED_MAX_PAGES` and the
+    table (`tests/test_kernel_blocks.py` pins the picks at the cells'
+    shapes)."""
+    return _pages_that_fit(2 * 2 * page_size * width * itemsize, n_pages,
+                           _GROUPED_STEP_BYTES, _GROUPED_MAX_PAGES)
+
+
+def _bf16_parts(x):
+    """x as float32 arrays that are each exactly a bfloat16 and sum to x
+    exactly: one for a bfloat16 input, three for float32 (the top 8
+    significant bits, the next 8, the last 8, by masks: no rounding, no
+    conversion). A product of two parts is exact in float32, so a matrix
+    product of parts in ONE pass of the MXU is exact up to its float32
+    accumulation."""
+    if x.dtype == jnp.bfloat16:
+        return (x.astype(jnp.float32),)
+    x = x.astype(jnp.float32)
+
+    def top(v):
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                            jnp.float32)
+    hi = top(x)
+    rest = x - hi
+    mid = top(rest)
+    return hi, mid, rest - mid
+
+
+def _one_pass(a, b, contract):
+    """One pass of the MXU over operands that hold bfloat16 values."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=jax.lax.Precision.DEFAULT,
+                               preferred_element_type=jnp.float32)
+
+
+def _sum_row_blocks(x, n: int):
+    """x [n * r, c] -> [r, c], the sum of its n blocks of r rows."""
+    r = x.shape[0] // n
+    out = x[:r]
+    for i in range(1, n):
+        out = out + x[i * r:(i + 1) * r]
+    return out
+
+
+def _paged_attn_grouped_kernel(bt_ref, cl_ref, lane_ref, group_ref, n_ref,
+                               q_ref, k_hbm, v_hbm, o_ref, acc_ref, m_ref,
+                               l_ref, k_buf, v_buf, sem, *, page_size,
+                               n_pages, scale, D, G, P):
+    """Grouped K/V heads. Grid (items): `page_walk`'s list, as the
+    full-heads kernel walks it (item t is page group `group_ref[t]` of lane
+    `lane_ref[t]`, a lane's items consecutive, its softmax state in VMEM
+    scratch, a row a query head: `m_ref`, `l_ref` [H, 128], every lane the
+    same). q_ref holds ALL the query heads of the lane, [H, D] with
+    H = Hkv * G: query heads h*G .. h*G+G-1 read K/V head h, the lanes
+    [h*D, (h+1)*D) of a page.
+
+    The pools stay in HBM and the kernel fetches a step's P pages of K and
+    of V itself, one async copy a page into one of two slots (`k_buf`,
+    `v_buf` [2, P * page, Hkv * D]): item t + 1's copies start before item
+    t is computed. As 2P blocks of the pipeline the same pages cost 56 ns
+    of its bookkeeping each, serial with the compute (PERF.md section 6,
+    PR 36).
+
+    The products are float32's, made of bfloat16 parts (`_bf16_parts`):
+    every part of one operand against every part of the other, nine exact
+    partial products where `highest` keeps six, accumulated in float32.
+    They are arranged so that K and V cross the MXU once a part. Scores:
+    K's three parts stacked as ROWS stream against one latched tile that
+    holds q's parts side by side, head by head in their K/V head's lanes
+    (`q_rows`), the sum over K's parts is a sum of row blocks and the sum
+    over q's parts a sum of row blocks of the transpose [3H, span].
+    Weighted sum: p's parts stacked as rows stream against each latched
+    part of V."""
     from jax.experimental import pallas as pl
 
-    k_refs, v_refs = refs[:P], refs[P:2 * P]
-    o_ref, acc_ref, m_ref, l_ref = refs[2 * P:]
-    b = pl.program_id(0)
-    i = pl.program_id(1)
+    t = pl.program_id(0)
+    g = group_ref[t]
+    ctx = cl_ref[lane_ref[t]]
     span = P * page_size
+    W = k_buf.shape[-1]
+    H = (W // D) * G
+    slot = jax.lax.rem(t, 2)
 
-    @pl.when(i == 0)
+    def copies(item, slot):
+        """The 2P page copies of `item` into `slot`; None waits for them
+        (a wait needs the copy's shape and semaphore, not its source). A
+        loop of `_COPIES_UNROLLED` pages a turn: written out whole the
+        kernel's text, and with it the seconds a program that calls it
+        takes to trace and lower, grows with P (PERF.md section 6,
+        PR 36: a cell's warm `setup_s`); one page a turn leaves the
+        table's lookups nothing to overlap with."""
+        unrolled = min(P, _COPIES_UNROLLED)
+
+        def turn(n, carry):
+            for u in range(unrolled):
+                j = n * unrolled + u
+                page = 0 if item is None else bt_ref[
+                    lane_ref[item],
+                    jnp.minimum(group_ref[item] * P + j, n_pages - 1)]
+                rows = pl.ds(pl.multiple_of(j * page_size, page_size),
+                             page_size)
+                for i, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                                (v_hbm, v_buf))):
+                    copy = pltpu.make_async_copy(
+                        hbm.at[page], buf.at[slot, rows], sem.at[i, slot])
+                    copy.wait() if item is None else copy.start()
+            return carry
+        jax.lax.fori_loop(0, P // unrolled, turn, 0)
+
+    @pl.when(t == 0)
+    def _first():
+        copies(0, 0)
+
+    @pl.when(t + 1 < n_ref[0])
+    def _next():
+        copies(t + 1, 1 - slot)
+
+    @pl.when(g == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    ctx = cl_ref[b]
+    copies(None, slot)
 
-    @pl.when(i * span < ctx)
+    # false only in an idle lane's one item
+    @pl.when(g * span < ctx)
     def _compute():
-        for h in range(k_refs[0].shape[-1] // D):
+        q_parts = _bf16_parts(q_ref[...])                       # [H, D] each
+        row_head = jax.lax.broadcasted_iota(jnp.int32, (H, D), 0) // G
+        # row (part, query head) holds its part of q in the lanes of its
+        # K/V head and zeros elsewhere; whole lane tiles of rows
+        q_rows = jnp.concatenate(
+            [jnp.concatenate([jnp.where(row_head == h, x, 0.0)
+                              for h in range(W // D)], axis=1)
+             for x in q_parts], axis=0)
+        pad = -len(q_parts) * H % _LANE
+        if pad:
+            q_rows = jnp.concatenate(
+                [q_rows, jnp.zeros((pad, W), jnp.float32)], axis=0)
+        k_parts = _bf16_parts(k_buf[slot])
+        v_parts = _bf16_parts(v_buf[slot])
+        s = _one_pass(jnp.concatenate(k_parts, axis=0), q_rows,
+                      ((1,), (1,)))                     # [parts * span, R]
+        s = _sum_row_blocks(s, len(k_parts)).T                  # [R, span]
+        s = _sum_row_blocks(s[:len(q_parts) * H], len(q_parts)) * scale
+        pos = g * span + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        live = pos < ctx
+        s = jnp.where(live, s, _NEG)                            # [H, span]
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_ref[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        for h in range(W // D):
             rows, lanes = slice(h * G, (h + 1) * G), slice(h * D, (h + 1) * D)
-            q = q_ref[rows, :].astype(jnp.float32)              # [G, D]
-            k = jnp.concatenate([r[:, lanes] for r in k_refs],
-                                axis=0).astype(jnp.float32)     # [span, D]
-            v = jnp.concatenate([r[:, lanes] for r in v_refs],
-                                axis=0).astype(jnp.float32)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32) * scale     # [G, span]
-            pos = i * span + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            live = pos < ctx
-            s = jnp.where(live, s, _NEG)
-            m_prev = m_ref[rows, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.where(live, jnp.exp(s - m_new), 0.0)
-            corr = jnp.exp(m_prev - m_new)
-            l_new = l_ref[rows, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
-            acc_ref[rows, :] = acc_ref[rows, :] * corr + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32)
-            m_ref[rows, :] = jnp.broadcast_to(m_new, (G, m_ref.shape[1]))
-            l_ref[rows, :] = jnp.broadcast_to(l_new, (G, l_ref.shape[1]))
+            p_parts = _bf16_parts(p[rows])
+            p_rows = jnp.concatenate(p_parts, axis=0)           # [3G, span]
+            pv = _one_pass(p_rows, v_parts[0][:, lanes], ((1,), (0,)))
+            for v in v_parts[1:]:
+                pv = pv + _one_pass(p_rows, v[:, lanes], ((1,), (0,)))
+            acc_ref[rows, :] = (acc_ref[rows, :] * corr[rows]
+                                + _sum_row_blocks(pv, len(p_parts)))
 
-    @pl.when(i == live_ref[0] - 1)
+    @pl.when(g == jnp.maximum(pl.cdiv(ctx, span), 1) - 1)
     def _finalize():
         # ctx == 0 (idle slot): acc and l still zero -> exactly zero
         o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
                       ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "pages", "interpret"))
 def _paged_attn_grouped_pallas(q, k_pages, v_pages, block_tables,
-                               context_lens, scale, interpret=False):
+                               context_lens, scale, pages, interpret=False):
     """q [B, H, D] over pools [num_pages, page_size, Hkv * D], H a
-    multiple of Hkv. The page axis of the grid stops at the LONGEST
-    context's last page group (a traced bound, as megablox's tile count
-    is): pages past it hold nothing for any sequence. A step's P pages
-    are P blocks of the same pool, each with its own row of the block
-    table (a slot past the table's end reads its last entry, and is
-    masked by its position)."""
+    multiple of Hkv; `pages` pages of K and of V to a grid step. The grid
+    is `page_walk`'s list of (lane, live page group) items, its bound
+    their number, traced: a page group without a live token is no grid
+    step, whatever the call's longest context. The pools are handed over
+    where they lie (`pl.ANY`) and the kernel copies its pages itself, each
+    by its own entry of the block table (a slot past the table's end reads
+    its last entry, and is masked by its position)."""
     from jax.experimental import pallas as pl
 
     B, H, D = q.shape
@@ -501,43 +643,38 @@ def _paged_attn_grouped_pallas(q, k_pages, v_pages, block_tables,
     page_size, width = k_pages.shape[1:]
     n_pages = block_tables.shape[1]
     G = H // (width // D)
-    P = min(_GROUPED_PAGES, n_pages)
-    span = P * page_size
-    n_live = jnp.clip(-(-jnp.max(context_lens) // span), 1,
-                      -(-n_pages // P)).astype(jnp.int32)
-
-    def page(j):
-        return pl.BlockSpec(
-            (None, page_size, width),
-            lambda b, i, bt, cl, nl: (
-                bt[b, jnp.minimum(i * P + j, n_pages - 1)], 0, 0))
-
-    qspec = pl.BlockSpec((None, H, D), lambda b, i, bt, cl, nl: (b, 0, 0))
-    pages = [page(j) for j in range(P)]
+    P = pages
+    lane, group, n_items = page_walk(context_lens, P * page_size,
+                                     pl.cdiv(n_pages, P))
+    qspec = pl.BlockSpec((None, H, D),
+                         lambda t, bt, cl, ln, gr, n: (ln[t], 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    slots = pltpu.VMEM((2, P * page_size, width), k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, n_live),
-        in_specs=[qspec] + pages + pages,
+        num_scalar_prefetch=5,
+        grid=(n_items[0],),
+        in_specs=[qspec, pool, pool],
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((H, D), jnp.float32),
                         pltpu.VMEM((H, _LANE), jnp.float32),
-                        pltpu.VMEM((H, _LANE), jnp.float32)],
+                        pltpu.VMEM((H, _LANE), jnp.float32),
+                        slots, slots, pltpu.SemaphoreType.DMA((2, 2))],
     )
+    # the items carry a lane's softmax state and the next item's copies
     params = None if interpret else pltpu.CompilerParams(
-        dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY))
+        dimension_semantics=(pltpu.ARBITRARY,))
     return pl.pallas_call(
         functools.partial(_paged_attn_grouped_kernel, page_size=page_size,
-                          scale=scale, D=D, G=G, P=P),
+                          n_pages=n_pages, scale=scale, D=D, G=G, P=P),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         compiler_params=params,
         interpret=interpret,
-    )(block_tables, context_lens, n_live[None], q,
-      *([k_pages] * P), *([v_pages] * P))
+    )(block_tables, context_lens, lane, group, n_items, q, k_pages, v_pages)
 
 
 def _check_compiles_grouped(dtype, H: int, Hkv: int, D: int, page_size: int,
-                            n_pages: int):
+                            n_pages: int, pages: int):
     """Eager compile check of the grouped kernel (`tiling.compile_check`)."""
     def run():
         q = jnp.ones((2, H, D), dtype)
@@ -545,13 +682,13 @@ def _check_compiles_grouped(dtype, H: int, Hkv: int, D: int, page_size: int,
         bt = jnp.zeros((2, n_pages), jnp.int32)
         cl = jnp.full((2,), page_size, jnp.int32)
         return _paged_attn_grouped_pallas(q, kp, kp, bt, cl,
-                                          float(1.0 / np.sqrt(D)),
+                                          float(1.0 / np.sqrt(D)), pages,
                                           interpret=_INTERPRET)
 
     _tiling.compile_check(
         "paged_attn_grouped", run, dtype=jnp.dtype(dtype).name, heads=H,
         kv_heads=Hkv, head_dim=D, page_size=page_size, pages_per_seq=n_pages,
-        interpret=_INTERPRET)
+        pages_per_step=pages, interpret=_INTERPRET)
 
 
 def _check_compiles(dtype, H: int, D: int, page_size: int, n_pages: int,
@@ -587,7 +724,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     Dispatch mirrors `flash_attention`: an eligible call takes the Pallas
     page walk, all heads to a program, after one eager compile check that
     raises (grouped K/V heads: the grouped kernel, all query heads of a
-    sequence to a program); anything else (off the TPU without interpret
+    sequence to a grid step); anything else (off the TPU without interpret
     mode, fp16, a head size off the lane groups) takes the XLA gather. Safe to call at
     trace time of an outer jit (the check runs eagerly at trace, like
     every kernel in this package)."""
@@ -605,11 +742,13 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
             _INTERPRET or (D % _LANE == 0 and (H // Hkv) % 8 == 0)):
         # grouped K/V heads: a K/V head is a whole number of lane tiles
         # of the folded page and its query heads whole sublane tiles of q
-        _check_compiles_grouped(q.dtype, H, Hkv, D, page_size, n_pages)
+        pages = grouped_pages_per_step(Hkv * D, page_size, q.dtype.itemsize,
+                                       n_pages)
+        _check_compiles_grouped(q.dtype, H, Hkv, D, page_size, n_pages, pages)
         _stats["pallas"] += 1
         _stats["grouped"] += 1
         return _paged_attn_grouped_pallas(q, k_pages, v_pages, block_tables,
-                                          context_lens, float(scale),
+                                          context_lens, float(scale), pages,
                                           interpret=_INTERPRET)
     # a head is a whole number of lane tiles or a whole fraction of one
     # (`_lane_groups`)

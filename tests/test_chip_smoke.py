@@ -24,6 +24,8 @@ TINY_KERNEL_SHAPES = {
     "flash": dict(B=1, L=128, H=2, D=64),
     "layer_norm": dict(R=256, N=128),
     "paged": dict(B=2, H=4, D=64, page_size=8, pages_per_seq=3),
+    "paged_grouped": [dict(B=4, H=8, Hkv=2, D=64, page_size=8,
+                           pages_per_seq=5)],
     "bn": [(264, 128)],            # 264 = one block + a masked tail
     "conv_bn": [(264, 128, 256)],
 }
@@ -66,7 +68,7 @@ def test_phases_pass_at_tiny_size(interpreted):
     assert report["ok"] and not failed, failed
     phases = report["phases"]
     assert phases["kernels"]["compiled_by"] == "interpreter"
-    assert phases["kernels"]["checks"] == 18
+    assert phases["kernels"]["checks"] == 19
     # the train step dispatched the Pallas attention kernel, not XLA's
     assert phases["train"]["paths"]["flash_attention"]["pallas"] >= 1
     # the decode step reached paged attention, with the reason when XLA

@@ -195,9 +195,26 @@ def _paged_pick(monkeypatch, H, D, page_size, n_pages):
     return pick
 
 
+def _grouped_pick(monkeypatch, H, Hkv, D, page_size, n_pages):
+    """Pages a grid step the dispatch hands the grouped kernel (query
+    heads on fewer K/V heads), or "xla"; kernel and check stubbed."""
+    seen = []
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    monkeypatch.setattr(pa, "_check_compiles_grouped", lambda *a: None)
+    monkeypatch.setattr(pa, "_paged_attn_grouped_pallas",
+                        lambda q, *a, **kw: seen.append(a[-1]) or q)
+    q = jnp.ones((1, H, D), jnp.float32)
+    pool = jnp.ones((2, page_size, Hkv * D), jnp.float32)
+    pa.paged_attention(q, pool, pool, jnp.zeros((1, n_pages), jnp.int32),
+                       jnp.asarray([3], jnp.int32))
+    (pick,) = seen or ["xla"]
+    return pick
+
+
 _PICK = {
     "flash": _flash_pick,
     "paged_attn": _paged_pick,
+    "paged_attn_grouped": _grouped_pick,
     # None: the shape stays on XLA (the unfused composition for conv_bn)
     "layer_norm": lambda mp, R, N: ln._block_rows_for(R, N),
     "softmax_ce": lambda mp, N, V: sce._static_blocks(N, V),
@@ -254,6 +271,7 @@ _CELL_PICKS = [
     ("paged_attn", (30, 128, 16, 128), (30, 2)),
     # nemo3n_serve_closed64: 32 held experts of 2688 x 1856, top-6; the 64
     # lanes' 384 assignments and a prefill bucket's
+    ("paged_attn_grouped", (32, 2, 128, 16, 128), 32),
     ("moe_tiles", (384, 2688, 1856), (32, 2688, 128)),
     ("moe_tiles", (384, 1856, 2688), (32, 512, 896)),
     ("moe_tiles", (6 * 512, 2688, 1856), (64, 2688, 128)),
@@ -262,6 +280,9 @@ _CELL_PICKS = [
     # prefill buckets to 4096 and max_len 5120 through the forward-only
     # flash path; 32 held SwiGLU experts of 2304 x 896 (gate and up
     # stacked to 1792), top-8
+    # (its full layers' table of 320 slots, its sliding layers' ring of 64)
+    ("paged_attn_grouped", (32, 4, 128, 16, 320), 32),
+    ("paged_attn_grouped", (32, 4, 128, 16, 64), 32),
     ("flash_forward", (64,), (64, 64)),
     ("flash_forward", (256,), (256, 256)),
     ("flash_forward", (1024,), (256, 512)),
@@ -274,6 +295,9 @@ _CELL_PICKS = [
     ("moe_tiles", (8 * 4096, 896, 2304), (64, 896, 768)),
     # no cell: a head size off the lane groups takes the XLA gather
     ("paged_attn", (8, 80, 16, 8), "xla"),
+    # no cell: a table under the pick's bound, and pools twice as wide
+    ("paged_attn_grouped", (32, 4, 128, 16, 8), 8),
+    ("paged_attn_grouped", (64, 8, 128, 16, 320), 16),
     # no cell yet (ROADMAP D4): ResNet-50 b128 NHWC bottleneck stages,
     # [N*H*W, C] and the 1x1 convs (R, Cin, Cout)
     ("fused_bn", (128 * 28 * 28, 512), 256),
@@ -296,6 +320,20 @@ def _case_id(case):
                          ids=[_case_id(c) for c in _CELL_PICKS])
 def test_pick_at_cell_shape(family, shape, pick, monkeypatch):
     assert _PICK[family](monkeypatch, *shape) == pick
+
+
+@pytest.mark.parametrize("H,D,n_pages", [(12, 64, 64), (16, 128, 128),
+                                         (4, 128, 128), (30, 128, 128)])
+def test_a_full_heads_call_keeps_its_kernel_counters_and_pick(
+        H, D, n_pages, monkeypatch):
+    """The grouped kernel's own pick (PR 36) moves nothing for a pool that
+    holds every query head: the call counts under "folded", never under
+    "grouped", and its pages a step are `pages_per_step`'s."""
+    before = dict(pa._stats)
+    pick = _paged_pick(monkeypatch, H, D, 16, n_pages)
+    assert pick == (H, pa.pages_per_step(H * D, 16, 4, n_pages))
+    moved = {k: pa._stats[k] - before[k] for k in before}
+    assert moved == {**dict.fromkeys(before, 0), "pallas": 1, "folded": 1}
 
 
 # --------------- the picks run, under the Pallas interpreter -----------------
@@ -360,6 +398,23 @@ def _run_paged(pick, D, page_size, n_pages):
                                rtol=0, atol=5e-6)
 
 
+def _run_grouped(pages, G, Hkv, page_size, n_pages):
+    """The grouped kernel at `pages` a grid step, `G` query heads on each
+    of `Hkv` K/V heads of 128; two sequences."""
+    rng = np.random.default_rng(pages + G)
+    D, P = 128, 2 * n_pages + 1
+    q = _randn(rng, (2, Hkv * G, D))
+    kp, vp = (_randn(rng, (P, page_size, Hkv * D)) for _ in range(2))
+    bt = jnp.asarray(rng.integers(1, P, (2, n_pages)).astype(np.int32))
+    cl = jnp.asarray([n_pages * page_size - 3, page_size + 1], jnp.int32)
+    sc = float(1.0 / np.sqrt(D))
+    out = pa._paged_attn_grouped_pallas(q, kp, vp, bt, cl, sc, pages,
+                                        interpret=True)
+    ref = pa._paged_attention_grouped_xla(q, kp, vp, bt, cl, sc)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=0, atol=5e-6)
+
+
 # the rows of `_CELL_PICKS` with L <= 512 whose blocks differ, batch and
 # heads cut to 2
 _RUN = [
@@ -377,6 +432,10 @@ _RUN = [
     ("paged_attn", _run_paged, ((2, 4), 128, 16, 11)),
     ("paged_attn", _run_paged, ((2, 8), 128, 16, 11)),
     ("paged_attn", _run_paged, ((2, 2), 128, 16, 11)),
+    # the grouped pick at both cells' head groupings (8 and 16 query
+    # heads a K/V head), over a table it does not divide
+    ("paged_attn_grouped", _run_grouped, (32, 8, 2, 16, 40)),
+    ("paged_attn_grouped", _run_grouped, (32, 16, 1, 16, 40)),
 ]
 
 

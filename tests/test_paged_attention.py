@@ -248,6 +248,117 @@ class TestPageWalk:
             assert live == walked == int(n_items[0])
 
 
+def _grouped_case(Hkv, G, n, P, ctx, D=128, S=8, seed=0):
+    """q over pools of `Hkv` K/V heads and a table of `n` slots, lane b
+    owning pages 2 + b * n onward; every slot past a lane's last live
+    GROUP of `P` pages points at a page of NaN."""
+    rng = np.random.default_rng(seed + 1000 * Hkv + 10 * G + P)
+    ctx = np.asarray(ctx, np.int32)
+    B, nan_page = len(ctx), 1
+    pages = 2 + B * n
+    q = rng.normal(size=(B, Hkv * G, D)).astype(np.float32)
+    kp = rng.normal(size=(pages, S, Hkv * D)).astype(np.float32)
+    vp = rng.normal(size=(pages, S, Hkv * D)).astype(np.float32)
+    kp[nan_page] = vp[nan_page] = np.nan
+    bt = 2 + np.arange(B * n, dtype=np.int32).reshape(B, n)
+    for b in range(B):
+        bt[b, -(-int(ctx[b]) // (P * S)) * P:] = nan_page
+    return (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(bt), jnp.asarray(ctx), nan_page)
+
+
+def _ragged(span, full):
+    """Contexts that hold every edge of the walk in one call: idle lanes
+    first, last and between live ones, one token, one under / at / one
+    over a group's edge, the table's full length."""
+    return [min(c, full) for c in (0, 1, span - 1, 0, span, span + 1, full,
+                                   0)]
+
+
+class TestGroupedWalk:
+    """The grouped kernel's walk (PR 36): `page_walk`'s list of (lane,
+    live page group) items, P pages a grid step fetched by the kernel's
+    own copies, against the XLA gather."""
+
+    # (Hkv, G, table slots, P); pages of 8 tokens
+    SHAPES = [(4, 8, 19, 4), (4, 8, 16, 8), (2, 16, 19, 2), (2, 16, 12, 16),
+              (1, 8, 9, 1), (2, 2, 8, 4)]
+
+    @pytest.mark.parametrize("traffic", ["ragged", "ring"])
+    @pytest.mark.parametrize("Hkv,G,n,P", SHAPES)
+    def test_walk_matches_the_gather_and_reads_no_dead_group(
+            self, Hkv, G, n, P, traffic):
+        S = 8
+        ctx = _ragged(P * S, n * S) if traffic == "ragged" else [n * S] * 4
+        q, kp, vp, bt, cl, nan_page = _grouped_case(Hkv, G, n, P, ctx)
+        scale = float(1 / np.sqrt(q.shape[-1]))
+        out = np.asarray(pa._paged_attn_grouped_pallas(
+            q, kp, vp, bt, cl, scale, P, interpret=True))
+        assert np.all(np.isfinite(out)), \
+            "a page past a lane's last live group reached the sum"
+        for b, c in enumerate(ctx):
+            if c == 0:                          # an idle lane: exact zeros
+                assert np.all(out[b] == 0.0), b
+        # the gather reads every slot of the table: give it zeros there
+        ref = pa._paged_attention_grouped_xla(
+            q, kp.at[nan_page].set(0.0), vp.at[nan_page].set(0.0), bt, cl,
+            scale)
+        np.testing.assert_allclose(out, np.asarray(ref), rtol=0, atol=1e-5)
+
+    @pytest.mark.parametrize("Hkv,G,n,P", SHAPES)
+    def test_grid_steps_are_the_counted_groups(self, Hkv, G, n, P,
+                                               monkeypatch):
+        """The grid's bound IS `page_walk`'s item count over the call's
+        own lengths, which `page_group_counts` (the engine's counter)
+        gives as `walked`: run un-jitted, the list is concrete."""
+        S = 8
+        ctx = _ragged(P * S, n * S)
+        q, kp, vp, bt, cl, _ = _grouped_case(Hkv, G, n, P, ctx)
+        seen = []
+        walk = pa.page_walk
+
+        def spy(context_lens, span, n_groups):
+            out = walk(context_lens, span, n_groups)
+            seen.append((span, n_groups, int(out[2][0])))
+            return out
+
+        monkeypatch.setattr(pa, "page_walk", spy)
+        pa._paged_attn_grouped_pallas.__wrapped__(
+            q, kp, vp, bt, cl, 0.1, P, interpret=True)
+        (span, n_groups, items), = seen
+        assert (span, n_groups) == (P * S, -(-n // P))
+        live, walked = pa.page_group_counts(np.asarray(ctx), span)
+        assert items == walked == live + sum(c == 0 for c in ctx)
+
+    def test_bfloat16_pools_take_one_part(self):
+        q, kp, vp, bt, cl, nan_page = _grouped_case(
+            2, 8, 12, 4, _ragged(32, 96))
+        bf = lambda x: x.astype(jnp.bfloat16)  # noqa: E731
+        out = pa._paged_attn_grouped_pallas(bf(q), bf(kp), bf(vp), bt, cl,
+                                            0.1, 4, interpret=True)
+        assert out.dtype == jnp.bfloat16
+        ref = pa._paged_attention_grouped_xla(
+            bf(q), bf(kp).at[nan_page].set(0.0), bf(vp).at[nan_page].set(0.0),
+            bt, cl, 0.1)
+        np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)),
+                                   np.asarray(ref.astype(jnp.float32)),
+                                   rtol=0, atol=1e-2)
+
+    @pytest.mark.parametrize("x", [1.0, -3.1415927, 1e-30, 65504.1, 0.0,
+                                   1.0000001, -2.9999998])
+    def test_the_parts_are_bfloat16_and_sum_to_the_value(self, x):
+        parts = pa._bf16_parts(jnp.full((8, 128), x, jnp.float32))
+        assert len(parts) == 3
+        for part in parts:
+            assert part.dtype == jnp.float32
+            np.testing.assert_array_equal(
+                np.asarray(part),
+                np.asarray(part.astype(jnp.bfloat16).astype(jnp.float32)))
+        total = np.asarray(parts[0], np.float64) + np.asarray(
+            parts[1], np.float64) + np.asarray(parts[2], np.float64)
+        np.testing.assert_array_equal(total, np.float64(np.float32(x)))
+
+
 def _np_pages(P, S, HD):
     return np.zeros((P, S, HD), np.float32)
 
@@ -832,6 +943,10 @@ class TestMellumShapesCompileForTheChip:
         ring = sds((1 + self.B * self.W // self.PAGE, self.PAGE, width))
         scale = float(1 / np.sqrt(self.D))
 
+        def pick(table):
+            return pa.grouped_pages_per_step(width, self.PAGE, 4,
+                                             table.shape[1])
+
         def decode(q, k, v, kp, vp, rk, rv, bt, cl, slots, active):
             c = self._cache(kp, vp, rk, rv, bt, cl)
             ctx = jnp.take(cl, slots, mode="clip")
@@ -841,10 +956,12 @@ class TestMellumShapesCompileForTheChip:
                                      active)
             a = pa._paged_attn_grouped_pallas(
                 q, rk, rv, table,
-                jnp.where(active, jnp.minimum(ctx + 1, self.W), 0), scale)
+                jnp.where(active, jnp.minimum(ctx + 1, self.W), 0), scale,
+                pick(table))
             kp, vp = pa._append_impl(kp, vp, k, v, rows, ctx, active)
             b = pa._paged_attn_grouped_pallas(
-                q, kp, vp, rows, jnp.where(active, ctx + 1, 0), scale)
+                q, kp, vp, rows, jnp.where(active, ctx + 1, 0), scale,
+                pick(rows))
             return a + b, kp, vp, rk, rv
 
         def prefill(k, v, kp, vp, rk, rv, bt, cl, slot, length):
@@ -869,7 +986,73 @@ class TestMellumShapesCompileForTheChip:
         for program in compiled:
             rep = pool_relayout_report(program, [pool, ring])
             assert rep["pool_relayout_copies"] == 0, rep
-            assert rep["temp_size_in_bytes"] < 1e6, rep
+            # the two work lists' reductions hold 2.2 MB (PR 36; 0.2 before)
+            assert rep["temp_size_in_bytes"] < 4e6, rep
+
+    def test_each_table_has_one_work_list_a_step(self, v5e_chip,
+                                                 monkeypatch):
+        """Every full layer's call computes `page_walk` from the same
+        lengths and every sliding layer's from `min(context + 1, window)`:
+        a decode step of two layers of each kind holds the lists'
+        reductions (the cumulative sum, the count of lanes behind an item)
+        as often as a step of one of each, and no copy of either pool."""
+        import re
+        from collections import Counter
+        from paddle_tpu.analysis import pool_relayout_report
+        from paddle_tpu.models import decode_blocks as blocks
+
+        def sds(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+        width = self.HKV * self.D
+        pool = sds((self.POOL, self.PAGE, width))
+        ring = sds((1 + self.B * self.W // self.PAGE, self.PAGE, width))
+        # the dispatch the models' layers call, as on the chip
+        monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+        monkeypatch.setattr(pa, "_check_compiles_grouped", lambda *a: None)
+
+        def step(layers):
+            def run(q, k, v, pools, rings, bt, cl, slots, active):
+                from paddle_tpu.models.decode_cache import (KV, KV_WINDOW,
+                                                            PagedKVCache)
+                kp, vp = ([p[0] for p in pools], [p[1] for p in pools])
+                rk, rv = ([r[0] for r in rings], [r[1] for r in rings])
+                c = PagedKVCache(
+                    kp, vp, bt, cl, self.PAGE, self.H, self.D,
+                    layer_kinds=[KV, KV_WINDOW] * layers,
+                    num_kv_heads=self.HKV, window_k=rk, window_v=rv,
+                    window=self.W)
+                ctx = jnp.take(cl, slots, mode="clip")
+                rows = jnp.take(bt, slots, axis=0, mode="clip")
+                for i in range(layers):
+                    q = q + blocks.paged_decode_attention(c, i, q, k, v, rows,
+                                                          ctx, active)
+                    q = q + blocks.ring_decode_attention(c, i, q, k, v, slots,
+                                                         ctx, active)
+                return (q, list(zip(c.k_pages, c.v_pages)),
+                        list(zip(c.window_k, c.window_v)))
+            rows = sds((self.B, width))
+            return jax.jit(run, donate_argnums=(3, 4)).lower(
+                sds((self.B, self.H, self.D)), rows, rows,
+                [(pool, pool)] * layers, [(ring, ring)] * layers,
+                sds((self.B, self.PER_SEQ), jnp.int32),
+                sds((self.B,), jnp.int32), sds((self.B,), jnp.int32),
+                sds((self.B,), jnp.bool_)).compile()
+
+        def ops(compiled):
+            return Counter(re.findall(r" = \S+ ([a-z\-]+)\(",
+                                      compiled.as_text()))
+
+        before = pa._stats["grouped"]
+        one, two = step(1), step(2)
+        assert pa._stats["grouped"] == before + 6
+        n1, n2 = ops(one), ops(two)
+        assert n1["custom-call"] >= 2 and \
+            n2["custom-call"] - n1["custom-call"] >= 2      # the kernels
+        assert n1["reduce-window"] + n1["reduce"] >= 4, n1  # two lists
+        for op in ("reduce-window", "reduce", "iota", "sort"):
+            assert n2[op] == n1[op], (op, n1[op], n2[op])
+        rep = pool_relayout_report(two, [pool, ring])
+        assert rep["pool_relayout_copies"] == 0, rep
 
     @pytest.mark.parametrize("window", [1024, None], ids=["band", "causal"])
     @pytest.mark.parametrize("L", [64, 4096])

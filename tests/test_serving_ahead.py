@@ -28,6 +28,7 @@ from paddle_tpu.inference.disagg import DisaggPipeline
 from paddle_tpu.inference.sampling import SamplingParams
 from paddle_tpu.inference.serving import ServingEngine
 from paddle_tpu.models.gpt import GPT, GPTConfig
+from paddle_tpu.models.mellum import Mellum, MellumConfig
 from paddle_tpu.models.nemotron_h import NemotronH, NemotronHConfig
 from paddle_tpu.models.olmo_hybrid import OlmoHybrid, OlmoHybridConfig
 
@@ -59,6 +60,8 @@ def model(kind: str):
                               dropout=0.0, attn_dropout=0.0))
         elif kind == "olmo_hybrid":
             m = OlmoHybrid(OlmoHybridConfig.tiny(1))
+        elif kind == "mellum":
+            m = Mellum(MellumConfig.tiny())
         else:
             m = NemotronH(NemotronHConfig.tiny("MEM*E"))
         m.eval()
@@ -190,21 +193,25 @@ def test_counters_follow_the_dispatches_and_a_last_token_is_read_at_once():
     eng.close()
 
 
-@pytest.mark.parametrize("kind", ["gpt", "olmo_hybrid", "nemotron_h"])
+@pytest.mark.parametrize("kind", ["gpt", "olmo_hybrid", "nemotron_h",
+                                  "mellum"])
 def test_page_walk_counters_follow_the_dispatched_contexts(kind):
-    """`page_groups_live` / `page_groups_walked` (PR 34): what one layer's
-    full-heads paged-attention walk visits, summed over dispatches from
-    the host's own lengths; a padding lane is one idle step; an engine
-    whose attention takes the grouped kernel counts nothing."""
+    """`page_groups_live` / `page_groups_walked` (PR 34): what one paged
+    layer's paged-attention walk visits, summed over dispatches from the
+    host's own lengths; a padding lane is one idle step; an engine whose
+    attention takes the grouped kernel counts at that kernel's pick
+    (PR 36)."""
     from paddle_tpu.ops.pallas import paged_attention as pa
     eng = engine(kind, name=f"walk-{kind}", max_batch=4)
     c = eng.cache
-    if kind == "nemotron_h":
-        assert c.num_kv_heads != c.num_heads and eng._walk_span == 0
+    if kind in ("nemotron_h", "mellum"):    # the full layers' pages alone
+        assert c.num_kv_heads != c.num_heads
+        assert eng._walk_span == PAGE * pa.grouped_pages_per_step(
+            c.num_kv_heads * c.head_dim, PAGE, 4, c.pages_per_seq)
     else:
         assert eng._walk_span == PAGE * pa.pages_per_step(
             c.num_heads * c.head_dim, PAGE, 4, c.pages_per_seq)
-    span = eng._walk_span or PAGE
+    span = eng._walk_span
     want = {"live": 0, "walked": 0, "padding": 0}
     fused = eng._fused_jit
 
@@ -222,11 +229,8 @@ def test_page_walk_counters_follow_the_dispatched_contexts(kind):
     reqs = closed_loop(eng, traffic(7, seed=4, sampled=False), clients=3)
     assert all(r.state == "done" for r in reqs) and want["padding"] > 0
     got = (eng.stats["page_groups_live"], eng.stats["page_groups_walked"])
-    if kind == "nemotron_h":
-        assert got == (0, 0)
-    else:
-        assert got == (want["live"], want["walked"])
-        assert got[1] == got[0] + want["padding"]
+    assert got == (want["live"], want["walked"]) and got[0] > 0
+    assert got[1] == got[0] + want["padding"]
     assert {"page_groups_live", "page_groups_walked"} <= set(
         eng.status()["stats"])
     eng.close()

@@ -296,6 +296,14 @@ class DisaggPipeline:
                  max_worker_restarts: int = 3):
         import jax
 
+        if getattr(engine.model, "draft_tokens", 0):
+            from ..models.decode_cache import DraftingUnsupported
+            raise DraftingUnsupported(
+                "disaggregated prefill/decode (DisaggPipeline)",
+                "a hand-off of the standing draft and of the drafting "
+                "module's rows beside the K/V pages (a prefill worker "
+                "runs the main model alone)",
+                draft_tokens=int(engine.model.draft_tokens))
         if engine.cache.has_state:
             from ..models.decode_cache import StateLayersUnsupported
             d = engine.cache.describe()

@@ -313,8 +313,13 @@ class _PrefixCache:
     (`drop_page`) evicts its entries the moment the last holder frees
     it — the registry can never hand out a recycled page."""
 
-    def __init__(self, page_size: int):
+    def __init__(self, page_size: int, lookahead: int = 0):
         self.page_size = int(page_size)
+        # tokens PAST a page that its K/V depends on: a drafting model's
+        # MTP row i is made from the token at i + 1, so its page is the
+        # same only where that token is too (and a partial tail, whose
+        # last row depends on a token not sampled yet, is never shared)
+        self.lookahead = int(lookahead)
         self._full: Dict[Tuple[int, ...], int] = {}
         self._partial: Dict[Tuple[int, ...], int] = {}
         self._by_page: Dict[int, List[Tuple[str, Tuple[int, ...]]]] = {}
@@ -330,23 +335,23 @@ class _PrefixCache:
         self._by_page.setdefault(page, []).append((kind, key))
 
     def register(self, tokens: Sequence[int], pages: Sequence[int]):
-        ps = self.page_size
+        ps, ahead = self.page_size, self.lookahead
         tokens = tuple(int(t) for t in tokens)
-        for i in range(len(tokens) // ps):
-            self._put("full", tokens[:(i + 1) * ps], pages[i])
-        if len(tokens) % ps:
+        for i in range((len(tokens) - ahead) // ps):
+            self._put("full", tokens[:(i + 1) * ps + ahead], pages[i])
+        if len(tokens) % ps and not ahead:
             self._put("partial", tokens, pages[len(tokens) // ps])
 
     def lookup(self, tokens: Sequence[int]) -> Tuple[List[int], int]:
         """(shared_pages, shared_len): the longest registered chain
         covering a prefix of `tokens`. shared_len is page-aligned unless
         the exact-match partial tail joined (then == len(tokens))."""
-        ps = self.page_size
+        ps, ahead = self.page_size, self.lookahead
         tokens = tuple(int(t) for t in tokens)
         pages: List[int] = []
         n = 0
-        for i in range(len(tokens) // ps):
-            page = self._full.get(tokens[:(i + 1) * ps])
+        for i in range((len(tokens) - ahead) // ps):
+            page = self._full.get(tokens[:(i + 1) * ps + ahead])
             if page is None:
                 break
             pages.append(page)
@@ -398,9 +403,15 @@ class Request:
         self.slot: Optional[int] = None
         self.pages: List[int] = []
         self.shared_tokens = 0         # prefix tokens served from shared pages
-        # decode tokens dispatched for this request and not yet read back
-        # (0 or 1: the engine keeps at most one iteration in flight)
+        # the MOST decode tokens dispatched for this request and not yet
+        # read back: the engine keeps at most one iteration in flight, and
+        # an iteration yields one token, or up to two of a drafting model
         self.unread = 0
+        # of a drafting model: (k, token) for each draft the engine read,
+        # the model's guess at `generated[k]` made before that token was
+        # sampled (the prefill's, then the standing draft after each
+        # iteration); what a draft turned out to be changes no token
+        self.drafts: List[Tuple[int, int]] = []
         self._done = threading.Event()
 
     # -- latency accounting ---------------------------------------------------
@@ -592,6 +603,39 @@ class ServingEngine:
       request's own slot, both ahead, in the device's order, of any
       prefill, page copy or injection that reuses them.
 
+    A DRAFTING model (`model.draft_tokens` = 1: a multi-token-prediction
+    module of its own, `models/exaone_moe.py`; the model's configuration
+    is the only switch) is stepped by the same loop, and an iteration
+    yields ONE OR TWO tokens a lane. Each lane carries its last token
+    t_n (sampled, not yet through the model) and a standing draft of
+    t_{n+1}; the ONE decode program runs the model over both rows
+    (`forward_verify`), samples t*_{n+1} and t*_{n+2} (keys by the token's
+    index, as ever), accepts the draft where it EQUALS t*_{n+1}, so that
+    the tokens are those of plain decoding whatever the sampler, runs the
+    module over both rows for the next draft (`draft_decode`) and
+    advances the lane's length by 1 or 2 on the device
+    (`accept_drafts`). What changes in the loop: the program returns
+    int32 [W, 4] (t*_{n+1}, t*_{n+2}, how many are new, the standing
+    draft) and `_last_tokens` is [max_batch + 1, 3] (token, draft, how
+    many tokens the request has generated: the next sample's index);
+    `Request.unread` counts the MOST tokens unread (2 an iteration), so
+    `_may_run_ahead` holds a request that could end by length at two
+    tokens an iteration; the host's `_context_lens`, the
+    page-walk counters, `stats["draft_tokens"]` / `["accepted_tokens"]`
+    and `Request.drafts` are booked when the iteration is READ (between
+    dispatch and read the host knows a context to within one); `_read`
+    books up to two tokens in order and drops what follows an end of
+    sequence or the budget (`discarded_tokens`); `_ensure_capacity` owns
+    exclusively every page through the furthest row the undrained run
+    may write; the prefill program also runs the module over the prompt
+    (`draft_prefill`) and returns the first draft beside the first token;
+    the prefix cache shares a page only where the token AFTER it matches
+    too (the module's row i is made from token i + 1) and never a partial
+    tail. The `pt.engine.bookkeep` span then carries `seq` and `tokens`
+    (an annotation's arguments are fixed when it opens, and the fetch is
+    what learns the count). `disagg` and TP decode refuse such a model by
+    name (`DraftingUnsupported`).
+
     `share_prefix` (default True) admits requests whose prompt prefix
     is already resident (page-aligned prefix chains; exact-duplicate
     prompts additionally share the partial tail page) by FORKING the
@@ -611,6 +655,20 @@ class ServingEngine:
                              f"got {decode_mode!r}")
         model.eval()
         self.model = model
+        # tokens the model drafts a lane and iteration with a module of
+        # its own (0: today's one-token step, untouched)
+        self._drafts = int(getattr(model, "draft_tokens", 0) or 0)
+        if self._drafts > 1:
+            raise NotImplementedError(
+                f"a model that drafts {self._drafts} tokens an iteration: "
+                f"the verify step is written for one draft a lane")
+        if self._drafts and mesh is not None:
+            from ..models.decode_cache import DraftingUnsupported
+            raise DraftingUnsupported(
+                "tensor-parallel decode (ServingEngine(mesh=...))",
+                "sharding the drafting module's pool and the verify "
+                "step's two rows a lane over the TP axis",
+                draft_tokens=self._drafts)
         self.name = name
         self.max_batch = int(max_batch)
         self.max_len = int(max_len)
@@ -660,7 +718,7 @@ class ServingEngine:
                 self.cache = model.init_cache(max_batch, max_len,
                                               page_size=page_size,
                                               num_pages=capped)
-        self._prefix = _PrefixCache(page_size)
+        self._prefix = _PrefixCache(page_size, lookahead=self._drafts)
         self.allocator = PageAllocator(self.cache.num_pages,
                                        on_release=self._prefix.drop_page)
         self._reset_tables()
@@ -690,6 +748,8 @@ class ServingEngine:
         # -1 while the iteration that samples it is unread: the decode
         # program then takes it from `_last_tokens`, its own row
         self._cur_tokens = np.zeros((self.max_batch,), np.int32)
+        # of a drafting model: the standing draft that goes with it
+        self._cur_drafts = np.zeros((self.max_batch,), np.int32)
         # the one decode iteration dispatched and not read back yet:
         # (tokens on the device, the Request of each lane, lane bucket,
         # iteration number), or None. `_step_lock` keeps a drain asked
@@ -733,6 +793,7 @@ class ServingEngine:
                       "table_refreshes": 0, "h2d_transfers": 0,
                       "ahead_iterations": 0, "drained_for_length": 0,
                       "discarded_tokens": 0,
+                      "draft_tokens": 0, "accepted_tokens": 0,
                       "page_groups_live": 0, "page_groups_walked": 0,
                       "launches": 0, "read_wait_s": 0.0, "step_wall_s": 0.0,
                       "min_free_pages": self.allocator.free_pages}
@@ -740,7 +801,7 @@ class ServingEngine:
         self._walk_span = self._page_walk_span()
         desc = self.cache.describe()
         self.stats.update({k: desc[k] for k in (
-            "kv_layers", "window_layers", "state_layers",
+            "kv_layers", "draft_layers", "window_layers", "state_layers",
             "state_bytes_per_slot")})
         # request-scoped observability plane: lifecycle tracer, sliding-
         # window SLO tracker, and a bounded ring of per-iteration
@@ -790,8 +851,10 @@ class ServingEngine:
         self._context_lens = np.zeros((self.cache.max_batch,), np.int32)
         self._tables_dirty = False
         self._lens_dirty = False
-        self._last_tokens = self._put(
-            np.zeros((self.cache.max_batch + 1,), np.int32))
+        # a drafting model's row: (token, standing draft, tokens generated)
+        self._last_tokens = self._put(np.zeros(
+            (self.cache.max_batch + 1,) + (3,) * bool(self._drafts),
+            np.int32))
 
     def _put(self, host):
         """One host-to-device transfer of a NumPy array nobody writes to
@@ -870,6 +933,9 @@ class ServingEngine:
         the host. Entry `max_batch` takes the padding lanes' writes."""
         import jax.numpy as jnp
         from ..jit import _swapped_state
+        if self._drafts:
+            return self._verify_and_draft(params, buffers, cache,
+                                          last_tokens, lanes_i, lanes_f)
         tokens, slot_map, lane_active, top_k, seeds, steps = lanes_i
         lane_active = lane_active.astype(bool)
         temp, top_p = lanes_f
@@ -881,6 +947,56 @@ class ServingEngine:
         nxt = jnp.where(lane_active, nxt, 0)
         return nxt, cache, last_tokens.at[slot_map].set(nxt)
 
+    def _verify_and_draft(self, params, buffers, cache, last, lanes_i,
+                          lanes_f):
+        """A drafting model's decode iteration, traced as
+        `_fused_step_fn` (the program keeps that name): `lanes_i` int32
+        [7, W] carries the standing drafts as a seventh row, `last` int32
+        [max_batch + 1, 3] is each slot's (last token, standing draft,
+        tokens generated so far), fed wherever the host sent the token as -1.
+        Returns (int32 [W, 4]: the two tokens the main model samples, how
+        many of them are new (1, or 2 where the draft was the first), the
+        next standing draft; cache; `last` updated)."""
+        import jax
+        import jax.numpy as jnp
+        from ..jit import _swapped_state
+        tokens, slot_map, lane_active, top_k, seeds, steps, drafts = lanes_i
+        lane_active = lane_active.astype(bool)
+        temp, top_p = lanes_f
+        known = tokens >= 0
+        row = last[slot_map]
+        tokens = jnp.where(known, tokens, row[:, 0])
+        drafts = jnp.where(known, drafts, row[:, 1])
+        steps = jnp.where(known, steps, row[:, 2])
+        W = tokens.shape[0]
+        twice = lambda x: jnp.repeat(x, 2)   # noqa: E731  (a lane's 2 rows)
+        with tape_mod.no_grad(), _swapped_state(self.model, params, buffers):
+            logits, hid, cache = self.model.forward_verify(
+                Tensor(jnp.stack([tokens, drafts], axis=1)), cache,
+                lane_active, slot_map=slot_map)
+            with jax.named_scope("spec_verify"):
+                # token `steps` from the row of t_n, token `steps + 1`
+                # from the draft's row: what plain decoding samples there
+                # if the draft is what it sampled here
+                sampled = sample_logits(
+                    logits.data.reshape(2 * W, -1), twice(temp),
+                    twice(top_k), twice(top_p), twice(seeds),
+                    jnp.stack([steps, steps + 1], axis=1).reshape(-1)
+                ).reshape(W, 2)
+                accepted = lane_active & (drafts == sampled[:, 0])
+            guesses, cache = self.model.draft_decode(
+                hid, Tensor(sampled), cache, lane_active, slot_map=slot_map)
+            cache = self.model.accept_drafts(cache, accepted, lane_active,
+                                             slot_map=slot_map)
+        guesses = jnp.argmax(guesses.data, axis=-1).astype(jnp.int32)
+        pick = lambda x: jnp.where(accepted, x[:, 1], x[:, 0])  # noqa: E731
+        n_new = lane_active.astype(jnp.int32) + accepted
+        out = jnp.stack([sampled[:, 0], sampled[:, 1], n_new,
+                         pick(guesses)], axis=1)
+        out = jnp.where(lane_active[:, None], out, 0)
+        return out, cache, last.at[slot_map].set(
+            jnp.stack([pick(sampled), pick(guesses), steps + n_new], axis=1))
+
     def _prefill_fn(self, params, buffers, cache, ids, scalars, floats):
         """`scalars` int32 [6] is slot, length, write start, top-k, seed,
         step; `floats` float32 [2] temperature, top-p (`_prefill` packs
@@ -889,6 +1005,22 @@ class ServingEngine:
         slot, length, write_start = scalars[0], scalars[1], scalars[2]
         top_k, seed, step = scalars[3:4], scalars[4:5], scalars[5:6]
         temp, top_p = floats[0:1], floats[1:2]
+        if self._drafts:
+            # the first token, then the module over the prompt with it:
+            # int32 [2], the token and the first standing draft
+            import jax.numpy as jnp
+            with tape_mod.no_grad(), _swapped_state(self.model, params,
+                                                    buffers):
+                logits, cache, hid = self.model.forward_prefill(
+                    Tensor(ids), cache, slot, length,
+                    write_start=write_start, with_hidden=True)
+                nxt = sample_logits(logits.data, temp, top_k, top_p, seed,
+                                    step)
+                guess, cache = self.model.draft_prefill(
+                    hid, Tensor(ids), nxt, cache, slot, length,
+                    write_start=write_start)
+            return jnp.concatenate(
+                [nxt, jnp.argmax(guess.data, -1).astype(jnp.int32)]), cache
         with tape_mod.no_grad(), _swapped_state(self.model, params, buffers):
             logits, cache = self.model.forward_prefill(
                 Tensor(ids), cache, slot, length, write_start=write_start)
@@ -1380,7 +1512,8 @@ class ServingEngine:
                 requeued += 1
             leaked = self.allocator.outstanding()
             reserved = self.allocator.reserved_pages
-            self._prefix = _PrefixCache(self.page_size)
+            self._prefix = _PrefixCache(self.page_size,
+                                        lookahead=self._drafts)
             self.cache = self.model.init_cache(
                 self.max_batch, self.max_len, page_size=self.page_size,
                 num_pages=self.cache.num_pages)
@@ -1651,7 +1784,11 @@ class ServingEngine:
             self._prefix.register(tokens, pages)
         t0 = time.perf_counter()
         with _span("prefill.fetch", seq=seq):
-            tok = int(np.asarray(nxt)[0])
+            got = np.asarray(nxt)
+            tok = int(got[0])
+        if self._drafts:
+            self._cur_drafts[slot] = got[1]
+            req.drafts.append((len(req.generated) + 1, int(got[1])))
         self.stats["read_wait_s"] += time.perf_counter() - t0
         self.tracer.prefill_done(req.rid)
         now = time.monotonic()
@@ -1702,16 +1839,27 @@ class ServingEngine:
         copy-on-write — one donated dispatch copies the page across
         every layer's pools, the block table repoints, and the other
         sharers keep the original. Preempts the youngest request when
-        the pool is dry."""
+        the pool is dry. A drafting model's iteration writes TWO rows,
+        and where one is unread the host knows the first of them to
+        within one: every page from the nearest row it may write to the
+        furthest is grown and owned."""
         from ..ops.pallas import paged_attention as _pa
+        ps, per = self.page_size, 1 + self._drafts
         for slot in list(active_slots):
             req = self._slots[slot]
             if req is None:
                 continue
             # by tokens DISPATCHED: an unread iteration has written its
-            # position already
+            # position already (`unread` counts the most it may have)
             ctx = len(req.prompt) + len(req.generated) + req.unread
-            need = ctx // self.page_size + 1
+            # the rows this dispatch may write: from the one after the
+            # fewest tokens the unread iteration yields to the draft's
+            # after the most
+            first = ctx - 1 - req.unread + req.unread // per
+            last = ctx - 1 + self._drafts
+            # never past what `make_request` held the pool to
+            need = min((last + 1) // ps + 1,
+                       -(-(len(req.prompt) + req.max_new_tokens) // ps))
             dead = False
             while len(req.pages) < need:
                 page = self._alloc_one_or_preempt(req)
@@ -1723,24 +1871,25 @@ class ServingEngine:
                 self._tables_dirty = True
             if dead or self._slots[slot] is not req:
                 continue
-            # copy-on-write: the page receiving this iteration's K/V
-            # write (position ctx-1 = the token sampled last iteration)
-            write_idx = (ctx - 1) // self.page_size
-            if write_idx >= len(req.pages):
-                continue
-            old = req.pages[write_idx]
-            if not self.allocator.is_shared(old):
-                continue
-            fresh = self._alloc_one_or_preempt(req)
-            if fresh is None:
-                continue
-            self.cache.k_pages, self.cache.v_pages = _pa.cow_copy_pages(
-                self.cache.k_pages, self.cache.v_pages, old, fresh)
-            self._block_tables[slot, write_idx] = fresh
-            self._tables_dirty = True
-            req.pages[write_idx] = fresh
-            self.allocator.free([old])  # drop this holder's shared ref
-            self.stats["cow_copies"] += 1
+            # copy-on-write: the page(s) receiving this iteration's K/V
+            # write (without drafts: position ctx-1 = the token sampled
+            # last iteration)
+            for write_idx in range(first // ps, last // ps + 1):
+                if write_idx >= len(req.pages):
+                    break
+                old = req.pages[write_idx]
+                if not self.allocator.is_shared(old):
+                    continue
+                fresh = self._alloc_one_or_preempt(req)
+                if fresh is None:
+                    break
+                self.cache.k_pages, self.cache.v_pages = _pa.cow_copy_pages(
+                    self.cache.k_pages, self.cache.v_pages, old, fresh)
+                self._block_tables[slot, write_idx] = fresh
+                self._tables_dirty = True
+                req.pages[write_idx] = fresh
+                self.allocator.free([old])  # drop this holder's shared ref
+                self.stats["cow_copies"] += 1
 
     def _youngest_running(self) -> Optional[Request]:
         running = [r for r in self._slots if r is not None]
@@ -1753,15 +1902,16 @@ class ServingEngine:
         decode bucket covering the active count), packed as the decode
         program takes them: int32 [6, W] (tokens, or -1 for "the one the
         unread iteration sampled for this slot"; slot map, lane-active,
-        top-k, seeds, steps) and float32 [2, W] (temperature, top-p).
+        top-k, seeds, steps; for a drafting model a seventh row, the
+        standing drafts) and float32 [2, W] (temperature, top-p).
         Padding lanes carry the slot sentinel `max_batch` (clamp-gather +
         drop-scatter in forward_decode) and greedy sampling params (so an
         all-greedy batch keeps the sampler's argmax fast path)."""
         n = len(active_slots)
         W = self._decode_bucket(n)
-        lanes_i = np.zeros((6, W), np.int32)
+        lanes_i = np.zeros((6 + self._drafts, W), np.int32)
         lanes_f = np.zeros((2, W), np.float32)
-        tokens, slot_map, lane_active, top_k, seeds, steps = lanes_i
+        tokens, slot_map, lane_active, top_k, seeds, steps = lanes_i[:6]
         temp, top_p = lanes_f
         slot_map[:] = self.max_batch
         top_p[:] = 1.0
@@ -1776,6 +1926,8 @@ class ServingEngine:
             top_p[i] = sp.top_p
             seeds[i] = req.seed
             steps[i] = len(req.generated) + req.unread
+            if self._drafts:
+                lanes_i[6, i] = self._cur_drafts[slot]
         return W, lanes_i, lanes_f
 
     def _decode_iteration(self, active_slots: List[int]) -> int:
@@ -1820,23 +1972,22 @@ class ServingEngine:
         finally:
             _cw.pop_entry(prev)
         # the program bumped each active lane's length: so does the host
-        self._context_lens[active_slots] += 1
+        # (by how much a drafting model's did, `_read` will know)
+        if not self._drafts:
+            self._context_lens[active_slots] += 1
         reqs = [self._slots[slot] for slot in active_slots]
         for req in reqs:
-            req.unread += 1
+            req.unread += 1 + self._drafts
         # until this iteration is read its tokens are the device's alone
         self._cur_tokens[active_slots] = -1
         self._inflight = (nxt, reqs, W, self.stats["iterations"], seq)
         self.stats["iterations"] += 1
         self.stats["launches"] += 1
         self.stats["ahead_iterations"] += in_flight is not None
-        if self._walk_span:
+        if self._walk_span and not self._drafts:
             # one layer's walk of this iteration; a padding lane is idle
-            from ..ops.pallas.paged_attention import page_group_counts
-            live, walked = page_group_counts(
-                self._context_lens[active_slots], self._walk_span)
-            self.stats["page_groups_live"] += live
-            self.stats["page_groups_walked"] += walked + W - len(reqs)
+            self._count_page_walk(self._context_lens[active_slots],
+                                  W - len(reqs))
         self.stats["decode_wall_s"] += time.perf_counter() - t0
         if in_flight is not None:
             self._read(in_flight)
@@ -1845,13 +1996,21 @@ class ServingEngine:
             self._drain()
         return len(reqs)
 
+    def _count_page_walk(self, lengths, idle_lanes: int):
+        """One paged layer's walk over lanes of these lengths."""
+        from ..ops.pallas.paged_attention import page_group_counts
+        live, walked = page_group_counts(lengths, self._walk_span)
+        self.stats["page_groups_live"] += live
+        self.stats["page_groups_walked"] += walked + idle_lanes
+
     @staticmethod
     def _may_run_ahead(reqs: List[Request]) -> bool:
         """Whether the iteration just dispatched for `reqs` may stay
         unread while the next one is dispatched: only if none of its
-        tokens is a request's last by length. That token frees a slot,
-        and whoever takes the slot should find the device idle, not one
-        iteration ahead."""
+        tokens is a request's last by length (`unread` counts the MOST
+        tokens an unread iteration may yield: two of a drafting model).
+        That token frees a slot, and whoever takes the slot should find
+        the device idle, not one iteration ahead."""
         return all(len(r.generated) + r.unread < r.max_new_tokens
                    for r in reqs if r.state == "running")
 
@@ -1867,7 +2026,9 @@ class ServingEngine:
         """Fetch one dispatched iteration's tokens and book each to the
         REQUEST its lane was dispatched for (the slot may be somebody
         else's by now). A request that has ended since, by an end of
-        sequence in the iteration before, drops its token."""
+        sequence in the iteration before, drops its token. A drafting
+        model's lane brings one or two tokens, booked in order: what
+        follows an end of sequence or the budget's last is dropped."""
         nxt, reqs, W, iteration, seq = in_flight
         t0 = time.perf_counter()
         with _span("fetch", seq=seq, iteration=iteration):
@@ -1876,27 +2037,74 @@ class ServingEngine:
         self.stats["decode_wall_s"] += waited
         self.stats["read_wait_s"] += waited
         newest = self._inflight is None
-        with _span("bookkeep", lanes=W):
+        per = 1 + self._drafts
+        if self._drafts:
+            emitted = [nxt_np[i, :nxt_np[i, 2]].tolist()
+                       for i in range(len(reqs))]
+            self._book_drafts(reqs, nxt_np, W)
+            args = {"lanes": W, "seq": seq,
+                    "tokens": sum(len(toks) for toks in emitted)}
+        else:
+            emitted = [[int(tok)] for tok in nxt_np[:len(reqs)]]
+            args = {"lanes": W}
+        with _span("bookkeep", **args):
             produced = 0
             for i, req in enumerate(reqs):
-                req.unread -= 1
+                req.unread -= per
                 if req.state != "running":
                     continue
-                tok = int(nxt_np[i])
+                slot, toks = req.slot, self._kept(req, emitted[i])
                 self.tracer.decode_iteration(req.rid, bucket=W,
-                                             path=self.decode_mode)
-                self._record_token(req, tok)
-                produced += 1
-                if newest and req.state == "running":
-                    self._cur_tokens[req.slot] = tok
+                                             path=self.decode_mode,
+                                             tokens=len(toks))
+                for tok in toks:
+                    self._record_token(req, tok)
+                produced += len(toks)
+                if req.state != "running":
+                    continue
+                if newest:
+                    self._cur_tokens[slot] = toks[-1]
+                if self._drafts:
+                    req.drafts.append((len(req.generated),
+                                       int(nxt_np[i, 3])))
+                    self._cur_drafts[slot] = nxt_np[i, 3]
             self.stats["decode_tokens"] += produced
-            self.stats["discarded_tokens"] += len(reqs) - produced
+            self.stats["discarded_tokens"] += sum(map(len, emitted)) \
+                - produced
             if _metrics.enabled():
                 # re-publish occupancy AFTER completions so a drained
                 # batch reads 0 even when no further step() runs
                 _M_OCC.set(sum(r is not None for r in self._slots),
                            model=self.name)
             self._note_introspection(len(reqs), iteration + 1)
+
+    @staticmethod
+    def _kept(req: Request, toks: List[int]) -> List[int]:
+        """What of an iteration's tokens the request takes: up to its
+        budget, and nothing after an end of sequence."""
+        toks = toks[:req.max_new_tokens - len(req.generated)]
+        if req.eos_id >= 0 and req.eos_id in toks:
+            toks = toks[:toks.index(req.eos_id) + 1]
+        return toks
+
+    def _book_drafts(self, reqs: List[Request], nxt_np, W: int):
+        """What the host could not know at the dispatch of a drafting
+        model's iteration: how far each lane's context moved (1, or 2
+        where its draft was accepted), hence the page groups the paged
+        layers walked (two rows a lane, at the context's length and one
+        more) and the drafts verified and accepted."""
+        live = [i for i, r in enumerate(reqs)
+                if r.state == "running" and self._slots[r.slot] is r]
+        slots = [reqs[i].slot for i in live]
+        if self._walk_span:
+            before = self._context_lens[slots]
+            self._count_page_walk(
+                np.concatenate([before + 1, before + 2]),
+                2 * (W - len(live)))
+        self._context_lens[slots] += nxt_np[live, 2]
+        self.stats["draft_tokens"] += len(reqs)
+        self.stats["accepted_tokens"] += int((nxt_np[:len(reqs), 2] == 2)
+                                             .sum())
 
     def _record_token(self, req: Request, tok: int):
         req.generated.append(tok)
@@ -2095,4 +2303,10 @@ class ServingEngine:
                 "last_swap": dict(self.last_swap) if self.last_swap
                              else None,
                 "stats": dict(self.stats),
+                # of a drafting model: the share of verified drafts that
+                # were the main model's own token
+                "draft_acceptance": (
+                    self.stats["accepted_tokens"]
+                    / self.stats["draft_tokens"]
+                    if self.stats["draft_tokens"] else None),
             }

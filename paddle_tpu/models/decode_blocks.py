@@ -5,9 +5,14 @@ passes, the head under a scope, and the parts of `forward_prefill` /
 arguments, the lanes' view of the tables, a full-attention layer's append
 and paged attention, a sliding-window layer's ring, a prompt's grouped
 attention through the flash kernel, the length bump, the last real
-position). `models/olmo_hybrid.py`, `models/nemotron_h.py` and
-`models/mellum.py` call these; the rest of ROADMAP D1 (one model runner
-instead of a copy a model) is a `simplicity` issue's.
+position), the attention layer with grouped K/V heads and q/k norms that
+`models/mellum.py` and `models/exaone_moe.py` both have
+(`GroupedAttention`), and the same appends and attentions for R new rows
+a lane (`*_rows_attention`: a model that verifies a draft gives every
+lane the positions `context .. context + R - 1` in one call).
+`models/olmo_hybrid.py`, `models/nemotron_h.py`, `models/mellum.py` and
+`models/exaone_moe.py` call these; the rest of ROADMAP D1 (one model
+runner instead of a copy a model) is a `simplicity` issue's.
 """
 from __future__ import annotations
 
@@ -17,6 +22,8 @@ import jax.numpy as jnp
 from .. import nn
 from ..framework.tensor import Tensor
 from ..ops import _dispatch as _d
+from ..ops import reshape
+from ..ops import rope as _rope
 from .gpt import GPT
 
 # Matrix products of float32 weights run in three bfloat16 passes
@@ -73,6 +80,78 @@ class ExactLinear(nn.Linear):
 
     def forward(self, x):
         return _d.call(_matmul_exact, (x, self.weight))
+
+
+class GroupedAttention(nn.Layer):
+    """Attention with grouped K/V heads (query head h reads K/V head
+    ``h // (heads / kv_heads)``), no bias, q and k RMS-normed per head over
+    their D values before any rotation, every product at `EXACT`.
+    `window` tokens (None: every past token) is the mask of a prompt's
+    attention; `rope` is one group of a config's `rope_parameters`, or
+    None for a layer that rotates nothing."""
+
+    def __init__(self, hidden: int, heads: int, kv_heads: int, head_dim: int,
+                 eps: float, window=None, rope=None):
+        super().__init__()
+        self.heads, self.kv_heads, self.head_dim = heads, kv_heads, head_dim
+        self.window = window
+        self.q_proj = ExactLinear(hidden, heads * head_dim)
+        self.k_proj = ExactLinear(hidden, kv_heads * head_dim)
+        self.v_proj = ExactLinear(hidden, kv_heads * head_dim)
+        self.o_proj = ExactLinear(heads * head_dim, hidden)
+        self.q_norm = nn.RMSNorm(head_dim, eps)
+        self.k_norm = nn.RMSNorm(head_dim, eps)
+        self.rope_kind = None
+        if rope is not None:
+            self.rope_kind = rope.get("rope_type", "default")
+            # constants of the configuration, not weights
+            self.inv_freq, self.rope_factor = _rope.inverse_frequencies(
+                head_dim, rope)
+
+    def qkv(self, u, positions):
+        """u Tensor ``[B, L, h]`` at `positions` ``[B, L]`` (or ``[L]``):
+        q ``[B, L, H, D]`` and k ``[B, L, Hkv, D]``, normed per head and
+        rotated where the layer rotates, and v ``[B, L, Hkv, D]``
+        (arrays)."""
+        B, L, _ = u.shape
+        D = self.head_dim
+        q = self.q_norm(reshape(self.q_proj(u), [B, L, self.heads, D]))
+        k = self.k_norm(reshape(self.k_proj(u), [B, L, self.kv_heads, D]))
+        v = self.v_proj(u).data.reshape(B, L, self.kv_heads, D)
+        if self.rope_kind is None:
+            return q.data, k.data, v
+        q, k = _rope.rotate(q.data, k.data,
+                            jnp.broadcast_to(positions, (B, L)),
+                            self.inv_freq, self.rope_factor, self.rope_kind)
+        return q, k, v
+
+    def attend(self, q, k, v):
+        """A prompt's attention under this layer's mask: the flash
+        kernel's forward, which skips what lies outside the band, its
+        products at `highest` (they sit in front of a router)."""
+        from ..ops.pallas import flash_attention as _fa
+        return _fa.flash_attention(q, k, v, causal=True, window=self.window,
+                                   precision="highest")
+
+    def output(self, out):
+        """The heads' outputs ``[B, L, H, D]`` -> the layer's Tensor."""
+        B, L = out.shape[:2]
+        return self.o_proj(Tensor(out.reshape(B, L, self.heads
+                                              * self.head_dim)))
+
+
+# the head's reason, for a product at `EXACT` whose shape a program meets
+# once (a dense MLP among expert layers, a projection of two hidden widths):
+# traced bare it reaches the device trace as `dot_general:` with no scope
+exact = jax.jit(_matmul_exact)
+
+
+class ExactLinearOnce(ExactLinear):
+    """`ExactLinear` under an inner jit of its own (`exact`). Serving only:
+    the product is made on the arrays, outside the tape."""
+
+    def forward(self, x):
+        return Tensor(exact(x.data, self.weight.data))
 
 
 class TokensToLogits:
@@ -155,6 +234,45 @@ def bump_lengths(cache, slot_map, ctx, active):
         cache.context_lens = jnp.where(active, ctx + 1, ctx)
 
 
+def advance_lengths(cache, slot_map, ctx, n_new):
+    """`n_new` ``[B]`` more tokens in each sequence (0 for a lane that is
+    not active), decided on the device: a verified draft makes it 2."""
+    n_new = n_new.astype(jnp.int32)
+    if slot_map is not None:
+        cache.context_lens = cache.context_lens.at[slot_map].add(
+            n_new, mode="drop")
+    else:
+        cache.context_lens = ctx + n_new
+
+
+def _rows_as_lanes(bt_or_slots, ctx, active, R: int):
+    """Each lane's R new rows as R lanes of their own: the lane's table
+    row (or slot), the positions ``ctx .. ctx + R - 1`` and its activity,
+    ``[B * R]`` each, a lane's rows consecutive."""
+    positions = (ctx[:, None] + jnp.arange(R, dtype=ctx.dtype)).reshape(-1)
+    return (jnp.repeat(bt_or_slots, R, axis=0), positions,
+            jnp.repeat(active, R))
+
+
+def paged_rows_attention(cache, i, q, k, v, bt, ctx, active):
+    """R rows a lane of a full-attention layer: append K/V ``[B, R,
+    Hkv*D]`` at positions ``ctx .. ctx + R - 1`` of pools `i`, then attend
+    with q ``[B, R, H, D]``, row r over the keys ``<= ctx + r``: B x R
+    lanes of the one-query kernel over the lanes' table rows repeated
+    (it reads a lane's pages R times; a kernel that reads them once for
+    all R queries is a later `perf_opt`'s). Returns ``[B, R, H, D]``."""
+    from ..ops.pallas import paged_attention as _pa
+    B, R = q.shape[:2]
+    table, positions, live = _rows_as_lanes(bt, ctx, active, R)
+    cache.k_pages[i], cache.v_pages[i] = _pa.cache_append(
+        cache.k_pages[i], cache.v_pages[i], k.reshape(B * R, -1),
+        v.reshape(B * R, -1), table, positions, live)
+    out = _pa.paged_attention(
+        q.reshape((B * R,) + q.shape[2:]), cache.k_pages[i],
+        cache.v_pages[i], table, jnp.where(live, positions + 1, 0))
+    return out.reshape(q.shape)
+
+
 def paged_decode_attention(cache, i, q, k, v, bt, ctx, active):
     """One token of a full-attention layer: append its K/V ``[B, Hkv*D]``
     to pools `i`, then attend with q ``[B, H, D]`` over the pages (the new
@@ -201,6 +319,22 @@ def ring_decode_attention(cache, i, q, k, v, slots, ctx, active):
     return _pa.paged_attention(
         q, cache.window_k[i], cache.window_v[i], table,
         jnp.where(active, jnp.minimum(ctx + 1, W), 0))
+
+
+def ring_rows_attention(cache, i, q, k, v, slots, ctx, active):
+    """R rows a lane of a sliding-window layer, q ``[B, R, H, D]`` and
+    K/V ``[B, R, Hkv*D]`` at positions ``ctx .. ctx + R - 1``: one row
+    at a time, write it and attend, `ring_decode_attention` R times. Not
+    all writes first: the row that position ``ctx + 1`` takes is the one
+    position ``ctx + 1 - window`` holds, which the query at ``ctx`` still
+    sees. A row whose token turns out rejected is overwritten by the next
+    call's write at the same position before anything reads it, and the
+    position it displaced is by then outside every later query's window.
+    Returns ``[B, R, H, D]``."""
+    return jnp.stack(
+        [ring_decode_attention(cache, i, q[:, r], k[:, r], v[:, r], slots,
+                               ctx + r, active)
+         for r in range(q.shape[1])], axis=1)
 
 
 def ring_prefill_write(cache, i, k, v, slot, length):

@@ -51,6 +51,20 @@ class WindowLayersUnsupported(NotImplementedError):
         self.missing = missing
 
 
+class DraftingUnsupported(NotImplementedError):
+    """A serving path that knows one new token a lane and iteration was
+    asked to serve a model that drafts with a module of its own and
+    verifies the draft in the next iteration (`draft_tokens` > 0)."""
+
+    def __init__(self, path: str, missing: str, *, draft_tokens: int):
+        super().__init__(
+            f"{path} cannot serve a model that drafts {draft_tokens} "
+            f"token(s) an iteration with a module of its own: missing "
+            f"protocol: {missing}")
+        self.path = path
+        self.missing = missing
+
+
 def _nbytes(x) -> int:
     """Of an array or of its shape alone (`jax.eval_shape`)."""
     return math.prod(x.shape) * np.dtype(x.dtype).itemsize
@@ -111,6 +125,15 @@ class PagedKVCache:
     paged layers only (prefill recomputes the prompt whole and REWRITES
     the slot's ring, as it overwrites a recurrent state).
 
+    ``draft_layers`` of the ``"kv"`` layers, the LAST ones of
+    ``layer_kinds``, belong to a model's drafting module (a
+    multi-token-prediction block: `models/exaone_moe.py`) and not to a
+    decoder layer: pools like any other, under the same block table and
+    the same lengths (the module's row i is made from the main model's
+    hidden state at i and the token at i + 1, so after an iteration it
+    holds as many rows as the main layers do), so that allocation,
+    growth, copy-on-write and `pool_bytes()` count them with the rest.
+
     ``layer_kinds`` names each model layer's kind (default: every layer
     paged K/V, the GPT case); a layer of kind ``"none"`` (a feed-forward
     or expert block that is a layer of its own) holds nothing here.
@@ -129,7 +152,8 @@ class PagedKVCache:
                  num_kv_heads: Optional[int] = None,
                  counters: Optional[dict] = None,
                  window_k: Sequence = (), window_v: Sequence = (),
-                 window: int = 0):
+                 window: int = 0, draft_layers: int = 0):
+        self.draft_layers = int(draft_layers)
         self.window_k = list(window_k)
         self.window_v = list(window_v)
         self.window = int(window)
@@ -220,6 +244,7 @@ class PagedKVCache:
         return {
             "layer_kinds": list(self.layer_kinds),
             "kv_layers": len(self.k_pages),
+            "draft_layers": self.draft_layers,
             "window_layers": len(self.window_k),
             "window": self.window,
             "window_bytes": self.window_bytes(),
@@ -248,16 +273,19 @@ class PagedKVCache:
                  self.context_lens, self.states, self.conv_states,
                  self.counters, self.window_k, self.window_v),
                 (self.page_size, self.num_heads, self.head_dim,
-                 self.layer_kinds, self.num_kv_heads, self.window))
+                 self.layer_kinds, self.num_kv_heads, self.window,
+                 self.draft_layers))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         k, v, bt, cl, states, conv, counters, window_k, window_v = children
-        page_size, num_heads, head_dim, kinds, num_kv_heads, window = aux
+        (page_size, num_heads, head_dim, kinds, num_kv_heads, window,
+         draft_layers) = aux
         return cls(k, v, bt, cl, page_size, num_heads, head_dim,
                    states=states, conv_states=conv, layer_kinds=kinds,
                    num_kv_heads=num_kv_heads, counters=counters,
-                   window_k=window_k, window_v=window_v, window=window)
+                   window_k=window_k, window_v=window_v, window=window,
+                   draft_layers=draft_layers)
 
 
 def _register_cache_pytree():

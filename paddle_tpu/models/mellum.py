@@ -53,9 +53,7 @@ from ..framework.tensor import Tensor
 from ..nn.initializer import Uniform
 from ..ops import moe as _moe
 from ..ops import reshape
-from ..ops import rope as _rope
 from . import decode_blocks as _blocks
-from .decode_blocks import ExactLinear as _Linear
 from .decode_cache import (KV, KV_WINDOW, PagedKVCache,
                            WindowLayersUnsupported)
 
@@ -143,53 +141,17 @@ class MellumConfig:
             **changes})
 
 
-class MellumAttention(nn.Layer):
+class MellumAttention(_blocks.GroupedAttention):
+    """`decode_blocks.GroupedAttention` at the configuration's sizes, the
+    layer kind's own window and `rope_parameters` group."""
+
     def __init__(self, cfg: MellumConfig, kind: str):
-        super().__init__()
-        h, D = cfg.hidden_size, cfg.head_dim
+        super().__init__(
+            cfg.hidden_size, cfg.num_attention_heads,
+            cfg.num_key_value_heads, cfg.head_dim, cfg.rms_norm_eps,
+            window=int(cfg.sliding_window) if kind == SLIDING else None,
+            rope=cfg.rope_parameters[kind])
         self.kind = kind
-        self.heads, self.kv_heads, self.head_dim = (
-            cfg.num_attention_heads, cfg.num_key_value_heads, D)
-        self.window = int(cfg.sliding_window) if kind == SLIDING else None
-        self.q_proj = _Linear(h, self.heads * D)
-        self.k_proj = _Linear(h, self.kv_heads * D)
-        self.v_proj = _Linear(h, self.kv_heads * D)
-        self.o_proj = _Linear(self.heads * D, h)
-        self.q_norm = nn.RMSNorm(D, cfg.rms_norm_eps)
-        self.k_norm = nn.RMSNorm(D, cfg.rms_norm_eps)
-        parameters = cfg.rope_parameters[kind]
-        self.rope_kind = parameters.get("rope_type", "default")
-        # constants of the configuration, not weights
-        self.inv_freq, self.rope_factor = _rope.inverse_frequencies(
-            D, parameters)
-
-    def qkv(self, u, positions):
-        """u Tensor ``[B, L, h]`` at `positions` ``[B, L]`` (or ``[L]``):
-        q ``[B, L, H, D]`` and k ``[B, L, Hkv, D]``, normed per head and
-        rotated, and v ``[B, L, Hkv, D]`` (arrays)."""
-        B, L, _ = u.shape
-        D = self.head_dim
-        q = self.q_norm(reshape(self.q_proj(u), [B, L, self.heads, D]))
-        k = self.k_norm(reshape(self.k_proj(u), [B, L, self.kv_heads, D]))
-        v = self.v_proj(u).data.reshape(B, L, self.kv_heads, D)
-        q, k = _rope.rotate(q.data, k.data,
-                            jnp.broadcast_to(positions, (B, L)),
-                            self.inv_freq, self.rope_factor, self.rope_kind)
-        return q, k, v
-
-    def attend(self, q, k, v):
-        """A prompt's attention under this layer's mask: the flash
-        kernel's forward, which skips what lies outside the band, its
-        products at `highest` (they sit in front of a router)."""
-        from ..ops.pallas import flash_attention as _fa
-        return _fa.flash_attention(q, k, v, causal=True, window=self.window,
-                                   precision="highest")
-
-    def output(self, out):
-        """The heads' outputs ``[B, L, H, D]`` -> the layer's Tensor."""
-        B, L = out.shape[:2]
-        return self.o_proj(Tensor(out.reshape(B, L, self.heads
-                                              * self.head_dim)))
 
 
 class MellumExperts(nn.Layer):

@@ -1100,3 +1100,103 @@ class TestMellumShapesCompileForTheChip:
         # rows, the stacked halves and the output of every assignment
         assert compiled.memory_analysis().temp_size_in_bytes \
             < 5 * tokens * 8 * 2304 * 4 + 1e6
+
+
+# ------------ two rows a lane: a model that verifies a draft (PR 37) ----------
+
+
+class TestKExaoneShapesCompileForTheChip:
+    """`kexaone_236b_a23b`'s pools hold 8 K/V heads of 128 for 64 query
+    heads: 4,097 pages for a full layer and for the MTP block, a ring of
+    128 tokens for each of 32 slots for a sliding one; a decode iteration
+    brings TWO rows a lane (`decode_blocks.*_rows_attention`: 64 lanes of
+    the one-query kernel); its expert layers stack 8 SwiGLU experts of
+    6144 x 2048. Compiled for a described v5e, nothing runs."""
+
+    H, HKV, D, B, R, W, PAGE, POOL, PER_SEQ = 64, 8, 128, 32, 2, 128, 16, \
+        4097, 128
+
+    def test_two_rows_a_lane_update_both_pool_shapes_in_place(
+            self, v5e_chip, monkeypatch):
+        from paddle_tpu.analysis import pool_relayout_report
+        from paddle_tpu.models import decode_blocks as blocks
+        from paddle_tpu.models.decode_cache import (KV, KV_WINDOW,
+                                                    PagedKVCache)
+
+        def sds(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+        width = self.HKV * self.D
+        pool = sds((self.POOL, self.PAGE, width))
+        ring = sds((1 + self.B * self.W // self.PAGE, self.PAGE, width))
+        # the dispatch the model's layers call, as on the chip
+        monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+        monkeypatch.setattr(pa, "_check_compiles_grouped", lambda *a: None)
+
+        def step(q, k, v, kp, vp, rk, rv, bt, cl, slots, active):
+            c = PagedKVCache([kp], [vp], bt, cl, self.PAGE, self.H, self.D,
+                             layer_kinds=[KV, KV_WINDOW],
+                             num_kv_heads=self.HKV, window_k=[rk],
+                             window_v=[rv], window=self.W)
+            ctx = jnp.take(cl, slots, mode="clip")
+            rows = jnp.take(bt, slots, axis=0, mode="clip")
+            a = blocks.paged_rows_attention(c, 0, q, k, v, rows, ctx, active)
+            b = blocks.ring_rows_attention(c, 0, q, k, v, slots, ctx, active)
+            return (a + b, c.k_pages[0], c.v_pages[0], c.window_k[0],
+                    c.window_v[0])
+
+        before = pa._stats["grouped"]
+        rows = sds((self.B, self.R, width))
+        compiled = jax.jit(step, donate_argnums=(3, 4, 5, 6)).lower(
+            sds((self.B, self.R, self.H, self.D)), rows, rows, pool, pool,
+            ring, ring, sds((self.B, self.PER_SEQ), jnp.int32),
+            sds((self.B,), jnp.int32), sds((self.B,), jnp.int32),
+            sds((self.B,), jnp.bool_)).compile()
+        # the pages once at 64 lanes, the ring twice at 32
+        assert pa._stats["grouped"] == before + 3
+        assert compiled.as_text().count("tpu_custom_call") >= 3
+        rep = pool_relayout_report(compiled, [pool, ring])
+        assert rep["pool_relayout_copies"] == 0, rep
+        assert rep["temp_size_in_bytes"] < 8e6, rep
+
+    @pytest.mark.parametrize("window", [128, None], ids=["band", "causal"])
+    @pytest.mark.parametrize("L", [64, 2048])
+    def test_the_flash_forward_takes_the_window_at_highest(self, v5e_chip,
+                                                           L, window):
+        from paddle_tpu.ops.pallas import flash_attention as fa
+
+        def sds(heads):
+            return jax.ShapeDtypeStruct((1, L, heads, self.D), jnp.float32,
+                                        sharding=v5e_chip)
+        compiled = jax.jit(lambda q, k, v: fa._fa_fwd_pallas(
+            q, k, v, None, True, float(1 / np.sqrt(self.D)),
+            blocks=fa._static_blocks(L, L), window=window,
+            precision="highest")[0]).lower(
+                sds(self.H), sds(self.HKV), sds(self.HKV)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            <= 2.1 * L * self.H * self.D * 4
+
+    @pytest.mark.parametrize("tokens", [64, 2048], ids=["decode", "prefill"])
+    def test_the_swiglu_products_read_the_stacked_weights_in_place(
+            self, v5e_chip, tokens, monkeypatch):
+        from paddle_tpu.ops import moe
+        monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+
+        def sds(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+        compiled = jax.jit(
+            lambda u, e, w, w1, w2: moe.held_experts(
+                u, e, w, w1, w2, form="swiglu")).lower(
+            sds((tokens, 6144)), sds((tokens, 8), jnp.int32),
+            sds((tokens, 8)), sds((8, 4096, 6144)),
+            sds((8, 2048, 6144))).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= 2
+        made = [line for line in text.splitlines()
+                if (" = f32[8,4096,6144]" in line
+                    or " = f32[8,2048,6144]" in line)
+                and " parameter(" not in line]
+        assert not made, made[:2]
+        # rows, the stacked halves and the output of every assignment
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < 5 * tokens * 8 * 6144 * 4 + 1e6

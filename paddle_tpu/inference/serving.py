@@ -10,10 +10,26 @@ KV cache (ops/pallas/paged_attention.py) —
   admission happens per iteration (a finished sequence's slot is refilled
   on the very next step, never at epoch/batch boundaries);
 * **prefill is shape-bucketed**: a prompt pads up to the smallest
-  configured bucket, so the whole serving life of the engine compiles
+  bucket that holds it, so the whole serving life of the engine compiles
   one prefill executable per bucket — the retrace watchdog stays quiet
   and the PR-8 persistent compile cache
-  (``PADDLE_TPU_COMPILE_CACHE_DIR``) makes cold-start cheap;
+  (``PADDLE_TPU_COMPILE_CACHE_DIR``) makes cold-start cheap. The default
+  buckets are a LADDER of `max_len` alone (`_prefill_ladder`): the powers
+  of two from 128 (from 256 once `max_len` reaches 2048) and, from 512
+  up, the step halfway to the next, then `max_len` itself: 256, 512,
+  768, 1024, 1536, 2048 for a `max_len` of 2048. Every layer computes
+  the whole bucket, so the padding is paid in device time: a step
+  between the powers of two bounds it by a half where doubling bounds it
+  by the whole prompt. Why no finer: every bucket is a program to trace,
+  compile, load and warm, 2-5 s of a WARM start apiece once it holds the
+  kernels and their compile checks (the programs under 64 rows that the
+  ladder replaced held none and cost a quarter of that), so the ladder
+  keeps the count of such programs at what powers of two from 16 had;
+  under 128 rows a prefill costs its weights' bytes whatever its rows,
+  and a half step under 512 saves at most 128 rows a prompt (PERF.md
+  section 6, PR 38). Every step is a multiple of 64 (the
+  linear-attention scan's chunk); `prefill_buckets=` replaces the
+  ladder;
 * the **decode iteration is ONE donated, jitted executable per lane
   bucket**: all transformer layers, the paged-attention kernel, the
   K/V page append, the in-graph sampling draw
@@ -450,6 +466,23 @@ def _pow2_buckets(lo: int, hi: int) -> List[int]:
     return out
 
 
+def _prefill_ladder(max_len: int) -> List[int]:
+    """The default prefill buckets, a function of `max_len` alone: the
+    powers of two from 128 (from 256 once `max_len` reaches 2048) and,
+    from 512 up, 3/2 of each, then `max_len` itself. From 512 up no
+    bucket is more than 1.5 times the one before it, and each is a
+    multiple of 64 but `max_len`. 1024: 128, 256, 512, 768, 1024.
+    2048: 256, 512, 768, 1024, 1536, 2048."""
+    out, b = [], min(256 if max_len >= 2048 else 128, int(max_len))
+    while b < max_len:
+        out.append(b)
+        if b >= 512 and 3 * b // 2 < max_len:
+            out.append(3 * b // 2)
+        b <<= 1
+    out.append(int(max_len))
+    return out
+
+
 def _inject_pages_impl(k_pages, v_pages, k_payload, v_payload, page_ids):
     """Scatter a prefill worker's per-layer KV page payload into the
     decode pools (disaggregated handoff). The pools are DONATED — the
@@ -503,7 +536,10 @@ class ServingEngine:
       A released slot's row is zeroed on the host alone: no lane names
       an idle slot, and the next prefill overwrites its length;
     * ``forward_prefill(ids [1, bucket], cache, slot, length,
-      write_start=)`` computes the prompt whole, writes its K/V into the
+      write_start=)`` (`bucket`: the smallest of `prefill_buckets` that
+      holds the prompt; by default `_prefill_ladder(max_len)`, so it
+      may be 3/2 of a power of two as well as one, and is never under
+      `min(128, max_len)`) computes the prompt whole, writes its K/V into the
       slot's pages from `write_start` on (a shared prefix's pages are
       already there) and OVERWRITES the slot's recurrent state and its
       window rings (whatever `write_start` is: the prompt is computed
@@ -723,7 +759,7 @@ class ServingEngine:
                                        on_release=self._prefix.drop_page)
         self._reset_tables()
         if prefill_buckets is None:
-            prefill_buckets = _pow2_buckets(min(16, max_len), max_len)
+            prefill_buckets = _prefill_ladder(max_len)
         self.prefill_buckets = sorted(set(int(b) for b in prefill_buckets))
         if self.prefill_buckets[-1] < max_len:
             self.prefill_buckets.append(max_len)
@@ -786,6 +822,7 @@ class ServingEngine:
         self.handoff_source = None
         # rolling stats for bench/status
         self.stats = {"iterations": 0, "prefills": 0, "decode_tokens": 0,
+                      "prefill_tokens": 0, "prefill_padded_tokens": 0,
                       "completed": 0, "preemptions": 0, "decode_wall_s": 0.0,
                       "cow_copies": 0, "prefix_hit_tokens": 0,
                       "shared_admissions": 0, "swaps": 0, "restarts": 0,
@@ -1780,6 +1817,9 @@ class ServingEngine:
         self.stats["launches"] += 1
         self._context_lens[slot] = len(tokens)   # as the program set it
         self.stats["prefills"] += 1
+        # rows the program computed for tokens, and for the bucket's padding
+        self.stats["prefill_tokens"] += len(tokens)
+        self.stats["prefill_padded_tokens"] += bucket - len(tokens)
         if self.share_prefix:
             self._prefix.register(tokens, pages)
         t0 = time.perf_counter()

@@ -228,9 +228,19 @@ _PICK = {
 
 # (family, shape, pick). Flash: (B, L, H, D, dtype), causal, no mask, ->
 # (blocks | "small", backward kernel). The serving rows are the engines'
-# prefill buckets (`_pow2_buckets(16, max_len)`, B=1, float32) from the
-# first the kernel takes (L >= 64) to max_len, on both sides of
-# `_SMALL_MAX_L` = 512 and of the small path's VMEM bound, which H moves.
+# prefill buckets (B=1, float32), on both sides of `_SMALL_MAX_L` = 512
+# and of the small path's VMEM bound, which H moves: first the powers of
+# two from the first the kernel takes (L >= 64) to max_len, the buckets
+# every cell ran until PR 38 (`_pow2_buckets(16, max_len)`; rows kept
+# letter for letter: the ladder still holds 256, 512, ... and a test may
+# pass any bucket), then, under "the ladder's steps between them", the
+# 3/2 steps of `serving._prefill_ladder(max_len)` (768, 1536; 3072 for
+# max_len 5120) that PR 38 added to each cell.
+# the flash pick at the ladder length the default blocks do not divide:
+# at 768 the k tail of (256, 512) beat (256, 384) at five head shapes of
+# seven (PERF.md section 6, PR 38, the chip chain), so it stays
+FLASH_768 = (256, 512)
+
 _CELL_PICKS = [
     # gpt2s_train_b8s1024: bf16 compute, b8 x s1024
     ("flash", (8, 1024, 12, 64, "bfloat16"), ((256, 512), "fused")),
@@ -293,6 +303,41 @@ _CELL_PICKS = [
     ("moe_tiles", (8 * 16, 2304, 1792), (32, 2304, 256)),
     ("moe_tiles", (8 * 4096, 2304, 1792), (64, 2304, 256)),
     ("moe_tiles", (8 * 4096, 896, 2304), (64, 896, 768)),
+    # ---- the ladder's steps between the powers of two (PR 38) ----
+    # gpt2s_serve_closed32 (max_len 1024): 768
+    ("flash", (1, 768, 12, 64, "float32"), (FLASH_768, "fused")),
+    ("layer_norm", (768, 768), 256),
+    # gpt3xl_serve_closed16 (max_len 2048): and 1536
+    ("flash", (1, 768, 16, 128, "float32"), (FLASH_768, "fused")),
+    ("flash", (1, 1536, 16, 128, "float32"), ((256, 512), "fused")),
+    ("layer_norm", (768, 2048), 256),
+    ("layer_norm", (1536, 2048), 256),
+    # olmoh7b_serve_closed32 (max_len 2048)
+    ("flash", (1, 768, 30, 128, "float32"), (FLASH_768, "fused")),
+    ("flash", (1, 1536, 30, 128, "float32"), ((256, 512), "fused")),
+    # nemo3n_serve_closed64 (max_len 2048; its prompt attention is the
+    # masked product, no flash rows): top-6 of a bucket's rows
+    ("moe_tiles", (6 * 768, 2688, 1856), (64, 2688, 128)),
+    ("moe_tiles", (6 * 768, 1856, 2688), (64, 512, 896)),
+    ("moe_tiles", (6 * 1536, 2688, 1856), (64, 2688, 128)),
+    ("moe_tiles", (6 * 1536, 1856, 2688), (64, 512, 896)),
+    # mellum2_serve_closed64_code (max_len 5120): and 3072; top-8
+    ("flash_forward", (768,), FLASH_768),
+    ("flash_forward", (1536,), (256, 512)),
+    ("flash_forward", (3072,), (256, 512)),
+    ("moe_tiles", (8 * 768, 2304, 1792), (64, 2304, 256)),
+    ("moe_tiles", (8 * 768, 896, 2304), (64, 896, 768)),
+    ("moe_tiles", (8 * 1536, 2304, 1792), (64, 2304, 256)),
+    ("moe_tiles", (8 * 1536, 896, 2304), (64, 896, 768)),
+    ("moe_tiles", (8 * 3072, 2304, 1792), (64, 2304, 256)),
+    ("moe_tiles", (8 * 3072, 896, 2304), (64, 896, 768)),
+    # kexaone_serve_closed32_reason (max_len 2048; 64 on 8 heads through
+    # the forward-only path, rows above): 8 held SwiGLU experts of
+    # 6144 x 2048 (gate and up stacked to 4096), top-8
+    ("moe_tiles", (8 * 256, 6144, 4096), (64, 512, 512)),
+    ("moe_tiles", (8 * 768, 6144, 4096), (64, 512, 512)),
+    ("moe_tiles", (8 * 768, 2048, 6144), (64, 2048, 256)),
+    ("moe_tiles", (8 * 1536, 2048, 6144), (64, 2048, 256)),
     # no cell: a head size off the lane groups takes the XLA gather
     ("paged_attn", (8, 80, 16, 8), "xla"),
     # no cell: a table under the pick's bound, and pools twice as wide
@@ -424,8 +469,11 @@ _RUN = [
     ("flash", _run_flash, ((64, 64), 64, 128)),
     ("flash", _run_flash, ((128, 128), 128, 128)),
     ("flash", _run_flash, ((256, 512), 512, 128)),
+    # the ladder's step at 768 (PR 38): a k block with a tail of 256
+    ("flash", _run_flash, (FLASH_768, 768, 64)),
     ("layer_norm", _run_layer_norm, (256, 512, 768)),
     ("layer_norm", _run_layer_norm, (256, 256, 2048)),
+    ("layer_norm", _run_layer_norm, (256, 768, 768)),   # a ladder step
     # each paged-attention pick's pages a step, over a table they do not
     # divide
     ("paged_attn", _run_paged, ((2, 8), 64, 16, 11)),

@@ -312,6 +312,13 @@ class DisaggPipeline:
                 "a hand-off of the slot's recurrent and convolution state "
                 "beside its K/V pages (KVHandoff carries pages only)",
                 kv_layers=d["kv_layers"], state_layers=d["state_layers"])
+        if engine.cache.latent_layers:
+            from ..models.decode_cache import LatentCacheUnsupported
+            raise LatentCacheUnsupported(
+                "disaggregated prefill/decode (DisaggPipeline)",
+                "a hand-off of latent rows (KVHandoff carries a K and a V "
+                "page a layer, made by a prefill worker's K/V layers)",
+                latent_layers=engine.cache.latent_layers)
         if engine.cache.has_window:
             from ..models.decode_cache import WindowLayersUnsupported
             d = engine.cache.describe()

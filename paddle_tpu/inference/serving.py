@@ -510,8 +510,11 @@ class ServingEngine:
       heads, which grouped heads make narrower than the query's), for
       each layer that carries a recurrence a fixed-size state per batch
       slot, nothing for a layer that carries nothing from token to token
-      (an expert block that is a layer of its own), for each layer that
-      attends over a sliding window a RING of `window` tokens a batch
+      (an expert block that is a layer of its own), for each layer of
+      latent attention ONE pool of latent rows under the same tables
+      (kind `latent`: a row serves every head's key and value, and its
+      pages are allocated, shared and copied as K/V pages are), for each
+      layer that attends over a sliding window a RING of `window` tokens a batch
       slot (kind `kv_window`: K/V folded like the pools, never more
       whatever the context, at a table that is a function of the slot
       and is computed inside the programs: the engine stores none, sends
@@ -559,7 +562,8 @@ class ServingEngine:
       ValueError naming the protocol, and a model with recurrent-state
       layers refuses it, as `inference/disagg.py` does, with
       `StateLayersUnsupported` naming the protocol that is missing (a
-      model with sliding-window layers: `WindowLayersUnsupported`).
+      model with sliding-window layers: `WindowLayersUnsupported`, one
+      with latent-attention layers: `LatentCacheUnsupported`).
 
     Drive it either synchronously (`submit` then `run_until_idle`,
     tests/bench) or with the background thread (`start()`; `close()`
@@ -934,11 +938,16 @@ class ServingEngine:
     def _page_walk_span(self) -> int:
         """Tokens one grid step of the paged-attention walk covers at this
         cache's shape (the full-heads kernel's pick or, with grouped K/V
-        heads, the grouped kernel's), or 0 where no layer is paged."""
+        heads, the grouped kernel's, or for latent rows the latent
+        kernel's), or 0 where no layer is paged."""
         from ..ops.pallas import paged_attention as _pa
         c = self.cache
         if not c.k_pages:
             return 0
+        if c.latent_layers:
+            return self.page_size * _pa.latent_pages_per_step(
+                c.k_pages[0].shape[-1], self.page_size,
+                c.k_pages[0].dtype.itemsize, c.pages_per_seq)
         pick = (_pa.pages_per_step if c.num_kv_heads == c.num_heads
                 else _pa.grouped_pages_per_step)
         return self.page_size * pick(

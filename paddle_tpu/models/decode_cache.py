@@ -1,5 +1,6 @@
 """The decode cache a model hands the serving engine: paged K/V for the
-layers that attend over every past token, a fixed ring of K/V per batch
+layers that attend over every past token, a paged LATENT row a token for
+the layers of latent attention, a fixed ring of K/V per batch
 slot for the layers that attend over a sliding window, a fixed-size state
 per batch slot for the layers that carry a recurrence, all in one pytree.
 
@@ -19,6 +20,7 @@ KV = "kv"          # a layer with a paged K and V pool
 KV_WINDOW = "kv_window"   # a layer whose K/V is a ring of `window` tokens a slot
 STATE = "state"    # a layer with a recurrent state and a convolution tail
 NONE = "none"      # a layer that carries nothing from token to token
+LATENT = "latent"  # a layer with ONE paged pool of latent rows, no V pool
 
 
 class StateLayersUnsupported(NotImplementedError):
@@ -47,6 +49,20 @@ class WindowLayersUnsupported(NotImplementedError):
             f"{path} cannot serve a model with sliding-window layers "
             f"({window_layers} window rings, {kv_layers} paged K/V): "
             f"missing protocol: {missing}")
+        self.path = path
+        self.missing = missing
+
+
+class LatentCacheUnsupported(NotImplementedError):
+    """A serving path that moves or shards K and V pages by heads was
+    asked to serve a model of latent attention, whose pool holds one
+    latent row a token for all heads and no V pool."""
+
+    def __init__(self, path: str, missing: str, *, latent_layers: int):
+        super().__init__(
+            f"{path} cannot serve a model with latent-attention layers "
+            f"({latent_layers} latent pools, no K/V heads to shard or hand "
+            f"off): missing protocol: {missing}")
         self.path = path
         self.missing = missing
 
@@ -134,6 +150,23 @@ class PagedKVCache:
     holds as many rows as the main layers do), so that allocation,
     growth, copy-on-write and `pool_bytes()` count them with the rest.
 
+    A layer of kind ``"latent"`` (latent attention, `models/deepseek_v2.py`)
+    keeps ONE pool and no V pool: its entry of ``k_pages`` is
+    ``[num_pages, page_size, latent_width]``, a row a token holding what
+    every head's key and value are made from (the compressed ``c_kv``, then
+    the rotated key part shared by all heads, then zeros to
+    `latent_width`), under the same block table and lengths as any paged
+    layer, so that allocation, growth, copy-on-write, a prefix hit,
+    `audit()` and `pool_bytes()` take it with the rest. A cache holds
+    latent layers or ``"kv"`` layers, not both (``v_pages`` then lines up
+    with ``k_pages``). ``latent_width`` is a multiple of 128 for the
+    folded pool's reason above: DeepSeek-V2's row of 512 + 64 is stored
+    640 wide, since a row of 576 fills four lane tiles and half a fifth
+    and the chip would pad or re-lay out the pool in every program. The
+    kernel reads a row once for the scores (all of it) and the values
+    (its first ``head_dim`` lanes: the latent's width). ``num_heads``
+    counts the query heads; ``num_kv_heads`` is 1.
+
     ``layer_kinds`` names each model layer's kind (default: every layer
     paged K/V, the GPT case); a layer of kind ``"none"`` (a feed-forward
     or expert block that is a layer of its own) holds nothing here.
@@ -172,16 +205,21 @@ class PagedKVCache:
             layer_kinds = (KV,) * len(self.k_pages)
         self.layer_kinds = tuple(layer_kinds)
         # layer index -> index into k_pages/v_pages or states/conv_states
-        counts = {KV: 0, KV_WINDOW: 0, STATE: 0, NONE: 0}
+        counts = {KV: 0, KV_WINDOW: 0, STATE: 0, NONE: 0, LATENT: 0}
         self._index = []
         for kind in self.layer_kinds:
             self._index.append(counts[kind])
             counts[kind] += 1
-        if counts[KV] != len(self.k_pages) \
+        if counts[LATENT] and (counts[KV] or self.v_pages):
+            raise ValueError(
+                f"layer_kinds {self.layer_kinds}: latent layers keep no V "
+                f"pool and cannot share a cache with paged K/V layers")
+        if counts[KV] + counts[LATENT] != len(self.k_pages) \
                 or counts[STATE] != len(self.states) \
                 or counts[KV_WINDOW] != len(self.window_k):
             raise ValueError(
-                f"layer_kinds {self.layer_kinds} names {counts[KV]} paged, "
+                f"layer_kinds {self.layer_kinds} names "
+                f"{counts[KV] + counts[LATENT]} paged, "
                 f"{counts[KV_WINDOW]} window and {counts[STATE]} state "
                 f"layers; the cache holds {len(self.k_pages)} pools, "
                 f"{len(self.window_k)} rings and {len(self.states)} states")
@@ -216,13 +254,17 @@ class PagedKVCache:
         return bool(self.window_k)
 
     @property
+    def latent_layers(self) -> int:
+        return self.layer_kinds.count(LATENT)
+
+    @property
     def window_pages(self) -> int:
         """Pages of one slot's ring."""
         return self.window // self.page_size
 
     def pool_bytes(self) -> int:
-        """Device bytes of K and V: every paged layer's pools and every
-        window layer's rings."""
+        """Device bytes of K and V: every paged layer's pools (a latent
+        layer's one), and every window layer's rings."""
         return self.window_bytes() + sum(
             _nbytes(p) for p in self.k_pages + self.v_pages)
 
@@ -243,7 +285,10 @@ class PagedKVCache:
         slots = max(1, self.max_batch)
         return {
             "layer_kinds": list(self.layer_kinds),
-            "kv_layers": len(self.k_pages),
+            "kv_layers": self.layer_kinds.count(KV),
+            "latent_layers": self.latent_layers,
+            "latent_width": (self.k_pages[0].shape[-1]
+                             if self.latent_layers else None),
             "draft_layers": self.draft_layers,
             "window_layers": len(self.window_k),
             "window": self.window,

@@ -25,9 +25,23 @@ chosen, `norm_topk_prob`)::
     chosen = top_k(p)                        the order of p is the order of z
     w_e = p_e / (sum of p over the chosen)
 
-Both return each token's `margin`, the distance between the last score
+`group_limited_route` (DeepSeek-V2's `group_limited_greedy`: softmax
+probabilities, the experts in `n_group` equal groups, a group's score its
+largest probability; not renormalised, `norm_topk_prob` false, and
+scaled)::
+
+    z = u W_r;  p = softmax(z)               over all E experts
+    g_j = max of z over group j              the order of p in a group
+    kept = the topk_group best groups
+    chosen = top_k(z) over the experts of the kept groups
+    w_e = scale * p_e
+
+All return each token's `margin`, the distance between the last score
 chosen and the first left out (of ``s + b``, and of ``z``: the softmax
-squeezes the distances it is given, the logits are what a rounding moves).
+squeezes the distances it is given, the logits are what a rounding
+moves); `group_limited_route` the first left out AMONG THE KEPT GROUPS,
+and beside it the `group_margin`, the distance between the last group
+kept and the first left out (of ``g``).
 
 Experts (`held_experts`): no token is dropped and no expert has a
 capacity. The T x k (token, expert) assignments are sorted by expert, the
@@ -63,7 +77,8 @@ _HI = jax.lax.Precision.HIGHEST
 
 # which grouped product a caller's expert layers took, counted at trace
 # time of the caller (one a layer)
-_stats = {"route": 0, "softmax_route": 0, "gmm": 0, "ragged_dot": 0}
+_stats = {"route": 0, "softmax_route": 0, "group_route": 0, "gmm": 0,
+          "ragged_dot": 0}
 
 # tests set True: megablox runs in the Pallas interpreter on the CPU
 _INTERPRET = False
@@ -126,6 +141,51 @@ def softmax_route(u, w_router, *, top_k: int):
     _stats["softmax_route"] += 1
     with jax.named_scope("route"):
         return _softmax_route_impl(u, w_router, top_k=int(top_k))
+
+
+@functools.partial(jax.jit, static_argnames=("top_k", "n_group",
+                                             "topk_group", "scale"))
+def _group_route_impl(u, w_router, top_k: int, n_group: int,
+                      topk_group: int, scale: float):
+    f32 = jnp.float32
+    z = jnp.matmul(u.astype(f32), w_router.astype(f32), precision=_HI)
+    p = jax.nn.softmax(z, axis=-1)
+    E = z.shape[-1]
+    groups = jnp.max(z.reshape(*z.shape[:-1], n_group, E // n_group), -1)
+    gtop, gidx = jax.lax.top_k(groups, topk_group + 1)
+    group_margin = gtop[..., topk_group - 1] - gtop[..., topk_group]
+    kept = jnp.any(gidx[..., :topk_group, None]
+                   == jnp.arange(n_group, dtype=gidx.dtype), axis=-2)
+    eligible = jnp.repeat(kept, E // n_group, axis=-1)
+    top, idx = jax.lax.top_k(jnp.where(eligible, z, -jnp.inf), top_k + 1)
+    margin = top[..., top_k - 1] - top[..., top_k]
+    idx = idx[..., :top_k]
+    weights = scale * jnp.take_along_axis(p, idx, axis=-1)
+    return idx.astype(jnp.int32), weights, margin, group_margin
+
+
+def group_limited_route(u, w_router, *, top_k: int, n_group: int,
+                        topk_group: int, scale: float = 1.0):
+    """u ``[T, h]``, w_router ``[h, E]``, E a multiple of `n_group`.
+    Returns ``(experts [T, k] int32, weights [T, k] float32, margin [T],
+    group_margin [T])``: the experts the `top_k` largest logits among the
+    `topk_group` groups whose largest logit is largest, the weights their
+    softmax probabilities over ALL E experts times `scale` (not
+    renormalised), `margin` the distance between the k-th and the
+    (k+1)-th logit of the kept groups (needs ``topk_group * E / n_group >
+    k``), `group_margin` between the `topk_group`-th and the next group's
+    largest logit (needs ``n_group > topk_group``)."""
+    E = w_router.shape[-1]
+    if E % n_group or not topk_group < n_group \
+            or topk_group * (E // n_group) <= top_k:
+        raise ValueError(f"group_limited_route: {E} experts in {n_group} "
+                         f"groups, {topk_group} kept, top-{top_k}")
+    _stats["group_route"] += 1
+    with jax.named_scope("route"):
+        return _group_route_impl(u, w_router, top_k=int(top_k),
+                                 n_group=int(n_group),
+                                 topk_group=int(topk_group),
+                                 scale=float(scale))
 
 
 def _tiles(m: int, k: int, n: int):

@@ -32,8 +32,9 @@ saves softmax-out for bwd, and handles arbitrary attention masks). Here:
   `precision` sets the kernel's matrix products as `ops/moe.py` sets
   megablox's (`jax.default_matmul_precision` while the kernel is traced:
   Mosaic takes "default", one bfloat16 pass, or "highest"), for a model
-  whose attention sits in front of a router. The backward pass refuses
-  all three by name.
+  whose attention sits in front of a router; V may have a head size of
+  its own (DeepSeek-V2's prefill: q and k of 192, v of 128), which the
+  output takes. The backward pass refuses all four by name.
 
 `_stats` counts dispatch decisions at trace time so tests can assert the
 kernel path is actually exercised (round-1 review found the old fwd-only
@@ -720,12 +721,13 @@ def _fa_fwd_pallas(q, k, v, mask, causal, scale, mask_is_bool=False,
                    interpret=False, blocks=None, window=None, precision=None):
     """Returns (out [B,L,H,D], lse [B,H,Lq] f32). mask may be None.
     `blocks` is (block_q, block_k); None = `_static_blocks`. `window`,
-    `precision` and K/V of fewer heads than q: the module's docstring,
-    "forward only"."""
+    `precision`, K/V of fewer heads than q and V of another head size than
+    q and K (the output takes V's): the module's docstring, "forward
+    only"."""
     from jax.experimental import pallas as pl
 
     B, Lq, H, D = q.shape
-    Lk = k.shape[1]
+    Lk, Dv = k.shape[1], v.shape[-1]
     G = H // k.shape[2]             # query heads a K/V head
     block_q, block_k = _blocks_or_static(blocks, Lq, Lk)
     qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
@@ -747,7 +749,7 @@ def _fa_fwd_pallas(q, k, v, mask, causal, scale, mask_is_bool=False,
     in_specs = [
         pl.BlockSpec((None, None, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
         pl.BlockSpec((None, None, block_k, D), kv_index),
-        pl.BlockSpec((None, None, block_k, D), kv_index),
+        pl.BlockSpec((None, None, block_k, Dv), kv_index),
     ]
     args = [qt, kt, vt]
     if mask is not None:
@@ -758,7 +760,7 @@ def _fa_fwd_pallas(q, k, v, mask, causal, scale, mask_is_bool=False,
     # default in force while the kernel is traced
     with (jax.default_matmul_precision(precision) if precision
           else contextlib.nullcontext()):
-        out, lse = _fa_fwd_call(kernel, grid, in_specs, args, B, H, Lq, D,
+        out, lse = _fa_fwd_call(kernel, grid, in_specs, args, B, H, Lq, Dv,
                                 block_q, q.dtype, interpret)
     return jnp.swapaxes(out, 1, 2), lse[..., 0]
 
@@ -1176,6 +1178,7 @@ def _banded_xla(q, k, v, causal, scale, window, precision):
     reference."""
     B, Lq, H, D = q.shape
     Lk, Hkv = k.shape[1], k.shape[2]
+    Dv = v.shape[-1]
     qg = q.reshape(B, Lq, Hkv, H // Hkv, D)
     rows = jnp.arange(Lq, dtype=jnp.int32)[:, None] + (Lk - Lq)
     cols = jnp.arange(Lk, dtype=jnp.int32)[None, :]
@@ -1191,7 +1194,7 @@ def _banded_xla(q, k, v, causal, scale, window, precision):
         s = jnp.where(keep, s, -jnp.inf)
         out = jnp.einsum("bkgqs,bskd->bqkgd",
                          jax.nn.softmax(s, axis=-1).astype(v.dtype), v)
-    return out.reshape(B, Lq, H, D).astype(q.dtype)
+    return out.reshape(B, Lq, H, Dv).astype(q.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -1212,20 +1215,20 @@ def _forward_only_bwd(causal, scale, window, precision, interpret, blocks,
                       res, do):
     raise NotImplementedError(
         "flash_attention: the backward pass takes no `window`, no "
-        "`precision` and no grouped K/V heads (forward only: serving's "
-        "prefill); train through the masked product, or add them to "
-        "_fa_bwd_fused_kernel")
+        "`precision`, no grouped K/V heads and no value head size of its "
+        "own (forward only: serving's prefill); train through the masked "
+        "product, or add them to _fa_bwd_fused_kernel")
 
 
 _flash_forward_only.defvjp(_forward_only_fwd, _forward_only_bwd)
 
 
 def _forward_only(q, k, v, causal, scale, window, precision):
-    """`flash_attention` with a `window`, a `precision` or grouped K/V
-    heads: the forward kernel alone where it is eligible, the masked
-    product otherwise."""
+    """`flash_attention` with a `window`, a `precision`, grouped K/V
+    heads or a value head size of its own: the forward kernel alone where
+    it is eligible, the masked product otherwise."""
     B, Lq, H, D = q.shape
-    Lk, Hkv = k.shape[1], k.shape[2]
+    Lk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
     if window is not None:
         if not causal or int(window) < 1:
             raise ValueError("flash_attention: `window` needs causal=True "
@@ -1233,7 +1236,7 @@ def _forward_only(q, k, v, causal, scale, window, precision):
                              f"window={window}")
         window = int(window)
         _stats["window"] += 1
-    if H % Hkv or v.shape != k.shape:
+    if H % Hkv or v.shape[:3] != k.shape[:3]:
         raise ValueError(f"flash_attention: {H} query heads do not divide "
                          f"over K/V of shape {k.shape} / {v.shape}")
     eligible = ((_on_tpu() or _INTERPRET) and Lq >= 64 and Lk >= 64
@@ -1248,14 +1251,15 @@ def _forward_only(q, k, v, causal, scale, window, precision):
     def run():
         return _fa_fwd_pallas(
             jnp.ones((1, Lq, H, D), q.dtype), jnp.ones((1, Lk, Hkv, D), q.dtype),
-            jnp.ones((1, Lk, Hkv, D), q.dtype), None, bool(causal),
+            jnp.ones((1, Lk, Hkv, Dv), q.dtype), None, bool(causal),
             float(scale), interpret=_INTERPRET, blocks=blocks, window=window,
             precision=precision)
 
     _tiling.compile_check(
         "flash_attention_forward_only", run, dtype=jnp.dtype(q.dtype).name,
-        q=(Lq, H, D), k=(Lk, Hkv, D), causal=bool(causal), window=window,
-        precision=precision, blocks=blocks, interpret=_INTERPRET)
+        q=(Lq, H, D), k=(Lk, Hkv, D), v=(Lk, Hkv, Dv), causal=bool(causal),
+        window=window, precision=precision, blocks=blocks,
+        interpret=_INTERPRET)
     _stats["pallas"] += 1
     return _flash_forward_only(q, k, v, bool(causal), float(scale), window,
                                precision, _INTERPRET, blocks)
@@ -1291,9 +1295,10 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     >= 64, streamed K/V so Lk is HBM-bounded); XLA composition otherwise.
 
     `window` (causal only: key j for query i iff ``i - window < j <= i``),
-    `precision` ("default" or "highest", of the kernel's products) and K/V
-    of fewer heads than q take the FORWARD-ONLY path (`_forward_only`),
-    which takes no `mask` and no dropout.
+    `precision` ("default" or "highest", of the kernel's products), K/V
+    of fewer heads than q and V of another head size than q take the
+    FORWARD-ONLY path (`_forward_only`), which takes no `mask` and no
+    dropout.
 
     `dropout_p > 0` (training-time attention dropout) ALWAYS takes the XLA
     path: the fused kernels do not thread a dropout seed, and weight-level
@@ -1304,7 +1309,7 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
     if window is not None or precision is not None \
-            or k.shape[2] != q.shape[2]:
+            or k.shape[2] != q.shape[2] or v.shape[-1] != q.shape[-1]:
         if mask is not None or dropout_p > 0.0:
             raise ValueError("flash_attention: `window`, `precision` and "
                              "grouped K/V heads take no mask and no dropout")

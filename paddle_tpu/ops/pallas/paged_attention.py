@@ -44,6 +44,10 @@ pages:
   its products run on the MXU as float32's bfloat16 parts, K and V
   crossing it once a part (`_paged_attn_grouped_kernel`,
   `grouped_pages_per_step`).
+* the kernel for LATENT rows (`latent_attention`, DeepSeek-V2's absorbed
+  decode): ONE pool, a row a token that is every head's key and, in its
+  first lanes, every head's value; all the query heads of a lane read a
+  row once for both (`_paged_attn_latent_kernel`).
 * ``paged_attention_xla``: gather pages via ``k_pages[block_tables]``,
   mask past ``context_lens``, dense softmax. The path off the TPU, for
   fp16 and for head sizes the kernel's lane groups do not take, and the
@@ -97,9 +101,11 @@ _LANE = _tiling.LANE
 # ("folded": those of "pallas" that went to the full-heads kernel, whose
 # walk visits live page groups only, `pages_per_step` pages a grid step
 # (PR 34); "grouped": those that went to the kernel for grouped K/V heads,
-# which walks the same list, `grouped_pages_per_step` pages a step (PR 36))
+# which walks the same list, `grouped_pages_per_step` pages a step (PR 36);
+# "latent": calls of `latent_attention` on either path, "latent_pallas"
+# those that took its kernel)
 _stats = {"pallas": 0, "folded": 0, "grouped": 0, "xla": 0, "append": 0,
-          "cow": 0}
+          "cow": 0, "latent": 0, "latent_pallas": 0}
 
 # tests set True: the kernel runs in the Pallas interpreter on CPU, so
 # the real gather/online-softmax logic is exercised without a TPU
@@ -766,6 +772,259 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
                                context_lens, scale=scale)
 
 
+# ------------------------------ latent rows ----------------------------------
+#
+# Latent attention decoded in its ABSORBED form (`models/deepseek_v2.py`):
+# each query head's query is projected into the latent space beside its
+# rotary part, so that a head's score against token j is ``q_h . row_j``
+# over the whole row and its output (before the head's own up-projection)
+# is ``sum_j p_hj row_j[:Dv]``. One pool and one read of a row serve all
+# the heads' scores and values.
+
+# pages a grid step of the latent kernel holds: the two slots of its one
+# pool within 4 MiB and P within 16 (at a row of 640 float32, 16 pages: 1.3
+# MiB of slots and 2 MiB of the rows' bfloat16 parts); not tuned on the
+# chip
+_LATENT_STEP_BYTES = 4 << 20
+_LATENT_MAX_PAGES = 16
+
+# the partial products of two float32 operands' bfloat16 parts (hi, mid,
+# lo) that the latent kernel makes: six, those `highest` keeps (the three
+# left out are below float32's rounding of the sum)
+_SIX_PASSES = ((0, 0), (0, 1), (1, 0), (0, 2), (2, 0), (1, 1))
+
+
+def latent_pages_per_step(width: int, page_size: int, itemsize: int,
+                          n_pages: int) -> int:
+    """P of the latent kernel, as `grouped_pages_per_step`, for its ONE
+    pool of rows `width` wide."""
+    return _pages_that_fit(2 * page_size * width * itemsize, n_pages,
+                           _LATENT_STEP_BYTES, _LATENT_MAX_PAGES)
+
+
+def _passes(a_parts, b_parts, contract):
+    """The product of two operands given as bfloat16 parts, in the passes
+    of `_SIX_PASSES` (one where both are bfloat16)."""
+    out = None
+    for i, j in _SIX_PASSES:
+        if i < len(a_parts) and j < len(b_parts):
+            t = _one_pass(a_parts[i], b_parts[j], contract)
+            out = t if out is None else out + t
+    return out
+
+
+def _paged_attn_latent_kernel(bt_ref, cl_ref, lane_ref, group_ref, n_ref,
+                              q_ref, pool_hbm, o_ref, acc_ref, m_ref, l_ref,
+                              buf, sem, *, page_size, n_pages, scale, P):
+    """Grid (items): `page_walk`'s list, as the grouped kernel walks it;
+    q_ref holds ALL the query heads of the lane ``[H, W]`` (a head's
+    absorbed query, its rotary part, zeros), o_ref their outputs ``[H,
+    Dv]``. The kernel copies a step's P pages of the one pool itself into
+    one of two slots (`buf` [2, P * page, W]), item t + 1's before item t
+    is computed, as `_paged_attn_grouped_kernel` does for two pools.
+
+    Scores: every head against every row of the step, over all W lanes;
+    values: the same rows' first Dv lanes. Both products are float32's,
+    made of bfloat16 parts in the six passes of `_SIX_PASSES`."""
+    from jax.experimental import pallas as pl
+
+    t = pl.program_id(0)
+    g = group_ref[t]
+    ctx = cl_ref[lane_ref[t]]
+    span = P * page_size
+    Dv = o_ref.shape[-1]
+    slot = jax.lax.rem(t, 2)
+
+    def copies(item, slot):
+        """The P page copies of `item` into `slot`; None waits for them."""
+        unrolled = min(P, _COPIES_UNROLLED)
+
+        def turn(n, carry):
+            for u in range(unrolled):
+                j = n * unrolled + u
+                page = 0 if item is None else bt_ref[
+                    lane_ref[item],
+                    jnp.minimum(group_ref[item] * P + j, n_pages - 1)]
+                copy = pltpu.make_async_copy(
+                    pool_hbm.at[page],
+                    buf.at[slot, pl.ds(pl.multiple_of(j * page_size,
+                                                      page_size),
+                                       page_size)],
+                    sem.at[slot])
+                copy.wait() if item is None else copy.start()
+            return carry
+        jax.lax.fori_loop(0, P // unrolled, turn, 0)
+
+    @pl.when(t == 0)
+    def _first():
+        copies(0, 0)
+
+    @pl.when(t + 1 < n_ref[0])
+    def _next():
+        copies(t + 1, 1 - slot)
+
+    @pl.when(g == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    copies(None, slot)
+
+    # false only in an idle lane's one item
+    @pl.when(g * span < ctx)
+    def _compute():
+        row_parts = _bf16_parts(buf[slot])                    # [span, W]
+        s = _passes(_bf16_parts(q_ref[...]), row_parts,
+                    ((1,), (1,))) * scale                     # [H, span]
+        pos = g * span + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        live = pos < ctx
+        s = jnp.where(live, s, _NEG)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_ref[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        pv = _passes(_bf16_parts(p), tuple(x[:, :Dv] for x in row_parts),
+                     ((1,), (0,)))                            # [H, Dv]
+        acc_ref[...] = acc_ref[...] * corr + pv
+
+    @pl.when(g == jnp.maximum(pl.cdiv(ctx, span), 1) - 1)
+    def _finalize():
+        # ctx == 0 (idle slot): acc and l still zero -> exactly zero
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
+                      ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "value_width", "pages",
+                                             "interpret"))
+def _paged_attn_latent_pallas(q, pool, block_tables, context_lens, scale,
+                              value_width, pages, interpret=False):
+    """q [B, H, W] over a pool [num_pages, page_size, W]; `pages` pages to
+    a grid step of `page_walk`'s list. Returns [B, H, value_width]."""
+    from jax.experimental import pallas as pl
+
+    B, H, W = q.shape
+    page_size = pool.shape[1]
+    n_pages = block_tables.shape[1]
+    P = pages
+    lane, group, n_items = page_walk(context_lens, P * page_size,
+                                     pl.cdiv(n_pages, P))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(n_items[0],),
+        in_specs=[pl.BlockSpec((None, H, W),
+                               lambda t, bt, cl, ln, gr, n: (ln[t], 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, H, value_width),
+                               lambda t, bt, cl, ln, gr, n: (ln[t], 0, 0)),
+        scratch_shapes=[pltpu.VMEM((H, value_width), jnp.float32),
+                        pltpu.VMEM((H, _LANE), jnp.float32),
+                        pltpu.VMEM((H, _LANE), jnp.float32),
+                        pltpu.VMEM((2, P * page_size, W), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))],
+    )
+    params = None if interpret else pltpu.CompilerParams(
+        dimension_semantics=(pltpu.ARBITRARY,))
+    return pl.pallas_call(
+        functools.partial(_paged_attn_latent_kernel, page_size=page_size,
+                          n_pages=n_pages, scale=scale, P=P),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, value_width), q.dtype),
+        compiler_params=params,
+        interpret=interpret,
+    )(block_tables, context_lens, lane, group, n_items, q, pool)
+
+
+def latent_attention_xla(q, pool, block_tables, context_lens, scale,
+                         value_width: int):
+    """The gather reference of `latent_attention`, at `highest`: the
+    path off the TPU and the kernel's parity reference. A sequence with
+    ``context_lens == 0`` outputs zeros."""
+    B, H, W = q.shape
+    L = block_tables.shape[1] * pool.shape[1]
+    rows = pool[block_tables].reshape(B, L, W).astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    s = jnp.einsum("bhw,blw->bhl", q.astype(jnp.float32), rows,
+                   precision=hi) * scale
+    live = (jnp.arange(L, dtype=jnp.int32)[None, None, :]
+            < context_lens[:, None, None])
+    s = jnp.where(live, s, _NEG)
+    p = jnp.where(live, jnp.exp(s - jnp.max(s, -1, keepdims=True)), 0.0)
+    p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    return jnp.einsum("bhl,blv->bhv", p, rows[..., :value_width],
+                      precision=hi).astype(q.dtype)
+
+
+def _check_compiles_latent(dtype, H: int, W: int, value_width: int,
+                           page_size: int, n_pages: int, pages: int):
+    """Eager compile check of the latent kernel (`tiling.compile_check`)."""
+    def run():
+        q = jnp.ones((2, H, W), dtype)
+        pool = jnp.ones((max(n_pages, 2), page_size, W), dtype)
+        bt = jnp.zeros((2, n_pages), jnp.int32)
+        cl = jnp.full((2,), page_size, jnp.int32)
+        return _paged_attn_latent_pallas(q, pool, bt, cl, 1.0, value_width,
+                                         pages, interpret=_INTERPRET)
+
+    _tiling.compile_check(
+        "paged_attn_latent", run, dtype=jnp.dtype(dtype).name, heads=H,
+        width=W, value_width=value_width, page_size=page_size,
+        pages_per_seq=n_pages, pages_per_step=pages, interpret=_INTERPRET)
+
+
+def latent_attention(q, pool, block_tables, context_lens, scale: float,
+                     value_width: int):
+    """Single-token decode attention over a pool of LATENT rows.
+
+    q [B, H, W]: each query head's query in the row's space (the absorbed
+    latent query, its rotary part, zeros where the row is zero); pool
+    [num_pages, page_size, W]; block_tables [B, pages_per_seq] int32;
+    context_lens [B] int32. Returns [B, H, value_width]: each head's
+    softmax(scale * q_h . row_j) over its context, applied to the rows'
+    first `value_width` lanes. The kernel where it is eligible (on the TPU
+    or in interpret mode; W and `value_width` whole lane tiles, H whole
+    sublane tiles, after one eager compile check that raises), the XLA
+    gather otherwise."""
+    B, H, W = q.shape
+    page_size = pool.shape[1]
+    n_pages = block_tables.shape[1]
+    _stats["latent"] += 1
+    kernels = ((_on_tpu() or _INTERPRET) and q.dtype == pool.dtype
+               and q.dtype != jnp.dtype(jnp.float16) and isinstance(H, int)
+               and (_INTERPRET or (W % _LANE == 0 and value_width % _LANE == 0
+                                   and H % 8 == 0)))
+    if not kernels:
+        return latent_attention_xla(q, pool, block_tables, context_lens,
+                                    scale, value_width)
+    pages = latent_pages_per_step(W, page_size, q.dtype.itemsize, n_pages)
+    _check_compiles_latent(q.dtype, H, W, value_width, page_size, n_pages,
+                           pages)
+    _stats["latent_pallas"] += 1
+    return _paged_attn_latent_pallas(q, pool, block_tables, context_lens,
+                                     float(scale), int(value_width), pages,
+                                     interpret=_INTERPRET)
+
+
+def latent_append(pool, rows, block_tables, context_lens, active):
+    """One latent row a sequence ``[B, W]`` at position ``context_lens[b]``
+    of each active one (the null page otherwise); traceable."""
+    _stats["append"] += 1
+    slot, off = _write_slots(block_tables, context_lens, active,
+                             pool.shape[1])
+    return pool.at[slot, off].set(_rows_like(pool, rows))
+
+
+def latent_prefill_append(pool, seq, page_ids, length, start=0):
+    """`prefill_append` for a pool of latent rows: a prompt's rows
+    ``[L, W]`` into one sequence's pages, from `start` to `length`."""
+    pages, live = _prompt_pages(pool.shape[1], seq.shape[0], page_ids,
+                                length, start)
+    return _put_prompt(pool, seq, pages, live)
+
+
 # ------------------- tensor-parallel (head-sharded) path ---------------------
 #
 # Decode attention is embarrassingly parallel over HEADS: each head's
@@ -876,15 +1135,20 @@ def _append_impl(k_pages, v_pages, k_new, v_new, block_tables,
     page slot. Inactive slots write to the null page 0 at offset 0
     (garbage the attention mask never reads — the serving allocator
     reserves page 0)."""
-    page_size = k_pages.shape[1]
-    slot = jnp.take_along_axis(
-        block_tables, (context_lens // page_size)[:, None], axis=1)[:, 0]
-    off = context_lens % page_size
-    slot = jnp.where(active, slot, 0)
-    off = jnp.where(active, off, 0)
+    slot, off = _write_slots(block_tables, context_lens, active,
+                             k_pages.shape[1])
     k_pages = k_pages.at[slot, off].set(_rows_like(k_pages, k_new))
     v_pages = v_pages.at[slot, off].set(_rows_like(v_pages, v_new))
     return k_pages, v_pages
+
+
+def _write_slots(block_tables, context_lens, active, page_size: int):
+    """(page, offset) of each sequence's next token; (0, 0), the null
+    page, where it is not active."""
+    slot = jnp.take_along_axis(
+        block_tables, (context_lens // page_size)[:, None], axis=1)[:, 0]
+    off = context_lens % page_size
+    return jnp.where(active, slot, 0), jnp.where(active, off, 0)
 
 
 _append_jit = jax.jit(_append_impl, donate_argnums=(0, 1))
@@ -930,21 +1194,30 @@ def prefill_append(k_pages, v_pages, k_seq, v_seq, page_ids, length,
     as the same rows did in a [.., H, D] pool (PERF.md, PR 26). Each page
     the prompt spans is read, its live rows replaced, and written back;
     a page with no live row is parked on the null page 0."""
-    page_size = k_pages.shape[1]
-    n = -(-k_seq.shape[0] // page_size)      # pages the (padded) prompt spans
+    pages, live = _prompt_pages(k_pages.shape[1], k_seq.shape[0], page_ids,
+                                length, start)
+    return (_put_prompt(k_pages, k_seq, pages, live),
+            _put_prompt(v_pages, v_seq, pages, live))
+
+
+def _prompt_pages(page_size: int, L: int, page_ids, length, start):
+    """The pages a padded prompt of L rows spans (the null page where no
+    row of one is live) and which rows are live ``[n, page_size]``."""
+    n = -(-L // page_size)
     pos = jnp.arange(n * page_size, dtype=jnp.int32).reshape(n, page_size)
     live = (pos >= start) & (pos < length)
-    pages = jnp.where(jnp.any(live, axis=1), page_ids[:n], 0)
+    return jnp.where(jnp.any(live, axis=1), page_ids[:n], 0), live
 
-    def put(pool, seq):
-        rows = _rows_like(pool, seq)
-        rows = jnp.pad(rows, [(0, n * page_size - rows.shape[0])]
-                       + [(0, 0)] * (rows.ndim - 1))
-        rows = rows.reshape(n, page_size, *pool.shape[2:])
-        keep = live.reshape(n, page_size, *[1] * (pool.ndim - 2))
-        return pool.at[pages].set(jnp.where(keep, rows, pool[pages]))
 
-    return put(k_pages, k_seq), put(v_pages, v_seq)
+def _put_prompt(pool, seq, pages, live):
+    """A prompt's rows into `pages`, page by page, its live rows only."""
+    n, page_size = live.shape
+    rows = _rows_like(pool, seq)
+    rows = jnp.pad(rows, [(0, n * page_size - rows.shape[0])]
+                   + [(0, 0)] * (rows.ndim - 1))
+    rows = rows.reshape(n, page_size, *pool.shape[2:])
+    keep = live.reshape(n, page_size, *[1] * (pool.ndim - 2))
+    return pool.at[pages].set(jnp.where(keep, rows, pool[pages]))
 
 
 # --------------------------- copy-on-write fork -------------------------------

@@ -15,6 +15,12 @@ in two settings of the inverse frequencies (`rope_type` of a Hugging Face
              inv_freq[m] = theta_base^(-2m/D) ((1 - ramp[m]) + ramp[m] / factor);
              A = `attention_factor` (0.1 ln(factor) + 1 where none is given),
              on cos AND sin, so the scores carry A^2.
+             DeepSeek's pair (`mscale`, `mscale_all_dim`), where no
+             `attention_factor` is given: A = g(mscale) / g(mscale_all_dim)
+             on cos and sin, g(s) = 0.1 s ln(factor) + 1, and the softmax
+             scale carries g(mscale_all_dim)^2 (`softmax_scale_factor`): at
+             DeepSeek-V2's keys (0.707 both) A = 1 and the scores take
+             m^2 = 1.2608^2 from the scale, not from the rotation.
 
 Both are STATIC: one table of frequencies whatever the sequence's length
 (`transformers`' `_compute_default_rope_parameters` and
@@ -49,11 +55,29 @@ def yarn_correction_range(head_dim: int, base: float, original: int,
             min(math.ceil(c(beta_slow)), head_dim - 1))
 
 
+def yarn_get_mscale(factor: float, mscale: float = 1.0) -> float:
+    """g(s) = 0.1 s ln(factor) + 1, and 1 where the factor does not
+    stretch (DeepSeek's `yarn_get_mscale`)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def softmax_scale_factor(parameters: dict) -> float:
+    """What a `yarn` group with DeepSeek's `mscale_all_dim` multiplies the
+    attention's softmax scale by: g(mscale_all_dim)^2; 1 for any other
+    group (the scores of those carry A^2 through cos and sin)."""
+    if parameters.get("rope_type", "default") != "yarn" \
+            or not parameters.get("mscale_all_dim"):
+        return 1.0
+    return yarn_get_mscale(float(parameters["factor"]),
+                           float(parameters["mscale_all_dim"])) ** 2
+
+
 def inverse_frequencies(head_dim: int, parameters: dict):
     """`parameters`: one group of a config's `rope_parameters`
     (`rope_type`, `rope_theta` and, for `yarn`, `factor`,
     `original_max_position_embeddings`, `beta_fast`, `beta_slow`,
-    `attention_factor`). Returns (inv_freq float32 [head_dim / 2], A)."""
+    `attention_factor` or DeepSeek's `mscale` and `mscale_all_dim`).
+    Returns (inv_freq float32 [head_dim / 2], A)."""
     kind = parameters.get("rope_type", "default")
     base = float(parameters["rope_theta"])
     m = np.arange(head_dim // 2, dtype=np.float64)
@@ -73,7 +97,10 @@ def inverse_frequencies(head_dim: int, parameters: dict):
     ramp = np.clip((m - low) / (high - low), 0.0, 1.0)
     inv = inv * ((1.0 - ramp) + ramp / factor)
     A = parameters.get("attention_factor")
-    if A is None:
+    if A is None and "mscale_all_dim" in parameters:
+        A = (yarn_get_mscale(factor, float(parameters.get("mscale", 1)))
+             / yarn_get_mscale(factor, float(parameters["mscale_all_dim"])))
+    elif A is None:
         A = 0.1 * math.log(factor) + 1.0
     return inv.astype(np.float32), float(A)
 

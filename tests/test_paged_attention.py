@@ -359,6 +359,77 @@ class TestGroupedWalk:
         np.testing.assert_array_equal(total, np.float64(np.float32(x)))
 
 
+class TestLatentWalk:
+    """The latent kernel (PR 39): ONE pool of rows that are every head's
+    key and, in their first `Dv` lanes, every head's value; the same walk
+    over live page groups, P pages a grid step fetched by the kernel's own
+    copies, against the XLA gather at ragged contexts."""
+
+    # (heads, row width, value width, table slots, P); pages of 8 tokens
+    SHAPES = [(8, 128, 64, 19, 4), (16, 256, 128, 16, 8), (8, 128, 128, 9, 1),
+              (4, 128, 32, 12, 16)]
+
+    @pytest.mark.parametrize("H,W,Dv,n,P", SHAPES)
+    def test_walk_matches_the_gather_at_ragged_contexts(self, H, W, Dv, n,
+                                                        P):
+        S = 8
+        ctx = _ragged(P * S, n * S)
+        rng = np.random.default_rng(H + W + n + P)
+        B, nan_page = len(ctx), 1
+        q = jnp.asarray(rng.normal(size=(B, H, W)), jnp.float32)
+        pool = rng.normal(size=(2 + B * n, S, W)).astype(np.float32)
+        pool[nan_page] = np.nan
+        bt = 2 + np.arange(B * n, dtype=np.int32).reshape(B, n)
+        for b in range(B):
+            bt[b, -(-ctx[b] // (P * S)) * P:] = nan_page
+        pool, bt, cl = (jnp.asarray(pool), jnp.asarray(bt),
+                        jnp.asarray(ctx, jnp.int32))
+        out = np.asarray(pa._paged_attn_latent_pallas(
+            q, pool, bt, cl, 0.07, Dv, P, interpret=True))
+        assert out.shape == (B, H, Dv)
+        assert np.all(np.isfinite(out)), \
+            "a page past a lane's last live group reached the sum"
+        for b, c in enumerate(ctx):
+            if c == 0:
+                assert np.all(out[b] == 0.0), b
+        ref = pa.latent_attention_xla(q, pool.at[nan_page].set(0.0), bt, cl,
+                                      0.07, Dv)
+        np.testing.assert_allclose(out, np.asarray(ref), rtol=0, atol=2e-5)
+
+    def test_dispatch_takes_the_kernel_in_interpret_mode(self, interp):
+        q, pool, bt, cl = (jnp.ones((2, 8, 128)), jnp.ones((6, 8, 128)),
+                           jnp.asarray([[1, 2], [3, 4]], jnp.int32),
+                           jnp.asarray([5, 16], jnp.int32))
+        before = dict(pa._stats)
+        out = pa.latent_attention(q, pool, bt, cl, 0.1, 64)
+        assert pa._stats["latent_pallas"] == before["latent_pallas"] + 1
+        np.testing.assert_allclose(np.asarray(out), 1.0, rtol=1e-6)
+
+    def test_the_cpu_without_interpret_takes_the_gather(self):
+        before = dict(pa._stats)
+        pa.latent_attention(jnp.ones((2, 8, 128)), jnp.ones((6, 8, 128)),
+                            jnp.asarray([[1, 2], [3, 4]], jnp.int32),
+                            jnp.asarray([5, 16], jnp.int32), 0.1, 64)
+        assert pa._stats["latent"] == before["latent"] + 1
+        assert pa._stats["latent_pallas"] == before["latent_pallas"]
+
+    def test_the_row_appends_land_where_the_table_says(self):
+        pool = jnp.zeros((6, 4, 128))
+        bt = jnp.asarray([[2, 3], [4, 5]], jnp.int32)
+        rows = jnp.arange(2 * 128, dtype=jnp.float32).reshape(2, 128)
+        out = np.asarray(pa.latent_append(
+            pool, rows, bt, jnp.asarray([5, 2], jnp.int32),
+            jnp.asarray([True, False])))
+        np.testing.assert_array_equal(out[3, 1], np.asarray(rows[0]))
+        assert np.all(out[4] == 0) and np.all(out[0, 0] == rows[1])
+        seq = jnp.ones((9, 128))
+        out = np.asarray(pa.latent_prefill_append(
+            pool, seq, jnp.asarray([2, 3, 4], jnp.int32), 7, start=2))
+        assert np.all(out[2, :2] == 0) and np.all(out[2, 2:] == 1)
+        assert np.all(out[3, :3] == 1) and np.all(out[3, 3:] == 0)
+        assert np.all(out[4] == 0)
+
+
 def _np_pages(P, S, HD):
     return np.zeros((P, S, HD), np.float32)
 
@@ -1200,3 +1271,69 @@ class TestKExaoneShapesCompileForTheChip:
         # rows, the stacked halves and the output of every assignment
         assert compiled.memory_analysis().temp_size_in_bytes \
             < 5 * tokens * 8 * 6144 * 4 + 1e6
+
+
+class TestDeepseekV2ShapesCompileForTheChip:
+    """`deepseek_v2_236b_a21b`'s pools hold ONE latent row a token and
+    layer, 512 + 64 numbers stored 640 wide, 16,385 pages; 128 query heads
+    on 32 lanes read it in decode (`latent_attention`); prefill runs the
+    flash forward 32 heads at a time with q and k of 192, v of 128, up to
+    a bucket of 8,192. Compiled for a described v5e, nothing runs."""
+
+    H, W, DV, B, PAGE, POOL, PER_SEQ = 128, 640, 512, 32, 16, 16385, 512
+
+    def test_the_latent_pool_is_updated_in_place(self, v5e_chip,
+                                                 monkeypatch):
+        from paddle_tpu.analysis import pool_relayout_report
+
+        def sds(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+        pool = sds((self.POOL, self.PAGE, self.W))
+        monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+        monkeypatch.setattr(pa, "_check_compiles_latent", lambda *a: None)
+
+        def step(q, rows, pool, bt, cl, active):
+            pool = pa.latent_append(pool, rows, bt, cl, active)
+            out = pa.latent_attention(q, pool, bt,
+                                      jnp.where(active, cl + 1, 0), 0.11,
+                                      self.DV)
+            return out, pool
+
+        before = pa._stats["latent_pallas"]
+        decode = jax.jit(step, donate_argnums=(2,)).lower(
+            sds((self.B, self.H, self.W)), sds((self.B, self.W)), pool,
+            sds((self.B, self.PER_SEQ), jnp.int32), sds((self.B,), jnp.int32),
+            sds((self.B,), jnp.bool_)).compile()
+        assert pa._stats["latent_pallas"] == before + 1
+        assert "tpu_custom_call" in decode.as_text()
+        prefill = jax.jit(pa.latent_prefill_append,
+                          donate_argnums=(0,)).lower(
+            pool, sds((8192, self.W)), sds((self.PER_SEQ,), jnp.int32),
+            sds((), jnp.int32), sds((), jnp.int32)).compile()
+        for compiled in (decode, prefill):
+            rep = pool_relayout_report(compiled, [pool])
+            assert rep["pool_relayout_copies"] == 0, rep
+        assert pool_relayout_report(decode, [pool])["temp_size_in_bytes"] \
+            < 8e6
+
+    @pytest.mark.parametrize("L", [256, 8192])
+    def test_the_flash_forward_takes_a_value_width_of_its_own(
+            self, v5e_chip, L):
+        from paddle_tpu.ops.pallas import flash_attention as fa
+
+        def sds(d):
+            return jax.ShapeDtypeStruct((1, L, 32, d), jnp.float32,
+                                        sharding=v5e_chip)
+        compiled = jax.jit(lambda q, k, v: fa._fa_fwd_pallas(
+            q, k, v, None, True, 0.11, blocks=fa._static_blocks(L, L),
+            precision="highest")[0]).lower(sds(192), sds(192),
+                                           sds(128)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        out = jax.eval_shape(lambda q, k, v: fa._fa_fwd_pallas(
+            q, k, v, None, True, 0.11, blocks=fa._static_blocks(L, L),
+            precision="highest")[0], sds(192), sds(192), sds(128))
+        assert out.shape == (1, L, 32, 128)
+        # q, k and v transposed in, the output transposed out and back:
+        # 805 MB at 8,192 tokens (32 heads: a quarter of a layer's)
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            <= 1.01 * L * 32 * (2 * 192 + 3 * 128) * 4

@@ -419,9 +419,10 @@ class Request:
         self.slot: Optional[int] = None
         self.pages: List[int] = []
         self.shared_tokens = 0         # prefix tokens served from shared pages
-        # the MOST decode tokens dispatched for this request and not yet
-        # read back: the engine keeps at most one iteration in flight, and
-        # an iteration yields one token, or up to two of a drafting model
+        # the MOST tokens dispatched for this request and not yet read
+        # back: its prefill's one, and the engine keeps at most one decode
+        # iteration in flight, which yields one token, or up to two of a
+        # drafting model
         self.unread = 0
         # of a drafting model: (k, token) for each draft the engine read,
         # the model's guess at `generated[k]` made before that token was
@@ -601,27 +602,44 @@ class ServingEngine:
     takes the slot finds the device idle, as with every iteration read at
     once. What makes N+1 independent of the host's read: the last token
     sampled for each slot stays on the device (`_last_tokens`, int32
-    [max_batch + 1], donated through the decode program like the cache),
-    and a lane's token travels as -1, "the row's", while the iteration
-    that samples it is unread (a prefill's, a hand-off's and a read
-    iteration's token travel as they did). Positions, sampling step
-    counters and page growth count tokens DISPATCHED (`len(generated) +
-    Request.unread`). The unread record holds the Request of each lane,
-    not its slot. Everything that takes, frees or moves a slot outside
-    that bookkeeping calls `_drain()` first: an admission with a free
-    slot, a hand-off, preemption (a dry pool reads before it picks a
-    victim), a weight swap, `restart`, `close`, `shrink_pool`, `audit`,
-    `device_counters`; `pending()` stays true while an iteration is
-    unread. Every device program `step()` launches, decode iterations and
-    prefills in one series, carries a launch number: `seq` =
+    [max_batch + 1], donated through the decode program and the prefill
+    program like the cache), and a lane's token travels as -1, "the
+    row's", while the program that samples it is unread (a hand-off's and
+    a read iteration's token travel as they did). A PREFILL's token is
+    unread too: an admission dispatches its prefill and reads nothing, a
+    later admission of the step dispatches its own, and the step's decode
+    iteration, dispatched next, feeds the new lanes from the row; only
+    then are the prefills read, in the order of their launches, then
+    iteration N-1. So the device runs prefills and decode back to back,
+    and the host reads a first token while the iteration behind it runs.
+    A prefill whose token is its request's last by length is read at once
+    (`_may_run_ahead` again, the one predicate), so that its slot is free
+    for the same admission loop. Positions, sampling step counters and
+    page growth count tokens DISPATCHED (`len(generated) +
+    Request.unread`, where `unread` counts an unread prefill's one token
+    and the most an unread iteration may yield). The unread records hold
+    the Request of each lane and prefill, not its slot. Everything that
+    takes, frees or moves a slot outside that bookkeeping calls
+    `_drain()` first, which reads the prefills, then the iteration: an
+    admission with a free slot, a hand-off, preemption (a dry pool reads
+    before it picks a victim), a weight swap, `restart`, `close`,
+    `shrink_pool`, `audit`, `device_counters`; `pending()` stays true
+    while anything is unread. Every device program `step()` launches,
+    decode iterations and prefills in one series, carries a launch
+    number: `seq` =
     `stats["launches"]` read before the call and bumped after it, on the
     `pt.engine.dispatch` / `.prefill.dispatch` span that made the call and
     on the `.fetch` / `.prefill.fetch` span that reads its tokens (it rides
-    in the unread record for decode), so a reader of a trace joins each
+    in the unread records), so a reader of a trace joins each
     call to its execution on the device, first in first out. Each number
     is read exactly once, after its dispatch span closed (an unread
-    iteration that `close` or a failure drops is never read); page copies,
+    program that `close` or a failure drops is never read); page copies,
     injections and `eager` decode's per-op calls carry none.
+    `.prefill.fetch` opens under `pt.engine.step`, after the `.dispatch`
+    of the iteration behind it, with `behind` 1 where a later program was
+    dispatched before the read (`stats["prefill_reads_behind"]`: over
+    `prefills`, the share the mechanism engages); the reads made at once
+    for a last token open under `pt.engine.admit`.
     `stats["read_wait_s"]` over `stats["step_wall_s"]` is the share of the
     loop spent inside the two reads: near 1 the device sets the pace.
     `stats["iterations"]` counts dispatches, `decode_tokens`
@@ -668,7 +686,8 @@ class ServingEngine:
     sequence or the budget (`discarded_tokens`); `_ensure_capacity` owns
     exclusively every page through the furthest row the undrained run
     may write; the prefill program also runs the module over the prompt
-    (`draft_prefill`) and returns the first draft beside the first token;
+    (`draft_prefill`) and returns the first draft beside the first token,
+    both written into the slot's row with the next sample's index;
     the prefix cache shares a page only where the token AFTER it matches
     too (the module's row i is made from token i + 1) and never a partial
     tail. The `pt.engine.bookkeep` span then carries `seq` and `tokens`
@@ -796,6 +815,10 @@ class ServingEngine:
         # for from another thread (a governor's `shrink_pool`, a status
         # page's `device_counters`) out of a step
         self._inflight: Optional[tuple] = None
+        # the prefills dispatched and not read back yet, in launch order:
+        # (first token on the device, Request, launch number, bucket,
+        # prompt tokens)
+        self._unread_prefills: List[tuple] = []
         self._step_lock = threading.RLock()
         self._closed = False
         self._audited = False
@@ -833,7 +856,7 @@ class ServingEngine:
                       "handoffs": 0, "worker_prefills": 0,
                       "table_refreshes": 0, "h2d_transfers": 0,
                       "ahead_iterations": 0, "drained_for_length": 0,
-                      "discarded_tokens": 0,
+                      "discarded_tokens": 0, "prefill_reads_behind": 0,
                       "draft_tokens": 0, "accepted_tokens": 0,
                       "page_groups_live": 0, "page_groups_walked": 0,
                       "launches": 0, "read_wait_s": 0.0, "step_wall_s": 0.0,
@@ -863,7 +886,7 @@ class ServingEngine:
         # cache (the page pools update in place)
         self._fused_jit = jax.jit(self._fused_step_fn,
                                   donate_argnums=(2, 3))
-        self._prefill_jit = jax.jit(self._prefill_fn, donate_argnums=(2,))
+        self._prefill_jit = jax.jit(self._prefill_fn, donate_argnums=(2, 3))
         # disagg handoff injection: ONE donated executable per pow2
         # page-count bucket scatters a prefill worker's page payload
         # into the (possibly head-sharded) pools in place
@@ -1043,10 +1066,15 @@ class ServingEngine:
         return out, cache, last.at[slot_map].set(
             jnp.stack([pick(sampled), pick(guesses), steps + n_new], axis=1))
 
-    def _prefill_fn(self, params, buffers, cache, ids, scalars, floats):
+    def _prefill_fn(self, params, buffers, cache, last_tokens, ids, scalars,
+                    floats):
         """`scalars` int32 [6] is slot, length, write start, top-k, seed,
         step; `floats` float32 [2] temperature, top-p (`_prefill` packs
-        them on the host)."""
+        them on the host). The first token also goes into the slot's row
+        of `last_tokens` (donated, as in `_fused_step_fn`), so that the
+        decode iteration after the admission needs nothing from the host;
+        a drafting model's row takes (token, first draft, step + 1)."""
+        import jax.numpy as jnp
         from ..jit import _swapped_state
         slot, length, write_start = scalars[0], scalars[1], scalars[2]
         top_k, seed, step = scalars[3:4], scalars[4:5], scalars[5:6]
@@ -1054,7 +1082,6 @@ class ServingEngine:
         if self._drafts:
             # the first token, then the module over the prompt with it:
             # int32 [2], the token and the first standing draft
-            import jax.numpy as jnp
             with tape_mod.no_grad(), _swapped_state(self.model, params,
                                                     buffers):
                 logits, cache, hid = self.model.forward_prefill(
@@ -1065,15 +1092,17 @@ class ServingEngine:
                 guess, cache = self.model.draft_prefill(
                     hid, Tensor(ids), nxt, cache, slot, length,
                     write_start=write_start)
-            return jnp.concatenate(
-                [nxt, jnp.argmax(guess.data, -1).astype(jnp.int32)]), cache
+            nxt = jnp.concatenate(
+                [nxt, jnp.argmax(guess.data, -1).astype(jnp.int32)])
+            return nxt, cache, last_tokens.at[slot].set(
+                jnp.concatenate([nxt, step + 1]))
         with tape_mod.no_grad(), _swapped_state(self.model, params, buffers):
             logits, cache = self.model.forward_prefill(
                 Tensor(ids), cache, slot, length, write_start=write_start)
         # the FIRST generated token samples in-graph too (step counter 0,
         # or len(generated) on a post-preemption re-prefill)
         nxt = sample_logits(logits.data, temp, top_k, top_p, seed, step)
-        return nxt, cache
+        return nxt, cache, last_tokens.at[slot].set(nxt[0])
 
     def audit(self, emit: bool = True):
         """Statically audit the fused decode step (smallest lane bucket)
@@ -1107,11 +1136,11 @@ class ServingEngine:
         bucket = self.prefill_buckets[0]
         prefill = analysis.audit_program(
             self._prefill_fn,
-            (self._params, self._buffers, self.cache,
+            (self._params, self._buffers, self.cache, self._last_tokens,
              np.zeros((1, bucket), np.int32),
              np.array([0, 1, 0, 0, 0, 0], np.int32),  # slot 0, length 1
              np.array([0.0, 1.0], np.float32)),        # greedy
-            donate_argnums=(2,), relayout_of=pools,
+            donate_argnums=(2, 3), relayout_of=pools,
             name=f"serving_prefill:{self.name}", entry="serving_prefill",
             emit=emit)
         reports = [decode, prefill]
@@ -1226,14 +1255,15 @@ class ServingEngine:
         with self._lock:
             busy = bool(self._queue) or any(
                 r is not None for r in self._slots)
-        if busy or self._inflight is not None:
+        if busy or self._inflight is not None or self._unread_prefills:
             return True
         src = self.handoff_source
         return src is not None and src._handoff_peek() is not None
 
     def step(self) -> int:
         """ONE continuous-batching iteration: admit waiting requests into
-        free slots (bucketed prefill each, shared-prefix pages forked),
+        free slots (bucketed prefill each, shared-prefix pages forked,
+        the first token read after the decode dispatch below),
         grow pages for sequences crossing a page boundary and fork any
         shared page about to be written (copy-on-write), preempting the
         youngest on pool exhaustion, then one fused decode dispatch, whose
@@ -1357,7 +1387,7 @@ class ServingEngine:
         try:
             self._drain()
         except Exception:  # noqa: BLE001 — a dead device's tokens cannot
-            self._inflight = None  # be read: their requests fail below
+            pass           # be read: their requests fail below
         self._fail_outstanding("engine closed")
 
     def _fail_outstanding(self, error: str):
@@ -1365,7 +1395,8 @@ class ServingEngine:
             leftovers = list(self._queue) + [r for r in self._slots
                                              if r is not None]
             self._queue.clear()
-        self._inflight = None  # its requests fail with the rest
+        # what is unread fails with the rest
+        self._inflight, self._unread_prefills = None, []
         for req in leftovers:
             self._complete(req, "failed", error=error)
 
@@ -1780,15 +1811,18 @@ class ServingEngine:
                               requeue)
             if _metrics.enabled():
                 _M_QUEUE.set(depth, model=self.name)
-            if req.state != "running":
-                continue  # single-token request finished at prefill
-            self._cur_tokens[slot] = req.generated[-1]
+            if not self._may_run_ahead([req]):
+                # its first token is its last: read now, so that the slot
+                # it frees is free for this very admission loop
+                self._read_prefills()
 
     def _prefill(self, req: Request, slot: int, tokens: List[int],
                  pages: List[int], shared_len: int, bucket: int,
                  requeue: bool):
         """One admission past the queue: block-table row and padded ids
-        on the host, the bucketed prefill program, the first token."""
+        on the host, the bucketed prefill program. The first token stays
+        unread, on the device and in the slot's row of `_last_tokens`,
+        until `_read_prefills`."""
         if shared_len:
             self.stats["shared_admissions"] += 1
             self.stats["prefix_hit_tokens"] += shared_len
@@ -1818,9 +1852,9 @@ class ServingEngine:
             with _span("prefill.dispatch", seq=seq, transfers=transfers), \
                     self._dispatch_lock:
                 self._refresh_tables()
-                nxt, self.cache = self._prefill_jit(
-                    self._params, self._buffers, self.cache, ids, scalars,
-                    floats)
+                nxt, self.cache, self._last_tokens = self._prefill_jit(
+                    self._params, self._buffers, self.cache,
+                    self._last_tokens, ids, scalars, floats)
         finally:
             _cw.pop_entry(prev)
         self.stats["launches"] += 1
@@ -1831,25 +1865,40 @@ class ServingEngine:
         self.stats["prefill_padded_tokens"] += bucket - len(tokens)
         if self.share_prefix:
             self._prefix.register(tokens, pages)
-        t0 = time.perf_counter()
-        with _span("prefill.fetch", seq=seq):
-            got = np.asarray(nxt)
-            tok = int(got[0])
-        if self._drafts:
-            self._cur_drafts[slot] = got[1]
-            req.drafts.append((len(req.generated) + 1, int(got[1])))
-        self.stats["read_wait_s"] += time.perf_counter() - t0
-        self.tracer.prefill_done(req.rid)
-        now = time.monotonic()
-        if req.first_token_ts is None:
-            req.first_token_ts = now
-            if _metrics.enabled() and req.ttft_s is not None:
-                _M_TTFT.observe(req.ttft_s, model=self.name,
-                                path=self.decode_mode)
-            if req.ttft_s is not None:
-                self.slo.observe("ttft", req.ttft_s)
-        self._emit_admission(req, bucket, len(tokens))
-        self._record_token(req, tok)
+        # until it is read the token is the device's alone
+        req.unread += 1
+        self._cur_tokens[slot] = -1
+        self._unread_prefills.append((nxt, req, seq, bucket, len(tokens)))
+
+    def _read_prefills(self):
+        """Read and book the first token of every admission not read yet,
+        in the order of their launches. `behind` on the span, and
+        `stats["prefill_reads_behind"]`, say that a later program was
+        dispatched before the read: the device had it queued behind the
+        prefill while the host waited for the token."""
+        while self._unread_prefills:
+            nxt, req, seq, bucket, prompt_len = self._unread_prefills.pop(0)
+            behind = int(self.stats["launches"] > seq + 1)
+            t0 = time.perf_counter()
+            with _span("prefill.fetch", seq=seq, behind=behind):
+                got = np.asarray(nxt)
+            self.stats["read_wait_s"] += time.perf_counter() - t0
+            self.stats["prefill_reads_behind"] += behind
+            req.unread -= 1
+            if req.state != "running":
+                continue   # failed while its token was unread
+            if self._drafts:
+                req.drafts.append((len(req.generated) + 1, int(got[1])))
+            self.tracer.prefill_done(req.rid)
+            if req.first_token_ts is None:
+                req.first_token_ts = time.monotonic()
+                if _metrics.enabled() and req.ttft_s is not None:
+                    _M_TTFT.observe(req.ttft_s, model=self.name,
+                                    path=self.decode_mode)
+                if req.ttft_s is not None:
+                    self.slo.observe("ttft", req.ttft_s)
+            self._emit_admission(req, bucket, prompt_len)
+            self._record_token(req, int(got[0]))
 
     def _alloc_one_or_preempt(self, req: Request) -> Optional[int]:
         """One fresh page for `req`, preempting the youngest runner on a
@@ -1860,8 +1909,8 @@ class ServingEngine:
             if got is not None:
                 self._note_pool_watermark()
                 return got[0]
-            if self._inflight is not None:
-                # an end of sequence in the unread iteration frees pages,
+            if self._inflight is not None or self._unread_prefills:
+                # an end of sequence among the unread tokens frees pages,
                 # and may be `req`'s own
                 self._drain()
                 if req.state != "running":
@@ -1903,8 +1952,8 @@ class ServingEngine:
             ctx = len(req.prompt) + len(req.generated) + req.unread
             # the rows this dispatch may write: from the one after the
             # fewest tokens the unread iteration yields to the draft's
-            # after the most
-            first = ctx - 1 - req.unread + req.unread // per
+            # after the most (an unread prefill yields exactly one)
+            first = ctx - 1 - self._drafts * (req.unread // per)
             last = ctx - 1 + self._drafts
             # never past what `make_request` held the pool to
             need = min((last + 1) // ps + 1,
@@ -2038,6 +2087,9 @@ class ServingEngine:
             self._count_page_walk(self._context_lens[active_slots],
                                   W - len(reqs))
         self.stats["decode_wall_s"] += time.perf_counter() - t0
+        # the admissions' first tokens, while the device runs this
+        # iteration behind their prefills
+        self._read_prefills()
         if in_flight is not None:
             self._read(in_flight)
         if not self._may_run_ahead(reqs):
@@ -2064,9 +2116,11 @@ class ServingEngine:
                    for r in reqs if r.state == "running")
 
     def _drain(self):
-        """Read and book the unread iteration, if there is one. Whatever
-        takes, frees or moves a slot outside `_read` calls this first."""
+        """Read and book the unread prefills and iteration, if there are
+        any. Whatever takes, frees or moves a slot outside `_read` calls
+        this first."""
         with self._step_lock:
+            self._read_prefills()
             in_flight, self._inflight = self._inflight, None
             if in_flight is not None:
                 self._read(in_flight)

@@ -19,6 +19,7 @@ fast-sibling: tier-1-fast (XLA decode path on the CPU).
 """
 import os
 
+import jax
 import numpy as np
 import pytest
 
@@ -335,6 +336,35 @@ def test_the_token_row_is_donated_and_costs_no_transfer():
     # what the row holds for a slot is the last token sampled there
     np.testing.assert_array_equal(
         np.asarray(eng._last_tokens)[:2], [r.generated[-1] for r in reqs])
+    eng.close()
+
+
+def test_the_prefill_program_updates_the_token_row_in_place():
+    """The prefill program takes the row too and writes the slot's first
+    token into it: XLA aliases the donated row to the program's output,
+    `audit()` counts no copy of a pool in it, and the row holds the token
+    before the host has read it."""
+    from paddle_tpu.analysis.auditor import accepted_donations
+    eng = ServingEngine(model("gpt"), max_batch=2, max_len=48,
+                        page_size=PAGE, name="ht_prefill_row")
+    _, prefill = eng.audit(emit=False)
+    assert prefill.pool_relayout_copies == 0, prefill.render()
+    assert not [f for f in prefill.findings if f.check == "donation"], \
+        prefill.render()
+    args = (eng._params, eng._buffers, eng.cache, eng._last_tokens,
+            np.zeros((1, eng.prefill_buckets[0]), np.int32),
+            np.array([1, 5, 0, 0, 0, 0], np.int32),
+            np.array([0.0, 1.0], np.float32))
+    lowered = jax.jit(eng._prefill_fn, donate_argnums=(2, 3)).lower(*args)
+    row = len(jax.tree_util.tree_leaves(args[:3]))
+    assert row in accepted_donations(lowered.as_text())
+    req = eng.submit(prompts([9], 12)[0], max_new_tokens=4)
+    eng._admit()
+    before = eng._last_tokens
+    assert req.unread == 1 and not req.generated
+    token = int(np.asarray(eng._last_tokens)[req.slot])
+    eng._drain()
+    assert req.generated == [token] and eng._last_tokens is before
     eng.close()
 
 

@@ -480,7 +480,7 @@ class TestScopes:
             eng._params, eng._buffers, eng.cache, eng._last_tokens,
             *lanes).as_text(debug_info=True)
         prefill = jax.jit(eng._prefill_fn).lower(
-            eng._params, eng._buffers, eng.cache,
+            eng._params, eng._buffers, eng.cache, eng._last_tokens,
             np.zeros((1, 16), np.int32),
             np.array([0, 5, 0, 0, 0, 0], np.int32),
             np.array([0.0, 1.0], np.float32)).as_text(debug_info=True)

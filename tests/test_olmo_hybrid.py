@@ -451,7 +451,7 @@ class TestScopes:
             eng._params, eng._buffers, eng.cache, eng._last_tokens, lanes_i,
             lanes_f)
         prefill = jax.jit(eng._prefill_fn).lower(
-            eng._params, eng._buffers, eng.cache,
+            eng._params, eng._buffers, eng.cache, eng._last_tokens,
             np.zeros((1, 16), np.int32),
             np.array([0, 5, 0, 0, 0, 0], np.int32),     # slot 0, length 5
             np.array([0.0, 1.0], np.float32))

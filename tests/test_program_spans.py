@@ -35,7 +35,8 @@ ENGINE_SPANS = {
     "pt.engine.prefill.build": ("pt.engine.prefill", set()),
     "pt.engine.prefill.dispatch": ("pt.engine.prefill", {"seq",
                                                          "transfers"}),
-    "pt.engine.prefill.fetch": ("pt.engine.prefill", {"seq"}),
+    # read after the decode dispatch behind it (`behind`), in the step
+    "pt.engine.prefill.fetch": ("pt.engine.step", {"seq", "behind"}),
     "pt.engine.capacity": ("pt.engine.step", {"active"}),
     "pt.engine.lanes": ("pt.engine.step", {"lanes", "active"}),
     "pt.engine.upload": ("pt.engine.step", {"transfers"}),
@@ -132,7 +133,8 @@ def serve(directory=None, state_layers=False, window_layers=False):
              for k in ("iterations", "prefills", "completed",
                        "h2d_transfers", "table_refreshes",
                        "ahead_iterations", "drained_for_length",
-                       "launches", "read_wait_s", "step_wall_s")}
+                       "prefill_reads_behind", "launches", "read_wait_s",
+                       "step_wall_s")}
     eng.close()
     return {"reqs": reqs, "events": events, "delta": delta,
             "programs": programs}
@@ -272,6 +274,29 @@ def test_every_launch_is_read_once_after_its_call_closed(served):
     behind = sum(step_of(by_seq[c["args"]["seq"]]) - step_of(c)
                  for c in named(ev, "pt.engine.dispatch"))
     assert behind == served["delta"]["ahead_iterations"]
+
+
+def test_a_prefills_token_is_read_behind_the_decode_dispatched_after_it(
+        served):
+    """A prefill's `.prefill.fetch` opens in the step that admitted it,
+    after the `.dispatch` of that step's decode iteration, which carries a
+    later launch number; `behind` says so, and `prefill_reads_behind`
+    counts it. No output of the round is one token long, so every
+    admission is read behind."""
+    ev, delta = served["events"], served["delta"]
+    fetches = named(ev, "pt.engine.prefill.fetch")
+    assert len(fetches) == delta["prefills"] == delta["prefill_reads_behind"]
+    assert {f["args"]["behind"] for f in fetches} == {1}
+    for f in fetches:
+        step, = [s for s in named(ev, "pt.engine.step")
+                 if s["start"] <= f["start"] and f["end"] <= s["end"]]
+        before = [d["args"]["seq"] for d in named(ev, "pt.engine.dispatch")
+                  if step["start"] <= d["start"] and d["end"] <= f["start"]]
+        assert len(before) == 1 and before[0] > f["args"]["seq"]
+    # the reads of one step follow the launches' order
+    seqs = [f["args"]["seq"] for f in sorted(fetches,
+                                             key=lambda f: f["start"])]
+    assert seqs == sorted(seqs)
 
 
 @pytest.mark.parametrize("counter, spans", [

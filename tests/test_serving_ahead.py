@@ -1,12 +1,15 @@
-"""One decode iteration in flight behind the host (PR 32).
+"""One decode iteration in flight behind the host, and the
+prefills' first tokens read behind the decode iteration after them.
 
 `ServingEngine.step` dispatches iteration N+1 before it reads N's tokens
 whenever N cannot end a request by its length: each slot's last token
-stays on the device (`_last_tokens`, donated through the decode program),
-the host sends -1 for "take the row's", and the one unread iteration is
-read where a slot is about to be taken, freed or moved. The one predicate
-is `ServingEngine._may_run_ahead`; patched to False it gives the engine
-that reads every iteration in the step that dispatched it.
+stays on the device (`_last_tokens`, donated through the decode and the
+prefill programs), the host sends -1 for "take the row's", and the one
+unread iteration is read where a slot is about to be taken, freed or
+moved. A prefill's token is unread the same way until the step's decode
+iteration has been dispatched. The one predicate is
+`ServingEngine._may_run_ahead`; patched to False it gives the engine that
+reads every prefill and every iteration at once.
 
 Pinned here: the same tokens request by request, greedy and sampled,
 fused and eager, for a GPT, a model with recurrent-state layers and one
@@ -14,7 +17,10 @@ with expert and state-space blocks; the counters; a request's last token
 by length is read in the step that dispatched it; an end of sequence met
 one iteration late drops exactly one token and the slot's next tenant
 gets none of the old one's; every path that changes slots drains first;
-the page a run-ahead iteration writes is the one that is forked.
+the page a run-ahead iteration writes is the one that is forked; a mix
+of one- and two-token outputs, an end of sequence at the first token,
+a preemption and a prefix hit gives the same tokens with the prefills
+read behind, read at once and decoded eagerly.
 
 fast-sibling: tier-1-fast (XLA decode path on the CPU).
 """
@@ -71,7 +77,8 @@ def model(kind: str):
 
 @pytest.fixture
 def sync(monkeypatch):
-    """Switch running ahead off: every iteration is read at once."""
+    """Switch running ahead off: every prefill and every iteration is
+    read at once."""
     def off():
         monkeypatch.setattr(ServingEngine, "_may_run_ahead",
                             staticmethod(lambda reqs: False))
@@ -111,8 +118,10 @@ def closed_loop(eng, work, clients):
             if lanes[c] is not None and lanes[c].state in ("done", "failed"):
                 lanes[c] = None
             if lanes[c] is None and work:
-                prompt, n, sp = work.pop(0)
-                lanes[c] = eng.submit(prompt, max_new_tokens=n, sampling=sp)
+                # (prompt, max_new_tokens, sampling[, eos_id])
+                prompt, n, sp, *eos = work.pop(0)
+                lanes[c] = eng.submit(prompt, max_new_tokens=n, sampling=sp,
+                                      eos_id=eos[0] if eos else None)
                 reqs.append(lanes[c])
         if not eng.pending():
             return reqs
@@ -470,3 +479,121 @@ def test_the_page_a_run_ahead_iteration_writes_is_forked_first(sync):
     got = run("ah_cow", share=True)
     sync()
     assert got == run("ah_cow_ref", share=False)
+
+
+# ---- (f) a prefill's first token is read behind the decode after it
+
+def _mixed_work(sampled):
+    """(prompt, max_new_tokens, sampling, eos_id) for two clients: a one-
+    and a two-token output, a request whose end of sequence is its first
+    token, two requests with one prompt (the second admitted while the
+    first holds its pages: a prefix hit), two long ones that outgrow a
+    pool of eight pages together (a preemption, and a prefill again with
+    what the victim had generated), and a short one after them."""
+    rng = np.random.default_rng(11)
+    prompt = lambda n: rng.integers(1, 256, (n,)).tolist()  # noqa: E731
+    sps = [SamplingParams(temperature=0.8, top_k=(0, 20)[i % 2],
+                          top_p=(1.0, 0.9)[i % 2], seed=300 + i)
+           if sampled else None for i in range(8)]
+    shared, eos_first = prompt(16), prompt(10)
+    probe = engine(name=f"ah_first_{int(sampled)}")
+    first = probe.submit(eos_first, max_new_tokens=1, sampling=sps[2])
+    probe.run_until_idle()
+    probe.close()
+    return [(prompt(16), 1, sps[0], -1), (prompt(9), 2, sps[1], -1),
+            (eos_first, 6, sps[2], first.result(timeout=5)[0]),
+            (shared, 8, sps[3], -1), (shared, 8, sps[4], -1),
+            (prompt(14), 30, sps[5], -1), (prompt(14), 30, sps[6], -1),
+            (prompt(7), 3, sps[7], -1)]
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+def test_a_mix_gives_the_tokens_of_the_prefills_read_at_once_and_of_eager(
+        sampled, sync):
+    work = _mixed_work(sampled)
+
+    def run(name, mode="fused"):
+        eng = engine(name=name, decode_mode=mode, num_pages=9)
+        reqs = closed_loop(eng, work, clients=2)
+        out = [(r.generated, r.finish_reason) for r in reqs]
+        assert not eng.allocator.outstanding()
+        assert not eng._unread_prefills and eng._inflight is None
+        stats = dict(eng.stats)
+        eng.close()
+        return out, stats
+
+    behind, stats = run(f"ahp_mix_{int(sampled)}")
+    # the mix holds what it says: a one- and a two-token output, an end
+    # of sequence at the first token, a prefix hit, a preemption
+    assert [len(g) for g, _ in behind[:3]] == [1, 2, 1]
+    assert behind[2][1] == "eos"
+    assert stats["shared_admissions"] >= 1 and stats["preemptions"] >= 1
+    assert 0 < stats["prefill_reads_behind"] < stats["prefills"]
+    eager, _ = run(f"ahp_mix_eager_{int(sampled)}", mode="eager")
+    sync()
+    at_once, stats_sync = run(f"ahp_mix_sync_{int(sampled)}")
+    assert stats_sync["prefill_reads_behind"] == 0
+    assert behind == eager == at_once
+
+
+def test_every_admission_but_a_last_tokens_is_read_behind_a_later_launch():
+    eng = engine(name="ahp_behind", max_batch=3)
+    last_token = []                  # admissions whose first token is last
+    prefill = eng._prefill
+
+    def spy(req, *args):
+        last_token.append(req.max_new_tokens - len(req.generated) == 1)
+        return prefill(req, *args)
+
+    eng._prefill = spy
+    work = traffic(9, seed=12, sampled=True)
+    work.insert(4, (work[3][0], 5, None))          # a prefix hit
+    reqs = [eng.submit(p, max_new_tokens=n, sampling=sp)
+            for p, n, sp in work]
+    while eng.pending():
+        eng.step()
+        # every token a step dispatched by a prefill is read in the step
+        assert not eng._unread_prefills
+        for r in eng._slots:
+            if r is not None:
+                assert r.unread == int(eng._inflight is not None)
+    assert all(len(r.generated) == r.max_new_tokens for r in reqs)
+    s = eng.stats
+    assert s["shared_admissions"] >= 1 and not s["preemptions"]
+    assert len(last_token) == s["prefills"] == len(reqs)
+    assert 0 < sum(last_token) < len(last_token)
+    assert s["prefill_reads_behind"] == s["prefills"] - sum(last_token)
+    assert "prefill_reads_behind" in eng.status()["stats"]
+    eng.close()
+
+
+def test_a_drain_reads_the_unread_prefills_and_leaves_nothing_unread():
+    prompts = [np.random.default_rng(13).integers(1, 256, (n,)).tolist()
+               for n in (6, 12)]
+    eng = engine(name="ahp_drain")
+    reqs = [eng.submit(p, max_new_tokens=8) for p in prompts]
+    eng._admit()                     # both prefills dispatched, none read
+    assert len(eng._unread_prefills) == 2 and eng.pending()
+    assert [r.unread for r in reqs] == [1, 1]
+    assert not any(r.generated for r in reqs)
+    assert list(eng._cur_tokens) == [-1, -1]
+    eng._drain()
+    assert not eng._unread_prefills and eng._inflight is None
+    assert [r.unread for r in reqs] == [0, 0]
+    assert [len(r.generated) for r in reqs] == [1, 1]
+    assert eng.stats["prefill_reads_behind"] == 1   # the first, behind two
+    # the row holds what the prefill sampled, and the lanes feed from it
+    np.testing.assert_array_equal(np.asarray(eng._last_tokens)[:2],
+                                  [r.generated[0] for r in reqs])
+    eng.step()
+    eng._drain()
+    assert not eng._unread_prefills and eng._inflight is None
+    assert all(r.unread == 0 and len(r.generated) == 2 for r in reqs)
+    eng.run_until_idle()
+    got = [r.result(timeout=5) for r in reqs]
+    eng.close()
+    ref = engine(name="ahp_drain_ref")
+    want = [ref.submit(p, max_new_tokens=8) for p in prompts]
+    ref.run_until_idle()
+    assert got == [r.result(timeout=5) for r in want]
+    ref.close()

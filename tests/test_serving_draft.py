@@ -133,6 +133,46 @@ def test_tokens_equal_plain_decodings_with_both_branches_taken(sampled):
     eng.close()
 
 
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "sampled"])
+def test_the_first_draft_rides_in_the_row_with_the_unread_first_token(
+        sampled, monkeypatch):
+    """A prefill leaves (token, first draft, 1 + tokens before it) in the
+    slot's row, and the iteration behind it verifies that draft before
+    the host has read either: tokens AND drafts are those of the engine
+    that reads every prefill and iteration at once, and the tokens plain
+    decoding's."""
+    requests = traffic(7, seed=13, sampled=sampled)
+    requests.insert(2, (ids(9, seed=14), 1, None))        # a one-token one
+    eng = engine(name=f"row{int(sampled)}")
+    req = eng.submit(ids(11, seed=15), max_new_tokens=4)
+    eng._admit()
+    assert req.unread == 1 and not req.generated and not req.drafts
+    eng._drain()
+    (k, draft), = req.drafts
+    np.testing.assert_array_equal(np.asarray(eng._last_tokens)[req.slot],
+                                  [req.generated[0], draft, 1])
+    assert k == 1
+    eng.run_until_idle()
+    behind = serve(eng, requests)
+    s = eng.stats
+    assert 0 < s["prefill_reads_behind"] < s["prefills"]
+    assert s["ahead_iterations"] > 0
+    eng.close()
+    monkeypatch.setattr(ServingEngine, "_may_run_ahead",
+                        staticmethod(lambda reqs: False))
+    at_once_eng = engine(name=f"row_sync{int(sampled)}")
+    at_once = serve(at_once_eng, requests)
+    assert at_once_eng.stats["prefill_reads_behind"] == 0
+    at_once_eng.close()
+    assert [r.generated for r in behind] == [r.generated for r in at_once]
+    assert [r.drafts for r in behind] == [r.drafts for r in at_once]
+    want_eng = engine(plain(), name=f"row_plain{int(sampled)}")
+    want = [r.generated for r in serve(want_eng, requests)]
+    want_eng.close()
+    assert [r.generated for r in behind] == want
+
+
 def test_eager_mode_gives_the_same_tokens():
     requests = traffic(4, seed=5, sampled=False)
     fused, eager = engine(name="fused"), engine(name="eager",
@@ -224,8 +264,14 @@ def test_capacity_owns_every_page_the_undrained_run_may_write():
     eng = engine(name="capacity", max_batch=1, max_len=64)
     req = eng.submit(ids(13, seed=2), max_new_tokens=40)
     eng._admit()
-    assert len(req.generated) == 1 and len(req.pages) == 2
+    assert not req.generated and req.unread == 1 and len(req.pages) == 2
     slot = req.slot
+    # the prefill unread: it yields exactly one token, so the next
+    # dispatch writes rows 13 and 14, page 1
+    eng._ensure_capacity([slot])
+    assert len(req.pages) == 2
+    eng._drain()
+    assert len(req.generated) == 1 and req.unread == 0
     # nothing unread: rows 13 and 14 (known context 13), page 1
     eng._ensure_capacity([slot])
     assert len(req.pages) == 2
@@ -382,8 +428,10 @@ def _one_token_step(self, params, buffers, cache, last_tokens, lanes_i,
     return nxt, cache, last_tokens.at[slot_map].set(nxt)
 
 
-def _one_token_prefill(self, params, buffers, cache, ids, scalars, floats):
-    """`ServingEngine._prefill_fn` as it was."""
+def _one_token_prefill(self, params, buffers, cache, last_tokens, ids,
+                       scalars, floats):
+    """`ServingEngine._prefill_fn` as it was, with the first token left
+    in the slot's row of `last_tokens`."""
     from paddle_tpu.jit import _swapped_state
     slot, length, write_start = scalars[0], scalars[1], scalars[2]
     top_k, seed, step = scalars[3:4], scalars[4:5], scalars[5:6]
@@ -392,7 +440,7 @@ def _one_token_prefill(self, params, buffers, cache, ids, scalars, floats):
         logits, cache = self.model.forward_prefill(
             Tensor(ids), cache, slot, length, write_start=write_start)
     nxt = sample_logits(logits.data, temp, top_k, top_p, seed, step)
-    return nxt, cache
+    return nxt, cache, last_tokens.at[slot].set(nxt[0])
 
 
 def _other_model(kind):
@@ -420,7 +468,7 @@ def test_a_model_that_does_not_draft_lowers_to_the_program_it_did(kind):
     lanes = eng._lane_arrays([])[1:]
     assert lanes[0].shape == (6, 1)
     decode = (eng._params, eng._buffers, eng.cache, eng._last_tokens, *lanes)
-    prefill = (eng._params, eng._buffers, eng.cache,
+    prefill = (eng._params, eng._buffers, eng.cache, eng._last_tokens,
                np.zeros((1, 16), np.int32),
                np.array([0, 5, 0, 0, 0, 0], np.int32),
                np.array([0.0, 1.0], np.float32))

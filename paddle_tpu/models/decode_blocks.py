@@ -125,13 +125,14 @@ class GroupedAttention(nn.Layer):
                             self.inv_freq, self.rope_factor, self.rope_kind)
         return q, k, v
 
-    def attend(self, q, k, v):
+    def attend(self, q, k, v, length=None):
         """A prompt's attention under this layer's mask: the flash
-        kernel's forward, which skips what lies outside the band, its
-        products at `highest` (they sit in front of a router)."""
+        kernel's forward, which skips what lies outside the band and past
+        the prompt's live rows `length` (None: every row), its products
+        at `highest` (they sit in front of a router)."""
         from ..ops.pallas import flash_attention as _fa
         return _fa.flash_attention(q, k, v, causal=True, window=self.window,
-                                   precision="highest")
+                                   precision="highest", length=length)
 
     def output(self, out):
         """The heads' outputs ``[B, L, H, D]`` -> the layer's Tensor."""

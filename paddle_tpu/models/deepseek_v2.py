@@ -30,8 +30,9 @@ layer ONE pool of rows ``[c_kv ; rotated k_pe ; zeros]``, 640 wide for
 programs compute the same attention two ways:
 
 * PREFILL, EXPANDED: c_kv goes through W_UK and W_UV into every head's
-  K and V, and the flash forward runs causal over the bucket (q and k of
-  nope + rope, v of its own width), `_PREFILL_HEADS` heads at a time (and
+  K and V, and the flash forward runs causal over the prompt's live rows
+  of the bucket (q and k of nope + rope, v of its own width; the padding's
+  steps compute and fetch nothing), `_PREFILL_HEADS` heads at a time (and
   W_o by those heads' rows) so that the expanded q, K and V of a long
   prompt never exist for all heads at once; the latent rows are appended
   to the layer's pages.
@@ -265,11 +266,13 @@ class LatentAttention(nn.Layer):
             [c_kv, k_pe, jnp.zeros(c_kv.shape[:-1] + (pad,), c_kv.dtype)],
             axis=-1)
 
-    def expanded(self, c_q, q_pe, c_kv, k_pe):
+    def expanded(self, c_q, q_pe, c_kv, k_pe, length=None):
         """Prompts' attention, causal, EXPANDED: `_PREFILL_HEADS` heads a
         pass (their q_nope, K and V made for them alone, the flash forward
         over them, their share of W_o added in). c_q, q_pe, c_kv, k_pe as
-        `project` gives them. Returns [B, L, h]."""
+        `project` gives them; `length` the prompt's live rows (the flash
+        forward computes nothing past them; None: every row). Returns
+        [B, L, h]."""
         from ..ops.pallas import flash_attention as _fa
         B, L = c_kv.shape[:2]
         n = min(self.heads, _PREFILL_HEADS)
@@ -290,7 +293,7 @@ class LatentAttention(nn.Layer):
                 [k_nope, jnp.broadcast_to(k_pe[:, :, None],
                                           (B, L, n, self.rope_dim))], -1)
             o = _fa.flash_attention(q, k, v, causal=True, scale=self.scale,
-                                    precision="highest")
+                                    precision="highest", length=length)
             return out + jnp.einsum("blhv,hvo->blo", o, at(w_o),
                                     precision=_HI)
 
@@ -432,15 +435,15 @@ class DeepseekV2(_blocks.TokensToLogits, nn.Layer):
         return x, counted
 
     @staticmethod
-    def _expanded(attn, u, positions, append=None):
+    def _expanded(attn, u, positions, append=None, length=None):
         """Whole prompts [B, L] through MLA, expanded; a prompt's rows
         handed to `append(rows [L, width])` first (B = 1; None: no
-        cache)."""
+        cache), its live rows `length` (None: every row)."""
         c_q, _, q_pe, c_kv, k_pe = attn.project(u, positions, False)
         with jax.named_scope("latent"), jax.named_scope("prefill"):
             if append is not None:
                 append(attn.rows(c_kv, k_pe)[0])
-            return attn.expanded(c_q, q_pe, c_kv, k_pe)
+            return attn.expanded(c_q, q_pe, c_kv, k_pe, length)
 
     def forward(self, input_ids):
         """Whole-sequence logits [B, L, V] (B = 1), no cache: the expanded
@@ -510,7 +513,7 @@ class DeepseekV2(_blocks.TokensToLogits, nn.Layer):
                 cache.k_pages[i] = _pa.latent_prefill_append(
                     cache.k_pages[i], rows, page_row, length,
                     start=write_start)
-            return self._expanded(attn, u, p, append)
+            return self._expanded(attn, u, p, append, length)
 
         x, counted = self._layers(self._embed(input_ids), positions, attend,
                                   positions[None] < length)

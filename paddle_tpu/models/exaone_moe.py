@@ -483,7 +483,7 @@ class ExaoneMoe(_blocks.TokensToLogits, nn.Layer):
                 _blocks.paged_prefill_append(cache, cache.index_of(li),
                                              *rows, page_row, length,
                                              write_start)
-            return blk.attn.attend(q, k, v)
+            return blk.attn.attend(q, k, v, length)
 
         hid, counted = self._layers(self._embed(input_ids), positions,
                                     attend, positions[None] < length)
@@ -516,7 +516,7 @@ class ExaoneMoe(_blocks.TokensToLogits, nn.Layer):
             _blocks.paged_prefill_append(
                 cache, i, k[0].reshape(L, -1), v[0].reshape(L, -1),
                 page_row, length, write_start)
-            return blk.attn.attend(q, k, v)
+            return blk.attn.attend(q, k, v, length)
 
         with jax.named_scope("mtp"):
             x, counters = self._draft(hid, Tensor(following), positions,

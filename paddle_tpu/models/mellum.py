@@ -327,7 +327,7 @@ class Mellum(_blocks.TokensToLogits, nn.Layer):
                     else:
                         _blocks.paged_prefill_append(
                             cache, i, *rows, page_row, length, write_start)
-                    out = blk.attn.attend(q, k, v)
+                    out = blk.attn.attend(q, k, v, length)
                 h = x + blk.attn.output(out)
             x, counters = blk.experts(h, real)
             computed = computed + counters[:1]

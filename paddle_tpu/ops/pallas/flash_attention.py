@@ -34,7 +34,13 @@ saves softmax-out for bwd, and handles arbitrary attention masks). Here:
   Mosaic takes "default", one bfloat16 pass, or "highest"), for a model
   whose attention sits in front of a router; V may have a head size of
   its own (DeepSeek-V2's prefill: q and k of 192, v of 128), which the
-  output takes. The backward pass refuses all four by name.
+  output takes. Its walk (`_live_walk`) covers only the (q-block,
+  key-block) steps that hold a live query and a key it may see: the
+  steps above the diagonal, outside the band, and past `length` (a
+  prompt's live rows in its bucket, a scalar the kernel takes by scalar
+  prefetch) compute nothing and fetch nothing new, and the rows at and
+  past `length` come out as zeros. The backward pass refuses all five by
+  name.
 
 `_stats` counts dispatch decisions at trace time so tests can assert the
 kernel path is actually exercised (round-1 review found the old fwd-only
@@ -60,9 +66,10 @@ from .tiling import zero_tail_rows as _zero_tail_rows
 _NEG = -1e30
 
 # dispatch decisions, counted at trace time (reset freely in tests)
-# ("window": forward-only calls with a `window`, on either path)
+# ("window": forward-only calls with a `window`, on either path;
+# "live_length": forward-only calls given a `length` that took the kernel)
 _stats = {"pallas": 0, "pallas_fwd": 0, "pallas_bwd": 0, "xla": 0,
-          "window": 0}
+          "window": 0, "live_length": 0}
 
 # tests set True: kernels run in the Pallas interpreter on CPU, so the
 # real kernel logic + custom_vjp wiring is exercised without a TPU
@@ -211,18 +218,43 @@ def _apply_mask(s, mask_ref, mask_is_bool, rows, cols, q_len, kv_len,
 def _band_k_blocks(i, block_q, block_k, kv_offset, window, n_k):
     """(first, last) key block that holds a key some query of q-block `i`
     may see under ``row - window < col <= row`` (rows shifted by
-    `kv_offset`); `i` a Python int or a traced one."""
+    `kv_offset`; `window` None: ``col <= row``); `i` a Python int or a
+    traced one."""
     first_row = i * block_q + kv_offset
-    lo = jnp.maximum((first_row - window + 1) // block_k, 0)
+    lo = 0 if window is None else jnp.maximum(
+        (first_row - window + 1) // block_k, 0)
     hi = jnp.minimum((first_row + block_q - 1) // block_k, n_k - 1)
     return lo, hi
 
 
+def _live_walk(i, j, n, block_q, block_k, kv_offset, window, n_k):
+    """Step (q-block `i`, key block `j`) of a causal forward-only call whose
+    first `n` query rows are live (the rest a prompt's bucket padding, so
+    the keys from row ``n + kv_offset`` on are padding too): (the q block
+    and the key block the step names, whether it computes). A step
+    computes iff q-block `i` holds a live row and key block `j` a key one
+    of them may see (`_band_k_blocks`, cut at the last live key); every
+    other step names the block the step before it named, so the pipeline
+    fetches nothing for it. What the block index maps and the kernel's
+    `pl.when` both read; Python ints or traced ones."""
+    last_q = (jnp.maximum(n, 1) - 1) // block_q
+    qi = jnp.minimum(i, last_q)
+    lo, hi = _band_k_blocks(qi, block_q, block_k, kv_offset, window, n_k)
+    hi = jnp.minimum(hi, (jnp.maximum(n, 1) - 1 + kv_offset) // block_k)
+    kj = jnp.where(i > last_q, hi, jnp.clip(j, lo, hi))
+    return qi, kj, (i * block_q < n) & (j >= lo) & (j <= hi)
+
+
 def _fa_fwd_kernel(*refs, scale, causal, has_mask, mask_is_bool, block_q,
-                   block_k, q_len, kv_len, kv_offset, n_k, window=None):
-    """Grid (B, H, q-blocks, k-blocks); online softmax carried in scratch."""
+                   block_k, q_len, kv_len, kv_offset, n_k, window=None,
+                   live=False):
+    """Grid (B, H, q-blocks, k-blocks); online softmax carried in scratch.
+    `live`: the first ref is the live rows' count (scalar prefetch), and
+    the walk is `_live_walk`'s."""
     from jax.experimental import pallas as pl
 
+    if live:
+        n_ref, refs = refs[0], refs[1:]
     if has_mask:
         mask_ref, q_ref, k_ref, v_ref = refs[:4]
         o_ref, lse_ref, acc_ref, m_ref, l_ref = refs[4:]
@@ -297,6 +329,9 @@ def _fa_fwd_kernel(*refs, scale, causal, has_mask, mask_is_bool, block_q,
                                    n_k)
             active = active & (j >= lo)
             interior = interior & (j * block_k > last_row - window)
+        if live:
+            active = active & _live_walk(i, j, n_ref[0], block_q, block_k,
+                                         kv_offset, window, n_k)[2]
         pl.when(active & interior)(lambda: _compute(False))
         pl.when(active & jnp.logical_not(interior))(lambda: _compute(True))
     else:
@@ -306,7 +341,14 @@ def _fa_fwd_kernel(*refs, scale, causal, has_mask, mask_is_bool, block_q,
     def _finalize():
         m = m_ref[...][:, :1]
         l = jnp.maximum(l_ref[...][:, :1], 1e-30)
-        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o = acc_ref[...] / l
+        if live:
+            # padding rows come out as zeros (a q-block past the live rows
+            # computed nothing: its accumulator is already zero)
+            rows = i * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, o.shape, 0)
+            o = jnp.where(rows < n_ref[0], o, 0.0)
+        o_ref[...] = o.astype(o_ref.dtype)
         # row stats live in a [.., L, 8]-padded layout: Mosaic requires the
         # last two block dims be (8k, 128k) or equal to the array dims — a
         # 1-D (block_q,) stats block is rejected once B/H are squeezed
@@ -718,12 +760,15 @@ def _compiler_params(interpret, n_arbitrary=1):
     "causal", "scale", "mask_is_bool", "interpret", "blocks", "window",
     "precision"))
 def _fa_fwd_pallas(q, k, v, mask, causal, scale, mask_is_bool=False,
-                   interpret=False, blocks=None, window=None, precision=None):
+                   interpret=False, blocks=None, window=None, precision=None,
+                   live=None):
     """Returns (out [B,L,H,D], lse [B,H,Lq] f32). mask may be None.
-    `blocks` is (block_q, block_k); None = `_static_blocks`. `window`,
+    `blocks` is (block_q, block_k); None = `_static_blocks`. `live` (an
+    int32 scalar: the query rows before it are live), `window`,
     `precision`, K/V of fewer heads than q and V of another head size than
     q and K (the output takes V's): the module's docstring, "forward
-    only"."""
+    only"; a `window` walks `_live_walk`'s steps, every row live without
+    a `live`."""
     from jax.experimental import pallas as pl
 
     B, Lq, H, D = q.shape
@@ -733,21 +778,31 @@ def _fa_fwd_pallas(q, k, v, mask, causal, scale, mask_is_bool=False,
     qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
     n_q, n_k = pl.cdiv(Lq, block_q), pl.cdiv(Lk, block_k)
     grid = (B, H, n_q, n_k)
+    if window is not None and live is None:
+        live = Lq
     kernel = functools.partial(
         _fa_fwd_kernel, scale=scale, causal=causal, has_mask=mask is not None,
         mask_is_bool=mask_is_bool, block_q=block_q, block_k=block_k,
-        q_len=Lq, kv_len=Lk, kv_offset=Lk - Lq, n_k=n_k, window=window)
+        q_len=Lq, kv_len=Lk, kv_offset=Lk - Lq, n_k=n_k, window=window,
+        live=live is not None)
+    scalars = ()
+    if live is not None:
+        assert causal and mask is None, "a live length walks causal calls"
+        scalars = (jnp.reshape(live, (1,)).astype(jnp.int32),)
 
-    def kv_index(b, h, i, j):
-        if window is not None:
-            # a step outside the band names the block the band's nearest
-            # step named: the pipeline fetches nothing for it
-            j = jnp.clip(j, *_band_k_blocks(i, block_q, block_k, Lk - Lq,
-                                            window, n_k))
-        return (b, h if G == 1 else h // G, j, 0)
+    def walk(i, j, n_ref):
+        return _live_walk(i, j, n_ref[0], block_q, block_k, Lk - Lq, window,
+                          n_k)
+
+    def q_index(b, h, i, j, *n_ref):
+        return (b, h, walk(i, j, *n_ref)[0] if n_ref else i, 0)
+
+    def kv_index(b, h, i, j, *n_ref):
+        return (b, h if G == 1 else h // G,
+                walk(i, j, *n_ref)[1] if n_ref else j, 0)
 
     in_specs = [
-        pl.BlockSpec((None, None, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
+        pl.BlockSpec((None, None, block_q, D), q_index),
         pl.BlockSpec((None, None, block_k, D), kv_index),
         pl.BlockSpec((None, None, block_k, Dv), kv_index),
     ]
@@ -761,35 +816,42 @@ def _fa_fwd_pallas(q, k, v, mask, causal, scale, mask_is_bool=False,
     with (jax.default_matmul_precision(precision) if precision
           else contextlib.nullcontext()):
         out, lse = _fa_fwd_call(kernel, grid, in_specs, args, B, H, Lq, Dv,
-                                block_q, q.dtype, interpret)
+                                block_q, q.dtype, interpret, scalars)
     return jnp.swapaxes(out, 1, 2), lse[..., 0]
 
 
 def _fa_fwd_call(kernel, grid, in_specs, args, B, H, Lq, D, block_q, dtype,
-                 interpret):
+                 interpret, scalars=()):
+    """The forward's `pallas_call`; `scalars` go first, by scalar
+    prefetch (every index map then takes their refs last)."""
     from jax.experimental import pallas as pl
-    return pl.pallas_call(
-        kernel,
+    spec = dict(
         grid=grid,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((None, None, block_q, D),
-                         lambda b, h, i, j: (b, h, i, 0)),
+                         lambda b, h, i, j, *_: (b, h, i, 0)),
             pl.BlockSpec((None, None, block_q, _STATS_LANES),
-                         lambda b, h, i, j: (b, h, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, Lq, D), dtype),
-            jax.ShapeDtypeStruct((B, H, Lq, _STATS_LANES), jnp.float32),
+                         lambda b, h, i, j, *_: (b, h, i, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),
             pltpu.VMEM((block_q, _CARRY_LANES), jnp.float32),
             pltpu.VMEM((block_q, _CARRY_LANES), jnp.float32),
+        ])
+    if scalars:
+        spec = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars), **spec))
+    return pl.pallas_call(
+        kernel,
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, Lq, D), dtype),
+            jax.ShapeDtypeStruct((B, H, Lq, _STATS_LANES), jnp.float32),
         ],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-    )(*args)
+        **spec,
+    )(*scalars, *args)
 
 
 def _fa_bwd_fused_kernel(*refs, scale, causal, has_mask, mask_is_bool,
@@ -1171,11 +1233,11 @@ def _pallas_eligible(q, k, v, mask, causal) -> bool:
     return True
 
 
-def _banded_xla(q, k, v, causal, scale, window, precision):
+def _banded_xla(q, k, v, causal, scale, window, precision, length=None):
     """The forward-only call as a masked matrix product: q [B, L, H, D],
     k and v [B, Lk, Hkv, D], the group an axis of q and of the scores
-    (K/V are not repeated). Off the TPU, and the kernel's parity
-    reference."""
+    (K/V are not repeated); rows at and past `length` zeros. Off the TPU,
+    and the kernel's parity reference."""
     B, Lq, H, D = q.shape
     Lk, Hkv = k.shape[1], k.shape[2]
     Dv = v.shape[-1]
@@ -1194,39 +1256,43 @@ def _banded_xla(q, k, v, causal, scale, window, precision):
         s = jnp.where(keep, s, -jnp.inf)
         out = jnp.einsum("bkgqs,bskd->bqkgd",
                          jax.nn.softmax(s, axis=-1).astype(v.dtype), v)
-    return out.reshape(B, Lq, H, Dv).astype(q.dtype)
+    out = out.reshape(B, Lq, H, Dv).astype(q.dtype)
+    if length is not None:
+        out = jnp.where((jnp.arange(Lq) < length)[:, None, None], out, 0)
+    return out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_forward_only(q, k, v, causal, scale, window, precision, interpret,
-                        blocks):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash_forward_only(q, k, v, live, causal, scale, window, precision,
+                        interpret, blocks):
     return _fa_fwd_pallas(q, k, v, None, causal, scale, interpret=interpret,
-                          blocks=blocks, window=window,
-                          precision=precision)[0]
+                          blocks=blocks, window=window, precision=precision,
+                          live=live)[0]
 
 
-def _forward_only_fwd(q, k, v, causal, scale, window, precision, interpret,
-                      blocks):
-    return _flash_forward_only(q, k, v, causal, scale, window, precision,
-                               interpret, blocks), None
+def _forward_only_fwd(q, k, v, live, causal, scale, window, precision,
+                      interpret, blocks):
+    return _flash_forward_only(q, k, v, live, causal, scale, window,
+                               precision, interpret, blocks), None
 
 
 def _forward_only_bwd(causal, scale, window, precision, interpret, blocks,
                       res, do):
     raise NotImplementedError(
         "flash_attention: the backward pass takes no `window`, no "
-        "`precision`, no grouped K/V heads and no value head size of its "
-        "own (forward only: serving's prefill); train through the masked "
-        "product, or add them to _fa_bwd_fused_kernel")
+        "`precision`, no `length`, no grouped K/V heads and no value head "
+        "size of its own (forward only: serving's prefill); train through "
+        "the masked product, or add them to _fa_bwd_fused_kernel")
 
 
 _flash_forward_only.defvjp(_forward_only_fwd, _forward_only_bwd)
 
 
-def _forward_only(q, k, v, causal, scale, window, precision):
-    """`flash_attention` with a `window`, a `precision`, grouped K/V
-    heads or a value head size of its own: the forward kernel alone where
-    it is eligible, the masked product otherwise."""
+def _forward_only(q, k, v, causal, scale, window, precision, length=None):
+    """`flash_attention` with a `window`, a `precision`, a `length`,
+    grouped K/V heads or a value head size of its own: the forward kernel
+    alone where it is eligible (every row live where no `length` is
+    given), the masked product otherwise."""
     B, Lq, H, D = q.shape
     Lk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
     if window is not None:
@@ -1236,6 +1302,8 @@ def _forward_only(q, k, v, causal, scale, window, precision):
                              f"window={window}")
         window = int(window)
         _stats["window"] += 1
+    if length is not None and not causal:
+        raise ValueError("flash_attention: `length` needs causal=True")
     if H % Hkv or v.shape[:3] != k.shape[:3]:
         raise ValueError(f"flash_attention: {H} query heads do not divide "
                          f"over K/V of shape {k.shape} / {v.shape}")
@@ -1245,15 +1313,20 @@ def _forward_only(q, k, v, causal, scale, window, precision):
                 and not (causal and Lq > Lk))
     if not eligible:
         _stats["xla"] += 1
-        return _banded_xla(q, k, v, causal, scale, window, precision)
+        return _banded_xla(q, k, v, causal, scale, window, precision, length)
     blocks = _static_blocks(Lq, Lk)
+
+    def live(n):
+        # a causal call walks `_live_walk`'s steps (every row live without
+        # a `length`); a call without the triangle computes every step
+        return jnp.asarray(n, jnp.int32) if causal else None
 
     def run():
         return _fa_fwd_pallas(
             jnp.ones((1, Lq, H, D), q.dtype), jnp.ones((1, Lk, Hkv, D), q.dtype),
             jnp.ones((1, Lk, Hkv, Dv), q.dtype), None, bool(causal),
             float(scale), interpret=_INTERPRET, blocks=blocks, window=window,
-            precision=precision)
+            precision=precision, live=live(Lq))
 
     _tiling.compile_check(
         "flash_attention_forward_only", run, dtype=jnp.dtype(q.dtype).name,
@@ -1261,8 +1334,11 @@ def _forward_only(q, k, v, causal, scale, window, precision):
         window=window, precision=precision, blocks=blocks,
         interpret=_INTERPRET)
     _stats["pallas"] += 1
-    return _flash_forward_only(q, k, v, bool(causal), float(scale), window,
-                               precision, _INTERPRET, blocks)
+    if length is not None:
+        _stats["live_length"] += 1
+    return _flash_forward_only(q, k, v, live(Lq if length is None else length),
+                               bool(causal), float(scale), window, precision,
+                               _INTERPRET, blocks)
 
 
 def _flash_per_shard(km, q, k, v, mask, causal, scale):
@@ -1290,15 +1366,18 @@ def _flash_per_shard(km, q, k, v, mask, causal, scale):
 
 def flash_attention(q, k, v, mask=None, causal=False, scale=None,
                     dropout_p=0.0, dropout_key=None, window=None,
-                    precision=None):
+                    precision=None, length=None):
     """Dispatch: fused Pallas fwd+bwd on TPU (masks + causal + any seq len
     >= 64, streamed K/V so Lk is HBM-bounded); XLA composition otherwise.
 
     `window` (causal only: key j for query i iff ``i - window < j <= i``),
-    `precision` ("default" or "highest", of the kernel's products), K/V
-    of fewer heads than q and V of another head size than q take the
-    FORWARD-ONLY path (`_forward_only`), which takes no `mask` and no
-    dropout.
+    `precision` ("default" or "highest", of the kernel's products),
+    `length` (causal only, an int32 scalar, traced or not: the query rows
+    from it on are a prompt's bucket padding, computed by nobody and
+    returned as zeros; the rows before it are what the call without it
+    gives, bit for bit), K/V of fewer heads than q and V of another head
+    size than q take the FORWARD-ONLY path (`_forward_only`), which takes
+    no `mask` and no dropout.
 
     `dropout_p > 0` (training-time attention dropout) ALWAYS takes the XLA
     path: the fused kernels do not thread a dropout seed, and weight-level
@@ -1308,12 +1387,14 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
     benches and inference run dropout_p == 0 and stay fused."""
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
-    if window is not None or precision is not None \
+    if window is not None or precision is not None or length is not None \
             or k.shape[2] != q.shape[2] or v.shape[-1] != q.shape[-1]:
         if mask is not None or dropout_p > 0.0:
-            raise ValueError("flash_attention: `window`, `precision` and "
-                             "grouped K/V heads take no mask and no dropout")
-        return _forward_only(q, k, v, causal, scale, window, precision)
+            raise ValueError("flash_attention: `window`, `precision`, "
+                             "`length` and grouped K/V heads take no mask "
+                             "and no dropout")
+        return _forward_only(q, k, v, causal, scale, window, precision,
+                             length)
     if dropout_p > 0.0:
         _stats["xla"] += 1
         return flash_attention_xla(q, k, v, mask=mask, causal=causal,

@@ -1337,3 +1337,22 @@ class TestDeepseekV2ShapesCompileForTheChip:
         # 805 MB at 8,192 tokens (32 heads: a quarter of a layer's)
         assert compiled.memory_analysis().temp_size_in_bytes \
             <= 1.01 * L * 32 * (2 * 192 + 3 * 128) * 4
+
+    @pytest.mark.parametrize("L", [3072, 8192])
+    def test_the_flash_forward_walks_a_prompts_live_rows(self, v5e_chip, L):
+        """The prefill's call: the prompt's length a traced scalar, taken
+        by scalar prefetch; no more memory than the call without it."""
+        from paddle_tpu.ops.pallas import flash_attention as fa
+
+        def sds(d):
+            return jax.ShapeDtypeStruct((1, L, 32, d), jnp.float32,
+                                        sharding=v5e_chip)
+        compiled = jax.jit(lambda q, k, v, n: fa._fa_fwd_pallas(
+            q, k, v, None, True, 0.11, blocks=fa._static_blocks(L, L),
+            precision="highest", live=n)[0]).lower(
+                sds(192), sds(192), sds(128),
+                jax.ShapeDtypeStruct((), jnp.int32,
+                                     sharding=v5e_chip)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            <= 1.01 * L * 32 * (2 * 192 + 3 * 128) * 4

@@ -24,8 +24,15 @@ into the last:
      `--control-seconds`, with the reference's products in single
      bfloat16 passes (`default`): it must come out not correct at the
      cell's own limits, by `logit_gap`
+  f  the expanded prefill's flash forward alone (a pass of 32 heads, q and
+     k of 192, v of 128, `highest`) at the 3,072, 4,096 and 6,144 buckets,
+     a chain of calls by the host's clock: every step above the diagonal
+     fetching (no `live`: the walk before `flash_attention._live_walk`),
+     the diagonal clamped (every row live), and the prompt's length at
+     0.70 and 0.95 of the bucket; beside each, the six-pass bound of the
+     pairs its steps computed and of the live triangle's pairs
 
-    python tools/chip_check_deepseek_v2.py [--phases bmkt|c] [--tiny] [--seed 1]
+    python tools/chip_check_deepseek_v2.py [--phases bmktf|c] [--tiny] [--seed 1]
 
 PERF.md (PR 39) has the numbers of the runs on the chip.
 """
@@ -106,6 +113,23 @@ def main(argv=None) -> int:
     def say(name, body):
         result[name] = body
         print(json.dumps({name: body}), flush=True)
+
+    def host_ms(fn, times):
+        jax.block_until_ready(fn())
+        t = time.monotonic()
+        for _ in range(times):
+            out = fn()
+        jax.block_until_ready(out)
+        return 1e3 * (time.monotonic() - t) / times
+
+    # ---------------------------------- f: the prefill's flash forward alone
+    if "f" in args.phases:
+        say("f_flash_forward", flash_forward(
+            sizes, rng, host_ms, (256, 384) if args.tiny
+            else (3072, 4096, 6144), 16 if args.tiny else 32))
+        if not set(args.phases) - {"f"}:
+            print(json.dumps(result), flush=True)
+            return 0
 
     paddle.seed(args.seed)
     model = family.build(config)
@@ -294,14 +318,6 @@ def main(argv=None) -> int:
                  or not r["reference_in_bfloat16_passes"]["passes"])
             for r in runs)
 
-    def host_ms(fn, times):
-        jax.block_until_ready(fn())
-        t = time.monotonic()
-        for _ in range(times):
-            out = fn()
-        jax.block_until_ready(out)
-        return 1e3 * (time.monotonic() - t) / times
-
     # lanes at contexts spread as the cell's traffic's are
     ctx = np.linspace(min(1100, engine["max_len"] // 5),
                       engine["max_len"] - min(1300, engine["max_len"] // 4),
@@ -372,6 +388,59 @@ def main(argv=None) -> int:
                               "paged_attention": dict(pa._stats)}
     print(json.dumps(result), flush=True)
     return 0 if result.get("ok", True) else 1
+
+
+def flash_forward(sizes, rng, host_ms, buckets, heads):
+    """Phase f: ms a call of one expanded pass's flash forward, and the
+    six-pass bound (the kernel's products at `highest`, against the
+    bfloat16 peak) of the (query, key) pairs of the steps it computed and
+    of the live rows' causal triangle."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    qk, dv = sizes["nope"] + sizes["rope"], sizes["v_dim"]
+    scale = 1.0 / np.sqrt(qk)
+    # off the chip (the --tiny rehearsal) in the Pallas interpreter
+    interpret = jax.devices()[0].platform != "tpu"
+
+    def six_pass_ms(pairs):
+        return 6 * heads * 2 * (qk + dv) * pairs / 197e12 * 1e3
+
+    out = {"heads": heads, "qk": qk, "v": dv}
+    for L in buckets:
+        bq, bk = fa._static_blocks(L, L)
+        n_q, n_k = -(-L // bq), -(-L // bk)
+        q, k = (jnp.asarray(rng.normal(size=(1, L, heads, qk)), jnp.float32)
+                for _ in range(2))
+        v = jnp.asarray(rng.normal(size=(1, L, heads, dv)), jnp.float32)
+        call = jax.jit(lambda q, k, v, n: fa._fa_fwd_pallas(
+            q, k, v, None, True, scale, interpret=interpret,
+            blocks=(bq, bk), precision="highest", live=n)[0])
+        unclamped = jax.jit(lambda q, k, v: fa._fa_fwd_pallas(
+            q, k, v, None, True, scale, interpret=interpret,
+            blocks=(bq, bk), precision="highest")[0])
+        steps = lambda n: sum(  # noqa: E731
+            bool(fa._live_walk(i, j, n, bq, bk, 0, None, n_k)[2])
+            for i in range(n_q) for j in range(n_k))
+        row = {"blocks": [bq, bk], "grid_steps_a_head": n_q * n_k}
+        variants = [("unclamped", L, lambda: unclamped(q, k, v)),
+                    ("clamped", L, lambda: call(q, k, v, jnp.int32(L)))]
+        for share in (0.95, 0.70):
+            n = int(share * L)
+            variants.append((f"length_{share:.2f}", n,
+                             lambda n=n: call(q, k, v, jnp.int32(n))))
+        for name, n, fn in variants:
+            computed = steps(n)
+            row[name] = {
+                "length": n, "ms_a_call": host_ms(fn, 10),
+                "steps_computed_a_head": computed,
+                "six_pass_ms_of_steps": six_pass_ms(computed * bq * bk),
+                "six_pass_ms_of_live_triangle": six_pass_ms(n * (n + 1) / 2)}
+        out[str(L)] = row
+        del q, k, v
+    return out
 
 
 def control(seed: int, seconds: float) -> int:

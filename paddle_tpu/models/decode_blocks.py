@@ -274,15 +274,16 @@ def paged_rows_attention(cache, i, q, k, v, bt, ctx, active):
     return out.reshape(q.shape)
 
 
-def paged_decode_attention(cache, i, q, k, v, bt, ctx, active):
+def paged_decode_attention(cache, i, q, k, v, bt, ctx, active, scale=None):
     """One token of a full-attention layer: append its K/V ``[B, Hkv*D]``
     to pools `i`, then attend with q ``[B, H, D]`` over the pages (the new
-    token is part of its own context). Returns ``[B, H, D]``."""
+    token is part of its own context), the scores times `scale` (None:
+    1/sqrt(D)). Returns ``[B, H, D]``."""
     from ..ops.pallas import paged_attention as _pa
     cache.k_pages[i], cache.v_pages[i] = _pa.cache_append(
         cache.k_pages[i], cache.v_pages[i], k, v, bt, ctx, active)
     return _pa.paged_attention(q, cache.k_pages[i], cache.v_pages[i], bt,
-                               jnp.where(active, ctx + 1, 0))
+                               jnp.where(active, ctx + 1, 0), scale=scale)
 
 
 def paged_prefill_append(cache, i, k, v, page_row, length, write_start):

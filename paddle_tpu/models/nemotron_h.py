@@ -23,6 +23,20 @@ behind one RMSNorm, and the pattern string names each block's mixer::
                  (the Nemotron-H report, arXiv:2504.03624): order comes
                  from the Mamba-2 layers.
 
+Granite 4.0-H (`model_type` `granitemoehybrid`; Granite-4.0-H-Small is
+40 layers, 36 Mamba-2 and 4 attention by `layer_types`) is this model
+with options (`GraniteMoeHybridConfig`): each of its layers is TWO
+blocks here, its mixer (`M` or `*`) then an expert block `E`, and four
+multipliers scale what Nemotron-H leaves alone::
+
+    x = embedding_multiplier * wte[ids]
+    x = x + residual_multiplier * block_i(RMSNorm(x))     # every block
+    *: softmax(q k^T * attention_multiplier) v            # not 1/sqrt(D)
+    E: softmax over the top k LOGITS (`router` "softmax"), no bias, no
+       scale; experts and the shared expert SwiGLU (`expert_form`
+       "swiglu": (silu(u Wg) * u Wu) Wd, gate and up stacked)
+    logits = RMSNorm(x_final) wte^T / logits_scaling     # tied head
+
 `experts_held` = (first, count) says which routed experts this chip
 holds: one share of a deployment that spreads each expert layer over
 several chips. The router keeps its full width, the shared expert is held
@@ -47,8 +61,9 @@ state in place.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +81,7 @@ from .decode_cache import KV, NONE, STATE, PagedKVCache
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
 PUBLISHED_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+ROUTERS = ("sigmoid", "softmax")
 
 
 @dataclasses.dataclass
@@ -104,6 +120,16 @@ class NemotronHConfig:
     # not in the source: (first, count) of the routed experts held here;
     # () => all of them
     experts_held: Tuple[int, ...] = ()
+    # Granite 4.0-H's options (`GraniteMoeHybridConfig`); each default is
+    # Nemotron-H's behaviour. attention_multiplier None: 1/sqrt(head_dim);
+    # logits_scaling divides the logits
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
+    tie_word_embeddings: bool = False
+    router: str = "sigmoid"
+    expert_form: str = "relu2"
 
     def __post_init__(self):
         n = self.num_hidden_layers
@@ -129,6 +155,13 @@ class NemotronHConfig:
             raise ValueError("num_experts_per_tok must be below "
                              "n_routed_experts")
         self.experts_held = (int(first), int(count))
+        if self.router not in ROUTERS or self.expert_form not in _moe.FORMS:
+            raise ValueError(f"router {self.router!r} must be one of "
+                             f"{ROUTERS}, expert_form {self.expert_form!r} "
+                             f"one of {_moe.FORMS}")
+        if self.router == "softmax" and self.routed_scaling_factor != 1.0:
+            raise ValueError("the softmax router scales nothing: "
+                             "routed_scaling_factor must be 1.0")
 
     @property
     def d_inner(self) -> int:
@@ -150,6 +183,100 @@ class NemotronHConfig:
             n_groups=2, chunk_size=16, n_routed_experts=8,
             num_experts_per_tok=2, moe_intermediate_size=32,
             moe_shared_expert_intermediate_size=64), **changes})
+
+
+# Granite-4.0-H-Small's 40 layers: attention at 5, 15, 25 and 35
+GRANITE_LAYER_TYPES = tuple(
+    "attention" if i % 10 == 5 else "mamba" for i in range(40))
+_GRANITE_MIXERS = {"mamba": MAMBA, "attention": ATTENTION}
+
+
+@dataclasses.dataclass
+class GraniteMoeHybridConfig:
+    """Granite 4.0-H under the source's own keys (Hugging Face
+    `config.json` of `granitemoehybrid`), and `experts_held`. The model is
+    `NemotronH` with Granite's options (`as_nemotron_h`): a layer of
+    `layer_types` is its mixer block then an expert block, `mamba` -> ME
+    and `attention` -> *E. A depth cut keeps the leading
+    `num_hidden_layers` entries of `layer_types`."""
+    vocab_size: int = 100352
+    hidden_size: int = 4096
+    num_hidden_layers: int = 40
+    layer_types: Tuple[str, ...] = GRANITE_LAYER_TYPES
+    rms_norm_eps: float = 1e-5
+    max_position_embeddings: int = 131072
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    attention_multiplier: float = 0.0078125
+    embedding_multiplier: float = 12.0
+    residual_multiplier: float = 0.22
+    logits_scaling: float = 16.0
+    tie_word_embeddings: bool = True
+    mamba_n_heads: int = 128
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    mamba_conv_bias: bool = True
+    num_local_experts: int = 72
+    num_experts_per_tok: int = 10
+    intermediate_size: int = 768
+    shared_intermediate_size: int = 1536
+    # not in the source: the Mamba-2 initialiser's step range, and (first,
+    # count) of the routed experts held here; () => all of them
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    experts_held: Tuple[int, ...] = ()
+
+    def as_nemotron_h(self) -> NemotronHConfig:
+        n = self.num_hidden_layers
+        types = tuple(self.layer_types)[:n]
+        if len(types) != n or set(types) - set(_GRANITE_MIXERS):
+            raise ValueError(f"layer_types must name {n} layers as mamba "
+                             f"or attention, got {types!r}")
+        pattern = "".join(_GRANITE_MIXERS[t] + EXPERTS for t in types)
+        return NemotronHConfig(
+            vocab_size=self.vocab_size, hidden_size=self.hidden_size,
+            num_hidden_layers=len(pattern), hybrid_override_pattern=pattern,
+            layer_norm_epsilon=self.rms_norm_eps,
+            max_position_embeddings=self.max_position_embeddings,
+            num_attention_heads=self.num_attention_heads,
+            num_key_value_heads=self.num_key_value_heads,
+            head_dim=self.hidden_size // self.num_attention_heads,
+            mamba_num_heads=self.mamba_n_heads,
+            mamba_head_dim=self.mamba_d_head,
+            ssm_state_size=self.mamba_d_state, n_groups=self.mamba_n_groups,
+            conv_kernel=self.mamba_d_conv, chunk_size=self.mamba_chunk_size,
+            use_conv_bias=self.mamba_conv_bias,
+            time_step_min=self.time_step_min,
+            time_step_max=self.time_step_max,
+            time_step_floor=self.time_step_floor,
+            n_routed_experts=self.num_local_experts,
+            num_experts_per_tok=self.num_experts_per_tok,
+            moe_intermediate_size=self.intermediate_size,
+            moe_shared_expert_intermediate_size=self.shared_intermediate_size,
+            routed_scaling_factor=1.0, experts_held=self.experts_held,
+            embedding_multiplier=self.embedding_multiplier,
+            residual_multiplier=self.residual_multiplier,
+            attention_multiplier=self.attention_multiplier,
+            logits_scaling=self.logits_scaling,
+            tie_word_embeddings=self.tie_word_embeddings,
+            router="softmax", expert_form="swiglu")
+
+    @staticmethod
+    def tiny(layer_types=("mamba", "attention", "mamba"), **changes):
+        """Both mixers, one group of 8 state-space heads; 8 experts
+        top-3, 2 K/V heads for 4 query heads of 16."""
+        return GraniteMoeHybridConfig(**{**dict(
+            vocab_size=256, hidden_size=64,
+            num_hidden_layers=len(layer_types),
+            layer_types=tuple(layer_types), max_position_embeddings=512,
+            num_attention_heads=4, num_key_value_heads=2, mamba_n_heads=8,
+            mamba_d_head=8, mamba_d_state=16, mamba_chunk_size=16,
+            num_local_experts=8, num_experts_per_tok=3,
+            intermediate_size=32, shared_intermediate_size=64), **changes})
 
 
 class NemotronHMamba(nn.Layer):
@@ -282,8 +409,10 @@ class NemotronHMamba(nn.Layer):
 
 
 class NemotronHExperts(nn.Layer):
-    """A sigmoid-routed dropless expert block holding `experts_held` of
-    the layer's routed experts and the shared expert whole."""
+    """A dropless expert block holding `experts_held` of the layer's
+    routed experts and the shared expert whole: sigmoid-routed relu^2
+    experts, or by the config's `router` and `expert_form` softmax-routed
+    SwiGLU experts (Granite 4.0-H)."""
 
     def __init__(self, cfg: NemotronHConfig):
         super().__init__()
@@ -291,23 +420,46 @@ class NemotronHExperts(nn.Layer):
         self.first, held = cfg.experts_held
         self.top_k = cfg.num_experts_per_tok
         self.scale = float(cfg.routed_scaling_factor)
+        self.router_kind, self.form = cfg.router, cfg.expert_form
+        # gate and up stacked as one matrix, the gate's rows first
+        # (ops/moe.py)
+        stacked = 2 if cfg.expert_form == "swiglu" else 1
         bound = math.sqrt(6.0 / (h + cfg.n_routed_experts))
         self.router = self.create_parameter(
             (h, cfg.n_routed_experts),
             default_initializer=Uniform(-bound, bound))
-        # the published one is learned; it only selects. Zeros would leave
-        # the path untested
-        self.e_score_correction_bias = self.create_parameter(
-            (cfg.n_routed_experts,),
-            default_initializer=Uniform(-0.05, 0.05))
+        if cfg.router == "sigmoid":
+            # the published one is learned; it only selects. Zeros would
+            # leave the path untested
+            self.e_score_correction_bias = self.create_parameter(
+                (cfg.n_routed_experts,),
+                default_initializer=Uniform(-0.05, 0.05))
         # stacked once, [E_held, f, h] both (W1 transposed: ops/moe.py)
         bound = math.sqrt(6.0 / (h + f))
         self.w1 = self.create_parameter(
-            (held, f, h), default_initializer=Uniform(-bound, bound))
+            (held, stacked * f, h), default_initializer=Uniform(-bound, bound))
         self.w2 = self.create_parameter(
             (held, f, h), default_initializer=Uniform(-bound, bound))
         fs = cfg.moe_shared_expert_intermediate_size
-        self.shared_up, self.shared_down = _Linear(h, fs), _Linear(fs, h)
+        self.shared_up = _Linear(h, stacked * fs)
+        self.shared_down = _Linear(fs, h)
+
+    def _route(self, flat):
+        if self.router_kind == "softmax":
+            return _moe.softmax_route(flat, self.router.data,
+                                      top_k=self.top_k)
+        return _moe.sigmoid_route(
+            flat, self.router.data, self.e_score_correction_bias.data,
+            top_k=self.top_k, scale=self.scale)
+
+    def _shared(self, u):
+        mid = self.shared_up(u).data
+        if self.form == "swiglu":
+            fs = mid.shape[-1] // 2
+            mid = jax.nn.silu(mid[..., :fs]) * mid[..., fs:]
+        else:
+            mid = jnp.square(jax.nn.relu(mid))
+        return self.shared_down(Tensor(mid))
 
     def forward(self, u, active=None):
         """u Tensor [.., h] -> (the block's output Tensor, counters [3]
@@ -319,15 +471,12 @@ class NemotronHExperts(nn.Layer):
         if active is not None:
             active = jnp.broadcast_to(active, lead).reshape(-1)
         with jax.named_scope("mlp"), jax.named_scope("moe"):
-            experts, weights, margin = _moe.sigmoid_route(
-                flat, self.router.data, self.e_score_correction_bias.data,
-                top_k=self.top_k, scale=self.scale)
+            experts, weights, margin = self._route(flat)
             routed, counters = _moe.held_experts(
                 flat, experts, weights, self.w1.data, self.w2.data,
-                first=self.first, active=active)
+                first=self.first, active=active, form=self.form)
             with jax.named_scope("shared"):
-                shared = self.shared_down(
-                    Tensor(jnp.square(jax.nn.relu(self.shared_up(u).data))))
+                shared = self._shared(u)
             out = shared.data + routed.reshape(*lead, h)
         return Tensor(out), counters, margin.reshape(lead)
 
@@ -347,8 +496,8 @@ class NemotronHAttention(nn.Layer):
         return self.q_proj(u), self.k_proj(u), self.v_proj(u)
 
 
-@jax.jit
-def _grouped_causal_attention(q, k, v):
+@functools.partial(jax.jit, static_argnames=("scale",))
+def _grouped_causal_attention(q, k, v, scale=None):
     """A prompt's attention as a masked matrix product at `highest`: q
     ``[B, L, Hkv, G, D]``, k and v ``[B, L, Hkv, D]`` (the group is an axis
     of q and of the scores; K/V are not repeated). Not the flash kernel:
@@ -357,10 +506,11 @@ def _grouped_causal_attention(q, k, v):
     from the float32 reference's, so that 83 prompts of 96 met other
     experts somewhere in their 256 tokens (PERF.md, PR 31); here attention
     is 2 blocks of 13 at prompts of at most 2048, and the whole square is
-    a millisecond."""
+    a millisecond. `scale` None: 1/sqrt(D)."""
     hi = jax.lax.Precision.HIGHEST
     L, D = q.shape[1], q.shape[-1]
-    s = jnp.einsum("bqkgd,bskd->bkgqs", q, k, precision=hi) / math.sqrt(D)
+    s = jnp.einsum("bqkgd,bskd->bkgqs", q, k, precision=hi)
+    s = s / math.sqrt(D) if scale is None else s * scale
     s = jnp.where(jnp.tril(jnp.ones((L, L), bool)), s, -jnp.inf)
     return jnp.einsum("bkgqs,bskd->bqkgd", jax.nn.softmax(s, axis=-1), v,
                       precision=hi)
@@ -383,8 +533,12 @@ class NemotronHBlock(nn.Layer):
 
 
 class NemotronH(_blocks.TokensToLogits, nn.Layer):
-    def __init__(self, cfg: NemotronHConfig):
+    def __init__(self, cfg):
+        """`cfg` a `NemotronHConfig`, or a `GraniteMoeHybridConfig`, which
+        is this model with Granite's options."""
         super().__init__()
+        if isinstance(cfg, GraniteMoeHybridConfig):
+            cfg = cfg.as_nemotron_h()
         self.cfg = cfg
         # `wte`, as the decode protocol's other models name it
         self.wte = nn.Embedding(cfg.vocab_size, cfg.hidden_size)
@@ -392,8 +546,37 @@ class NemotronH(_blocks.TokensToLogits, nn.Layer):
             [NemotronHBlock(cfg, kind)
              for kind in cfg.hybrid_override_pattern])
         self.norm_f = nn.RMSNorm(cfg.hidden_size, cfg.layer_norm_epsilon)
-        # the head routes nothing: three passes, as the other hybrid's
-        self.lm_head = _blocks.HighLinear(cfg.hidden_size, cfg.vocab_size)
+        if not cfg.tie_word_embeddings:
+            # the head routes nothing: three passes, as the other hybrid's
+            self.lm_head = _blocks.HighLinear(cfg.hidden_size,
+                                              cfg.vocab_size)
+
+    def _embed(self, ids):
+        x = super()._embed(ids)
+        m = self.cfg.embedding_multiplier
+        if m == 1.0:
+            return x
+        with jax.named_scope("embed"):
+            return Tensor(x.data * m)
+
+    def _add(self, x, mixed):
+        """The residual stream after a block: x + residual_multiplier *
+        mixed."""
+        r = self.cfg.residual_multiplier
+        return x + (mixed if r == 1.0 else Tensor(mixed.data * r))
+
+    def _logits(self, x):
+        cfg = self.cfg
+        if not cfg.tie_word_embeddings and cfg.logits_scaling == 1.0:
+            return super()._logits(x)
+        with jax.named_scope("ln"):
+            x = self.norm_f(x)
+        with jax.named_scope("logits"):
+            # tied: the embedding's rows are the head's columns, three
+            # passes as the untied head's
+            w = (self.wte.weight.data.T if cfg.tie_word_embeddings
+                 else self.lm_head.weight.data)
+            return Tensor(_blocks.head(x.data, w) / cfg.logits_scaling)
 
     def _full_attention(self, attn, q, k, v):
         """Causal attention over whole sequences; q [B, L, H*D], k and v
@@ -404,7 +587,8 @@ class NemotronH(_blocks.TokensToLogits, nn.Layer):
                      cfg.head_dim)
         out = _grouped_causal_attention(
             q.data.reshape(B, L, Hkv, H // Hkv, D),
-            k.data.reshape(B, L, Hkv, D), v.data.reshape(B, L, Hkv, D))
+            k.data.reshape(B, L, Hkv, D), v.data.reshape(B, L, Hkv, D),
+            scale=cfg.attention_multiplier)
         return attn.o_proj(Tensor(out.reshape(B, L, H * D)))
 
     def forward(self, input_ids):
@@ -418,7 +602,7 @@ class NemotronH(_blocks.TokensToLogits, nn.Layer):
                                                  *blk.mixer.qkv(u))
             else:   # both return (output, and two things a cache wants)
                 mixed, _, _ = blk.mixer(u)
-            x = x + mixed
+            x = self._add(x, mixed)
         return self._logits(x)
 
     # ------------------- decode protocol (inference/serving.py) -------------
@@ -492,7 +676,7 @@ class NemotronH(_blocks.TokensToLogits, nn.Layer):
                         cache, i, k.data[0], v.data[0], page_row, length,
                         write_start)
                     mixed = self._full_attention(blk.mixer, q, k, v)
-            x = x + mixed
+            x = self._add(x, mixed)
         cache.context_lens = cache.context_lens.at[slot].set(length)
         # logits of the LAST REAL position only
         return self._logits(_blocks.last_real_position(x, length)), cache
@@ -528,11 +712,12 @@ class NemotronH(_blocks.TokensToLogits, nn.Layer):
                     out = _blocks.paged_decode_attention(
                         cache, i, q.data.reshape(
                             B, cfg.num_attention_heads, cfg.head_dim),
-                        k.data[:, 0], v.data[:, 0], bt, ctx, active)
+                        k.data[:, 0], v.data[:, 0], bt, ctx, active,
+                        scale=cfg.attention_multiplier)
                     mixed = blk.mixer.o_proj(reshape(
                         Tensor(out),
                         [B, 1, cfg.num_attention_heads * cfg.head_dim]))
-            x = x + mixed
+            x = self._add(x, mixed)
         cache.counters["moe"] = counted
         _blocks.bump_lengths(cache, slot_map, ctx, active)
         return self._logits(reshape(x, [B, cfg.hidden_size])), cache

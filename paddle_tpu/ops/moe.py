@@ -243,17 +243,21 @@ def _held_impl(u, experts, weights, w1, w2, active, first: int, path: str,
     T, h = u.shape
     k = experts.shape[1]
     held = w1.shape[0]
-    local = experts - first
-    here = (local >= 0) & (local < held) & active[:, None]
-    # sort the assignments by expert; absent experts' and padding lanes'
-    # go to the end under the key `held`
-    key = jnp.where(here, local, held).reshape(T * k)
-    tm = _tiles(T * k, h, w1.shape[1])[0]
-    pad = -(T * k) % tm                       # the kernel wants whole tiles
-    key = jnp.pad(key, (0, pad), constant_values=held)
-    order = jnp.argsort(key, stable=True)
-    group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-    rows = jnp.take(u, jnp.minimum(order // k, T - 1), axis=0)
+    # `permute`: the moves of rows around the grouped products, apart in
+    # the device trace from the products themselves
+    with jax.named_scope("permute"):
+        local = experts - first
+        here = (local >= 0) & (local < held) & active[:, None]
+        # sort the assignments by expert; absent experts' and padding
+        # lanes' go to the end under the key `held`
+        key = jnp.where(here, local, held).reshape(T * k)
+        tm = _tiles(T * k, h, w1.shape[1])[0]
+        pad = -(T * k) % tm                   # the kernel wants whole tiles
+        key = jnp.pad(key, (0, pad), constant_values=held)
+        order = jnp.argsort(key, stable=True)
+        group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(
+            jnp.int32)
+        rows = jnp.take(u, jnp.minimum(order // k, T - 1), axis=0)
     mid = _grouped_matmul(rows, w1, group_sizes, transpose_rhs=True,
                           path=path)
     if form == "swiglu":
@@ -263,12 +267,15 @@ def _held_impl(u, experts, weights, w1, w2, active, first: int, path: str,
         mid = jnp.square(jax.nn.relu(mid))
     out = _grouped_matmul(mid, w2, group_sizes, transpose_rhs=False,
                           path=path)
-    # back to the assignments' own order, then each token sums its k rows;
-    # a row that met no expert carries whatever the kernel left there
-    back = jnp.argsort(order)[:T * k]
-    out = jnp.take(out, back, axis=0).reshape(T, k, h)
-    w = jnp.where(here, weights, 0.0).astype(out.dtype)
-    y = jnp.sum(jnp.where(here[..., None], out, 0.0) * w[..., None], axis=1)
+    with jax.named_scope("permute"):
+        # back to the assignments' own order, then each token sums its k
+        # rows; a row that met no expert carries whatever the kernel left
+        # there
+        back = jnp.argsort(order)[:T * k]
+        out = jnp.take(out, back, axis=0).reshape(T, k, h)
+        w = jnp.where(here, weights, 0.0).astype(out.dtype)
+        y = jnp.sum(jnp.where(here[..., None], out, 0.0) * w[..., None],
+                    axis=1)
     counters = jnp.stack([jnp.sum(group_sizes),
                           jnp.sum(group_sizes > 0),
                           jnp.max(group_sizes)]).astype(jnp.int32)

@@ -338,6 +338,17 @@ _CELL_PICKS = [
     ("moe_tiles", (8 * 768, 6144, 4096), (64, 512, 512)),
     ("moe_tiles", (8 * 768, 2048, 6144), (64, 2048, 256)),
     ("moe_tiles", (8 * 1536, 2048, 6144), (64, 2048, 256)),
+    # granite4hs_serve_closed64 (max_len 2048; its prompt attention is the
+    # masked product): 9 held SwiGLU experts of 4096 x 768 (gate and up
+    # stacked to 1536), top-10, the 64 lanes' 640 assignments and the
+    # buckets' rows; the shared expert's (4096 -> 1536) is no grouped
+    # product
+    ("moe_tiles", (10 * 64, 4096, 1536), (64, 512, 768)),
+    ("moe_tiles", (10 * 64, 768, 4096), (64, 768, 512)),
+    ("moe_tiles", (10 * 8, 4096, 1536), (8, 512, 768)),
+    ("moe_tiles", (10 * 256, 4096, 1536), (64, 512, 768)),
+    ("moe_tiles", (10 * 1536, 4096, 1536), (64, 512, 768)),
+    ("moe_tiles", (10 * 1536, 768, 4096), (64, 768, 512)),
     # no cell: a head size off the lane groups takes the XLA gather
     ("paged_attn", (8, 80, 16, 8), "xla"),
     # no cell: a table under the pick's bound, and pools twice as wide

@@ -10,6 +10,7 @@ fast-sibling: every class here is tier-1 except the timing probe
 (TestSuperLinear.test_per_token_cost_flat_vs_dense_slow), whose fast
 sibling is test_paged_growth_structure.
 """
+import functools
 import os
 
 import numpy as np
@@ -1353,3 +1354,95 @@ class TestDeepseekV2ShapesCompileForTheChip:
         assert "tpu_custom_call" in compiled.as_text()
         assert compiled.memory_analysis().temp_size_in_bytes \
             <= 1.01 * L * 32 * (2 * 192 + 3 * 128) * 4
+
+
+class TestGraniteShapesCompileForTheChip:
+    """`granite4_h_small_32b_a9b`'s Mamba-2 layers carry a state of
+    [128, 64, 128] a slot over ONE group of 128 heads, 64 slots; its one
+    attention layer's pools hold 8 K/V heads of 128 for 32 query heads
+    (4 a K/V head: the XLA gather, not the grouped kernel), 6,145 pages;
+    its expert layers 9 stacked SwiGLU experts of 4096 x 768. Compiled for
+    a described v5e, nothing runs: the decode step updates the state and
+    the pools in place."""
+
+    B, H, HKV, D, POOL, PER_SEQ = 64, 32, 8, 128, 6145, 128
+
+    @staticmethod
+    def _sds(chip, shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def test_the_state_is_stepped_in_place(self, v5e_chip):
+        """One Mamba-2 layer's decode step in lane mode at the cell's
+        widths: 0 copies of the state's shape."""
+        from paddle_tpu.analysis import pool_relayout_report
+        from paddle_tpu.framework import tape
+        from paddle_tpu.framework.tensor import Tensor
+        from paddle_tpu.jit import _swapped_state
+        from paddle_tpu.models.nemotron_h import (GraniteMoeHybridConfig,
+                                                  NemotronHMamba)
+        cfg = GraniteMoeHybridConfig(num_hidden_layers=1).as_nemotron_h()
+        built = {}
+
+        def make():
+            built["m"] = m = NemotronHMamba(cfg)
+            return {k: p.data for k, p in m.named_parameters()}
+        params = jax.tree.map(
+            lambda a: self._sds(v5e_chip, a.shape), jax.eval_shape(make))
+        mixer = built["m"]
+
+        def step(params, u, states, conv_states, active, slot_map):
+            with tape.no_grad(), _swapped_state(mixer, params, {}):
+                out, states, conv_states = mixer.step(
+                    Tensor(u), states, conv_states, active, slot_map)
+            return out.data, states, conv_states
+        state = self._sds(v5e_chip, (self.B, 128, 64, 128))
+        compiled = jax.jit(step, donate_argnums=(2, 3)).lower(
+            params, self._sds(v5e_chip, (self.B, 1, 4096)), state,
+            self._sds(v5e_chip, (self.B, 3, 8448)),
+            self._sds(v5e_chip, (self.B,), jnp.bool_),
+            self._sds(v5e_chip, (self.B,), jnp.int32)).compile()
+        assert pool_relayout_report(
+            compiled, [state])["pool_relayout_copies"] == 0
+
+    def test_the_pools_are_updated_in_place_at_the_multiplier(self,
+                                                              v5e_chip):
+        from paddle_tpu.analysis import pool_relayout_report
+        from paddle_tpu.models.decode_cache import PagedKVCache
+        from paddle_tpu.models.decode_blocks import paged_decode_attention
+        sds = functools.partial(self._sds, v5e_chip)
+        pool = sds((self.POOL, 16, self.HKV * self.D))
+
+        def decode(q, k, v, kp, vp, bt, cl, active):
+            cache = PagedKVCache([kp], [vp], bt, cl, 16, self.H, self.D,
+                                 num_kv_heads=self.HKV)
+            out = paged_decode_attention(cache, 0, q, k, v, bt, cl, active,
+                                         scale=0.0078125)
+            return out, cache.k_pages[0], cache.v_pages[0]
+        rows = sds((self.B, self.HKV * self.D))
+        compiled = jax.jit(decode, donate_argnums=(3, 4)).lower(
+            sds((self.B, self.H, self.D)), rows, rows, pool, pool,
+            sds((self.B, self.PER_SEQ), jnp.int32),
+            sds((self.B,), jnp.int32), sds((self.B,), jnp.bool_)).compile()
+        assert pool_relayout_report(
+            compiled, [pool])["pool_relayout_copies"] == 0
+
+    @pytest.mark.parametrize("tokens", [64, 1536], ids=["decode", "prefill"])
+    def test_the_swiglu_products_read_the_stacked_weights_in_place(
+            self, v5e_chip, tokens, monkeypatch):
+        """`ops/moe.held_experts` at top-10 of the cell's experts through
+        the megablox kernel, at the tiles `_tiles` picks."""
+        from paddle_tpu.ops import moe
+        monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+        sds = functools.partial(self._sds, v5e_chip)
+        compiled = jax.jit(lambda u, e, w, w1, w2: moe.held_experts(
+            u, e, w, w1, w2, form="swiglu")).lower(
+            sds((tokens, 4096)), sds((tokens, 10), jnp.int32),
+            sds((tokens, 10)), sds((9, 1536, 4096)),
+            sds((9, 768, 4096))).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= 2
+        made = [line for line in text.splitlines()
+                if (" = f32[9,1536,4096]" in line
+                    or " = f32[9,768,4096]" in line)
+                and " parameter(" not in line]
+        assert not made, made[:2]
